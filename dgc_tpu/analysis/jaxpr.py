@@ -34,6 +34,7 @@ compilation, so a full verify sweep stays inside the t1 wall-clock
 budget.
 """
 
+import contextlib
 from dataclasses import dataclass, field
 from typing import (Callable, Dict, Iterable, List, Optional, Sequence,
                     Set, Tuple)
@@ -110,10 +111,14 @@ def _source_of(eqn) -> str:
             # dgcver anchors are planted through kernels.vtag — the
             # actionable site is the CALLER (where the tag lives), not
             # the helper's own checkpoint_name line
-            for fr in source_info_util.user_frames(eqn.source_info):
+            for fr in source_info_util.user_frames(
+                    eqn.source_info.traceback):
                 fn = fr.file_name.replace("\\", "/")
+                # function_name is the qualified name on this Python
+                # ("vtag.<locals>.leaf")
                 if not (fn.endswith("dgc_tpu/ops/kernels.py")
-                        and fr.function_name in ("vtag", "leaf")):
+                        and fr.function_name.rsplit(".", 1)[-1]
+                        in ("vtag", "leaf")):
                     return (f"{fr.file_name}:{fr.start_line} "
                             f"({fr.function_name})")
         return str(source_info_util.summarize(eqn.source_info))
@@ -144,7 +149,13 @@ class _Flattener:
     def __init__(self):
         self.prog = FlatProgram()
         self._next = 0
-        self._ids: Dict[int, int] = {}       # id(Var) -> global id
+        #: id(Var) -> global id, for the jaxpr being walked. One map per
+        #: INLINING of a sub-jaxpr (see :meth:`_scope`): JAX caches the
+        #: traced jaxpr of a jitted helper (``x.at[i].add`` is one since
+        #: 0.5), so two call sites share one Jaxpr object and its Var
+        #: objects — a single global map would merge their dataflow and
+        #: let taint cross between unrelated calls
+        self._ids: Dict[int, int] = {}
 
     def _gid(self, var) -> Optional[int]:
         from jax._src import core
@@ -165,6 +176,19 @@ class _Flattener:
         self._ids[id(var)] = gid
         if self.prog.avals.get(gid) is None:
             self.prog.avals[gid] = getattr(var, "aval", None)
+
+    @contextlib.contextmanager
+    def _scope(self):
+        """A fresh id map for one inlining of a sub-jaxpr. Jaxprs are
+        closed — a body names only its own binders, constvars and
+        locals — so nothing of the caller's map is needed inside; the
+        caller binds the binders (:meth:`_alias` / :meth:`_fresh`)
+        after entering."""
+        outer, self._ids = self._ids, {}
+        try:
+            yield
+        finally:
+            self._ids = outer
 
     def _fresh(self, var) -> int:
         gid = self._next
@@ -219,20 +243,19 @@ class _Flattener:
                 for _, s in subs)
 
         if positional and name == "cond":
-            for _, sub in subs:
-                sj, _ = _open(sub)
-                for bv, gid in zip(sj.invars, in_gids[1:]):
-                    self._alias(bv, gid)
-                self._walk(sub, depth + 1, vmem)
             # every branch writes the same call outputs: alias the call
             # outvars to each branch's outvars via a join eqn
-            out_gids = tuple(self._gid(v) for v in eqn.outvars)
             join_ins: List[int] = []
             for _, sub in subs:
                 sj, _ = _open(sub)
-                join_ins.extend(
-                    g for g in (self._gid(v) for v in sj.outvars)
-                    if g is not None)
+                with self._scope():
+                    for bv, gid in zip(sj.invars, in_gids[1:]):
+                        self._alias(bv, gid)
+                    self._walk(sub, depth + 1, vmem)
+                    join_ins.extend(
+                        g for g in (self._gid(v) for v in sj.outvars)
+                        if g is not None)
+            out_gids = tuple(self._gid(v) for v in eqn.outvars)
             self.prog.eqns.append(FlatEqn(
                 f"{name}[join]", tuple(join_ins), out_gids,
                 {}, src, depth, vmem))
@@ -241,13 +264,14 @@ class _Flattener:
         if positional:
             _, sub = subs[0]
             sj, _ = _open(sub)
-            for bv, gid in zip(sj.invars, in_gids):
-                self._alias(bv, gid)
-            self._walk(sub, depth + 1, vmem)
+            with self._scope():
+                for bv, gid in zip(sj.invars, in_gids):
+                    self._alias(bv, gid)
+                self._walk(sub, depth + 1, vmem)
+                sub_outs = tuple(
+                    g for g in (self._gid(v) for v in sj.outvars)
+                    if g is not None)
             out_gids = tuple(self._gid(v) for v in eqn.outvars)
-            sub_outs = tuple(
-                g for g in (self._gid(v) for v in sj.outvars)
-                if g is not None)
             # scan's ys outputs are stacked copies of the body outs; a
             # join eqn keeps the dependency without claiming identity
             self.prog.eqns.append(FlatEqn(
@@ -265,13 +289,15 @@ class _Flattener:
         bridge_outs: List[int] = []
         for _, sub in subs:
             sj, _ = _open(sub)
-            fresh_ins = tuple(self._fresh(v) for v in sj.invars)
-            self.prog.eqns.append(FlatEqn(
-                f"{name}[bind]", ins, fresh_ins, {}, src, depth, sub_vmem))
-            self._walk(sub, depth + 1, sub_vmem)
-            bridge_outs.extend(
-                g for g in (self._gid(v) for v in sj.outvars)
-                if g is not None)
+            with self._scope():
+                fresh_ins = tuple(self._fresh(v) for v in sj.invars)
+                self.prog.eqns.append(FlatEqn(
+                    f"{name}[bind]", ins, fresh_ins, {}, src, depth,
+                    sub_vmem))
+                self._walk(sub, depth + 1, sub_vmem)
+                bridge_outs.extend(
+                    g for g in (self._gid(v) for v in sj.outvars)
+                    if g is not None)
         out_gids = tuple(self._gid(v) for v in eqn.outvars)
         self.prog.eqns.append(FlatEqn(
             f"{name}[join]", tuple(ins) + tuple(bridge_outs), out_gids,

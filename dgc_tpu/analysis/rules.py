@@ -191,14 +191,11 @@ class Allowlist:
 
 
 def load_allowlist(path: Optional[str] = None) -> Allowlist:
-    """Parse ``allowlist.toml`` (tomllib on 3.11+, tomli before)."""
+    """Parse ``allowlist.toml``."""
     path = path or DEFAULT_ALLOWLIST_PATH
     if not os.path.exists(path):
         return Allowlist()
-    try:
-        import tomllib
-    except ImportError:             # Python < 3.11: the vendored reader
-        import tomli as tomllib
+    import tomllib
     with open(path, "rb") as f:
         data = tomllib.load(f)
     entries = list(data.get("allow", []))

@@ -1478,12 +1478,34 @@ class FlatDGCEngine:
         per wire entry on CPU — minutes per step at warmup-ratio
         payloads), so at real scale off-TPU the engine silently keeps
         the XLA scatter path."""
+        if not getattr(self.c, "fused_apply", False):
+            return False
         if kernels._interpret() and self.payload_size > 4096:
             return False
-        return (getattr(self.c, "fused_apply", False)
-                and m is not None and not int8_ef
-                and dt == jnp.float32
-                and self.T % kernels._LANE == 0)
+        return self._apply_kernel_ok("fused_apply", m, int8_ef, dt)
+
+    def _decline(self, flag: str, why: str) -> bool:
+        """A kernel flag the user set that the engine cannot honour for a
+        reason OTHER than bucket geometry: on the TPU backend that is an
+        error, not a silent fall-through to the XLA path (off-TPU the
+        fall-through stays: the CPU parity tests stack the flags on
+        every wire format). Returns False so gates can ``return`` it."""
+        if kernels.use_pallas():
+            raise ValueError(
+                f"DGCCompressor({flag}=True) cannot be honoured: {why}")
+        return False
+
+    def _apply_kernel_ok(self, flag: str, m, int8_ef: bool, dt) -> bool:
+        """Preconditions the two fused apply kernels share — none of
+        them bucket geometry (see :meth:`_decline`)."""
+        if (m is not None and not int8_ef and dt == jnp.float32  # dgclint: ok[tracer-branch] — memory/wire dtype/T are plan-static Python values, not tracers
+                and self.T % kernels._LANE == 0):
+            return True
+        return self._decline(
+            flag, "the fused apply kernel needs error-feedback memory "
+            "(DGCSGDMemory), an f32 value wire and no int8 error feedback "
+            f"— got memory={type(m).__name__}, int8_error_feedback="
+            f"{int8_ef}, wire dtype={jnp.dtype(dt).name}")
 
     def _use_fused_select(self, b: "_Bucket") -> bool:
         """Whether a bucket's selection runs the fused
@@ -1518,14 +1540,18 @@ class FlatDGCEngine:
         and a serial-interpreter work bound off-TPU (oversize buckets
         silently keep the unfused path there — the `_use_fused_apply`
         convention, so the CPU parity oracles stay fast)."""
-        if not self._megakernel or self._mem is None:
+        if not self._megakernel:
             return False
+        sdt = (self._mem.dtype or self.layout.dtype) if self._mem else None
+        if (sdt is None or np.dtype(sdt) != np.dtype(np.float32)
+                or np.dtype(self.layout.dtype) != np.dtype(np.float32)):
+            return self._decline(
+                "megakernel", "the forward megakernel needs error-feedback "
+                "memory (DGCSGDMemory) with f32 state and f32 gradients — "
+                f"got memory={type(self._mem).__name__}, state dtype={sdt}, "
+                f"layout dtype={self.layout.dtype}")
         b = self.buckets[bi]
         if self._use_seg_kernel(b) or self._use_3d(b):
-            return False
-        sdt = self._mem.dtype or self.layout.dtype
-        if (np.dtype(sdt) != np.dtype(np.float32)
-                or np.dtype(self.layout.dtype) != np.dtype(np.float32)):
             return False
         if not (0 < b.max_sel <= min(b.cols, kernels._MR_MAX_K)):
             return False
@@ -1551,9 +1577,7 @@ class FlatDGCEngine:
             return False
         if kernels._interpret() and self.payload_size > 4096:
             return False
-        return (m is not None and not int8_ef
-                and dt == jnp.float32
-                and self.T % kernels._LANE == 0)
+        return self._apply_kernel_ok("megakernel", m, int8_ef, dt)
 
     def _compensate_megakernel(self, mmt, vec, grad, sent_bits):
         """Forward-megakernel compensate over [0, T): eligible buckets

@@ -1,7 +1,8 @@
 """Regime-aware exchange planner: pick the cheapest wire per bucket.
 
 The BENCH trajectory shows DGC winning the modeled 32x25GbE fabric by
->5x while LOSING v5e-8 ICI by ~20x (BENCH_r05 ``ici_v5e8.ratio`` 0.048):
+>5x while LOSING v5e-8 ICI by ~20x (round-5 driver bench,
+``ici_v5e8.ratio`` 0.048, earlier installation):
 the sparse pipeline's fixed compute overhead (~0.106 ms at ResNet-20)
 dwarfs a 0.005 ms dense psum when the wire is ~400x Ethernet. DGC is a
 slow-fabric algorithm; the fix is not a faster sparse path on ICI but a
@@ -115,8 +116,9 @@ BUILTIN_FABRICS: Dict[str, Fabric] = {
 
 
 class CostModel(NamedTuple):
-    """Compute-side coefficients (ms). Calibrated against the BENCH_r05
-    ResNet-20 medians (fixed ~0.106 ms sparse overhead at 272k params)
+    """Compute-side coefficients (ms). Calibrated against the round-5
+    driver bench's ResNet-20 medians on an earlier installation (fixed
+    ~0.106 ms sparse overhead at 272k params; no chip has refit them)
     and the measured int8 quantize bound (<= 0.3 ms at ResNet-50 payload
     scale); synthetic tests override fields to steer decisions."""
     #: per-bucket fixed cost of running the sparse pipeline at all

@@ -1417,9 +1417,9 @@ def _dgc_forward_kernel(n_ref, g_ref, m_ref, v_ref, b_ref, om_ref, ov_ref,
          jnp.full((1, kp), -jnp.inf, ov.dtype),
          jnp.zeros((1, kp), ov.dtype),
          jnp.zeros((1, kp), jnp.int32)))
-    s_ref[...] = s
-    pv_ref[...] = v
-    pi_ref[...] = i
+    s_ref[0] = s
+    pv_ref[0] = v
+    pi_ref[0] = i
 
 
 @functools.partial(jax.jit, static_argnames=("base", "k", "momentum",
@@ -1476,7 +1476,11 @@ def dgc_forward_rows(grad: jax.Array, mmt: jax.Array, vec: jax.Array,
                          memory_space=pltpu.VMEM)
     bspec = pl.BlockSpec((1, wr, _LANE), lambda r, nn: (r, 0, 0),
                          memory_space=pltpu.VMEM)
-    ospec = pl.BlockSpec((1, kp), lambda r, nn: (r, 0),
+    # [R, 1, kp] payload outputs: a (1, kp) block of an [R, kp] array
+    # is neither (8, 128)-divisible nor the full extent, which Mosaic
+    # refuses; with the unit middle dim the block's last two dims ARE
+    # the array's
+    ospec = pl.BlockSpec((1, 1, kp), lambda r, nn: (r, 0, 0),
                          memory_space=pltpu.VMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -1491,16 +1495,16 @@ def dgc_forward_rows(grad: jax.Array, mmt: jax.Array, vec: jax.Array,
         grid_spec=grid_spec,
         out_shape=(jax.ShapeDtypeStruct((R * nblk, _LANE), mmt.dtype),
                    jax.ShapeDtypeStruct((R * nblk, _LANE), vec.dtype),
-                   jax.ShapeDtypeStruct((R, kp), vec.dtype),
-                   jax.ShapeDtypeStruct((R, kp), vec.dtype),
-                   jax.ShapeDtypeStruct((R, kp), jnp.int32)),
+                   jax.ShapeDtypeStruct((R, 1, kp), vec.dtype),
+                   jax.ShapeDtypeStruct((R, 1, kp), vec.dtype),
+                   jax.ShapeDtypeStruct((R, 1, kp), jnp.int32)),
         # in-place state update (see fused_compensate_bits); indices
         # count the scalar-prefetch operand first
         input_output_aliases={2: 0, 3: 1},
         interpret=_interpret(),
     )(numels, g2, m2, v2, rb)
     return (om.reshape(-1), ov.reshape(-1),
-            s[:, :k], v[:, :k], i[:, :k])
+            s[:, 0, :k], v[:, 0, :k], i[:, 0, :k])
 
 
 # ------------------------------------------------------------------ #
@@ -1552,25 +1556,23 @@ def _payload_apply_body(pc_ref, first_ref, cnt_ref, pv_ref, po_ref,
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, _LANE), 1)
 
     def body(j, carry):
-        off = po_ref[0, j]           # in-chunk offset, [0, _APPLY_CHUNK)
-        v = pv_ref[0, j]
+        off = po_ref[j]              # in-chunk offset, [0, _APPLY_CHUNK)
+        v = pv_ref[j]
         if divisor is not None:
             v = v / divisor          # fused worker average (decompress)
-        f = pf_ref[0, j]
+        f = pf_ref[j]
         r = off // _LANE
         c = off % _LANE
         # value add: one dynamic-sublane row RMW; duplicates within a
         # chunk serialize through the loop in sorted-index order
         onehot = jnp.where(lane == c, v, jnp.zeros((), v.dtype))
-        cur = pl.load(acc_ref, (pl.ds(r, 1), slice(None)))
-        pl.store(acc_ref, (pl.ds(r, 1), slice(None)), cur + onehot)
+        acc_ref[pl.ds(r, 1), :] = acc_ref[pl.ds(r, 1), :] + onehot
         # transmit bit (word layout of pack_sent_bits): word row
         # off//4096, word lane off%128, bit (off//128)%32 — the chunk
         # base contributes 0 to each (a multiple of 4096*32 rows)
         wrow = off // (32 * _LANE)
         bvec = jnp.where(lane == c, f << (r % 32), jnp.zeros((), jnp.int32))
-        bcur = pl.load(bits_ref, (pl.ds(wrow, 1), slice(None)))
-        pl.store(bits_ref, (pl.ds(wrow, 1), slice(None)), bcur | bvec)
+        bits_ref[pl.ds(wrow, 1), :] = bits_ref[pl.ds(wrow, 1), :] | bvec
         return carry
 
     jax.lax.fori_loop(0, cnt_ref[p], body, 0)
@@ -1594,7 +1596,7 @@ def _stage_payload(values, indices, flags, total: int):
     and :func:`dgc_apply_rows` (plain XLA: one sort + cumsum + one
     payload-sized staging scatter — op-for-op the original epilogue
     staging, so the unfused program stays byte-identical). Returns the
-    scalar-prefetch maps, the staged [npages, _APPLY_PAGE] operands, and
+    scalar-prefetch maps, the staged flat [npages * _APPLY_PAGE] operands, and
     ``npages``."""
     n = values.shape[0]
     nchunks = -(-total // _APPLY_CHUNK)
@@ -1634,9 +1636,7 @@ def _stage_payload(values, indices, flags, total: int):
     pcount = jnp.clip(
         counts[page_chunk] - (pageid - page_start[page_chunk]) * pg,
         0, pg)
-    return (page_chunk, first, pcount,
-            stage_v.reshape(npages, pg), stage_o.reshape(npages, pg),
-            stage_f.reshape(npages, pg), npages)
+    return page_chunk, first, pcount, stage_v, stage_o, stage_f, npages
 
 
 @_trace.phased("apply")
@@ -1696,16 +1696,16 @@ def _payload_apply_call(kernel, values, indices, flags, total: int,
         assert bits_donor.shape == (brows * _LANE,), bits_donor.shape
         bits_donor = bits_donor.reshape(brows, _LANE)
 
-    pspec = lambda dt: pl.BlockSpec((1, pg), lambda p, pc, fr, ct: (p, 0),
-                                    memory_space=pltpu.SMEM)
+    # flat [npages * pg] staged operands, one 1-D page per grid step (a
+    # (1, pg) block of a 2-D array is not a legal Mosaic block shape)
+    pspec = pl.BlockSpec((pg,), lambda p, pc, fr, ct: (p,),
+                         memory_space=pltpu.SMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(npages,),
         in_specs=[
-            pspec(values.dtype),
-            pspec(jnp.int32),
-            pspec(jnp.int32),
-            pl.BlockSpec(memory_space=pltpu.ANY),     # bits donor
+            pspec, pspec, pspec,
+            pl.BlockSpec(memory_space=pl.ANY),        # bits donor
         ],
         out_specs=(
             pl.BlockSpec((_CHUNK_ROWS, _LANE),

@@ -15,7 +15,6 @@ Launchers in ``script/`` show the three standard entries: single host,
 (``sample_slurm.sh`` parity).
 """
 
-import inspect
 import os
 import time
 from typing import Optional
@@ -46,9 +45,7 @@ def initialize_multihost(coordinator_address: Optional[str] = None,
 
     ``timeouts`` forwards ``initialization_timeout`` /
     ``heartbeat_timeout_seconds`` / ``shutdown_timeout_seconds`` to
-    ``jax.distributed.initialize`` (keywords this JAX doesn't accept are
-    dropped — the older releases hard-code those two timeouts server
-    side). The shutdown timeout matters on cold
+    ``jax.distributed.initialize``. The shutdown timeout matters on cold
     machines: processes reach the coordination service's shutdown barrier
     skewed by however much their compile times diverge, and the 300 s
     default is shorter than a cold multi-minute XLA compile — the barrier
@@ -108,8 +105,6 @@ def initialize_multihost(coordinator_address: Optional[str] = None,
              or os.environ.get("MEGASCALE_COORDINATOR_ADDRESS"))
     if not multi:
         return False
-    accepted = inspect.signature(jax.distributed.initialize).parameters
-    kwargs = {k: v for k, v in timeouts.items() if k in accepted}
     # bounded retry around the coordination-service dial-in: worker
     # processes of a pod/Slurm job start skewed, and a worker that dials
     # in before the coordinator is listening gets a connection error it
@@ -126,7 +121,7 @@ def initialize_multihost(coordinator_address: Optional[str] = None,
                 coordinator_address=coordinator_address,
                 num_processes=num_processes,
                 process_id=process_id,
-                **kwargs)
+                **timeouts)
             return True
         except Exception as e:
             last_err = e

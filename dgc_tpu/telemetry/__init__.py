@@ -12,7 +12,7 @@ Three layers, one schema (``registry``):
   schema-versioned JSONL (with rotation), plus CSV/summary readers.
 * :mod:`dgc_tpu.telemetry.regress` — CLI regression gate comparing a fresh
   bench/telemetry run against a recorded baseline
-  (``python -m dgc_tpu.telemetry.regress BENCH_r05.json runs/new.jsonl``).
+  (``python -m dgc_tpu.telemetry.regress runs/baseline.json runs/new.jsonl``).
 
 Plus the tracing/postmortem layer (same sink, own schemas):
 

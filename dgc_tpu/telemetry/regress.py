@@ -3,7 +3,7 @@
 Compares a fresh run against a recorded baseline and exits nonzero on >tol
 regressions in step time, overhead, or wire volume::
 
-    python -m dgc_tpu.telemetry.regress BENCH_r05.json runs/new.jsonl --tol 0.10
+    python -m dgc_tpu.telemetry.regress runs/baseline.json runs/new.jsonl --tol 0.10
 
 Either side may be:
 

@@ -62,6 +62,21 @@ SCOPE_PREFIX = "dgcph."
 _ENABLED = os.environ.get("DGC_TRACE", "") == "1"
 
 
+def _key_compile_cache_on_metadata(on: bool) -> None:
+    """The markers live in op metadata only, and JAX's persistent
+    compilation cache leaves metadata out of its key by default: a
+    marker build would be served the marker-free executable of the same
+    program, and the profile would carry no phase names (seen on the
+    chip, PR 21 — the traced run hit the untraced run's cache entry)."""
+    import jax
+    jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                      bool(on))
+
+
+if _ENABLED:
+    _key_compile_cache_on_metadata(True)
+
+
 def enabled() -> bool:
     """Whether device phase markers trace into new programs."""
     return _ENABLED
@@ -75,6 +90,7 @@ def enable(on: bool = True) -> bool:
     global _ENABLED
     prev = _ENABLED
     _ENABLED = bool(on)
+    _key_compile_cache_on_metadata(_ENABLED)
     return prev
 
 

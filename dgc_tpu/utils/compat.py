@@ -1,37 +1,19 @@
-"""Version-adaptive JAX API surface.
+"""The repo's spelling of two JAX entry points.
 
-``shard_map`` moved from ``jax.experimental.shard_map`` (kwarg
-``check_rep``) to ``jax.shard_map`` (kwarg ``check_vma``) across the
-0.4.x -> 0.5+ series. The engine only ever disables the replication
-check (collectives inside the worker are explicit), so the shim maps
-``check_vma=False`` onto whichever spelling this JAX provides. Import
-``shard_map`` from here instead of from ``jax`` directly.
-
-``enable_x64`` similarly graduated from ``jax.experimental`` to the
-``jax`` top level; the shim re-exports whichever exists.
+``shard_map`` is ``jax.shard_map`` with the replication check off by
+default: collectives inside the worker are explicit, so the engine only
+ever disables it. ``enable_x64`` is ``jax.enable_x64``. One installation
+is supported (the JAX in this container); there is no fallback to the
+``jax.experimental`` spellings of older releases.
 """
 
 import jax
 
 __all__ = ["enable_x64", "shard_map"]
 
-if hasattr(jax, "enable_x64"):
-    enable_x64 = jax.enable_x64
-else:
-    from jax.experimental import enable_x64
+enable_x64 = jax.enable_x64
 
 
-if hasattr(jax, "shard_map"):
-
-    def shard_map(f, mesh=None, in_specs=None, out_specs=None,
-                  check_vma=False):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, mesh=None, in_specs=None, out_specs=None,
-                  check_vma=False):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_vma)
+def shard_map(f, mesh=None, in_specs=None, out_specs=None, check_vma=False):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)

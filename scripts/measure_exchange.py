@@ -73,7 +73,7 @@ def worker(args):
 
     import jax.numpy as jnp
     import numpy as np
-    from jax.experimental.shard_map import shard_map
+    from dgc_tpu.utils.compat import shard_map
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     W = len(jax.devices())
@@ -121,7 +121,7 @@ def worker(args):
                         jax.lax.all_gather(i[0], "data"))
             return shard_map(body, mesh=mesh,
                              in_specs=(P("data"), P("data")),
-                             out_specs=(P(), P()), check_rep=False)(v, i)
+                             out_specs=(P(), P()))(v, i)
 
         dense_ms = time_op(dense, g, iters=args.iters)
         sparse_ms = time_op(sparse, vals, idx, iters=args.iters)

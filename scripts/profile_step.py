@@ -49,21 +49,27 @@ def main():
     from dgc_tpu import (Compression, DGCCompressor, DGCSGDMemory,
                          DistributedOptimizer, dgc_sgd, sgd)
     from dgc_tpu import models
-    from dgc_tpu.parallel import make_mesh
+    from dgc_tpu.parallel import data_sharding, make_mesh
     from dgc_tpu.training import (build_train_step, make_flat_setup,
                                   make_flat_state, shard_state)
+    from dgc_tpu.utils import compile_cache
+    from dgc_tpu.utils.device import require_tpu
     from dgc_tpu.utils.pytree import named_flatten
 
+    compile_cache.enable()
+    require_tpu("profile_step.py")
     model = getattr(models, args.model)()
     size = 32 if args.model.startswith("resnet2") else 224
     ncls = 10 if size == 32 else 1000
     W = len(jax.devices())
     mesh = make_mesh(W)
     npr = np.random.RandomState(0)
-    images = jax.device_put(jnp.asarray(
-        npr.randn(W * args.bs, size, size, 3), jnp.float32))
-    labels = jax.device_put(jnp.asarray(
-        npr.randint(0, ncls, W * args.bs), jnp.int32))
+    batch_sharding = data_sharding(mesh)
+    images = jax.device_put(
+        npr.randn(W * args.bs, size, size, 3).astype(np.float32),
+        batch_sharding)
+    labels = jax.device_put(
+        npr.randint(0, ncls, W * args.bs).astype(np.int32), batch_sharding)
     v = model.init(jax.random.PRNGKey(42), jnp.zeros((1, size, size, 3)),
                    train=True)
     named, _ = named_flatten(v["params"])

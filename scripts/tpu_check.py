@@ -5,11 +5,13 @@ CPU pytest runs the Pallas kernels in interpret mode and approx_max_k
 lowers to an exact sort there, so CI cannot catch a Mosaic compilation or
 recall regression. This script runs ON THE REAL CHIP and asserts:
 
-1. compiled-Pallas == interpret-mode (bitwise) for fused_compensate,
+1. compiled-Pallas == jnp reference (bitwise) for fused_compensate,
    fused_compensate_masked, fused_compensate_bits (the shipped bit-packed
    transmit record, incl. the half-group layout and the bf16 state form),
-   ladder_counts, and topk_rows at the engine's ResNet-50 operating
-   shapes;
+   ladder_counts, topk_rows, the segment-top-2 candidate kernels, and the
+   four opt-in kernels (select_pack_rows incl. its multi-round form,
+   payload_apply_bits, dgc_forward_rows, dgc_apply_rows) at the engine's
+   ResNet-50 operating shapes;
 2. approx-selection recall >= 0.95 at every ResNet-50 approx bucket
    (exact top-k reference computed on the same device).
 
@@ -35,10 +37,9 @@ def check_kernels():
     """Compiled vs interpret equality at engine shapes. Returns
     {name: bool}."""
     from dgc_tpu.ops import kernels
+    from dgc_tpu.utils.device import require_tpu
 
-    assert kernels.use_pallas(), (
-        "tpu_check must run on a TPU backend (jax.default_backend()="
-        f"{jax.default_backend()})")
+    require_tpu("tpu_check")
     rng = np.random.RandomState(0)
     out = {}
 
@@ -174,6 +175,74 @@ def check_kernels():
         and np.array_equal(np.asarray(cv2), np.asarray(rv2))
         and np.array_equal(np.asarray(ccv)[:nseg], np.asarray(rcv))
         and np.array_equal(np.asarray(cci)[:nseg], np.asarray(rci)))
+
+    # --- the opt-in kernels (fused_select / fused_apply / megakernel) at
+    #     the ResNet-50 engine geometry, ratio 0.001: T = 27,068,416 with
+    #     a 25,583-entry payload per worker; the exact-selection buckets
+    #     the fused select owns on the TPU backend are [8, 16384] k=17
+    #     and [11, 65536] k=66 ---
+    def eq(got, want):
+        return all(np.array_equal(np.asarray(g_), np.asarray(w_))
+                   for g_, w_ in zip(got, want))
+
+    def rows_with_tails(R, cols_):
+        """Signed rows whose tails past ``numels`` are structural zeros
+        (ParamLayout.flatten's invariant)."""
+        x_ = rng.randn(R, cols_).astype(np.float32)
+        numels = rng.randint(cols_ // 2, cols_ + 1, R).astype(np.int32)
+        x_[np.arange(cols_)[None, :] >= numels[:, None]] = 0.0
+        return jnp.asarray(x_), jnp.asarray(numels)
+
+    for R, cols_, k in ((8, 16384, 17), (11, 65536, 66)):
+        xs, ns = rows_with_tails(R, cols_)
+        out[f"select_pack_rows_{R}x{cols_}_k{k}"] = eq(
+            kernels.select_pack_rows(xs, ns, k),
+            kernels.select_pack_rows_reference(xs, ns, k))
+    # multi-round form (k > 128): the [11, 65536] bucket at the wm5
+    # warm-up ratio 0.0032, the widest selection the TPU gate
+    # (max_sel * cols <= 16M) admits there
+    xs, ns = rows_with_tails(11, 65536)
+    out["select_pack_rows_mr_11x65536_k210"] = eq(
+        kernels.select_pack_rows(xs, ns, 210),
+        kernels.select_pack_rows_reference(xs, ns, 210))
+
+    # forward megakernel on the one ResNet-50 bucket it owns ([8, 16384]
+    # at flat base 26,935,296 — NOT word-group aligned, so the per-row
+    # funnel shifts of _realign_bits_rows run), at k=17 and at a
+    # multi-lane-block k (the warm-up ratio 0.0316)
+    T50, base50, R, cols_ = 27_068_416, 26_935_296, 8, 16384
+    nreg = R * cols_
+    bits50 = kernels.pack_sent_bits(
+        jnp.asarray(rng.choice(T50, 25_583, replace=False)
+                    .astype(np.int32)), T50)
+    gr, mr, vr = (jnp.asarray(rng.randn(nreg), jnp.float32)
+                  for _ in range(3))
+    _, ns = rows_with_tails(R, cols_)
+    for k in (17, 518):
+        out[f"dgc_forward_rows_{R}x{cols_}_k{k}"] = eq(
+            kernels.dgc_forward_rows(gr, mr, vr, bits50, base50,
+                                     ns, k, 0.9, False, True),
+            kernels.dgc_forward_rows_reference(gr, mr, vr, bits50, base50,
+                                               ns, k, 0.9, False, True))
+
+    # apply epilogues over the whole [T] buffer: one worker's payload,
+    # unique coordinates (the bitwise contract; duplicates differ by
+    # scatter order only), a random subset flagged as locally sent
+    pidx = jnp.asarray(rng.choice(T50, 25_583, replace=False)
+                       .astype(np.int32))
+    pval = jnp.asarray(rng.randn(25_583), jnp.float32)
+    pflag = jnp.asarray(rng.rand(25_583) < 0.5)
+    out["payload_apply_bits"] = eq(
+        jax.jit(lambda v_, i_, f_, d_: kernels.payload_apply_bits(
+            v_, i_, f_, T50, bits_donor=d_))(pval, pidx, pflag, bits50),
+        jax.jit(lambda v_, i_, f_: kernels.payload_apply_bits_reference(
+            v_, i_, f_, T50))(pval, pidx, pflag))
+    out["dgc_apply_rows"] = eq(
+        jax.jit(lambda v_, i_, f_, d_: kernels.dgc_apply_rows(
+            v_, i_, f_, T50, bits_donor=d_, divisor=4.0))(
+                pval, pidx, pflag, bits50),
+        jax.jit(lambda v_, i_, f_: kernels.dgc_apply_rows_reference(
+            v_, i_, f_, T50, divisor=4.0))(pval, pidx, pflag))
     return out
 
 
@@ -273,6 +342,9 @@ def check_recall_3d(threshold: float = 0.95):
 
 
 def main():
+    from dgc_tpu.utils import compile_cache
+
+    compile_cache.enable()
     kernels_ok = check_kernels()
     recall = check_recall()
     recall.update(check_recall_3d())

@@ -53,13 +53,8 @@ def main():
     workdir = sys.argv[3]
     assert phase in ("baseline", "save", "resume", "supervised"), phase
 
-    import getpass
-    import tempfile
-    jax.config.update("jax_compilation_cache_dir",
-                      os.path.join(tempfile.gettempdir(),
-                                   f"dgc_tpu_test_jax_cache_"
-                                   f"{getpass.getuser()}"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    from dgc_tpu.utils import compile_cache
+    compile_cache.enable()
 
     import jax.numpy as jnp
     import numpy as np

@@ -51,13 +51,8 @@ def main():
     from dgc_tpu.parallel.multihost import (host_local_to_global,
                                             initialize_multihost)
 
-    import getpass
-    import tempfile
-    jax.config.update("jax_compilation_cache_dir",
-                      os.path.join(tempfile.gettempdir(),
-                                   f"dgc_tpu_test_jax_cache_"
-                                   f"{getpass.getuser()}"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    from dgc_tpu.utils import compile_cache
+    compile_cache.enable()
 
     os.environ["JAX_COORDINATOR_ADDRESS"] = coord
     os.environ["JAX_NUM_PROCESSES"] = str(num_procs)

@@ -38,20 +38,13 @@ def main():
         host_local_to_global, initialize_multihost, is_coordinator)
 
     # persistent compilation cache SHARED by both processes (and across
-    # test invocations — a stable tmp location, not the per-test dir): on
-    # a small/loaded host, cold-compiling the train step in both processes
-    # can outlast the coordination service's 300 s shutdown barrier when
-    # one process is starved — the cache removes that variance (warm
-    # runs: ~30 s total)
-    import getpass
-    import tempfile
-    # user-scoped: a shared dir would be unwritable for every user but
-    # its creator on multi-user hosts, silently disabling the cache
-    jax.config.update("jax_compilation_cache_dir",
-                      os.path.join(tempfile.gettempdir(),
-                                   f"dgc_tpu_test_jax_cache_"
-                                   f"{getpass.getuser()}"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    # tests — JAX_COMPILATION_CACHE_DIR or <checkout>/.jax_cache, not the
+    # per-test dir): on a small/loaded host, cold-compiling the train
+    # step in both processes can outlast the coordination service's
+    # 300 s shutdown barrier when one process is starved — the cache
+    # removes that variance (warm runs: ~30 s total)
+    from dgc_tpu.utils import compile_cache
+    compile_cache.enable()
 
     os.environ["JAX_COORDINATOR_ADDRESS"] = coord
     os.environ["JAX_NUM_PROCESSES"] = str(num_procs)

@@ -52,13 +52,8 @@ def main():
 
     # same shared persistent compile cache as multiproc_worker.py (this
     # worker's step function is built identically, so it reuses the entry)
-    import getpass
-    import tempfile
-    jax.config.update("jax_compilation_cache_dir",
-                      os.path.join(tempfile.gettempdir(),
-                                   f"dgc_tpu_test_jax_cache_"
-                                   f"{getpass.getuser()}"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    from dgc_tpu.utils import compile_cache
+    compile_cache.enable()
 
     os.environ["JAX_COORDINATOR_ADDRESS"] = coord
     os.environ["JAX_NUM_PROCESSES"] = str(num_procs)

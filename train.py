@@ -87,7 +87,7 @@ def drain_loss_log(writer, loss_log, on_loss=None):
     return loss
 
 
-def main():
+def main(argv=None):
     parser = argparse.ArgumentParser()
     parser.add_argument("--configs", nargs="+", required=True)
     parser.add_argument("--devices", default="tpu")
@@ -96,8 +96,9 @@ def main():
     parser.add_argument("--evaluate", action="store_true")
     parser.add_argument("--suffix", default="")
     parser.add_argument("--profile", action="store_true",
-                        help="write a device trace of the first training "
-                             "steps to <save_path>/profile")
+                        help="write a device trace of 8 training steps "
+                             "(after the first, compiling one) to "
+                             "<save_path>/profile")
     parser.add_argument("--trace", action="store_true",
                         help="structured tracing: host-side spans + "
                              "device-side dgcph.* phase markers, saved as "
@@ -127,25 +128,33 @@ def main():
                              "needs the fleet taps (configs/fleet.py); "
                              "same as stacking configs/adaptive.py or "
                              "setting DGC_ADAPTIVE=1")
-    args, opts = parser.parse_known_args()
+    args, opts = parser.parse_known_args(argv)
 
-    if args.cpu_mesh or args.devices == "cpu":
+    on_cpu = bool(args.cpu_mesh or args.devices == "cpu")
+    if on_cpu:
         n = args.cpu_mesh or 1
         flags = os.environ.get("XLA_FLAGS", "")
         if "--xla_force_host_platform_device_count" not in flags:
             os.environ["XLA_FLAGS"] = (
                 f"{flags} --xla_force_host_platform_device_count={n}").strip()
     import jax
-    if args.cpu_mesh or args.devices == "cpu":
+    from dgc_tpu.utils import compile_cache
+    compile_cache.enable()
+    if on_cpu:
         jax.config.update("jax_platforms", "cpu")
-    # multi-host wiring (TPU pods / Slurm; no-op single host) must precede
-    # ANY backend use — even a jax.process_index() in a log line initializes
-    # the local backend and breaks jax.distributed.initialize
-    if not (args.cpu_mesh or args.devices == "cpu"):
+        _multihost = False
+    else:
+        # multi-host wiring (TPU pods / Slurm; no-op single host) must
+        # precede ANY backend use — even a jax.process_index() in a log
+        # line initializes the local backend and breaks
+        # jax.distributed.initialize
         from dgc_tpu.parallel.multihost import initialize_multihost
         _multihost = initialize_multihost()
-    else:
-        _multihost = False
+        # the CPU backend is a different program (jnp references instead
+        # of the Pallas kernels): training on it must be asked for by
+        # name, never fallen into because no chip was found
+        from dgc_tpu.utils.device import require_tpu
+        require_tpu("train.py (without --cpu_mesh N or --devices cpu)")
     import jax.numpy as jnp
 
     from dgc_tpu.compression.flat import ParamLayout
@@ -733,12 +742,14 @@ def main():
         metrics = None
         loss_log = []
         base_key = jax.random.PRNGKey(seed)
-        # --profile traces the first 8 steps of the first trained epoch and
-        # then keeps training normally (the trace stops, the epoch doesn't)
-        profile_left = 8 if (args.profile and epoch == last_epoch + 1) else 0
-        if profile_left:
-            jax.profiler.start_trace(
-                os.path.join(configs.train.save_path, "profile"))
+        # --profile traces 8 steady-state steps of the first trained epoch
+        # and then keeps training normally (the trace stops, the epoch
+        # doesn't). The trace starts once the epoch's first step has
+        # finished: that step compiles, and its host events fill the
+        # profiler's 1M-event export and push the device ops out of it
+        # (seen on the chip, PR 21)
+        profile_pending = bool(args.profile and epoch == last_epoch + 1)
+        profile_left = 0
         batches = None
         try:
             # background-thread batch prep (DataLoader-worker role) plus
@@ -834,7 +845,13 @@ def main():
                             state, images, labels,
                             jax.random.fold_in(
                                 base_key, epoch * 100003 + bidx))
-                if profile_left:
+                if profile_pending:
+                    profile_pending = False
+                    jax.block_until_ready(metrics["loss"])
+                    jax.profiler.start_trace(
+                        os.path.join(configs.train.save_path, "profile"))
+                    profile_left = 8
+                elif profile_left:
                     profile_left -= 1
                     if profile_left == 0:
                         jax.block_until_ready(metrics["loss"])
