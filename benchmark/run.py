@@ -1,0 +1,497 @@
+"""One run of one cell: set up, warm up, check, measure, print.
+
+    python3 benchmark/run.py --workload <name> --seed <n> --seconds <s> \
+        --trace <0|1>
+
+A fresh process per run. It refuses a backend that is not a TPU, builds the
+cell's arms from the repo's config tree (``benchmark/build.py``), warms up
+exactly the programs the window uses, checks the exchange engine against
+the plain reference and the arms against each other, and then measures
+for ``--seconds`` seconds in rounds: ``round_steps`` donated per-dispatch
+steps of one arm, dispatched back to back and ended by one
+``block_until_ready``, then the same for the other arm, the order of the
+arms alternating from round to round. Nothing may compile inside the
+window; a run in which something does exits non-zero.
+
+``--trace 1`` builds the steps with the ``dgcph.*`` markers on (their
+executables have cache entries of their own), runs the same window, then
+profiles ``trace_steps`` steps of each arm in one profiler session and
+reports the cell's per-layer metrics instead of its end-to-end ones.
+
+With ``DGC_BENCH_KEEP_TRACE=<dir>`` in the environment the profiler's
+directory is copied there before it is reduced (to look at a trace a reader
+finds nothing in).
+
+The last line of standard output is the result, one JSON object. Earlier
+lines (JSON objects with an ``event`` key) carry the set-up split, the
+check's numbers, the per-round rows with quartiles, and the phase tables.
+"""
+
+import time
+
+_T0 = time.perf_counter()        # process start, give or take the interpreter
+
+import argparse
+import gc
+import json
+import os
+import shutil
+import statistics
+import sys
+import tempfile
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark import cells, rounds
+from benchmark.spans import Spans
+
+#: both arms see the same weights and the same first batch, so their
+#: step-0 losses differ only by how two XLA programs fuse and order the
+#: same float32 sums: up to 3.7e-6 relative on the chip (ResNet-50, four
+#: seeds, PR 22). Computing in bfloat16 would show as some 1e-3.
+STEP0_LOSS_RTOL = 1e-4
+#: steps each arm runs alone, after its first (compiling) call, before its
+#: memory peak is read
+SOLO_WARMUP_STEPS = 2
+KEEP_TRACE_ENV = "DGC_BENCH_KEEP_TRACE"
+
+
+def log(event, **fields):
+    print(json.dumps({"event": event, **fields}), flush=True)
+
+
+class CompileCounter:
+    """Programs JAX built: compiled, or fetched from its persistent cache
+    (the duration event fires for both)."""
+
+    def __init__(self):
+        import jax.monitoring
+        self.programs = self.from_cache = 0
+        jax.monitoring.register_event_listener(self._on_event)
+        jax.monitoring.register_event_duration_secs_listener(
+            self._on_duration)
+
+    def _on_event(self, event, **_):
+        if event == "/jax/compilation_cache/cache_hits":
+            self.from_cache += 1
+
+    def _on_duration(self, event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.programs += 1
+
+    def snapshot(self):
+        return {"programs": self.programs, "from_cache": self.from_cache}
+
+
+class ArmRun:
+    """One arm while it runs: the built arm, its state, its losses."""
+
+    def __init__(self, arm, state, seed):
+        import jax
+        self.arm, self.name, self.state = arm, arm.name, state
+        self.key = jax.random.PRNGKey(seed)
+        self.steps = 0
+        self.losses = []
+
+    def dispatch(self, images, labels):
+        import jax
+        key = jax.random.fold_in(self.key, self.steps)
+        if self.arm.k_loop is not None:
+            self.state, losses = self.arm.k_loop(self.state, images, labels,
+                                                 key)
+        else:
+            self.state, metrics = self.arm.step(self.state, images, labels,
+                                                key)
+            losses = metrics["loss"]
+        self.steps += losses.size
+        self.losses.append(losses)
+        return losses
+
+
+def run_round(run, feed, spans, dispatches):
+    """``dispatches`` back-to-back dispatches of one arm and one wait;
+    returns the round's wall seconds and the steps it ran."""
+    import jax
+    before = run.steps
+    t0 = time.perf_counter()
+    for _ in range(dispatches):
+        with spans.span(run.name, "input.next"):
+            images, labels = next(feed)
+        with spans.span(run.name, "dispatch"):
+            run.dispatch(images, labels)
+    with spans.span(run.name, "wait"):
+        jax.block_until_ready(run.state)
+    return time.perf_counter() - t0, run.steps - before
+
+
+def hbm_peak_bytes(devices):
+    """Peak device memory on the fullest of ``devices``, as the runtime
+    reports it: the peak of live arrays plus the peak it reserved for
+    programs' scratch (their activations), which ``peak_bytes_in_use``
+    alone leaves out (5.3 GB of 7.2 at ResNet-50, PERF.md)."""
+    def peak(d):
+        stats = d.memory_stats() or {}
+        return (stats.get("peak_bytes_in_use", 0)
+                + stats.get("peak_bytes_reserved", 0))
+    return max((peak(d) for d in devices), default=0)
+
+
+def engine_info(arm):
+    """What the readers may know of the dgc arm's engine: its sizes."""
+    setup, memory = arm.setup, arm.dist.compressor.memory
+    item = int(setup.layout.dtype.itemsize)
+    state_dtype = getattr(memory, "dtype", None)
+    return {"T": int(getattr(setup.engine, "T", 0)),
+            "total": int(setup.layout.total),
+            "payload_size": int(getattr(setup.engine, "payload_size", 0)),
+            "grad_itemsize": item,
+            "state_itemsize": (int(state_dtype.itemsize)
+                               if state_dtype is not None else item)}
+
+
+def measure(cell, seed, seconds, trace, devices=None):
+    """Everything between "backend up" and "result": returns the result's
+    parts as a dict. ``devices`` is for the rehearsals (virtual CPU
+    devices); on the chip it stays None."""
+    import jax
+    import numpy as np
+
+    from benchmark import build, inputs
+    from benchmark.check import exchange_check
+
+    split = {}
+    mark = time.perf_counter()
+
+    def lap(name):
+        nonlocal mark
+        now = time.perf_counter()
+        split[name] = split.get(name, 0.0) + now - mark
+        mark = now
+
+    counter = CompileCounter()
+    traffic = cell.traffic
+    if trace:
+        # before any step is traced: the markers bake in at trace time,
+        # and this also keys the compile cache on op metadata (PR 21)
+        from dgc_tpu.telemetry import trace as dgc_trace
+        dgc_trace.enable(True)
+    mesh = build.make_mesh(cell, devices)
+    cell_devices = list(mesh.devices.flat)
+    spans = Spans()
+    scan = traffic["loop"] == "scan"
+    runs, feed, first_batch = {}, None, None
+    first_loss, dgc_peak, engine = {}, None, None
+    check = {"ok": True, "skipped": "no dgc arm in this traffic"}
+    lap("backend_and_mesh")
+
+    try:
+        for name in traffic["arms"]:
+            arm = build.build_arm(cell, name, mesh)
+            lap("build_" + name)
+            if feed is None:
+                gb = arm.world * traffic["per_chip_batch"]
+                geom = (arm.image_size, arm.num_classes, mesh)
+                if traffic["input"] == "pipeline":
+                    feed = inputs.pipeline_feed(
+                        seed, gb, traffic["pool_batches"], *geom)
+                else:
+                    n = traffic["pool_batches" if scan else "round_steps"]
+                    resident = inputs.resident_batches(seed, gb, n, *geom)
+                    feed = (inputs.scan_feed(resident, mesh) if scan
+                            else inputs.resident_feed(resident))
+                first_batch = next(feed)
+                jax.block_until_ready(first_batch)
+                lap("data")
+            run = runs[name] = ArmRun(arm, build.init_state(arm, seed), seed)
+            jax.block_until_ready(run.state)
+            lap("init_" + name)
+            # the first call compiles (or loads) the one program this arm
+            # uses; same weights, same batch, same key for every arm
+            loss = run.dispatch(*first_batch)
+            first_loss[name] = float(np.ravel(jax.device_get(loss))[0])
+            lap("first_step_" + name)
+            for _ in range(SOLO_WARMUP_STEPS):
+                run.dispatch(*first_batch)
+            jax.block_until_ready(run.state)
+            lap("warmup")
+            if name == "dgc":
+                # the DGC job's own peak: before another arm's state (or
+                # the check's temporaries) exists on the device
+                dgc_peak = hbm_peak_bytes(cell_devices)
+                engine = engine_info(arm)
+                check = exchange_check(arm, seed)
+                lap("check")
+        names = list(runs)
+        dispatches = traffic["round_steps"]
+        # one whole interleaved round, discarded (bench.py: the first
+        # round after compile runs slow); it also fills the pipeline
+        for name in names:
+            run_round(runs[name], feed, spans, dispatches)
+        lap("warmup")
+
+        losses_ref = list(first_loss.values())
+        step0_ok = all(abs(v - losses_ref[0])
+                       <= STEP0_LOSS_RTOL * abs(losses_ref[0])
+                       for v in losses_ref)
+        log("check", exchange=check, step0_loss=first_loss,
+            step0_loss_rtol=STEP0_LOSS_RTOL, step0_ok=step0_ok)
+
+        # ---- the window ------------------------------------------------ #
+        for run in runs.values():
+            run.losses.clear()
+        span_mark = spans.mark()
+        before = counter.snapshot()
+        rows, steps_per_round = [], None
+        gc.collect()
+        gc.disable()
+        t_start = time.perf_counter()
+        setup_s = t_start - _T0
+        while True:
+            row = {}
+            for name in rounds.arm_order(names, len(rows)):
+                row[name], steps_per_round = run_round(
+                    runs[name], feed, spans, dispatches)
+            rows.append(row)
+            if (time.perf_counter() - t_start >= seconds
+                    and len(rows) % len(names) == 0):
+                break
+        window_s = time.perf_counter() - t_start
+        gc.enable()
+        after = counter.snapshot()
+        if after != before:
+            raise SystemExit(
+                f"benchmark: something compiled inside the measured window "
+                f"({before} -> {after}); the run is void")
+
+        losses = {name: np.concatenate([np.ravel(x) for x in
+                                        jax.device_get(run.losses)])
+                  for name, run in runs.items()}
+        attempted = int(sum(len(v) for v in losses.values()))
+        failed = int(sum(int(np.sum(~np.isfinite(v)))
+                         for v in losses.values()))
+        window_spans = spans.seconds(span_mark)
+
+        traced = None
+        if trace:
+            traced = _profile(cell, runs, feed, spans, steps_per_round
+                              // dispatches)
+    finally:
+        if feed is not None:
+            feed.close()
+
+    return {
+        "memory_peak_bytes": int(hbm_peak_bytes(cell_devices)),
+        "setup_s": setup_s, "split": split, "compiles": counter.snapshot(),
+        "rows": rows, "steps_per_round": steps_per_round,
+        "window_s": window_s, "attempted": attempted, "failed": failed,
+        "check": check, "step0_ok": step0_ok, "dgc_peak_bytes": dgc_peak,
+        "window_spans": window_spans, "traced": traced, "engine": engine,
+    }
+
+
+def _profile(cell, runs, feed, spans, steps_per_dispatch):
+    """``trace_steps`` steps of each arm in one profiler session; returns
+    the loaded events and the steps each arm ran in it."""
+    import jax
+
+    from benchmark import trace_reduce
+
+    dispatches = max(1, cell.traffic["trace_steps"] // steps_per_dispatch)
+    logdir = tempfile.mkdtemp(prefix="dgc_bench_trace_")
+    options = jax.profiler.ProfileOptions()
+    # with the python tracer off, 16 ResNet-50 steps export 44,899 events
+    # (985,190 for 8 steps with it on, PR 21). The harness's annotations
+    # are host-tracer events, so that tracer keeps its default level; what
+    # it records of host-to-device copies overflows the export where every
+    # step stages a batch (1,000,035 events, input 'pipeline', this PR)
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    steps = {}
+    try:
+        jax.profiler.start_trace(logdir, profiler_options=options)
+        spans.annotate = True
+        try:
+            for name, run in runs.items():
+                with jax.profiler.TraceAnnotation(f"bench:{name}:segment"):
+                    _, steps[name] = run_round(run, feed, spans, dispatches)
+        finally:
+            spans.annotate = False
+            jax.profiler.stop_trace()
+        keep = os.environ.get(KEEP_TRACE_ENV)
+        if keep:
+            shutil.copytree(logdir, keep, dirs_exist_ok=True)
+        events = trace_reduce.load_events(
+            trace_reduce.find_trace_file(logdir))
+    finally:
+        shutil.rmtree(logdir, ignore_errors=True)
+    return {"events": events, "steps": steps}
+
+
+# ---------------------------------------------------------------------- #
+# from a measurement to the result line                                  #
+# ---------------------------------------------------------------------- #
+
+def paired_summary(m):
+    """Medians and quartiles of the window, per arm and paired."""
+    rows, steps = m["rows"], m["steps_per_round"]
+    out = {"rounds": len(rows), "steps_per_round": steps,
+           "window_s": m["window_s"], "arms": {}}
+    for name in rows[0]:
+        q = rounds.quartiles(rounds.per_step_ms(rows, name, steps))
+        out["arms"][name] = {"q1": q[0], "median": q[1], "q3": q[2]}
+    if "dgc" in rows[0] and "dense" in rows[0]:
+        q = rounds.quartiles(rounds.paired_diff_ms(rows, "dgc", "dense",
+                                                   steps))
+        out["dgc_minus_dense_ms"] = {"q1": q[0], "median": q[1], "q3": q[2]}
+    return out
+
+
+def end_to_end_values(m, paired):
+    values = {"setup_s": m["setup_s"]}
+    if "dgc" in paired["arms"]:
+        values["step_ms"] = paired["arms"]["dgc"]["median"]
+    if "dense" in paired["arms"]:
+        values["dense_step_ms"] = paired["arms"]["dense"]["median"]
+    if "dgc_minus_dense_ms" in paired:
+        values["dgc_overhead_ms"] = paired["dgc_minus_dense_ms"]["median"]
+    if m["dgc_peak_bytes"]:
+        values["peak_hbm_gib"] = m["dgc_peak_bytes"] / 2 ** 30
+    return values
+
+
+def trace_view(m, paired, device_kind):
+    """What the per-layer readers get as ``trace``."""
+    from benchmark import trace_reduce
+
+    traced = m["traced"]
+    arms = trace_reduce.split_arms(traced["events"], traced["steps"])
+    return {
+        "arms": arms,
+        "tables": {name: trace_reduce.phase_table(a)
+                   for name, a in arms.items()},
+        "paired": paired,
+        "engine": m["engine"],
+        "peaks": cells.load_peaks(device_kind),
+    }
+
+
+def per_layer_values(cell, view, spans_seconds):
+    values = {}
+    for entry in cell.per_layer:
+        value = cells.load_reader(entry["name"])(view, spans_seconds, cell)
+        if value is not None:
+            values[entry["name"]] = float(value)
+    return values
+
+
+def breakdown(view):
+    """At most ten rows each: device seconds by arm and ``dgcph`` phase
+    over the traced window, and idle seconds by what the host was doing."""
+    from benchmark import trace_reduce
+    ops, idle = {}, {}
+    for name, arm in view["arms"].items():
+        table = view["tables"][name]
+        scale = table["steps"] / 1e3            # ms/step -> s in the window
+        for phase, ms in table["phases"].items():
+            label = phase if phase == "unattributed" else "dgcph." + phase
+            ops[f"{name}:{label}"] = ms * scale
+        for label, secs in trace_reduce.label_gaps(arm).items():
+            idle[f"{name}:{label}"] = secs
+
+    def top(d):
+        return [[k, v] for k, v in sorted(d.items(),
+                                          key=lambda kv: -kv[1])[:10]]
+
+    return {"device_ops": top(ops), "idle_gaps": top(idle)}
+
+
+def device_busy(view):
+    """(busy_s, window_s): both summed over the arms' windows (first
+    device op to last), averaged over the chips."""
+    arms = view["arms"].values()
+    return (sum(statistics.mean(c.busy_s for c in a.chips) for a in arms),
+            sum(statistics.mean(c.window_s for c in a.chips) for a in arms))
+
+
+def parse_args(argv):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--trace", type=int, choices=(0, 1), required=True)
+    return ap.parse_args(argv)
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    try:
+        cell = cells.load_cell(args.workload)
+    except cells.CellError as e:
+        raise SystemExit(f"benchmark: {e}")
+    try:
+        import jax
+
+        from dgc_tpu.utils import compile_cache
+        from dgc_tpu.utils.device import require_tpu
+    except ImportError as e:
+        raise SystemExit(f"benchmark: the system under test is not in this "
+                         f"checkout ({e})")
+    cache_dir = compile_cache.enable()
+    # every program, however quick to compile, comes from the cache in a
+    # warm run (JAX's default keeps only those that took over a second)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    require_tpu("benchmark/run.py")
+    log("start", workload=cell.name, seed=args.seed, seconds=args.seconds,
+        trace=args.trace, chips=cell.chips, config=cell.config_name,
+        traffic=cell.traffic_name, cache_dir=cache_dir,
+        cache_entries=compile_cache.entries(cache_dir),
+        import_s=time.perf_counter() - _T0)
+
+    m = measure(cell, args.seed, args.seconds, bool(args.trace))
+    paired = paired_summary(m)
+    log("setup", setup_s=m["setup_s"], split=m["split"],
+        compiles=m["compiles"],
+        cache_entries=compile_cache.entries(cache_dir))
+    log("memory", memory_peak_bytes=m["memory_peak_bytes"],
+        dgc_peak_bytes=m["dgc_peak_bytes"],
+        runtime_stats=jax.devices()[0].memory_stats())
+    log("rounds", **paired, rows=m["rows"])
+    log("spans", window={arm: {k: {"n": len(v), "median_ms":
+                                   statistics.median(v) * 1e3,
+                                   "sum_s": sum(v)}
+                               for k, v in by.items()}
+                         for arm, by in m["window_spans"].items()})
+
+    units = {e["name"]: e["unit"]
+             for e in cell.end_to_end + cell.per_layer}
+    d0 = jax.devices()[0]
+    device = {"platform": d0.platform, "kind": d0.device_kind,
+              "count": jax.device_count(),
+              "memory_peak_bytes": m["memory_peak_bytes"]}
+    result = {"correct": bool(m["check"]["ok"] and m["step0_ok"]
+                              and m["failed"] == 0),
+              "attempted": m["attempted"], "failed": m["failed"]}
+    if args.trace:
+        view = trace_view(m, paired, device["kind"])
+        values = per_layer_values(cell, view, m["window_spans"])
+        log("phase_tables", **view["tables"])
+        device["busy_s"], device["window_s"] = device_busy(view)
+        result["breakdown"] = breakdown(view)
+    else:
+        wanted = {e["name"] for e in cell.end_to_end}
+        values = {k: v for k, v in end_to_end_values(m, paired).items()
+                  if k in wanted}
+        missing = sorted(wanted - set(values))
+        if missing:
+            raise SystemExit(f"benchmark: workload '{cell.name}' did not "
+                             f"produce {missing}")
+    result["metrics"] = {k: {"value": v, "unit": units[k]}
+                         for k, v in values.items()}
+    result["device"] = device
+    print(json.dumps(result), flush=True)
+
+
+if __name__ == "__main__":
+    main()
