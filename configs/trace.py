@@ -1,27 +1,30 @@
 """Tracing knob (docs/TELEMETRY.md §Tracing): append to any config stack
-to turn structured tracing on:
+to turn structured tracing on (same as ``train.py --trace``):
 
     python train.py --configs configs/cifar/resnet20.py configs/dgc/wm5.py \
         configs/trace.py
 
-What it enables:
-* host-side spans (data load, step dispatch, exchange wait, checkpoint,
-  eval) streamed through the async telemetry sink and saved as a
-  Perfetto-loadable Chrome trace at <save_path>/trace.json;
-* device-side ``dgcph.<phase>[.b<bucket>]`` named-scope markers through
-  the DGC pipeline (compensate/threshold/select/pack/allgather/decode/
-  apply) — pure op metadata, zero new ops or collectives; a device
-  profile then attributes per-bucket per-phase cost via
-  dgc_tpu.telemetry.attrib.
+What it enables (``dgc_tpu.telemetry.trace.enable``, the one switch):
+* device-side ``dgcph.<phase>[.<part>][.b<bucket>]`` named scopes over the
+  whole step (params_view/plumbing/fwd_bwd/update.exchange/
+  update.optimizer/loss and the DGC pipeline's compensate/threshold/
+  select/pack/allgather/decode/apply/dense) — pure op metadata, zero new
+  ops or collectives; a device profile then attributes per-bucket
+  per-phase cost via dgc_tpu.telemetry.attrib or benchmark/trace_reduce;
+* the process-wide in-memory recorder: host spans where the work happens
+  (input.get_batch, input.queue_wait, input.stage, step.trace,
+  step.dispatch, step.drain, checkpoint.save, eval) and counts
+  (input.queue_depth, exchange.collective), written once at the end of
+  the run to <save_path>/trace_records.jsonl. With ``--profile`` every
+  span is also a ``dgc:<name>`` annotation in the profiler's own trace —
+  host spans beside the device lanes, one file, one clock.
 
-With this module absent the markers compile away byte-identically (the
-``trace-off-compiles-away`` contract in dgc_tpu/analysis/suite.py).
+With this module absent the scopes compile away byte-identically (the
+``trace-off-compiles-away`` contract in dgc_tpu/analysis/suite.py) and
+span()/count() return at once.
 """
 
 from dgc_tpu.utils.config import Config, configs
 
 configs.train.trace = Config()
 configs.train.trace.enabled = True
-# cap on in-memory host spans retained for the end-of-run trace.json
-# (the sink JSONL keeps everything regardless)
-configs.train.trace.max_events = 65536

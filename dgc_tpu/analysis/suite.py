@@ -307,13 +307,27 @@ def run_contract_suite(mesh=None, log: Callable[[str], None] = None,
         no_f64=True)
     run(gon.name, gon.check)
 
+    # the dense engine has its own memory/opt-state geometry: lower it
+    # against its own fixture state, not the DGC one (lowered here, with
+    # the markers off: it is the trace contracts' dense baseline)
+    state_d, step_dense, _, _ = build_fixture(mesh, compressor="none",
+                                              donate=False)
+    dense = _step_contract(
+        "dense-engine-no-gathers", state_d, step_dense, inputs,
+        collectives=DENSE_COLLECTIVES, no_f64=True)
+    run(dense.name, dense.check)
+
     # trace markers: lowering a fresh build while the phase markers are
     # ENABLED must add zero collectives (named scopes are pure metadata)
     # and the dgcph tokens must actually reach the compiled op metadata
     # (markers live in compiled op_name=..., not default StableHLO — so
     # this pin reads compiled text). Lowering is lazy: check() must run
-    # INSIDE the enable window.
+    # INSIDE the enable window. Both engines' builds pass through every
+    # scope the step opens: the parts of ``update``, ``params_view``,
+    # ``plumbing``, and the dense engine's own ``dense``.
     from dgc_tpu.telemetry import trace as _tr
+    step_scopes = ["dgcph.update.exchange", "dgcph.update.optimizer",
+                   "dgcph.params_view", "dgcph.plumbing", "dgcph.fwd_bwd"]
     prev_tr = _tr.enable(True)
     try:
         _, step_tron, _, _ = build_fixture(mesh, donate=False,
@@ -321,15 +335,25 @@ def run_contract_suite(mesh=None, log: Callable[[str], None] = None,
         tron = _step_contract(
             "trace-on-no-new-collectives", state, step_tron, inputs,
             collectives_delta=(plain, {"all-reduce": 0, "all-gather": 0}),
-            require_substrings_compiled=["dgcph."], no_f64=True)
+            require_substrings_compiled=step_scopes + ["dgcph.compensate"],
+            no_f64=True)
         run(tron.name, tron.check)
+        _, step_tron_dn, _, _ = build_fixture(mesh, compressor="none",
+                                              donate=False)
+        tron_dn = _step_contract(
+            "trace-on-no-new-collectives[dense]", state_d, step_tron_dn,
+            inputs,
+            collectives_delta=(dense, {"all-reduce": 0, "all-gather": 0}),
+            require_substrings_compiled=step_scopes + ["dgcph.dense"],
+            no_f64=True)
+        run(tron_dn.name, tron_dn.check)
     finally:
         _tr.enable(prev_tr)
 
     # trace off (the default): a fresh build after disable is
     # byte-identical to the plain build — phase() is Python-static, not a
     # traced no-op — and no dgcph token survives anywhere in the
-    # compiled module
+    # compiled module; the same for the dense engine's build
     _, step_troff, _, _ = build_fixture(mesh, donate=False,
                                         telemetry=False)
     troff = _step_contract(
@@ -337,6 +361,13 @@ def run_contract_suite(mesh=None, log: Callable[[str], None] = None,
         forbid_substrings_compiled=["dgcph."],
         identical_to=plain)
     run(troff.name, troff.check)
+    _, step_troff_dn, _, _ = build_fixture(mesh, compressor="none",
+                                           donate=False)
+    troff_dn = _step_contract(
+        "trace-off-compiles-away[dense]", state_d, step_troff_dn, inputs,
+        forbid_substrings_compiled=["dgcph."],
+        identical_to=dense)
+    run(troff_dn.name, troff_dn.check)
 
     # elastic=False must cost nothing: resharding lives entirely in the
     # restore path (resilience/elastic.py is host numpy), so a step built
@@ -358,15 +389,6 @@ def run_contract_suite(mesh=None, log: Callable[[str], None] = None,
         "donated-state-aliases-outputs", state, step_don, inputs,
         donation=[0])
     run(don.name, don.check)
-
-    # the dense engine has its own memory/opt-state geometry: lower it
-    # against its own fixture state, not the DGC one
-    state_d, step_dense, _, _ = build_fixture(mesh, compressor="none",
-                                              donate=False)
-    dense = _step_contract(
-        "dense-engine-no-gathers", state_d, step_dense, inputs,
-        collectives=DENSE_COLLECTIVES, no_f64=True)
-    run(dense.name, dense.check)
 
     # plan-matches-collectives: whatever regime mix the exchange planner
     # picks, its predicted collective counts (Plan.collectives) must

@@ -91,6 +91,16 @@ def _round_up(n: int, align: int) -> int:
     return -(-n // align) * align
 
 
+def _count_collective(kind: str, operand, axis, engine) -> None:
+    """One ``exchange.collective`` count where a collective is issued:
+    the bytes THIS worker hands it, from the traced operand's shape and
+    dtype — what the program puts on the wire, not what the planner
+    modeled (trace time only; returns at once with tracing off)."""
+    _trace.count("exchange.collective",
+                 int(operand.size) * operand.dtype.itemsize, kind=kind,
+                 axis=str(axis), engine=type(engine).__name__)
+
+
 class _BucketGeom(NamedTuple):
     """Ratio-independent geometry of one size bucket of compressed tensors:
     a [rows, cols] tile in the flat buffer starting at ``base``. Tensor
@@ -2091,6 +2101,7 @@ class FlatDGCEngine:
             from dgc_tpu.optim.adasum import adasum_allreduce
             return adasum_allreduce(block, axis_name, world_size)
         wire = (block.astype(jnp.float16) if self.c.fp16_values else block)
+        _count_collective("psum", wire, axis_name, self)
         total = jax.lax.psum(wire, axis_name).astype(block.dtype)
         return total / world_size if op == "average" else total
 
@@ -2161,6 +2172,7 @@ class FlatDGCEngine:
             # (optimizer.py:197-367) with each "sparsified node" acting as
             # one worker (Horovod's own hierarchical Adasum does the same:
             # in-node sum + normalize, Adasum across nodes).
+            _count_collective("psum", flat_grad, local_axis, self)
             flat_grad = jax.lax.psum(flat_grad, local_axis)
             if op in ("average", "adasum"):
                 flat_grad = flat_grad / local_size
@@ -2445,6 +2457,9 @@ class FlatDGCEngine:
         else:
             q_lane = q_wire if q_wire is not None else q4_wire
         with _trace.phase("allgather"):
+            for lane in (q_lane, f32_wire, f16_wire):
+                if lane is not None:
+                    _count_collective("all_gather", lane, axis_name, self)
             g_q = (jax.lax.all_gather(q_lane, axis_name)
                    if q_lane is not None else None)  # [W, i8+i4 bytes]
             g_f32 = (jax.lax.all_gather(f32_wire, axis_name)
@@ -2535,6 +2550,7 @@ class FlatDGCEngine:
                 words = (wparts[0] if len(wparts) == 1
                          else jnp.concatenate(wparts))
             with _trace.phase("allgather"):
+                _count_collective("all_gather", words, axis_name, self)
                 g_words = jax.lax.all_gather(words, axis_name)
             with _trace.phase("decode"):
                 nc = self._codec.nwords if self._codec is not None else 0
@@ -2554,6 +2570,7 @@ class FlatDGCEngine:
                     idx_wire = jnp.concatenate(
                         [idx_wire, chk.astype(self.index_dtype)])
             with _trace.phase("allgather"):
+                _count_collective("all_gather", idx_wire, axis_name, self)
                 g_idx_wire = jax.lax.all_gather(idx_wire, axis_name)
             with _trace.phase("decode"):
                 if checksum and self._codec is None:
@@ -2951,7 +2968,10 @@ class FlatDenseExchange:
         if op == "adasum":
             if local_axis is not None and local_size > 1:
                 # node-aggregated Adasum: the node mean is the participant
-                flat_grad = jax.lax.psum(flat_grad, local_axis) / local_size
+                with _trace.phase("dense"):
+                    _count_collective("psum", flat_grad, local_axis, self)
+                    flat_grad = (jax.lax.psum(flat_grad, local_axis)
+                                 / local_size)
             # full precision: fp16 dot/norm accumulations would overflow
             from dgc_tpu.optim.adasum import adasum_allreduce
             out = adasum_allreduce(flat_grad, axis_name, world_size)
@@ -2962,12 +2982,18 @@ class FlatDenseExchange:
             # applies to the cross-host link only, like the DGC engine.
             # Average divides BEFORE the wire cast — an undivided node sum
             # on an fp16 wire would overflow local_size x earlier.
-            flat_grad = jax.lax.psum(flat_grad, local_axis)
+            with _trace.phase("dense"):
+                _count_collective("psum", flat_grad, local_axis, self)
+                flat_grad = jax.lax.psum(flat_grad, local_axis)
             if op == "average":
                 flat_grad = flat_grad / local_size
         wire = self.c._wire(flat_grad)
-        total = self.c._unwire(jax.lax.psum(wire, axis_name),
-                               flat_grad.dtype)
+        # the gradient all-reduce DGC exists to replace: its own phase, as
+        # the engine's dense tail has, not filed under the step's update
+        with _trace.phase("dense"):
+            _count_collective("psum", wire, axis_name, self)
+            total = jax.lax.psum(wire, axis_name)
+        total = self.c._unwire(total, flat_grad.dtype)
         out = (total / world_size if op == "average" else total).astype(
             flat_grad.dtype)
         return (out, mem, stats) if telemetry else (out, mem)

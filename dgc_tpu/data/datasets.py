@@ -22,6 +22,7 @@ from typing import Dict, Optional, Sequence, Tuple
 import numpy as np
 
 from dgc_tpu.data.native import crop_flip_normalize
+from dgc_tpu.telemetry import trace as _trace
 
 __all__ = ["ArraySplit", "SyntheticSplit", "CIFAR", "ImageNet", "Synthetic",
            "CIFAR_MEAN", "CIFAR_STD", "IMAGENET_MEAN", "IMAGENET_STD"]
@@ -71,16 +72,18 @@ class ArraySplit:
 
     def get_batch(self, indices: np.ndarray
                   ) -> Tuple[np.ndarray, np.ndarray]:
-        imgs = self.images[indices]
-        if self.train and self.augment:
-            n = len(imgs)
-            ys = self._rng.randint(0, 2 * self.pad + 1, size=n)
-            xs = self._rng.randint(0, 2 * self.pad + 1, size=n)
-            flips = self._rng.randint(0, 2, size=n).astype(np.uint8)
-            return (crop_flip_normalize(imgs, ys, xs, flips, self.pad,
-                                        self.mean, self.std),
+        with _trace.span("input.get_batch", images=len(indices)):
+            imgs = self.images[indices]
+            if self.train and self.augment:
+                n = len(imgs)
+                ys = self._rng.randint(0, 2 * self.pad + 1, size=n)
+                xs = self._rng.randint(0, 2 * self.pad + 1, size=n)
+                flips = self._rng.randint(0, 2, size=n).astype(np.uint8)
+                return (crop_flip_normalize(imgs, ys, xs, flips, self.pad,
+                                            self.mean, self.std),
+                        self.labels[indices])
+            return (_normalize(imgs, self.mean, self.std),
                     self.labels[indices])
-        return _normalize(imgs, self.mean, self.std), self.labels[indices]
 
 
 class SyntheticSplit:
@@ -116,8 +119,9 @@ class SyntheticSplit:
         return len(self.images)
 
     def get_batch(self, indices: np.ndarray):
-        return (_normalize(self.images[indices], self.mean, self.std),
-                self.labels[indices])
+        with _trace.span("input.get_batch", images=len(indices)):
+            return (_normalize(self.images[indices], self.mean, self.std),
+                    self.labels[indices])
 
 
 def CIFAR(root: str, num_classes: int = 10, image_size: int = 32,

@@ -18,6 +18,7 @@ when no C toolchain is present; both are tested against the same oracle.
 """
 
 import ctypes
+import itertools
 import os
 import queue
 import subprocess
@@ -26,6 +27,8 @@ import threading
 from typing import Iterator, Optional, Tuple
 
 import numpy as np
+
+from dgc_tpu.telemetry import trace as _trace
 
 __all__ = ["crop_flip_normalize", "native_available", "Prefetcher",
            "stage_ahead"]
@@ -172,6 +175,11 @@ def crop_flip_normalize(images_u8: np.ndarray, ys: np.ndarray,
     return out
 
 
+def _nbytes(item) -> int:
+    parts = item if isinstance(item, (tuple, list)) else (item,)
+    return sum(getattr(a, "nbytes", 0) for a in parts)
+
+
 def stage_ahead(iterator, stage, depth: int = 1):
     """Keep ``depth`` staged items in flight ahead of the consumer.
 
@@ -182,11 +190,24 @@ def stage_ahead(iterator, stage, depth: int = 1):
     from collections import deque
     pending = deque()
     for item in iterator:
-        pending.append(stage(item))
+        with _trace.span("input.stage", seq=getattr(item, "seq", None),
+                         bytes=_nbytes(item)):
+            pending.append(stage(item))
         if len(pending) > depth:
             yield pending.popleft()
     while pending:
         yield pending.popleft()
+
+
+class _Batch(tuple):
+    """A batch as ``Prefetcher`` hands it on: the split's tuple, plus the
+    ``seq`` its producer gave it (``stage_ahead`` reads it, so one batch's
+    spans link from ``get_batch`` through the queue to staging)."""
+    seq = None
+
+
+#: batch ids, unique in the process (a run makes a ``Prefetcher`` per epoch)
+_SEQ = itertools.count()
 
 
 class Prefetcher:
@@ -214,8 +235,11 @@ class Prefetcher:
     def _fill(self, split, index_iter):
         try:
             for idx in index_iter:
-                if self._stop.is_set() or not self._put(
-                        ("item", split.get_batch(idx))):
+                seq = next(_SEQ)
+                with _trace.carry(seq=seq):
+                    batch = _Batch(split.get_batch(idx))
+                batch.seq = seq
+                if self._stop.is_set() or not self._put(("item", batch)):
                     return
         except BaseException as e:  # surface worker errors to the consumer
             self._put(("error", e))
@@ -236,7 +260,11 @@ class Prefetcher:
 
     def __iter__(self) -> Iterator[Tuple[np.ndarray, np.ndarray]]:
         while True:
-            kind, payload = self._q.get()
+            with _trace.span("input.queue_wait") as wait:
+                # what the consumer finds: 0 means it outran the producer
+                _trace.count("input.queue_depth", self._q.qsize())
+                kind, payload = self._q.get()
+                wait.set(seq=getattr(payload, "seq", None))
             if kind == "error":
                 raise payload
             if kind == "end":

@@ -72,7 +72,10 @@ from jax.experimental.pallas import tpu as pltpu
 # trace-off-compiles-away byte-identity contract. Their call sites in
 # compression/flat.py wrap them in phase("compensate") instead; the
 # caller's name stack prefixes nested-jit op names, so attribution sees
-# them either way.
+# them either way. Every ``pl.pallas_call`` passes ``name=`` (its jitted
+# kernel's own name, unique per site): the kernel's device events then
+# carry that name whatever scope calls it, and being unconditional it is
+# the same in trace-on and trace-off builds.
 from dgc_tpu.telemetry import trace as _trace
 
 __all__ = ["fused_compensate", "fused_compensate_reference",
@@ -210,6 +213,7 @@ def fused_compensate(grad: jax.Array, mmt: jax.Array, vec: jax.Array,
         # measured -3.6 ms/step at VGG, -0.5 at ResNet-50 (paired A/B)
         input_output_aliases={1: 0, 2: 1},
         interpret=_interpret(),
+        name="fused_compensate",
     )(g2, m2, v2)
     om, ov = om.reshape(-1), ov.reshape(-1)
     return (om[:n], ov[:n]) if pad else (om, ov)
@@ -314,6 +318,7 @@ def fused_compensate_masked(grad: jax.Array, mmt: jax.Array, vec: jax.Array,
         # in-place state update (see fused_compensate_bits)
         input_output_aliases={1: 0, 2: 1},
         interpret=_interpret(),
+        name="fused_compensate_masked",
     )(g2, m2, v2, k2)
     om, ov = om.reshape(-1), ov.reshape(-1)
     return (om[:n], ov[:n]) if pad else (om, ov)
@@ -585,6 +590,7 @@ def fused_compensate_bits(grad: jax.Array, mmt: jax.Array, vec: jax.Array,
         # carry otherwise pays
         input_output_aliases={1: 0, 2: 1},
         interpret=_interpret(),
+        name="fused_compensate_bits",
     )(g2, m2, v2, b2)
     om, ov = om.reshape(-1), ov.reshape(-1)
     return (om[:n], ov[:n]) if pad else (om, ov)
@@ -685,6 +691,7 @@ def ladder_counts(imp_rows: jax.Array, thr: jax.Array, lower_bound: float,
         out_specs=pl.BlockSpec((_SUBLANE, _LANE), lambda r, c: (r, 0),
                                memory_space=pltpu.VMEM),
         interpret=_interpret(),
+        name="ladder_counts",
     )(imp_rows, thr.reshape(-1, 1))
     return out[:R, :levels]
 
@@ -791,6 +798,7 @@ def topk_rows(x: jax.Array, k: int):
         in_specs=[spec_x],
         out_specs=(spec_o, spec_o),
         interpret=_interpret(),
+        name="topk_rows",
     )(x)
     return v[:R, :k], i[:R, :k]
 
@@ -910,6 +918,7 @@ def select_pack_rows(x: jax.Array, numels: jax.Array, k: int):
         in_specs=[spec_x, spec_n],
         out_specs=(spec_o, spec_o, spec_o),
         interpret=_interpret(),
+        name="select_pack_rows",
     )(x, numels.reshape(-1, 1))
     return s[:R, :k], v[:R, :k], i[:R, :k]
 
@@ -1036,6 +1045,7 @@ def _select_pack_rows_mr(x: jax.Array, numels: jax.Array, k: int):
         in_specs=[spec_x, spec_n],
         out_specs=(spec_o, spec_o, spec_o),
         interpret=_interpret(),
+        name="select_pack_rows_mr",
     )(x, numels.reshape(-1, 1))
     return s[:R, :k], v[:R, :k], i[:R, :k]
 
@@ -1172,6 +1182,7 @@ def seg_top2_candidates(v2d: jax.Array, base: int, rows: int, cols: int):
                          memory_space=pltpu.VMEM),
         ),
         interpret=_interpret(),
+        name="seg_top2_candidates",
     )(v2d)
     return (vals.reshape(rows, -1),
             seg_cols_local(blks.reshape(rows, nseg, 2, _LANE)))
@@ -1336,6 +1347,7 @@ def fused_compensate_bits_cands(grad: jax.Array, mmt: jax.Array,
         # in-place state update (see fused_compensate_bits)
         input_output_aliases={1: 0, 2: 1},
         interpret=_interpret(),
+        name="fused_compensate_bits_cands",
     )(g2, m2, v2, b2)
     return om.reshape(-1), ov.reshape(-1), cv, ci
 
@@ -1502,6 +1514,7 @@ def dgc_forward_rows(grad: jax.Array, mmt: jax.Array, vec: jax.Array,
         # count the scalar-prefetch operand first
         input_output_aliases={2: 0, 3: 1},
         interpret=_interpret(),
+        name="dgc_forward_rows",
     )(numels, g2, m2, v2, rb)
     return (om.reshape(-1), ov.reshape(-1),
             s[:, 0, :k], v[:, 0, :k], i[:, 0, :k])
@@ -1671,15 +1684,16 @@ def payload_apply_bits(values, indices, flags, total: int,
     unspecified scatter order — equal to f32 rounding. f32 values only
     (the engine gates). Returns ``(acc [total], bits
     [num_sent_words(total)])``."""
-    return _payload_apply_call(_payload_apply_kernel, values, indices,
-                               flags, total, bits_donor)
+    return _payload_apply_call(_payload_apply_kernel, "payload_apply_bits",
+                               values, indices, flags, total, bits_donor)
 
 
-def _payload_apply_call(kernel, values, indices, flags, total: int,
-                        bits_donor):
+def _payload_apply_call(kernel, name: str, values, indices, flags,
+                        total: int, bits_donor):
     """Shared staging + launch of the apply-epilogue kernels
     (:func:`payload_apply_bits` and :func:`dgc_apply_rows` differ only
-    in the kernel body's static divisor)."""
+    in the kernel body's static divisor and the ``name`` their device
+    events carry)."""
     n = values.shape[0]
     assert total % _LANE == 0, total
     assert indices.shape == (n,) and flags.shape == (n,)
@@ -1725,6 +1739,7 @@ def _payload_apply_call(kernel, values, indices, flags, total: int,
         # the dead previous-step record is rebuilt in place
         input_output_aliases={6: 1},
         interpret=_interpret(),
+        name=name,
     )(page_chunk, first, pcount, stage_v, stage_o, stage_f, bits_donor)
     return acc.reshape(-1), bits.reshape(-1)
 
@@ -1762,7 +1777,7 @@ def dgc_apply_rows(values, indices, flags, total: int, bits_donor=None,
         divisor = float(divisor)  # dgclint: ok[host-sync] — static by contract (the engine passes the Python world size), never a tracer
     return _payload_apply_call(
         functools.partial(_dgc_apply_kernel, divisor=divisor),
-        values, indices, flags, total, bits_donor)
+        "dgc_apply_rows", values, indices, flags, total, bits_donor)
 
 
 # ------------------------------------------------------------------ #
@@ -1791,6 +1806,7 @@ def _opaque_copy(x: jax.Array) -> jax.Array:
         out_shape=jax.ShapeDtypeStruct((rows, _LANE), flat.dtype),
         in_specs=[spec], out_specs=spec,
         interpret=_interpret(),
+        name="opaque_view",
     )(flat.reshape(rows, _LANE)).reshape(-1)
     return (out[:n] if pad else out).reshape(x.shape)
 
@@ -1885,6 +1901,7 @@ def _opaque_from(flat, base, numel, total):
         out_shape=jax.ShapeDtypeStruct((rows, _LANE), flat.dtype),
         in_specs=[spec_in], out_specs=spec_out,
         interpret=_interpret(),
+        name="opaque_view_from",
     )(flat.reshape(-1, _LANE))
     return out.reshape(-1)
 

@@ -27,6 +27,7 @@ import jax
 import optax
 
 from dgc_tpu.compression.base import Compressor
+from dgc_tpu.telemetry import trace as _trace
 from dgc_tpu.utils.pytree import named_flatten, named_unflatten
 
 __all__ = ["DistributedOptimizer"]
@@ -146,19 +147,24 @@ class DistributedOptimizer:
         checksum mismatch counter, see ``resilience.integrity``);
         ``send_frac`` forwards this worker's adaptive send fraction
         (``resilience.adaptive``; None is Python-static off)."""
-        if telemetry:
-            exchanged, mem_state, tstats = engine.exchange(
-                flat_grads, mem_state, key, self.axis_name, self.num_nodes,
-                local_axis=self.local_axis_name, local_size=self.local_size,
-                telemetry=True, health_out=health_out,
-                send_frac=send_frac)
-        else:
-            exchanged, mem_state = engine.exchange(
-                flat_grads, mem_state, key, self.axis_name, self.num_nodes,
-                local_axis=self.local_axis_name, local_size=self.local_size,
-                health_out=health_out, send_frac=send_frac)
-        updates, opt_state = self.optimizer.update(exchanged, opt_state,
-                                                   flat_params)
+        # parts of the step's ``update`` phase: the engine's own phases
+        # nest inside ``exchange``, and what they leave is its glue
+        with _trace.phase("update", part="exchange"):
+            if telemetry:
+                exchanged, mem_state, tstats = engine.exchange(
+                    flat_grads, mem_state, key, self.axis_name,
+                    self.num_nodes, local_axis=self.local_axis_name,
+                    local_size=self.local_size, telemetry=True,
+                    health_out=health_out, send_frac=send_frac)
+            else:
+                exchanged, mem_state = engine.exchange(
+                    flat_grads, mem_state, key, self.axis_name,
+                    self.num_nodes, local_axis=self.local_axis_name,
+                    local_size=self.local_size, health_out=health_out,
+                    send_frac=send_frac)
+        with _trace.phase("update", part="optimizer"):
+            updates, opt_state = self.optimizer.update(exchanged, opt_state,
+                                                       flat_params)
         if telemetry:
             return updates, opt_state, mem_state, tstats
         return updates, opt_state, mem_state
@@ -226,7 +232,9 @@ class DistributedOptimizer:
         """Full distributed update: exchange, then the wrapped optimizer
         (the reference's ``step()`` = synchronize + base step,
         optimizer.py:176-187)."""
-        exchanged, mem_state = self.exchange(grads, mem_state, key)
-        updates, opt_state = self.optimizer.update(exchanged, opt_state,
-                                                   params)
+        with _trace.phase("update", part="exchange"):
+            exchanged, mem_state = self.exchange(grads, mem_state, key)
+        with _trace.phase("update", part="optimizer"):
+            updates, opt_state = self.optimizer.update(exchanged, opt_state,
+                                                       params)
         return updates, opt_state, mem_state

@@ -16,9 +16,10 @@ Three layers, one schema (``registry``):
 
 Plus the tracing/postmortem layer (same sink, own schemas):
 
-* :mod:`dgc_tpu.telemetry.trace` — host-side span tracer (Chrome-trace/
-  Perfetto export through the sink) + device-side ``dgcph.*`` named-scope
-  phase markers, Python-static when off.
+* :mod:`dgc_tpu.telemetry.trace` — one switch, one in-memory recorder:
+  host spans and counts where the work happens (``dgc:*`` annotations in
+  the profiler's trace while a session is live) + device-side ``dgcph.*``
+  named-scope phase markers, Python-static when off.
 * :mod:`dgc_tpu.telemetry.attrib` — device-profile parsing: XLA ops →
   DGC phases/buckets via the markers; emits the per-bucket ``profile.json``
   cost table the exchange planner consumes.
@@ -42,11 +43,10 @@ from dgc_tpu.telemetry.registry import (
 from dgc_tpu.telemetry.flight import FlightRecorder, NonfiniteStreak
 from dgc_tpu.telemetry.sink import (SchemaMismatchError, TelemetrySink,
                                     read_run, summarize)
-from dgc_tpu.telemetry.trace import NULL_TRACER, SpanTracer
 
 __all__ = [
     "MetricSpec", "SCHEMA", "SCHEMA_VERSION", "STEP_METRICS", "RUN_METRICS",
     "make_header", "step_stat_names", "step_out_specs",
     "TelemetrySink", "SchemaMismatchError", "read_run", "summarize",
-    "SpanTracer", "NULL_TRACER", "FlightRecorder", "NonfiniteStreak",
+    "FlightRecorder", "NonfiniteStreak",
 ]

@@ -1,63 +1,69 @@
-"""Structured tracing: host-side spans + device-side phase markers.
+"""Structured tracing: one switch, one recorder (docs/TELEMETRY.md §Tracing).
 
-Two instruments, one switch (docs/TELEMETRY.md §Tracing):
+:func:`enable` (``--trace`` / ``configs/trace.py`` / ``DGC_TRACE=1``) turns
+on two instruments, and with it off neither leaves anything behind:
 
-* **Device phase markers** — :func:`phase` / :func:`phased` wrap the DGC
-  pipeline's stages (``compensate → threshold → select → pack →
-  allgather → decode → apply``, plus the step's ``fwd_bwd``/``update``/
-  ``loss`` regions) in ``jax.named_scope`` so every XLA op the stage
-  lowers carries a ``dgcph.<phase>[.b<bucket>]`` token in its
-  ``op_name`` metadata. A device profile (``jax.profiler.trace``) then
-  attributes each op to a phase and bucket — :mod:`telemetry.attrib`
-  does the aggregation. The markers are **Python-static**: with tracing
-  off (the default) :func:`phase` returns a nullcontext and the lowered
-  program is byte-identical to a build that never imported this module
-  (the ``trace-off-compiles-away`` contract in ``analysis/suite``);
-  with tracing on, scopes are pure metadata — zero new ops, zero new
-  collectives (``trace-on-no-new-collectives``).
+* **Device scopes** — :func:`phase` / :func:`phased` wrap the stages of the
+  step in ``jax.named_scope`` so every XLA op a stage lowers carries a
+  ``dgcph.<phase>[.<part>][.b<bucket>]`` token in its ``op_name`` metadata:
+  the DGC pipeline (``compensate → threshold → select → pack → allgather →
+  decode → apply``, ``dense``), the step's ``params_view``, ``plumbing``,
+  ``fwd_bwd``, ``update`` (parts ``exchange`` and ``optimizer``) and
+  ``loss``. A device profile then attributes each op to a phase and bucket
+  (``benchmark/trace_reduce.py``, :mod:`telemetry.attrib`; both read a
+  part token as its phase). The scopes are **Python-static**: off,
+  :func:`phase` returns a nullcontext and the lowered program is
+  byte-identical to a build that never imported this module (the
+  ``trace-off-compiles-away`` contract in ``analysis/suite``); on, they
+  are pure metadata — zero new ops, zero new collectives
+  (``trace-on-no-new-collectives``).
 
-* **Host spans** — :class:`SpanTracer` records wall-clock spans around
-  the harness's host work (data load, step dispatch, exchange wait,
-  checkpoint, eval) as Chrome-trace-event ``ph:"X"`` records. Completed
-  spans stream through the existing async :class:`telemetry.sink
-  .TelemetrySink` (``event: "span"`` records — the train loop never
-  blocks on trace I/O) and export as Perfetto-loadable Chrome-trace
-  JSON, either live (:meth:`SpanTracer.save`) or offline from a sink
-  JSONL (:func:`chrome_trace_from_records`, CLI below). When a device
-  profiler session is active, each span also opens a
-  ``jax.profiler.TraceAnnotation`` so host spans line up with device
-  lanes in the same Perfetto view.
-
-CLI: rebuild a Chrome trace from a telemetry JSONL run::
-
-    python -m dgc_tpu.telemetry.trace runs/telemetry.jsonl -o trace.json
+* **The recorder** — :func:`span`, :func:`count` and :func:`records` over
+  one process-wide, in-memory recorder that ``enable(True)`` creates.
+  Every layer reaches it as a module function, so spans (``input.*``,
+  ``step.*``, ``checkpoint.save``, ``eval``) and counts
+  (``input.queue_depth``, ``exchange.collective``) sit where the work
+  happens. A span records its name, start and end (``perf_counter_ns``),
+  thread, the id of the span that caused it and the ids its request
+  carries (``step``, ``seq``; inherited by what it causes); a count
+  belongs to the span open when it was made. While a ``jax.profiler``
+  session is live, and only then, a span also opens
+  ``jax.profiler.TraceAnnotation("dgc:" + name)``: the program's spans
+  land in the profiler's own trace, on its clock, beside the device lanes
+  (``train.py --trace --profile``). Off, :func:`span` returns one shared
+  null context and :func:`count` returns at once: no lock, no ``jax``.
 """
 
 import contextlib
 import functools
-import gzip
+import itertools
 import json
 import os
 import sys
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, Iterable, Iterator, List, Optional
+from typing import Any, Dict, List, Optional
 
-__all__ = ["PHASES", "SCOPE_PREFIX", "enabled", "enable", "phase",
-           "phased", "scope_name", "SpanTracer", "NULL_TRACER",
-           "chrome_trace_from_records", "validate_chrome_trace"]
+__all__ = ["PHASES", "SCOPE_PREFIX", "ANNOTATION_PREFIX", "enabled",
+           "enable", "phase", "phased", "scope_name", "span", "count",
+           "carry", "records", "step_summary", "step_annotation", "write"]
 
-#: canonical DGC phase vocabulary (attrib's table rows come out in this
+#: canonical phase vocabulary (attrib's table rows come out in this
 #: order; unknown tokens still aggregate — the list is not a gate)
 PHASES = ("compensate", "forward", "threshold", "select", "pack",
-          "allgather", "decode", "apply", "dense", "fwd_bwd", "update",
-          "loss")
+          "allgather", "decode", "apply", "dense", "params_view",
+          "plumbing", "fwd_bwd", "update", "loss")
 
-#: named-scope token prefix: scopes are ``dgcph.<phase>`` or
-#: ``dgcph.<phase>.b<bucket>`` — dots, not slashes, so one scope stays
-#: one path component of the op_name metadata
+#: named-scope token prefix: scopes are ``dgcph.<phase>``,
+#: ``dgcph.<phase>.<part>`` or ``dgcph.<phase>.b<bucket>`` — dots, not
+#: slashes, so one scope stays one path component of the op_name metadata
 SCOPE_PREFIX = "dgcph."
+#: prefix of the program's host annotations in a profiler trace
+ANNOTATION_PREFIX = "dgc:"
+MAX_RECORDS = 65536       # the recorder's ring: the newest records win
+#: ids a request carries from span to span (a train step; a batch)
+REQUEST_IDS = ("step", "seq")
 
 _ENABLED = os.environ.get("DGC_TRACE", "") == "1"
 
@@ -73,43 +79,56 @@ def _key_compile_cache_on_metadata(on: bool) -> None:
                       bool(on))
 
 
-if _ENABLED:
-    _key_compile_cache_on_metadata(True)
-
-
 def enabled() -> bool:
-    """Whether device phase markers trace into new programs."""
+    """Whether scopes trace into new programs and the recorder records."""
     return _ENABLED
 
 
 def enable(on: bool = True) -> bool:
-    """Flip the device-marker switch; returns the previous value.
+    """Flip the switch; returns the previous value. On creates the
+    recorder (an enabled process keeps the one it has), off drops it.
 
-    Takes effect at TRACE time: already-jitted programs keep their
-    compiled form (flip before ``build_train_step``)."""
-    global _ENABLED
+    The scopes take effect at TRACE time: already-jitted programs keep
+    their compiled form (flip before ``build_train_step``)."""
+    global _ENABLED, _RECORDER
     prev = _ENABLED
     _ENABLED = bool(on)
+    if not _ENABLED:
+        _RECORDER = None
+    elif _RECORDER is None:
+        _RECORDER = _Recorder()
     _key_compile_cache_on_metadata(_ENABLED)
     return prev
 
 
-def scope_name(name: str, bucket: int = -1) -> str:
-    """The named-scope token for a phase (``bucket < 0`` = no bucket)."""
-    return SCOPE_PREFIX + name + (f".b{bucket}" if bucket >= 0 else "")
+# ---------------------------------------------------------------------- #
+# device scopes                                                          #
+# ---------------------------------------------------------------------- #
+
+def scope_name(name: str, bucket: int = -1, part: Optional[str] = None
+               ) -> str:
+    """The named-scope token for a phase (``bucket < 0`` = no bucket).
+    A ``part`` names a region under the phase (``update.optimizer``);
+    readers parse ``.b<n>`` as the only suffix, so a part reads as its
+    phase and may not look like a bucket."""
+    if part is not None and (bucket >= 0 or not part.isalpha()):
+        raise ValueError(f"scope part {part!r}: letters only, and not "
+                         "together with a bucket")
+    return (SCOPE_PREFIX + name + (f".{part}" if part else "")
+            + (f".b{bucket}" if bucket >= 0 else ""))
 
 
-def phase(name: str, bucket: int = -1):
+def phase(name: str, bucket: int = -1, part: Optional[str] = None):
     """Device-side phase marker for use inside traced code.
 
     Off (default): a nullcontext — nothing traces, the compiled program
     is byte-identical to one that never called this. On: a
     ``jax.named_scope`` whose token lands in every enclosed op's
-    ``op_name`` metadata (attrib maps it back to phase/bucket)."""
+    ``op_name`` metadata (the readers map it back to phase/bucket)."""
     if not _ENABLED:
         return contextlib.nullcontext()
     import jax
-    return jax.named_scope(scope_name(name, bucket))
+    return jax.named_scope(scope_name(name, bucket, part))
 
 
 def phased(name: str):
@@ -125,216 +144,197 @@ def phased(name: str):
 
 
 # ---------------------------------------------------------------------- #
-# host spans                                                             #
+# the recorder                                                           #
 # ---------------------------------------------------------------------- #
 
-class SpanTracer:
-    """Host-side span recorder with Chrome-trace export.
+class _NullSpan:
+    """What :func:`span` and :func:`carry` return with tracing off."""
+    __slots__ = ()
 
-    Thread-safe; spans nest per-thread (each records its ``parent``).
-    ``sink`` — optional :class:`telemetry.sink.TelemetrySink`; completed
-    spans are enqueued as ``{"event": "span", ...}`` records (async, the
-    caller never blocks on I/O). The in-memory ring keeps the most
-    recent ``max_events`` spans for :meth:`save`/:meth:`chrome_trace`
-    and the per-step summary the flight recorder snapshots."""
+    def __enter__(self):
+        return self
 
-    def __init__(self, sink=None, max_events: int = 65536):
-        self._sink = sink
-        self._t0 = time.perf_counter()
-        self._events: deque = deque(maxlen=int(max_events))
+    def __exit__(self, *exc):
+        return False
+
+    def set(self, **args) -> None:
+        pass
+
+
+_NULL = _NullSpan()
+
+
+def _profiling():
+    """``jax.profiler`` while one of its sessions is live, else None (a
+    process that never imported jax has none, and stays without it)."""
+    jax = sys.modules.get("jax")
+    if jax is not None and jax.profiler.TraceAnnotation.is_enabled():
+        return jax.profiler
+    return None
+
+
+class _Carry:
+    """Request ids handed to what this thread opens next (:func:`carry`);
+    a span is a carry that also records itself."""
+    __slots__ = ("_rec", "args", "_ids", "_outer_ids")
+
+    def __init__(self, rec, args):
+        self._rec, self.args = rec, args
+
+    def __enter__(self):
+        th = self._rec.here()
+        self._outer_ids = th.ids
+        own = {k: self.args[k] for k in REQUEST_IDS if k in self.args}
+        self._ids = th.ids = {**th.ids, **own} if own else th.ids
+        return self
+
+    def __exit__(self, *exc):
+        self._rec.here().ids = self._outer_ids
+        return False
+
+
+class _Span(_Carry):
+    """One open span; ``with`` records it when it closes."""
+    __slots__ = ("name", "id", "_parent", "_t0", "_ann")
+
+    def __init__(self, rec, name, args):
+        super().__init__(rec, args)
+        self.name = name
+
+    def set(self, **args) -> None:
+        """Add what only the span's own work can tell (the ``seq`` of the
+        batch a queue handed over, the bytes staged)."""
+        self.args.update(args)
+
+    def __enter__(self):
+        super().__enter__()
+        stack = self._rec.here().stack
+        self.id = next(self._rec.ids)
+        self._parent = stack[-1] if stack else None
+        stack.append(self.id)
+        prof = _profiling()
+        self._ann = prof and prof.TraceAnnotation(
+            ANNOTATION_PREFIX + self.name, **{**self._ids, **self.args})
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        self._rec.here().stack.pop()
+        args = self.args
+        ids = {k: args.pop(k, self._ids.get(k)) for k in REQUEST_IDS}
+        self._rec.add({"kind": "span", "name": self.name, "id": self.id,
+                       "parent": self._parent,
+                       "thread": threading.get_ident(),
+                       "t0_ns": self._t0, "t1_ns": t1, **ids,
+                       "args": args}, ms=(t1 - self._t0) / 1e6)
+        return super().__exit__(*exc)
+
+
+class _Recorder:
+    """Spans and counts of one process, newest ``MAX_RECORDS`` kept."""
+
+    def __init__(self):
+        self.ids = itertools.count(1)       # next() is atomic in CPython
+        self._thread = threading.local()
         self._lock = threading.Lock()
-        self._stacks: Dict[int, List[str]] = {}
-        self._step_acc: Dict[str, float] = {}
+        self._ring: deque = deque(maxlen=MAX_RECORDS)
+        self._step_ms: Dict[str, float] = {}
 
-    def _now_us(self) -> float:
-        return (time.perf_counter() - self._t0) * 1e6
+    def here(self):
+        """This thread's ``stack`` of open span ids and request ``ids``."""
+        th = self._thread
+        if not hasattr(th, "stack"):
+            th.stack, th.ids = [], {}
+        return th
 
-    @contextlib.contextmanager
-    def span(self, name: str, **args):
-        """Record one wall-clock span; nests freely within a thread."""
-        tid = threading.get_ident()
+    def add(self, record: Dict[str, Any], ms: Optional[float] = None):
         with self._lock:
-            stack = self._stacks.setdefault(tid, [])
-            parent = stack[-1] if stack else None
-            stack.append(name)
-        # line host spans up with device lanes when a profiler session is
-        # live; lazy module lookup so a pure host consumer never imports jax
-        jax = sys.modules.get("jax")
-        ann = (jax.profiler.TraceAnnotation(f"host.{name}")
-               if jax is not None else contextlib.nullcontext())
-        t0 = self._now_us()
-        try:
-            with ann:
-                yield
-        finally:
-            dur = self._now_us() - t0
-            ev = {"name": name, "ph": "X", "ts": round(t0, 3),
-                  "dur": round(dur, 3), "pid": os.getpid(), "tid": tid,
-                  "args": dict(args)}
-            if parent is not None:
-                ev["args"]["parent"] = parent
-            with self._lock:
-                self._stacks[tid].pop()
-                self._events.append(ev)
-                self._step_acc[name] = (self._step_acc.get(name, 0.0)
-                                        + dur / 1e3)
-            if self._sink is not None:
-                self._sink.write_record({
-                    "event": "span", "name": name, "ts_us": ev["ts"],
-                    "dur_us": ev["dur"], "tid": tid, **ev["args"]})
+            self._ring.append(record)
+            if ms is not None:
+                self._step_ms[record["name"]] = self._step_ms.get(
+                    record["name"], 0.0) + ms
 
-    def wrap_iter(self, iterable: Iterable, name: str, **args) -> Iterator:
-        """Span each ``next()`` of an iterable (the data-load wait)."""
-        it = iter(iterable)
-        while True:
-            with self.span(name, **args):
-                try:
-                    v = next(it)
-                except StopIteration:
-                    return
-            yield v
+    def count(self, name, value, args):
+        th = self.here()
+        self.add({"kind": "count", "name": name, "value": value,
+                  "parent": th.stack[-1] if th.stack else None,
+                  "thread": threading.get_ident(),
+                  "t_ns": time.perf_counter_ns(),
+                  **{k: args.pop(k, th.ids.get(k)) for k in REQUEST_IDS},
+                  "args": args})
 
-    def step_summary(self, reset: bool = True) -> Dict[str, float]:
-        """Per-span-name total ms since the last summary (the flight
-        recorder stores one of these per step record)."""
+    def records(self) -> List[Dict[str, Any]]:
         with self._lock:
-            out = {k: round(v, 4) for k, v in self._step_acc.items()}
+            return list(self._ring)
+
+    def step_summary(self, reset: bool) -> Dict[str, float]:
+        with self._lock:
+            out = {k: round(v, 4) for k, v in self._step_ms.items()}
             if reset:
-                self._step_acc.clear()
+                self._step_ms.clear()
         return out
 
-    def events(self) -> List[Dict]:
-        with self._lock:
-            return list(self._events)
 
-    def chrome_trace(self) -> Dict:
-        """Perfetto-loadable Chrome-trace-event JSON object."""
-        return _chrome_obj(self.events())
-
-    def save(self, path: str) -> str:
-        """Atomically write the Chrome trace (``.gz`` suffix gzips)."""
-        return _write_json(self.chrome_trace(), path)
+_RECORDER: Optional[_Recorder] = None
+if _ENABLED:
+    enable(True)
 
 
-class _NullTracer:
-    """Do-nothing stand-in so harness code never branches per call."""
-
-    def span(self, name: str, **args):
-        return contextlib.nullcontext()
-
-    def wrap_iter(self, iterable, name, **args):
-        return iter(iterable)
-
-    def step_summary(self, reset: bool = True) -> Dict[str, float]:
-        return {}
-
-    def events(self) -> List[Dict]:
-        return []
-
-    def save(self, path: str) -> Optional[str]:
-        return None
+def span(name: str, **args):
+    """Record one host span, ``<layer>.<what>``; nests freely within a
+    thread. ``step=`` / ``seq=`` are the request's ids: what the span
+    causes on its thread inherits them. ``with span(...) as s`` gives
+    ``s.set(**args)`` for what is known only inside."""
+    rec = _RECORDER
+    return _NULL if rec is None else _Span(rec, name, args)
 
 
-NULL_TRACER = _NullTracer()
+def carry(**ids):
+    """Hand request ids to the spans a call opens on this thread without
+    a span of one's own (the producer's ``seq`` into ``get_batch``)."""
+    rec = _RECORDER
+    return _NULL if rec is None else _Carry(rec, ids)
 
 
-# ---------------------------------------------------------------------- #
-# Chrome-trace assembly / validation                                     #
-# ---------------------------------------------------------------------- #
-
-def _chrome_obj(events: List[Dict]) -> Dict:
-    pid = events[0]["pid"] if events else os.getpid()
-    meta = [{"ph": "M", "pid": pid, "tid": 0, "name": "process_name",
-             "args": {"name": "dgc-host"}}]
-    for tid in sorted({e["tid"] for e in events}):
-        meta.append({"ph": "M", "pid": pid, "tid": tid,
-                     "name": "thread_name",
-                     "args": {"name": f"host-thread-{tid}"}})
-    return {"displayTimeUnit": "ms", "traceEvents": meta + list(events)}
+def count(name: str, value, **args) -> None:
+    """Record one count; it belongs to the span open on this thread."""
+    rec = _RECORDER
+    if rec is not None:
+        rec.count(name, value, args)
 
 
-def chrome_trace_from_records(records: List[Dict]) -> Dict:
-    """Rebuild a Chrome trace from sink JSONL ``event: "span"`` records
-    (the async-sink export path: spans stream to JSONL during the run,
-    this converts offline)."""
-    events = []
-    for r in records:
-        if r.get("event") != "span":
-            continue
-        args = {k: v for k, v in r.items()
-                if k not in ("event", "name", "ts_us", "dur_us", "tid",
-                             "t_host")}
-        events.append({"name": r["name"], "ph": "X",
-                       "ts": float(r["ts_us"]), "dur": float(r["dur_us"]),
-                       "pid": os.getpid(), "tid": int(r.get("tid", 0)),
-                       "args": args})
-    events.sort(key=lambda e: e["ts"])
-    return _chrome_obj(events)
+def records() -> List[Dict[str, Any]]:
+    """The recorder's spans (in the order they closed) and counts."""
+    rec = _RECORDER
+    return [] if rec is None else rec.records()
 
 
-def validate_chrome_trace(obj: Dict) -> List[str]:
-    """Schema check for the exported trace (tests + a cheap guard before
-    handing a file to Perfetto). Returns violation strings; [] = valid."""
-    out = []
-    if not isinstance(obj.get("traceEvents"), list):
-        return ["traceEvents: missing or not a list"]
-    for i, ev in enumerate(obj["traceEvents"]):
-        ph = ev.get("ph")
-        if ph not in ("X", "M", "B", "E", "i"):
-            out.append(f"event {i}: bad ph {ph!r}")
-            continue
-        if not isinstance(ev.get("name"), str):
-            out.append(f"event {i}: name must be a string")
-        for k in ("pid", "tid"):
-            if not isinstance(ev.get(k), int):
-                out.append(f"event {i}: {k} must be an int")
-        if ph == "X":
-            for k in ("ts", "dur"):
-                v = ev.get(k)
-                if not isinstance(v, (int, float)) or v < 0:
-                    out.append(f"event {i}: {k} must be a number >= 0")
-    return out
+def step_summary(reset: bool = True) -> Dict[str, float]:
+    """Per-span-name total ms since the last summary (the flight
+    recorder stores one of these per step record)."""
+    rec = _RECORDER
+    return {} if rec is None else rec.step_summary(reset)
 
 
-def _write_json(obj: Dict, path: str) -> str:
-    """Atomic JSON write (tmp + rename; ``.gz`` suffix gzips)."""
-    d = os.path.dirname(os.path.abspath(path))
-    os.makedirs(d, exist_ok=True)
-    tmp = path + ".tmp"
-    if path.endswith(".gz"):
-        with gzip.open(tmp, "wt") as fh:
-            json.dump(obj, fh)
-    else:
-        with open(tmp, "w") as fh:
-            json.dump(obj, fh)
-    os.replace(tmp, path)
-    return path
+def step_annotation(step: int):
+    """``StepTraceAnnotation("dgc:step")`` while a profiler session is
+    live, so the profile groups device work by train step; else null."""
+    prof = _profiling() if _RECORDER is not None else None
+    return _NULL if prof is None else prof.StepTraceAnnotation(
+        ANNOTATION_PREFIX + "step", step_num=int(step))
 
 
-def _main(argv=None) -> int:
-    import argparse
-    ap = argparse.ArgumentParser(
-        prog="python -m dgc_tpu.telemetry.trace",
-        description="rebuild a Perfetto-loadable Chrome trace from a "
-                    "telemetry JSONL run's span records")
-    ap.add_argument("run", help="telemetry .jsonl file")
-    ap.add_argument("-o", "--out", default="trace.json",
-                    help="output Chrome-trace JSON (default trace.json)")
-    args = ap.parse_args(argv)
-    from dgc_tpu.telemetry import sink as _sink
-    _, records = _sink.read_run(args.run)
-    obj = chrome_trace_from_records(records)
-    n = sum(1 for e in obj["traceEvents"] if e.get("ph") == "X")
-    bad = validate_chrome_trace(obj)
-    if bad:
-        for b in bad:
-            print(f"trace: {b}", file=sys.stderr)
-        return 2
-    _write_json(obj, args.out)
-    print(f"wrote {args.out}: {n} spans "
-          f"(open at https://ui.perfetto.dev)")
-    return 0
-
-
-if __name__ == "__main__":
-    raise SystemExit(_main())
+def write(path: str) -> int:
+    """The records as JSON lines, written once (tmp + rename) when the
+    run ends; returns how many."""
+    recs = records()
+    os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
+    with open(path + ".tmp", "w") as fh:
+        fh.writelines(json.dumps(r, default=str) + "\n" for r in recs)
+    os.replace(path + ".tmp", path)
+    return len(recs)

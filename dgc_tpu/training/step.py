@@ -264,16 +264,20 @@ def build_train_step(apply_fn: Callable, dist_opt: DistributedOptimizer,
         def do_update(grads, params, opt_state, memory, key,
                       send_frac=None):
             health = {} if want_health else None
+            tstats = None
             if telemetry:
                 upd, opt_state, memory, tstats = dist_opt.update_flat(
                     grads, opt_state, params, memory, key, engine,
                     telemetry=True, health_out=health,
                     send_frac=send_frac)
+            else:
+                upd, opt_state, memory = dist_opt.update_flat(
+                    grads, opt_state, params, memory, key, engine,
+                    health_out=health, send_frac=send_frac)
+            # the add is the root of the optimizer's fusion, and a fusion
+            # carries its root's scope: without it the part reads nothing
+            with _trace.phase("update", part="optimizer"):
                 return params + upd, opt_state, memory, tstats, health
-            upd, opt_state, memory = dist_opt.update_flat(
-                grads, opt_state, params, memory, key, engine,
-                health_out=health, send_frac=send_frac)
-            return params + upd, opt_state, memory, None, health
     else:
         unpack_params = unpack_stats = pack_grads = pack_stats = (
             lambda x: x)
@@ -283,73 +287,77 @@ def build_train_step(apply_fn: Callable, dist_opt: DistributedOptimizer,
             del send_frac   # per-tensor path: adaptive requires flat
             upd, opt_state, memory = dist_opt.update(
                 grads, opt_state, params, memory, key)
-            return (optax.apply_updates(params, upd), opt_state, memory,
-                    None, None)
+            with _trace.phase("update", part="optimizer"):
+                return (optax.apply_updates(params, upd), opt_state, memory,
+                        None, None)
 
     per_worker_opt = dist_opt.per_worker_opt_state
 
     def worker(state: TrainState, images, labels, key, clock=None):
-        if (flat is not None and model_dtype is None
-                and getattr(dist_opt.compressor, "attributes", None)):
-            # break XLA's view of the per-tensor params as one [P]
-            # source: its auto-bf16 conv precision hoists the weight
-            # conversions into whole-buffer converted copies in the DGC
-            # build (~2.9 ms/step at VGG, r5 device profile + optimized
-            # HLO) while fusing them per-conv in the dense build. Views
-            # the simplifier can rewrite as slice(reshape(P)) get a real
-            # custom-call boundary (opaque_view — barriers are stripped
-            # before the late pass that forms the whole-buffer
-            # converts); the rest keep the cheaper optimization_barrier,
-            # which recovers a further ~0.4 ms by itself. The
-            # model_dtype path does its own single cast and never reads
-            # this tree.
-            lay = flat.layout
-            risky = lay.convert_hoist_risky()
+        with _trace.phase("params_view"):
+            if (flat is not None and model_dtype is None
+                    and getattr(dist_opt.compressor, "attributes", None)):
+                # break XLA's view of the per-tensor params as one [P]
+                # source: its auto-bf16 conv precision hoists the weight
+                # conversions into whole-buffer converted copies in the DGC
+                # build (~2.9 ms/step at VGG, r5 device profile + optimized
+                # HLO) while fusing them per-conv in the dense build. Views
+                # the simplifier can rewrite as slice(reshape(P)) get a real
+                # custom-call boundary (opaque_view — barriers are stripped
+                # before the late pass that forms the whole-buffer
+                # converts); the rest keep the cheaper optimization_barrier,
+                # which recovers a further ~0.4 ms by itself. The
+                # model_dtype path does its own single cast and never reads
+                # this tree.
+                lay = flat.layout
+                risky = lay.convert_hoist_risky()
 
-            def guard(n, a, fp=state.params):
-                if n not in risky:
-                    return jax.lax.optimization_barrier(a)
-                base, size = lay.offsets[n], lay.sizes[n]
-                if kernels.opaque_view_eligible(lay.total, base, size):
-                    # streamed straight from the flat buffer — the
-                    # sliced operand form pays a second materialized
-                    # tensor-sized copy
-                    return kernels.opaque_view_from(
-                        fp, base, size).reshape(lay.shapes[n])
-                return kernels.opaque_view(a)
+                def guard(n, a, fp=state.params):
+                    if n not in risky:
+                        return jax.lax.optimization_barrier(a)
+                    base, size = lay.offsets[n], lay.sizes[n]
+                    if kernels.opaque_view_eligible(lay.total, base, size):
+                        # streamed straight from the flat buffer — the
+                        # sliced operand form pays a second materialized
+                        # tensor-sized copy
+                        return kernels.opaque_view_from(
+                            fp, base, size).reshape(lay.shapes[n])
+                    return kernels.opaque_view(a)
 
-            params = lay.unflatten(state.params, transform=guard)
-        else:
-            params = unpack_params(state.params)
-        memory = _squeeze0(state.memory)
-        packed_stats = _squeeze0(state.batch_stats)
+                params = lay.unflatten(state.params, transform=guard)
+            else:
+                params = unpack_params(state.params)
+        with _trace.phase("plumbing"):
+            memory = _squeeze0(state.memory)
+            packed_stats = _squeeze0(state.batch_stats)
 
-        if len(axes) == 1:
-            widx = jax.lax.axis_index(axes[0])
-            key = jax.random.fold_in(key, widx)
-            dropout_key, sparsify_key = jax.random.split(key)
-        else:
-            # two-tier: dropout differs per worker; the SPARSIFY key is
-            # shared within a local group — every worker of a node holds the
-            # identical node-aggregated gradient and must make the identical
-            # selection, or the replicated (P()) outputs would diverge
-            nidx = jax.lax.axis_index(axes[0])
-            widx = nidx * local_size + jax.lax.axis_index(axes[1])
-            dropout_key = jax.random.split(
-                jax.random.fold_in(key, widx))[0]
-            sparsify_key = jax.random.split(
-                jax.random.fold_in(key, world + nidx))[1]
+            if len(axes) == 1:
+                widx = jax.lax.axis_index(axes[0])
+                key = jax.random.fold_in(key, widx)
+                dropout_key, sparsify_key = jax.random.split(key)
+            else:
+                # two-tier: dropout differs per worker; the SPARSIFY key
+                # is shared within a local group — every worker of a node
+                # holds the identical node-aggregated gradient and must
+                # make the identical selection, or the replicated (P())
+                # outputs would diverge
+                nidx = jax.lax.axis_index(axes[0])
+                widx = nidx * local_size + jax.lax.axis_index(axes[1])
+                dropout_key = jax.random.split(
+                    jax.random.fold_in(key, widx))[0]
+                sparsify_key = jax.random.split(
+                    jax.random.fold_in(key, world + nidx))[1]
 
-        if adaptive is not None:
-            # this worker's send fraction: LAST step's replicated policy
-            # verdict, carried in the donated state (one-step feedback —
-            # no extra collective; the verdict below refreshes it)
-            frac = state.adaptive["w_frac"][widx]
-        else:
-            frac = None
+            if adaptive is not None:
+                # this worker's send fraction: LAST step's replicated policy
+                # verdict, carried in the donated state (one-step feedback —
+                # no extra collective; the verdict below refreshes it)
+                frac = state.adaptive["w_frac"][widx]
+            else:
+                frac = None
 
-        mb_images = images.reshape((nbps, -1) + images.shape[1:])
-        mb_labels = labels.reshape((nbps, -1))
+            mb_images = images.reshape((nbps, -1) + images.shape[1:])
+            mb_labels = labels.reshape((nbps, -1))
 
         if flat is not None and model_dtype is not None:
             # mixed precision over the flat buffer: differentiate w.r.t.
@@ -383,7 +391,8 @@ def build_train_step(apply_fn: Callable, dist_opt: DistributedOptimizer,
                         i + 1), None
 
         stats0, memory0 = packed_stats, memory
-        zeros = jax.tree.map(jnp.zeros_like, state.params)
+        with _trace.phase("plumbing"):
+            zeros = jax.tree.map(jnp.zeros_like, state.params)
         with _trace.phase("fwd_bwd"):
             (grads, packed_stats, loss, _), _ = jax.lax.scan(
                 micro, (zeros, packed_stats, jnp.zeros((), jnp.float32),
@@ -394,8 +403,9 @@ def build_train_step(apply_fn: Callable, dist_opt: DistributedOptimizer,
             # identity — zero ops — when DGC_FAULTS is unset)
             grads = _faults.inject_nan_grads(grads, state.step)
 
-        opt_state0 = (_squeeze0(state.opt_state) if per_worker_opt
-                      else state.opt_state)
+        with _trace.phase("plumbing"):
+            opt_state0 = (_squeeze0(state.opt_state) if per_worker_opt
+                          else state.opt_state)
         with _trace.phase("update"):
             new_params, opt_state, memory, tstats, health = do_update(
                 grads, state.params, opt_state0, memory, sparsify_key,
@@ -471,18 +481,23 @@ def build_train_step(apply_fn: Callable, dist_opt: DistributedOptimizer,
         else:
             gstate = state.guards
 
-        new_state = TrainState(
-            step=state.step + 1,
-            params=new_params,
-            opt_state=(_expand0(opt_state) if per_worker_opt
-                       else opt_state),
-            memory=_expand0(memory),
-            batch_stats=_expand0(packed_stats),
-            guards=gstate,
-            adaptive=new_adaptive,
-        )
+        with _trace.phase("plumbing"):
+            new_state = TrainState(
+                step=state.step + 1,
+                params=new_params,
+                opt_state=(_expand0(opt_state) if per_worker_opt
+                           else opt_state),
+                memory=_expand0(memory),
+                batch_stats=_expand0(packed_stats),
+                guards=gstate,
+                adaptive=new_adaptive,
+            )
         return new_state, metrics
 
+    # the step's Python body runs while jit traces it, and only then: the
+    # span times the tracing and owns every count made under it
+    traced_as = {"compressor": type(dist_opt.compressor).__name__,
+                 "flat": flat is not None}
     metric_specs = {"loss": P()}
     if telemetry:
         from dgc_tpu.telemetry import registry
@@ -496,25 +511,27 @@ def build_train_step(apply_fn: Callable, dist_opt: DistributedOptimizer,
 
         @partial(jax.jit, donate_argnums=(0,) if donate else ())
         def step_fn(state, images, labels, key, clock):
-            specs = state_specs(state, axes, per_worker_opt)
-            sharded = shard_map(
-                worker, mesh=mesh,
-                in_specs=(specs, P(axes), P(axes), P(), P(axes)),
-                out_specs=(specs, metric_specs),
-                check_vma=False)
-            return sharded(state, images, labels, key, clock)
+            with _trace.span("step.trace", **traced_as):
+                specs = state_specs(state, axes, per_worker_opt)
+                sharded = shard_map(
+                    worker, mesh=mesh,
+                    in_specs=(specs, P(axes), P(axes), P(), P(axes)),
+                    out_specs=(specs, metric_specs),
+                    check_vma=False)
+                return sharded(state, images, labels, key, clock)
 
         return step_fn
 
     @partial(jax.jit, donate_argnums=(0,) if donate else ())
     def step_fn(state, images, labels, key):
-        specs = state_specs(state, axes, per_worker_opt)
-        sharded = shard_map(
-            worker, mesh=mesh,
-            in_specs=(specs, P(axes), P(axes), P()),
-            out_specs=(specs, metric_specs),
-            check_vma=False)
-        return sharded(state, images, labels, key)
+        with _trace.span("step.trace", **traced_as):
+            specs = state_specs(state, axes, per_worker_opt)
+            sharded = shard_map(
+                worker, mesh=mesh,
+                in_specs=(specs, P(axes), P(axes), P()),
+                out_specs=(specs, metric_specs),
+                check_vma=False)
+            return sharded(state, images, labels, key)
 
     return step_fn
 

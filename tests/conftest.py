@@ -34,3 +34,15 @@ def mesh8():
     from dgc_tpu.parallel import make_mesh
     assert len(jax.devices()) >= 8, "conftest failed to create 8 CPU devices"
     return make_mesh(8)
+
+
+@pytest.fixture
+def rec():
+    """``dgc_tpu.telemetry.trace`` switched on with a fresh recorder; the
+    switch goes back afterwards."""
+    from dgc_tpu.telemetry import trace
+    prev = trace.enable(False)
+    trace.enable(True)
+    yield trace
+    trace.enable(False)
+    trace.enable(prev)

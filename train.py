@@ -100,11 +100,12 @@ def main(argv=None):
                              "(after the first, compiling one) to "
                              "<save_path>/profile")
     parser.add_argument("--trace", action="store_true",
-                        help="structured tracing: host-side spans + "
-                             "device-side dgcph.* phase markers, saved as "
-                             "a Perfetto-loadable <save_path>/trace.json "
-                             "(docs/TELEMETRY.md §Tracing); same as "
-                             "stacking configs/trace.py")
+                        help="structured tracing: device-side dgcph.* "
+                             "scopes + the in-memory recorder of host "
+                             "spans and counts (with --profile: dgc:* "
+                             "annotations in the profile, one file, one "
+                             "clock; docs/TELEMETRY.md §Tracing); same "
+                             "as stacking configs/trace.py")
     parser.add_argument("--elastic", action="store_true",
                         help="allow resuming under a different world size: "
                              "reshard the per-worker DGC state "
@@ -606,22 +607,15 @@ def main(argv=None):
     prev_dispatch = None
 
     # structured tracing (configs/trace.py or --trace, docs/TELEMETRY.md
-    # §Tracing): device-side dgcph.* phase markers must be enabled BEFORE
-    # the step builds below (they bake into the program at trace time);
-    # host-side spans stream through the telemetry sink and are saved as
-    # a Chrome trace at the end of the run
+    # §Tracing): the one switch must flip BEFORE the step builds below
+    # (the dgcph.* scopes bake into the program at trace time). Host spans
+    # and counts go to the process-wide recorder from where the work
+    # happens; with --profile they are dgc:* annotations in the profile
     from dgc_tpu.telemetry import trace as _trace
     trccfg = configs.train.get("trace", None)
     trace_on = bool(args.trace or (trccfg and trccfg.get("enabled", False)))
-    tracer = _trace.NULL_TRACER
     if trace_on:
         _trace.enable(True)
-        tracer = _trace.SpanTracer(
-            sink=sink,
-            max_events=int(trccfg.get("max_events", 65536)) if trccfg
-            else 65536)
-        printr("[trace] device phase markers on; host spans -> "
-               + os.path.join(configs.train.save_path, "trace.json"))
 
     # host-side resilience: signal -> flag (the loop does the emergency
     # save at a step boundary); watchdog dumps stacks on a stalled step;
@@ -770,10 +764,6 @@ def main(argv=None):
                 batches,
                 lambda b: (host_local_to_global(b[0], mesh),
                            host_local_to_global(b[1], mesh)))
-            # span each next(): time the loop spends WAITING on batch
-            # prep + host->device staging (a hot data_load lane in the
-            # trace means the input pipeline is the bottleneck)
-            staged = tracer.wrap_iter(staged, "data_load")
             for rel_idx, (images, labels) in enumerate(staged):
                 bidx = bofs + rel_idx
                 # preemption check at the step boundary: agree_preempt is
@@ -816,7 +806,8 @@ def main(argv=None):
                 # span covers DISPATCH only (async jax: the call returns
                 # as soon as the step is enqueued) — device-side time
                 # lives in the profiler trace, not here
-                with tracer.span("step_dispatch", step=gstep):
+                with _trace.span("step.dispatch", step=gstep), \
+                        _trace.step_annotation(gstep):
                     if fleet_on:
                         # deterministic straggler drill (DGC_FAULTS=
                         # slow:ms=M on ONE process): sleep BEFORE the
@@ -877,7 +868,7 @@ def main(argv=None):
                         num_inputs=num_inputs,
                         loss=metrics["loss"],
                         guards=metrics.get("guards"),
-                        spans_ms=tracer.step_summary(),
+                        spans_ms=_trace.step_summary(),
                         last_ckpt_epoch=last_ckpt_epoch)
                 if watchdog is not None:
                     watchdog.beat()
@@ -919,9 +910,9 @@ def main(argv=None):
             if not logged:
                 loss_log.append((num_inputs, metrics["loss"]))
             # the drain is the epoch's one host sync: it waits for every
-            # enqueued step (exchange included) to complete — hence the
-            # span name. The streak breaker taps each converted loss.
-            with tracer.span("exchange_wait", epoch=epoch):
+            # enqueued step (exchange included) to complete. The streak
+            # breaker taps each converted loss.
+            with _trace.span("step.drain", epoch=epoch):
                 loss = drain_loss_log(
                     writer, loss_log,
                     on_loss=streak.update if streak is not None else None)
@@ -959,7 +950,7 @@ def main(argv=None):
                        f" alpha {autotuner.fabric.alpha_ms:.3g} ms — plan "
                        f"unchanged (no recompile)")
 
-        with tracer.span("eval", epoch=epoch):
+        with _trace.span("eval", epoch=epoch):
             meters = evaluate(state)
         best = False
         if configs.train.get("metric") is not None:
@@ -971,7 +962,7 @@ def main(argv=None):
             printr(f"[{k}] = {v:.2f}")
             writer.add_scalar(k, v, num_inputs)
 
-        with tracer.span("checkpoint", epoch=epoch):
+        with _trace.span("checkpoint.save", epoch=epoch):
             path = ckpt.save(epoch, state, meters, best=best,
                              topology=topology)
         last_ckpt_epoch = epoch
@@ -1032,11 +1023,9 @@ def main(argv=None):
             _surgery.clear_order(surgeon.order_path)
 
     if trace_on:
-        tpath = tracer.save(
-            os.path.join(configs.train.save_path, "trace.json"))
-        if tpath:
-            printr(f"[trace] chrome trace -> {tpath}  "
-                   "(load at ui.perfetto.dev)")
+        # the recorder's spans and counts, once, now that the run is over
+        _trace.write(os.path.join(configs.train.save_path,
+                                  "trace_records.jsonl"))
     if sink is not None:
         sink.close()
     writer.close()
