@@ -14,10 +14,11 @@ What it enables (``dgc_tpu.telemetry.trace.enable``, the one switch):
 * the process-wide in-memory recorder: host spans where the work happens
   (input.get_batch, input.queue_wait, input.stage, step.trace,
   step.dispatch, step.drain, checkpoint.save, eval) and counts
-  (input.queue_depth, exchange.collective), written once at the end of
-  the run to <save_path>/trace_records.jsonl. With ``--profile`` every
-  span is also a ``dgc:<name>`` annotation in the profiler's own trace —
-  host spans beside the device lanes, one file, one clock.
+  (input.queue_depth, exchange.collective, optimizer.wd_mask), written
+  once at the end of the run to <save_path>/trace_records.jsonl. With
+  ``--profile`` every span is also a ``dgc:<name>`` annotation in the
+  profiler's own trace — host spans beside the device lanes, one file,
+  one clock.
 
 With this module absent the scopes compile away byte-identically (the
 ``trace-off-compiles-away`` contract in dgc_tpu/analysis/suite.py) and

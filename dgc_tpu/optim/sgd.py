@@ -14,12 +14,25 @@ differ only in the gradient path.
 
 Both take ``lr`` as a float or a ``step -> lr`` schedule (the harness drives
 per-step warm-up through it, SURVEY.md §2.10) and an optional
-``weight_decay_mask`` pytree/callable marking which parameters receive weight
-decay (the reference's ``optimize_bn_separately`` puts BN params in a wd=0
-group, train.py:121-125). Mask leaves may be booleans (whole-tensor groups,
-like the reference's param groups) or 0/1 *arrays* — the latter supports the
-flat-buffer path where all parameters live in one [P] array and the BN split
-becomes a per-coordinate mask (``ParamLayout.mask_vector``).
+``weight_decay_mask`` marking which parameters receive weight decay (the
+reference's ``optimize_bn_separately`` puts BN params in a wd=0 group,
+train.py:121-125): a pytree, or a callable ``params -> pytree`` evaluated
+inside the trace. A mask leaf is one of
+
+* a Python bool — a whole-tensor group, like the reference's param groups;
+  the wd=0 branch is then dropped at trace time;
+* a boolean or 0/1 array built **inside** the trace — the flat-buffer
+  path, where every parameter lives in one [P] array and the BN split is
+  per coordinate. ``ParamLayout.mask_vector`` returns such a callable
+  (``flat.LayoutMask``): it builds the mask from an iota and a few range
+  compares, which fuse into the update, so the optimizer's one HBM-bound
+  fusion streams p, buf and g and nothing else;
+* a [P] array closed over by the step — supported, and the slow form: it
+  is a compile-time constant, XLA folds every expression below that uses
+  it (``wd * m``, ``m``, ``1 - m``) into a [P] constant of its own, and
+  the fusion streams each of them from HBM beside p, buf and g (three in
+  ``dgc_sgd``, one in ``sgd``: +0.43 and +0.13 ms per step at ResNet-50
+  on a v5e, PERF.md §6, PR 25).
 """
 
 from typing import Any, Callable, NamedTuple, Union

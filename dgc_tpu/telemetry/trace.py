@@ -22,10 +22,11 @@ on two instruments, and with it off neither leaves anything behind:
   one process-wide, in-memory recorder that ``enable(True)`` creates.
   Every layer reaches it as a module function, so spans (``input.*``,
   ``step.*``, ``checkpoint.save``, ``eval``) and counts
-  (``input.queue_depth``, ``exchange.collective``) sit where the work
-  happens. A span records its name, start and end (``perf_counter_ns``),
-  thread, the id of the span that caused it and the ids its request
-  carries (``step``, ``seq``; inherited by what it causes); a count
+  (``input.queue_depth``, ``exchange.collective``,
+  ``optimizer.wd_mask``) sit where the work happens. A span records its
+  name, start and end (``perf_counter_ns``), thread, the id of the span
+  that caused it and the ids its request carries (``step``, ``seq``;
+  inherited by what it causes); a count
   belongs to the span open when it was made. While a ``jax.profiler``
   session is live, and only then, a span also opens
   ``jax.profiler.TraceAnnotation("dgc:" + name)``: the program's spans
