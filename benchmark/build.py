@@ -11,18 +11,18 @@ one difference is on the harness's side of the line: ``model.init`` and
 few hundred eager ones ``train.py`` does (PERF.md, set-up).
 """
 
+import contextlib
 import functools
+import inspect
 import os
-from typing import Any, Callable, NamedTuple, Optional
+from typing import Any, Callable, Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding
 
-from benchmark.cells import ROOT, Cell, CellError
-
-#: ImageNet-1k's training set; only the LR schedule's epoch length reads it
-IMAGENET_TRAIN_IMAGES = 1_281_167
+from benchmark import inputs
+from benchmark.cells import DATA_KINDS, ROOT, Cell, CellError
 
 
 class Arm(NamedTuple):
@@ -31,8 +31,8 @@ class Arm(NamedTuple):
     setup: Any                # FlatSetup (layout, stats_layout, engine)
     mesh: Any
     world: int
-    image_size: int
-    num_classes: int
+    dataset: Dict[str, Any]   # the file's block of that name + built sizes
+    recipe: Dict[str, Any]    # the optimizer as configured, for model_check
     init: Callable            # jitted: PRNGKey -> TrainState, sharded
     step: Callable            # the program's jitted train step
     k_loop: Optional[Callable]  # loop 'scan': k steps in one dispatch
@@ -53,6 +53,22 @@ def _narrow_model_dtype(model):
     if dt is not None and jnp.dtype(dt).itemsize < 4:
         return dt
     return None
+
+
+def _keywords(node, *names) -> Dict[str, Any]:
+    """What the callable of config node ``node`` is called with for
+    ``names``: the node's own value, else the callable's default."""
+    defaults = inspect.signature(node.callable).parameters
+    return {n: node.get(n, defaults[n].default) for n in names}
+
+
+def matmul_precision(cell: Cell):
+    """The context everything of the cell's program is traced and called
+    in: the configuration file's ``matmul_precision``, or nothing."""
+    name = cell.config["matmul_precision"]
+    if name is None:
+        return contextlib.nullcontext()
+    return jax.default_matmul_precision(name)
 
 
 def make_mesh(cell: Cell, devices=None):
@@ -95,21 +111,20 @@ def build_arm(cell: Cell, arm: str, mesh) -> Arm:
     world = mesh.devices.size
     axis = mesh.axis_names[0]
     nbps = configs.train.get("num_batches_per_step", 1)
-    image_size = configs.dataset.image_size
-    num_classes = configs.dataset.num_classes
     model = configs.model()
-    sample = (1, image_size, image_size, 3)
+    kind = cfg["dataset"]["kind"]
+    built = {key: configs.dataset.get(key) for key in DATA_KINDS[kind]}
+    # what the generator makes: the file's block with the built sizes
+    dataset = {**cfg["dataset"], **built}
 
     def init_variables(key):
-        return model.init(key, jnp.zeros(sample), train=True)
+        return model.init(key, inputs.sample_input(dataset), train=True)
 
     variables = jax.eval_shape(init_variables, jax.random.PRNGKey(0))
     params = variables["params"]
     named_params, _ = named_flatten(params)
-    n_params = sum(int(p.size) for p in named_params.values())
+    built["num_parameters"] = sum(int(p.size) for p in named_params.values())
     sizes = cfg["sizes"]
-    built = {"num_parameters": n_params, "image_size": image_size,
-             "num_classes": num_classes}
     for key, got in built.items():
         if sizes[key] != got:
             raise CellError(
@@ -117,9 +132,9 @@ def build_arm(cell: Cell, arm: str, mesh) -> Arm:
                 f"{sizes[key]}, the built model has {got}")
 
     # LR exactly as train.py derives it (scaled by nbps * world, warm-up,
-    # the config's decay); the epoch length is ImageNet's
+    # the config's decay); the epoch length is the configuration file's
     global_batch = world * nbps * traffic["per_chip_batch"]
-    steps_per_epoch = num_steps_per_epoch(IMAGENET_TRAIN_IMAGES,
+    steps_per_epoch = num_steps_per_epoch(dataset["epoch_examples"],
                                           global_batch, drop_last=nbps > 1)
     decay = (configs.train.scheduler()
              if configs.train.get("scheduler") is not None else None)
@@ -144,6 +159,10 @@ def build_arm(cell: Cell, arm: str, mesh) -> Arm:
         wd_mask = layout.mask_vector(lambda n: "BatchNorm" not in n)
     optimizer = configs.train.optimizer(lr=lr_schedule,
                                         weight_decay_mask=wd_mask)
+    recipe = {"lr": lr_schedule,
+              "undecayed": "BatchNorm" if wd_mask is not None else None,
+              **_keywords(configs.train.optimizer, "momentum", "dampening",
+                          "weight_decay", "nesterov")}
     dist = DistributedOptimizer(optimizer, compression, axis_name=axis,
                                 world_size=world)
     setup = make_flat_setup(variables, dist)
@@ -167,8 +186,8 @@ def build_arm(cell: Cell, arm: str, mesh) -> Arm:
                             model_dtype=_narrow_model_dtype(model))
     k_loop = _make_k_loop(step, traffic["k"]) if scan else None
     return Arm(name=arm, dist=dist, setup=setup, mesh=mesh, world=world,
-               image_size=image_size, num_classes=num_classes, init=init,
-               step=step, k_loop=k_loop)
+               dataset=dataset, recipe=recipe, init=init, step=step,
+               k_loop=k_loop)
 
 
 def _make_k_loop(step, k: int):

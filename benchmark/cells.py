@@ -4,7 +4,14 @@
 per-layer metric sits in a file of its own, so a later PR adds a cell by
 adding files and entries and edits nothing that is here:
 
-* ``benchmark/configs/<config>.json`` (the path is the entry's ``file``)
+* ``benchmark/configs/<config>.json`` (the path is the entry's ``file``):
+  the modules that build it, its sizes, and a ``dataset`` block whose
+  ``kind`` (``images`` or ``tokens``) says what the generator makes for it
+* ``benchmark/references/<config>.py`` (the path is the configuration
+  file's ``reference``, or null with ``reference_why``): the model's plain
+  reference, ``loss_and_grads(params, inputs, labels)``, and the limits
+  the timed step is held to against it; the file then also states the
+  ``matmul_precision`` the harness runs the program at
 * ``benchmark/traffic/<traffic>.json``
 * ``benchmark/layer_metrics/<metric>.py`` with ``read(trace, spans, cell)``
 
@@ -23,6 +30,20 @@ BENCHMARK_JSON = os.path.join(ROOT, "BENCHMARK.json")
 ARMS = ("dgc", "dense")
 INPUTS = ("pipeline", "resident")
 LOOPS = ("dispatch", "scan")
+#: what a configuration's data is, and the sizes its file states for it
+#: (held to the built model's ``configs.dataset`` in ``build.py``)
+DATA_KINDS = {"images": ("image_size", "num_classes"),
+              "tokens": ("seq_len", "vocab_size")}
+#: what a reference module states beside ``loss_and_grads``: the limit of
+#: each number ``benchmark/model_check.py`` compares
+REFERENCE_LIMITS = ("LOSS_RTOL", "GRAD_RTOL", "UPDATE_RTOL",
+                    "CONSERVED_RTOL")
+#: what a configuration file may state as ``matmul_precision``: the names
+#: of ``jax.default_matmul_precision`` for float32 operands on the TPU
+#: (one, three and six bfloat16 passes)
+MATMUL_PRECISIONS = ("default", "high", "highest")
+#: traffic keys that say how token sequences are made
+TOKEN_KEYS = ("zipf_s", "doc_len_median", "doc_len_sigma")
 
 
 class CellError(ValueError):
@@ -63,8 +84,16 @@ def _want(obj, key, kind, where, default=None, required=True):
           if kind in (int, float, (int, float)) else isinstance(v, kind))
     if not ok:
         raise CellError(f"{where}: key '{key}' must be "
-                        f"{getattr(kind, '__name__', kind)}, got {v!r}")
+                        f"{getattr(kind, '__name__', 'a number')}, "
+                        f"got {v!r}")
     return v
+
+
+def _number_or_null(obj, key, where):
+    """A number, or None where the key is absent or null."""
+    if obj.get(key) is None:
+        return None
+    return _want(obj, key, (int, float), where)
 
 
 def _str_list(obj, key, where, required=False):
@@ -93,7 +122,7 @@ def load_benchmark(path: str = BENCHMARK_JSON) -> Dict[str, Any]:
 
 _TRAFFIC_KEYS = {"per_chip_batch", "arms", "input", "round_steps",
                  "trace_steps", "loop", "k", "modules", "dgc_modules",
-                 "compress_ratio", "pool_batches", "why"}
+                 "compress_ratio", "pool_batches", "why", *TOKEN_KEYS}
 
 
 def load_traffic(name: str, traffic_dir: Optional[str] = None
@@ -120,6 +149,7 @@ def load_traffic(name: str, traffic_dir: Optional[str] = None
         "compress_ratio": raw.get("compress_ratio"),
         "pool_batches": _want(raw, "pool_batches", int, where, default=16,
                               required=False),
+        **{key: _number_or_null(raw, key, where) for key in TOKEN_KEYS},
     }
     for key in ("per_chip_batch", "round_steps", "trace_steps",
                 "pool_batches"):
@@ -146,13 +176,23 @@ def load_traffic(name: str, traffic_dir: Optional[str] = None
                               and not isinstance(r, bool) and 0 < r <= 1):
         raise CellError(f"{where}: 'compress_ratio' must be null or in "
                         f"(0, 1], got {r!r}")
+    if t["zipf_s"] is not None and t["zipf_s"] < 0:
+        raise CellError(f"{where}: 'zipf_s' must be at least 0")
+    if t["doc_len_median"] is not None and t["doc_len_median"] < 1:
+        raise CellError(f"{where}: 'doc_len_median' must be null (one "
+                        "document per row) or at least 1")
+    if (t["doc_len_sigma"] is None) != (t["doc_len_median"] is None):
+        raise CellError(f"{where}: 'doc_len_sigma' goes with "
+                        "'doc_len_median': give both or neither")
+    if t["doc_len_sigma"] is not None and t["doc_len_sigma"] < 0:
+        raise CellError(f"{where}: 'doc_len_sigma' must be at least 0")
     return t
 
 
 def load_config(entry: Dict[str, Any]) -> Dict[str, Any]:
     """One configuration file: which of the repo's config modules build
-    it, what was overridden, reduced or assumed, and the sizes the built
-    model is held to."""
+    it, what was overridden, reduced or assumed, what its data is, the
+    sizes the built model is held to, and where its plain reference is."""
     name = entry.get("name", "?")
     where = f"config '{name}'"
     rel = _want(entry, "file", str, f"BENCHMARK.json {where}")
@@ -167,17 +207,101 @@ def load_config(entry: Dict[str, Any]) -> Dict[str, Any]:
         "assumed": _want(raw, "assumed", dict, where),
         "deployment": _want(raw, "deployment", str, where),
         "sizes": _want(raw, "sizes", dict, where),
+        "dataset": dict(_want(raw, "dataset", dict, where)),
     }
-    for key in ("num_parameters", "image_size", "num_classes"):
-        _want(cfg["sizes"], key, int, f"{where} sizes")
+    dataset, sizes = cfg["dataset"], cfg["sizes"]
+    kind = _want(dataset, "kind", str, f"{where} dataset")
+    if kind not in DATA_KINDS:
+        raise CellError(f"{where} dataset: 'kind' must be one of "
+                        f"{sorted(DATA_KINDS)}, got {kind!r}")
+    for key in ("num_parameters",) + DATA_KINDS[kind]:
+        _want(sizes, key, int, f"{where} sizes")
+    if _want(dataset, "epoch_examples", int, f"{where} dataset") < 1:
+        raise CellError(f"{where} dataset: 'epoch_examples' must be at "
+                        "least 1")
+    if kind == "tokens":
+        eos = _want(dataset, "eos_id", int, f"{where} dataset")
+        if not 0 <= eos < sizes["vocab_size"]:
+            raise CellError(f"{where} dataset: 'eos_id' {eos} is not an id "
+                            f"of a vocabulary of {sizes['vocab_size']}")
     for mod in cfg["modules"] + cfg["dgc_modules"]:
         if not os.path.isfile(os.path.join(ROOT, mod)):
             raise CellError(f"{where}: config module '{mod}' is not in "
                             "the repo")
+    if "reference" not in raw:
+        raise CellError(f"{where}: key 'reference' is missing (the path of "
+                        "the model's plain reference, or null with "
+                        "'reference_why')")
+    ref = cfg["reference"] = raw["reference"]
+    if ref is None:
+        cfg["reference_why"] = _want(raw, "reference_why", str, where)
+    else:
+        if not os.path.isfile(os.path.join(
+                ROOT, _want(raw, "reference", str, where))):
+            raise CellError(f"{where}: reference '{ref}' is not in the "
+                            "repo")
+    # the backend's default where the file says nothing. A reference
+    # needs it said: at the TPU's default a float32 step is as far from
+    # its reference as a bfloat16 one (PERF.md section 6, PR 26)
+    precision = cfg["matmul_precision"] = _want(
+        raw, "matmul_precision", str, where, required=ref is not None)
+    if precision is not None and precision not in MATMUL_PRECISIONS:
+        raise CellError(f"{where}: 'matmul_precision' must be one of "
+                        f"{list(MATMUL_PRECISIONS)}, got {precision!r}")
     if sorted(cfg["reduced"]) != sorted(entry.get("reduced", [])):
         raise CellError(f"{where}: 'reduced' {cfg['reduced']} differs from "
                         f"BENCHMARK.json's {entry.get('reduced')}")
     return cfg
+
+
+def _check_traffic_fits(traffic_name, traffic, config_name, kind):
+    """The traffic mix against the configuration's data kind."""
+    where = f"traffic '{traffic_name}' with config '{config_name}'"
+    if kind != "tokens":
+        given = [k for k in TOKEN_KEYS if traffic[k] is not None]
+        if given:
+            raise CellError(f"{where}: key(s) {given} say how token "
+                            f"sequences are made, and the configuration's "
+                            f"dataset kind is '{kind}'")
+        return
+    if traffic["zipf_s"] is None:
+        raise CellError(f"{where}: key 'zipf_s' is missing (a 'tokens' "
+                        "configuration needs the skew of its tokens)")
+    if traffic["input"] == "pipeline":
+        raise CellError(f"{where}: input 'pipeline' goes through the "
+                        "program's ArraySplit, which normalises images; the "
+                        "program has no token split yet, so a 'tokens' "
+                        "configuration needs input 'resident'")
+
+
+def _load_module(package: str, path: str):
+    """The module in the file ``path``, as ``benchmark.<package>.<stem>``
+    (a stem may hold dots: a metric's name)."""
+    stem = os.path.splitext(os.path.basename(path))[0].replace(".", "_")
+    spec = importlib.util.spec_from_file_location(
+        f"benchmark.{package}.{stem}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def load_reference(path: str):
+    """The plain reference of a configuration's model: the module at
+    ``path`` (relative to the repo's root) with ``loss_and_grads(params,
+    inputs, labels) -> (loss, grads)`` and the limits of
+    ``benchmark/model_check.py``'s four numbers."""
+    full = os.path.join(ROOT, path)
+    if not os.path.isfile(full):
+        raise CellError(f"reference '{path}': no such file")
+    mod = _load_module("references", full)
+    if not callable(getattr(mod, "loss_and_grads", None)):
+        raise CellError(f"reference '{path}' defines no loss_and_grads()")
+    for key in REFERENCE_LIMITS:
+        v = getattr(mod, key, None)
+        if not isinstance(v, float) or not v > 0:
+            raise CellError(f"reference '{path}': {key} must be a "
+                            f"positive float, got {v!r}")
+    return mod
 
 
 def _metrics_of(cell_name: str, entries: List[Dict[str, Any]]):
@@ -214,10 +338,12 @@ def load_cell(name: str, bench: Optional[Dict[str, Any]] = None,
             raise CellError(
                 f"{where}: per-layer metric '{m.get('name')}' moves "
                 f"'{m.get('moves')}', which this cell does not report")
-    return Cell(name=name, chips=chips,
-                config_name=cfg_name, config=load_config(cfgs[0]),
-                traffic_name=traffic_name,
-                traffic=load_traffic(traffic_name, traffic_dir),
+    config = load_config(cfgs[0])
+    traffic = load_traffic(traffic_name, traffic_dir)
+    _check_traffic_fits(traffic_name, traffic, cfg_name,
+                        config["dataset"]["kind"])
+    return Cell(name=name, chips=chips, config_name=cfg_name, config=config,
+                traffic_name=traffic_name, traffic=traffic,
                 end_to_end=e2e, per_layer=layer)
 
 
@@ -231,11 +357,7 @@ def load_reader(metric: str, readers_dir: Optional[str] = None) -> Callable:
     if not os.path.isfile(path):
         raise CellError(f"per-layer metric '{metric}': no reader at "
                         f"{os.path.relpath(path, ROOT)}")
-    spec = importlib.util.spec_from_file_location(
-        "benchmark.layer_metrics." + metric.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    read = getattr(mod, "read", None)
+    read = getattr(_load_module("layer_metrics", path), "read", None)
     if not callable(read):
         raise CellError(f"per-layer metric '{metric}': "
                         f"{os.path.relpath(path, ROOT)} defines no read()")

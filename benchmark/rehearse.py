@@ -6,8 +6,9 @@
 Workload names restrict ``aot`` to those cells (default: every cell).
 
 ``cpu``   tiny shapes on one CPU device through the harness's own
-          ``measure`` (pipeline, resident and scan traffic, untraced and
-          traced): wrong paths, arguments and control flow show here.
+          ``measure`` (an image and a token configuration; pipeline,
+          resident and scan traffic, untraced and traced): wrong paths,
+          arguments and control flow show here.
 ``mesh``  the same on a mesh of four virtual CPU devices: wrong meshes and
           sharding rules show here.
 ``aot``   both arms of every cell of BENCHMARK.json compiled at the real
@@ -62,13 +63,14 @@ def _run_tiny(name, trace):
     paired = run.paired_summary(m)
     assert m["failed"] == 0 and m["attempted"] > 0, m["attempted"]
     assert m["check"]["ok"], m["check"]
+    assert m["model_check"]["ok"], m["model_check"]
     assert m["step0_ok"]
     assert len(m["rows"]) % 2 == 0 and set(m["rows"][0]) == {"dgc", "dense"}
     assert set(run.end_to_end_values(m, paired)) >= {
         "setup_s", "step_ms", "dense_step_ms", "dgc_overhead_ms"}
     out = {"cell": name, "trace": trace, "rounds": len(m["rows"]),
            "steps": m["attempted"], "check": m["check"],
-           "compiles": m["compiles"]}
+           "model_check": m["model_check"], "compiles": m["compiles"]}
     if trace:
         events = m["traced"]["events"]
         names = [n for n, _, _ in trace_reduce.host_annotations(events)]
@@ -91,13 +93,15 @@ def _run_tiny(name, trace):
 
 
 def rehearse_cpu():
-    for name in ("tiny.steady", "tiny.resident", "tiny.scan"):
+    for name in ("tiny.steady", "tiny.resident", "tiny.scan",
+                 "tiny_lm.resident", "tiny_lm.scan"):
         print(json.dumps(_run_tiny(name, trace=False)), flush=True)
     print(json.dumps(_run_tiny("tiny.steady", trace=True)), flush=True)
 
 
 def rehearse_mesh():
-    print(json.dumps(_run_tiny("tiny.steady.x4", trace=False)), flush=True)
+    for name in ("tiny.steady.x4", "tiny_lm.resident.x4"):
+        print(json.dumps(_run_tiny(name, trace=False)), flush=True)
 
 
 def rehearse_aot(only=()):
@@ -106,7 +110,7 @@ def rehearse_aot(only=()):
     from jax.experimental import topologies
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from benchmark import build, check
+    from benchmark import build, check, inputs
     from dgc_tpu.ops import kernels
 
     # the engine asks the default backend (the CPU, here) which route to
@@ -133,13 +137,13 @@ def rehearse_aot(only=()):
                                        sharding=NamedSharding(mesh, P()))
             arm.init.lower(key).compile()
             state = jax.eval_shape(arm.init, jax.random.PRNGKey(0))
-            size = arm.image_size
-            compiled = arm.step.lower(
-                state,
-                jax.ShapeDtypeStruct((gb, size, size, 3), jnp.float32,
-                                     sharding=batch),
-                jax.ShapeDtypeStruct((gb,), jnp.int32, sharding=batch),
-                key).compile()
+            examples, labels = inputs.example_shapes(arm.dataset, gb)
+            with build.matmul_precision(cell):
+                compiled = arm.step.lower(
+                    state,
+                    jax.ShapeDtypeStruct(*examples, sharding=batch),
+                    jax.ShapeDtypeStruct(*labels, sharding=batch),
+                    key).compile()
             program = check.check_program(arm) if name == "dgc" else None
             if program is not None:
                 mem = program[0].lower(key).compile().memory_analysis()

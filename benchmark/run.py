@@ -6,12 +6,15 @@
 A fresh process per run. It refuses a backend that is not a TPU, builds the
 cell's arms from the repo's config tree (``benchmark/build.py``), warms up
 exactly the programs the window uses, checks the exchange engine against
-the plain reference and the arms against each other, and then measures
+its plain reference and the arms against each other, and then measures
 for ``--seconds`` seconds in rounds: ``round_steps`` donated per-dispatch
 steps of one arm, dispatched back to back and ended by one
 ``block_until_ready``, then the same for the other arm, the order of the
 arms alternating from round to round. Nothing may compile inside the
-window; a run in which something does exits non-zero.
+window; a run in which something does exits non-zero. Once the window has
+closed, the configuration's plain reference of the model follows the first
+steps that set-up drove through the window's own call
+(``benchmark/model_check.py``).
 
 ``--trace 1`` builds the steps with the ``dgcph.*`` markers on (their
 executables have cache entries of their own), runs the same window, then
@@ -52,8 +55,8 @@ from benchmark.spans import Spans
 #: same float32 sums: up to 3.7e-6 relative on the chip (ResNet-50, four
 #: seeds, PR 22). Computing in bfloat16 would show as some 1e-3.
 STEP0_LOSS_RTOL = 1e-4
-#: steps each arm runs alone, after its first (compiling) call, before its
-#: memory peak is read
+#: dispatches each arm runs alone, after its first (compiling) call, before
+#: its memory peak is read; with the first, what the model check follows
 SOLO_WARMUP_STEPS = 2
 KEEP_TRACE_ENV = "DGC_BENCH_KEEP_TRACE"
 
@@ -154,12 +157,20 @@ def engine_info(arm):
 def measure(cell, seed, seconds, trace, devices=None):
     """Everything between "backend up" and "result": returns the result's
     parts as a dict. ``devices`` is for the rehearsals (virtual CPU
-    devices); on the chip it stays None."""
+    devices); on the chip it stays None. Every program of the cell is
+    traced and called at the matmul precision its configuration states."""
+    from benchmark import build
+    with build.matmul_precision(cell):
+        return _measure(cell, seed, seconds, trace, devices)
+
+
+def _measure(cell, seed, seconds, trace, devices):
     import jax
     import numpy as np
 
     from benchmark import build, inputs
     from benchmark.check import exchange_check
+    from benchmark import model_check
 
     split = {}
     mark = time.perf_counter()
@@ -184,6 +195,7 @@ def measure(cell, seed, seconds, trace, devices=None):
     runs, feed, first_batch = {}, None, None
     first_loss, dgc_peak, engine = {}, None, None
     check = {"ok": True, "skipped": "no dgc arm in this traffic"}
+    followers = {}
     lap("backend_and_mesh")
 
     try:
@@ -192,13 +204,13 @@ def measure(cell, seed, seconds, trace, devices=None):
             lap("build_" + name)
             if feed is None:
                 gb = arm.world * traffic["per_chip_batch"]
-                geom = (arm.image_size, arm.num_classes, mesh)
                 if traffic["input"] == "pipeline":
                     feed = inputs.pipeline_feed(
-                        seed, gb, traffic["pool_batches"], *geom)
+                        seed, gb, traffic["pool_batches"], arm.dataset, mesh)
                 else:
                     n = traffic["pool_batches" if scan else "round_steps"]
-                    resident = inputs.resident_batches(seed, gb, n, *geom)
+                    resident = inputs.resident_batches(
+                        seed, gb, n, arm.dataset, traffic, mesh)
                     feed = (inputs.scan_feed(resident, mesh) if scan
                             else inputs.resident_feed(resident))
                 first_batch = next(feed)
@@ -207,13 +219,19 @@ def measure(cell, seed, seconds, trace, devices=None):
             run = runs[name] = ArmRun(arm, build.init_state(arm, seed), seed)
             jax.block_until_ready(run.state)
             lap("init_" + name)
+            # where the configuration has a reference of its model: host
+            # copies of the state round each of these dispatches
+            follow = followers[name] = model_check.Follower(cell, arm)
+            follow.snapshot(run)
             # the first call compiles (or loads) the one program this arm
             # uses; same weights, same batch, same key for every arm
             loss = run.dispatch(*first_batch)
             first_loss[name] = float(np.ravel(jax.device_get(loss))[0])
+            follow.snapshot(run)
             lap("first_step_" + name)
             for _ in range(SOLO_WARMUP_STEPS):
                 run.dispatch(*first_batch)
+                follow.snapshot(run)
             jax.block_until_ready(run.state)
             lap("warmup")
             if name == "dgc":
@@ -277,16 +295,25 @@ def measure(cell, seed, seconds, trace, devices=None):
         if trace:
             traced = _profile(cell, runs, feed, spans, steps_per_round
                               // dispatches)
+
+        # the program's peak, then its state freed, then the reference:
+        # nothing of the model check is in the peak or in setup_s
+        memory_peak = int(hbm_peak_bytes(cell_devices))
+        for run in runs.values():
+            run.state = None
+        model = model_check.compare(cell, followers, first_batch)
+        log("model_check", **model)
     finally:
         if feed is not None:
             feed.close()
 
     return {
-        "memory_peak_bytes": int(hbm_peak_bytes(cell_devices)),
+        "memory_peak_bytes": memory_peak,
         "setup_s": setup_s, "split": split, "compiles": counter.snapshot(),
         "rows": rows, "steps_per_round": steps_per_round,
         "window_s": window_s, "attempted": attempted, "failed": failed,
-        "check": check, "step0_ok": step0_ok, "dgc_peak_bytes": dgc_peak,
+        "check": check, "model_check": model, "step0_ok": step0_ok,
+        "dgc_peak_bytes": dgc_peak,
         "window_spans": window_spans, "traced": traced, "engine": engine,
     }
 
@@ -415,6 +442,14 @@ def device_busy(view):
             sum(statistics.mean(c.window_s for c in a.chips) for a in arms))
 
 
+def is_correct(m) -> bool:
+    """The run's verdict: the exchange and the timed step agree with
+    their references, the arms start from the same loss, and every loss
+    of the window is finite."""
+    return bool(m["check"]["ok"] and m["model_check"]["ok"]
+                and m["step0_ok"] and m["failed"] == 0)
+
+
 def parse_args(argv):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -470,9 +505,8 @@ def main(argv=None):
     device = {"platform": d0.platform, "kind": d0.device_kind,
               "count": jax.device_count(),
               "memory_peak_bytes": m["memory_peak_bytes"]}
-    result = {"correct": bool(m["check"]["ok"] and m["step0_ok"]
-                              and m["failed"] == 0),
-              "attempted": m["attempted"], "failed": m["failed"]}
+    result = {"correct": is_correct(m), "attempted": m["attempted"],
+              "failed": m["failed"]}
     if args.trace:
         view = trace_view(m, paired, device["kind"])
         values = per_layer_values(cell, view, m["window_spans"])

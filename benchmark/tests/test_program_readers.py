@@ -1,14 +1,14 @@
 """The per-layer readers of PR 23: the ones that read the new scopes, on a
-small hand-made trace that holds the new tokens and on the two chip
-fixtures (recorded before the scopes existed); and the ones that read the
-program's own recorder, on hand-made records, on the live recorder, and on
-a program that has none."""
+small hand-made trace that holds the new tokens (``test_trace_reduce.py``
+pins every reader whole on the two chip fixtures, recorded before the
+scopes existed); and the ones that read the program's own recorder, on
+hand-made records, on the live recorder, and on a program that has
+none."""
 
 import pytest
 
 from benchmark import cells, program_records
-from test_trace_reduce import (_chip_view, _host, _meta, _op, _read_all,
-                               _view)
+from test_trace_reduce import _host, _meta, _op, _view
 
 STEP = "jit(step_fn)/"
 UPDATE = STEP + "dgcph.update/"
@@ -72,73 +72,6 @@ def test_scope_readers_on_a_trace_with_the_new_tokens(metric, want):
     phases = view["tables"]["dgc"]["phases"]
     assert phases["plumbing"] == pytest.approx(0.01)
     assert view["tables"]["dense"]["phases"]["update"] == pytest.approx(0.2)
-
-
-#: what test_trace_reduce.py pins for the readers PR 22 brought, letter for
-#: letter (this PR may not edit that file, and its two whole-dictionary
-#: tests no longer hold with ten more entries in BENCHMARK.json)
-PR22_ONE_CHIP = {
-    "input.wait_ms": 0.003,
-    "step.fwd_bwd_ms": 49.136492187,
-    "step.update_ms": 4.141931289,
-    "exchange.device_ms": 9.965785043,
-    "exchange.unexplained_ms": -0.540872777,
-    "exchange.dgc_minus_dense_ms": 3.4,
-    "kernels.pallas_ms": 4.531906797,
-    "kernels.compensate_roofline": 82.869233906,
-    "collectives.ms": None,
-    "collectives.exposed_ms": None,
-    "device.idle_share": 0.0721400308,
-}
-PR22_FOUR_CHIPS = {
-    "input.wait_ms": 0.003,
-    "step.fwd_bwd_ms": 48.944180078,
-    "step.update_ms": 4.1420081055,
-    "exchange.device_ms": 13.8989252735,
-    "exchange.unexplained_ms": -10.445841858,
-    "exchange.dgc_minus_dense_ms": 3.4,
-    "kernels.pallas_ms": 4.5320640035,
-    "kernels.compensate_roofline": 82.874503457,
-    "collectives.ms": 0.1032683985,
-    "collectives.exposed_ms": 0.1032683985,
-    "device.idle_share": 2.74536607367,
-}
-#: eight of PR 23's ten find nothing in a trace and a process that hold
-#: nothing of PR 23
-PR23_ABSENT = dict.fromkeys((
-    "input.produce_ms", "step.trace_s", "step.params_view_ms",
-    "step.optimizer_ms", "exchange.glue_ms", "exchange.wire_bytes",
-    "exchange.dense_wire_bytes", "collectives.dense_arm_ms"))
-
-
-@pytest.mark.parametrize("fixture, steps, pr22, unscoped, waits", [
-    ("chip_trace_vgg16_bn.json.gz", 2, PR22_ONE_CHIP,
-     5.308634644, 1.091578241),
-    ("chip_trace_vgg16_bn_x4.json.gz", 1, PR22_FOUR_CHIPS,
-     5.3476499775, 1.090399471),
-])
-def test_every_reader_of_the_benchmark_on_the_chip_fixtures(
-        monkeypatch, fixture, steps, pr22, unscoped, waits):
-    """Every entry of BENCHMARK.json's per_layer, the whole result: the
-    eleven readers of PR 22 return what they returned before the ten were
-    added, and the ten gain their keys and nothing else. A PR 22 trace has
-    no part, no params_view, no dense-engine scope: those readers return
-    None; the two that need no new token read what ISSUE 23 quotes (5.31 /
-    5.35 unattributed, 1.09 of -done waits)."""
-    monkeypatch.setattr(program_records, "records", lambda: [])
-    view = _chip_view(fixture, steps)
-    got = _read_all(view)
-    want = {**pr22, **PR23_ABSENT,
-            "step.unscoped_ms": unscoped, "step.async_wait_ms": waits}
-    assert len(want) == 21 and set(got) == set(want)
-    assert got == {k: v if v is None else pytest.approx(v, rel=1e-6)
-                   for k, v in want.items()}
-    # the waits are part of the unscoped time, and it is XLA's own ops
-    # (no tf_op) that make up the -done families
-    arm = view["arms"]["dgc"]
-    done = [o for c in arm.chips for o in c.ops
-            if o.name.partition(".")[0] in ("copy-done", "slice-done")]
-    assert done and all(o.tf_op == "" and o.phase is None for o in done)
 
 
 # ---------------------------------------------------------------------- #
