@@ -289,7 +289,9 @@ def load_reference(path: str):
     """The plain reference of a configuration's model: the module at
     ``path`` (relative to the repo's root) with ``loss_and_grads(params,
     inputs, labels) -> (loss, grads)`` and the limits of
-    ``benchmark/model_check.py``'s four numbers."""
+    ``benchmark/model_check.py``'s four numbers; ``ROW_BLOCK`` where its
+    loss is a mean over rows and it is to be called on that many at a
+    time."""
     full = os.path.join(ROOT, path)
     if not os.path.isfile(full):
         raise CellError(f"reference '{path}': no such file")
@@ -301,6 +303,10 @@ def load_reference(path: str):
         if not isinstance(v, float) or not v > 0:
             raise CellError(f"reference '{path}': {key} must be a "
                             f"positive float, got {v!r}")
+    block = getattr(mod, "ROW_BLOCK", None)
+    if block is not None and not (type(block) is int and block >= 1):
+        raise CellError(f"reference '{path}': ROW_BLOCK must be a number "
+                        f"of rows, at least 1, got {block!r}")
     return mod
 
 

@@ -1,4 +1,5 @@
-"""Correctness of the exchange engine, outside the timed window.
+"""Correctness of the exchange engine, after the timed window, on a device
+the arms have left.
 
 The engine of the dgc arm (``flat_setup.engine``) is driven twice under the
 cell's mesh, at the cell's full geometry, on gradients made from the seed.
@@ -20,14 +21,18 @@ the second exchange must satisfy, against ``benchmark/reference.py``:
 * selection — recall of the transmitted set against the exact top-k of
   the compensated velocity is at least ``RECALL_FLOOR`` over all buckets,
   and per bucket within sampling error of it.
+
+The check is a handful of small programs (``check_program``), so that it
+needs less of the chip than the arm it checks: ``stage_bytes`` is what
+``rehearse.py aot`` holds against the chip's memory.
 """
 
-from typing import Any, Dict
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from benchmark import reference
 
@@ -57,28 +62,71 @@ FILL_FLOOR = 0.8
 def _real_mask(layout, total: int):
     """[total] bool: True where the flat layout stores a parameter (row
     tails, the gap and the tail padding are structural zeros, and the
-    selection relies on that)."""
-    order = sorted(layout.names, key=lambda n: layout.offsets[n])
-    starts = np.array([layout.offsets[n] for n in order], np.int64)
-    ends = starts + np.array([layout.sizes[n] for n in order], np.int64)
-    pos = jnp.arange(total, dtype=jnp.int32)
-    owner = jnp.searchsorted(jnp.asarray(starts, jnp.int32), pos,
-                             side="right") - 1
-    return (owner >= 0) & (pos < jnp.asarray(ends, jnp.int32)[owner])
+    selection relies on that). Range compares on an iota, one pair per run
+    of adjoining tensors, which fuse into whatever reads the mask: a
+    ``searchsorted`` over [total] positions held 24 B a coordinate of
+    temporaries (``rehearse.py aot``, PR 27)."""
+    runs = []
+    for lo, size in sorted((layout.offsets[n], layout.sizes[n])
+                           for n in layout.names):
+        if runs and runs[-1][1] == lo:
+            runs[-1][1] = lo + size
+        else:
+            runs.append([lo, lo + size])
+    pos = jax.lax.iota(jnp.int32, total)
+    real = jnp.zeros((total,), bool)
+    for lo, hi in runs:
+        real |= (pos >= lo) & (pos < hi)
+    return real
+
+
+class Stage(NamedTuple):
+    """One program of the check: its jitted function, the (abstract)
+    arguments it is called with, and the bytes a chip holds beside it
+    while it runs (what an earlier stage left and a later one needs)."""
+    name: str
+    fn: Callable
+    args: Tuple
+    held_bytes: int
+
+
+class Check(NamedTuple):
+    run: Callable        # PRNGKey -> counts on the host
+    summarize: Callable  # counts -> the result with ``ok``
+    stages: Callable     # () -> the programs ``run`` drives, for the law
 
 
 def exchange_check(arm, seed: int) -> Dict[str, Any]:
     """Run the check; returns its numbers and ``ok``."""
-    program = check_program(arm)
-    if program is None:
+    check = check_program(arm)
+    if check is None:
         return {"ok": True, "skipped": "the dgc arm has no sparse exchange"}
-    run, summarize = program
-    return summarize(jax.device_get(run(jax.random.PRNGKey(seed))))
+    return check.summarize(check.run(jax.random.PRNGKey(seed)))
 
 
-def check_program(arm):
-    """(the jitted check, PRNGKey -> counts; counts -> result), or None
-    where the arm's engine sends nothing sparse."""
+def program_bytes(compiled) -> int:
+    """What a compiled program needs of a chip while it runs, by its
+    ``memory_analysis()``: arguments + temporaries + outputs, less the
+    outputs that alias (donated) arguments."""
+    mem = compiled.memory_analysis()
+    return (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+
+
+def stage_bytes(check: Check) -> Dict[str, int]:
+    """Per stage, what the check needs of a chip while that stage runs:
+    its compiled program and what is held beside it."""
+    return {stage.name: program_bytes(stage.fn.lower(*stage.args).compile())
+            + stage.held_bytes for stage in check.stages()}
+
+
+def check_program(arm) -> Optional[Check]:
+    """The check as five small programs, or None where the arm's engine
+    sends nothing sparse. One program held g1, g2, two engine memories,
+    two canonical views and the reference's velocity at [T] all at once
+    (37.2 B/T at VGG, PR 26); here every stage holds what it reads, the
+    engine memory is donated from exchange to exchange, and only the
+    reference's velocity (4 B/T) crosses the second exchange."""
     from dgc_tpu.utils.compat import shard_map
 
     engine, layout, dist = arm.setup.engine, arm.setup.layout, arm.dist
@@ -87,34 +135,51 @@ def check_program(arm):
         return None
     mem_cfg = dist.compressor.memory
     T, total, world = engine.T, layout.total, arm.world
-    axes = dist.data_axes
+    mesh, axes = arm.mesh, dist.data_axes
     quota = np.concatenate([np.asarray(b.num_selects, np.int64)
                             for b in buckets])
     row_bucket = np.concatenate([np.full(b.rows, i, np.int32)
                                  for i, b in enumerate(buckets)])
+    # a worker's arrays travel between the stages stacked on a leading
+    # axis that is sharded over the workers
+    rep, per_worker = P(), P(axes)
+    stack = lambda tree: jax.tree.map(lambda x: x[None], tree)
+    mine = lambda tree: jax.tree.map(lambda x: x[0], tree)
 
-    def exchange(grad, mem, key):
-        return engine.exchange(grad, mem, key, dist.axis_name,
-                               dist.num_nodes,
-                               local_axis=dist.local_axis_name,
-                               local_size=dist.local_size)
+    def stage(worker, in_specs, out_specs, donate=()):
+        return jax.jit(shard_map(worker, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False),
+                       donate_argnums=donate)
 
-    def worker(key):
+    def worker_keys(key):
+        """[4, 2]: the keys of g1, g2 and of the two exchanges."""
         widx = jax.lax.axis_index(axes[0])
-        k1, k2, ks1, ks2 = jax.random.split(
-            jax.random.fold_in(key, widx), 4)
-        real = _real_mask(layout, total)
-        g1 = jnp.where(real, jax.random.normal(k1, (total,)), 0.0)
-        g2 = jnp.where(real, jax.random.normal(k2, (total,)), 0.0)
-        _, mem1 = exchange(g1, engine.init_memory(), ks1)
-        before = engine.memory_full(mem1)
-        out, mem2 = exchange(g2, mem1, ks2)
-        after = engine.memory_full(mem2)
+        return jax.random.split(jax.random.fold_in(key, widx), 4)
 
+    def gradient(key, i):
+        """Gradient ``i`` (0, 1) of this worker: a program of its own, so
+        that what making it takes is gone when it is read."""
+        g = jax.random.normal(worker_keys(key)[i], (total,))
+        return jnp.where(_real_mask(layout, total), g, 0.0)
+
+    def exchange(key, i, grad, mem):
+        out, mem = engine.exchange(grad, mine(mem), worker_keys(key)[2 + i],
+                                   dist.axis_name, dist.num_nodes,
+                                   local_axis=dist.local_axis_name,
+                                   local_size=dist.local_size)
+        return out, stack(mem)
+
+    def expect(grad, mem):
+        """The reference's compensated velocity of the exchange of
+        ``grad``, from the engine's canonical view of its memory."""
+        before = engine.memory_full(mine(mem))
         _, v_ref = reference.momentum_correction(
-            before["momentums"][:T], before["velocities"][:T], g2[:T],
+            before["momentums"][:T], before["velocities"][:T], grad[:T],
             mem_cfg.momentum, mem_cfg.nesterov)
-        residual = after["velocities"][:T]
+        return v_ref
+
+    def compare(mem, out, v_ref):
+        residual = engine.memory_full(mine(mem))["velocities"][:T]
         bits = jax.lax.bitcast_convert_type
         res_b, ref_b = (bits(residual.astype(jnp.float32), jnp.int32),
                         bits(v_ref, jnp.int32))
@@ -129,8 +194,11 @@ def check_program(arm):
         # one chip: bitwise. Several: each side is a float32 sum of at
         # most `world` terms in an order of its own
         tol = 0.0 if world == 1 else 4.0 * world * np.finfo(np.float32).eps
-        unconserved = jnp.sum(jnp.abs(lhs - rhs) > tol * scale)
+        return {"sent": sent,
+                "inexact": jax.lax.psum(inexact, axes),
+                "unconserved": jnp.sum(jnp.abs(lhs - rhs) > tol * scale)}
 
+    def recall(v_ref, sent):
         hits, counts = [], []
         for b in buckets:
             lo, hi = b.base, b.base + b.rows * b.cols
@@ -139,11 +207,12 @@ def check_program(arm):
                 sent[lo:hi].reshape(b.rows, b.cols))
             hits.append(h)
             counts.append(n)
+            # bucket by bucket: the next bucket's bit patterns are not
+            # made before this one's counts are in
+            v_ref, sent, _ = jax.lax.optimization_barrier((v_ref, sent, h))
         hits, counts = jnp.concatenate(hits), jnp.concatenate(counts)
         over_quota = jnp.sum(counts > jnp.asarray(quota, jnp.int32))
         return {
-            "inexact": jax.lax.psum(inexact, axes),
-            "unconserved": unconserved,
             "over_quota_rows": jax.lax.psum(over_quota, axes),
             "hits": jax.lax.psum(hits, axes),
             "sent": jax.lax.psum(counts, axes),
@@ -151,8 +220,47 @@ def check_program(arm):
                 jnp.sum(sent) - jnp.sum(counts), axes),
         }
 
-    run = jax.jit(shard_map(worker, mesh=arm.mesh, in_specs=P(),
-                            out_specs=P(), check_vma=False))
+    init = stage(lambda: stack(engine.init_memory()), (), per_worker)
+    gradient = stage(gradient, (rep, rep), per_worker)
+    exchange = stage(exchange, (rep, rep, per_worker, per_worker),
+                     (rep, per_worker), donate=(2, 3))
+    expect = stage(expect, (per_worker, per_worker), per_worker)
+    compare = stage(compare, (per_worker, rep, per_worker),
+                    {"sent": per_worker, "inexact": rep, "unconserved": rep})
+    recall = stage(recall, (per_worker, per_worker), rep)
+
+    def run(key):
+        mem = exchange(key, 0, gradient(key, 0), init())[1]
+        grad = gradient(key, 1)
+        v_ref = expect(grad, mem)
+        out, mem = exchange(key, 1, grad, mem)
+        counts = compare(mem, out, v_ref)
+        del mem, out                  # freed before the last stage runs
+        counts.update(recall(v_ref, counts.pop("sent")))
+        return jax.device_get(counts)
+
+    def stages():
+        def abstract(tree, spec):
+            return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=NamedSharding(mesh, spec)), tree)
+
+        key = abstract(jax.eval_shape(lambda: jax.random.PRNGKey(0)), rep)
+        index = abstract(jax.eval_shape(lambda: jnp.int32(0)), rep)
+        mem = abstract(jax.eval_shape(init), per_worker)
+        grad = abstract(jax.eval_shape(gradient, key, index), per_worker)
+        v_ref = abstract(jax.eval_shape(expect, grad, mem), per_worker)
+        out = abstract(jax.eval_shape(exchange, key, index, grad, mem)[0],
+                       rep)
+        sent = abstract(jax.eval_shape(compare, mem, out, v_ref)["sent"],
+                        per_worker)
+        nbytes = lambda tree: sum(x.size * x.dtype.itemsize
+                                  for x in jax.tree.leaves(tree)) // world
+        return (Stage("gradient", gradient, (key, index), nbytes(mem)),
+                Stage("expect", expect, (grad, mem), 0),
+                Stage("exchange", exchange, (key, index, grad, mem),
+                      nbytes(v_ref)),
+                Stage("compare", compare, (mem, out, v_ref), 0),
+                Stage("recall", recall, (v_ref, sent), 0))
 
     def summarize(got):
         nb = len(buckets)
@@ -169,10 +277,10 @@ def check_program(arm):
             "unconserved_coords": int(got["unconserved"]),
             "over_quota_rows": int(got["over_quota_rows"]),
             "sent_outside_rows": int(got["sent_outside_rows"]),
-            "fill": fill,
+            "fill": fill, "sent_per_bucket": [int(n) for n in sent_b],
             "recall": pooled, "recall_per_bucket": recall,
             "recall_floor": RECALL_FLOOR,
-            "recall_floor_per_bucket": [bucket_recall_floor(s)
+            "recall_floor_per_bucket": [float(bucket_recall_floor(s))
                                         for s in sent_b],
             "fill_floor": FILL_FLOOR,
         }
@@ -187,4 +295,4 @@ def check_program(arm):
                     for r, s in zip(recall, sent_b)))
         return result
 
-    return run, summarize
+    return Check(run, summarize, stages)

@@ -102,6 +102,36 @@ def compare(cell, followers: Dict[str, Follower], batch) -> Dict[str, Any]:
             "ok": bool(ok)}
 
 
+def _loss_and_grads(reference):
+    """The reference's ``loss_and_grads``, jitted; where the module states
+    ``ROW_BLOCK``, called on that many rows at a time, the blocks' losses
+    and gradients summed weighted by their share of the rows (rows are of
+    one length, so of the tokens): a plain float32 reference keeps every
+    layer's [heads, S, S] scores for its backward pass, and a whole batch
+    of long rows does not fit beside them."""
+    whole = jax.jit(reference.loss_and_grads)
+    block = getattr(reference, "ROW_BLOCK", None)
+    if block is None:
+        return whole
+
+    def blocked(params, inputs, labels):
+        rows = inputs.shape[0]
+        per_row = labels.shape[0] // rows     # token-major labels: [B*S]
+        loss, grads = 0.0, None
+        for lo in range(0, rows, block):
+            hi = min(lo + block, rows)
+            share = (hi - lo) / rows
+            l, g = whole(params, inputs[lo:hi],
+                         labels[lo * per_row:hi * per_row])
+            loss = loss + share * l
+            grads = jax.tree.map(
+                lambda g_: share * g_, g) if grads is None else jax.tree.map(
+                lambda acc, g_: acc + share * g_, grads, g)
+        return loss, grads
+
+    return blocked
+
+
 # ---------------------------------------------------------------------- #
 # the two arms                                                           #
 # ---------------------------------------------------------------------- #
@@ -118,7 +148,7 @@ def _follow_dense(f: Follower, batch_at):
     p0 = named(snaps[0]["params"])
     decayed = _decayed(recipe, p0)
     wd, m = recipe["weight_decay"], recipe["momentum"]
-    grad = jax.jit(f.reference.loss_and_grads)
+    grad = _loss_and_grads(f.reference)
 
     # the reference's own trajectory, float32 on the default device
     params = lay.unflatten(np.asarray(snaps[0]["params"]))
@@ -168,7 +198,7 @@ def _follow_dgc(f: Follower, batch_at):
             "no clipping")
     k = int(np.size(snaps[1]["losses"]))
     named = lambda flat: _by_tensor(lay.unflatten(np.asarray(flat)))
-    grad = jax.jit(f.reference.loss_and_grads)
+    grad = _loss_and_grads(f.reference)
     wd, m_opt = recipe["weight_decay"], recipe["momentum"]
     decayed = _decayed(recipe, named(snaps[0]["params"]))
 

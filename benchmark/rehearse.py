@@ -11,10 +11,11 @@ Workload names restrict ``aot`` to those cells (default: every cell).
           arguments and control flow show here.
 ``mesh``  the same on a mesh of four virtual CPU devices: wrong meshes and
           sharding rules show here.
-``aot``   both arms of every cell of BENCHMARK.json compiled at the real
-          size for a described ``v5e`` (one chip, or the 2x2 host), with
-          ``memory_analysis()`` of both arms added up against the chip's
-          16 GB: what the TPU's compiler would refuse shows here.
+``aot``   both arms of every cell of BENCHMARK.json, and the exchange
+          check's programs, compiled at the real size for a described
+          ``v5e`` (one chip, or the 2x2 host), with ``memory_analysis()``
+          held against the chip's 16 GB by ``memory_law``: what the
+          TPU's compiler would refuse shows here.
 
 No rehearsal prints a time, a rate or any other device metric: nothing
 here ran on the device.
@@ -42,6 +43,11 @@ from benchmark import cells
 
 FIXTURE = os.path.join(ROOT, "benchmark", "tests", "fixtures", "rehearsal")
 HBM_BYTES = 16e9
+#: the host beside one chip and beside the 2x2, and what a process of the
+#: harness held there before any state was made (14.2e9 at a 461M-parameter
+#: token model, one v5e, PR 27; PERF.md section 7.6)
+HOST_BYTES = {1: 40 * 2 ** 30, 4: 140 * 2 ** 30}
+HOST_BASELINE_BYTES = 14.2e9
 
 
 def fixture_cell(name):
@@ -104,13 +110,42 @@ def rehearse_mesh():
         print(json.dumps(_run_tiny(name, trace=False)), flush=True)
 
 
+def memory_law(row):
+    """What a cell needs, from the compiler's numbers in ``row`` (bytes a
+    chip). Of one chip: the arms' states together and the larger step's
+    temporaries (both states are resident, the steps run one at a time);
+    the exchange check runs on a device the arms have left, so it is held
+    to the chip on its own. Of the host, where the configuration has a
+    reference of its model: ``follower_copies`` copies of a chip's states
+    (``model_check.Follower``), which stay until the reference has
+    followed, on top of the process's baseline; the other chips' shares of
+    what is sharded and the follow's own float64 working copies come on
+    top and are not counted, so this refuses what cannot fit and admits
+    nothing for certain. Returns (chip bytes, host
+    bytes, the sentence that says so)."""
+    arms = [n for n in cells.ARMS if n in row]
+    states = [row[n]["argument_bytes"] for n in arms]
+    temp = max(row[n]["temp_bytes"] for n in arms)
+    needs = sum(states) + temp
+    check = row.get("check_bytes", 0)
+    copies = row.get("follower_copies", 0)
+    host = int(HOST_BASELINE_BYTES) + copies * sum(states)
+    return needs, host, (
+        f"{row['cell']}: needs {needs} B a chip (states "
+        f"{' + '.join(map(str, states))} together + the larger step's "
+        f"temporaries {temp}) and the exchange check {check} B on its own, "
+        f"of {int(HBM_BYTES)}; at least {host} B of the host ({copies} "
+        f"copies of the states for the model reference + "
+        f"{int(HOST_BASELINE_BYTES)}), of {HOST_BYTES[row['chips']]}")
+
+
 def rehearse_aot(only=()):
     import jax
     import jax.numpy as jnp
     from jax.experimental import topologies
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from benchmark import build, check, inputs
+    from benchmark import build, check, inputs, run
     from dgc_tpu.ops import kernels
 
     # the engine asks the default backend (the CPU, here) which route to
@@ -129,8 +164,10 @@ def rehearse_aot(only=()):
         mesh = build.make_mesh(cell, devices=topo.devices)
         gb = cell.chips * cell.traffic["per_chip_batch"]
         batch = NamedSharding(mesh, P(tuple(mesh.axis_names)))
-        row = {"cell": cell.name, "chips": cell.chips}
-        together = 0
+        # a snapshot before the first dispatch and after each followed one
+        row = {"cell": cell.name, "chips": cell.chips,
+               "follower_copies": (2 + run.SOLO_WARMUP_STEPS
+                                   if cell.config["reference"] else 0)}
         for name in cell.traffic["arms"]:
             arm = build.build_arm(cell, name, mesh)
             key = jax.ShapeDtypeStruct((2,), jnp.uint32,
@@ -146,34 +183,31 @@ def rehearse_aot(only=()):
                     key).compile()
             program = check.check_program(arm) if name == "dgc" else None
             if program is not None:
-                mem = program[0].lower(key).compile().memory_analysis()
-                row["check_temp_bytes"] = mem.temp_size_in_bytes
+                stages = check.stage_bytes(program)
+                row["check_stage_bytes"] = stages
+                row["check_bytes"] = max(stages.values())
+                row["check_bytes_per_T"] = (row["check_bytes"]
+                                            / arm.setup.engine.T)
             mem = compiled.memory_analysis()
             hlo = compiled.as_text()
-            # donated state: the outputs alias the arguments
-            per_chip = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
-                        + mem.output_size_in_bytes
-                        - mem.alias_size_in_bytes)
-            together += mem.argument_size_in_bytes
             row[name] = {
                 "argument_bytes": mem.argument_size_in_bytes,
                 "temp_bytes": mem.temp_size_in_bytes,
-                "program_bytes_per_chip": per_chip,
+                # donated state: the outputs alias the arguments
+                "program_bytes_per_chip": check.program_bytes(compiled),
                 "mosaic_calls": hlo.count("tpu_custom_call"),
                 "collectives": sorted({
                     op for op in ("all-reduce", "all-gather", "all-to-all",
                                   "collective-permute", "reduce-scatter")
                     if f" {op}(" in hlo or f" {op}-start(" in hlo}),
             }
-            row["largest_program_bytes"] = max(
-                row.get("largest_program_bytes", 0), per_chip)
-        # both arms' states are resident at once; the steps run one at a
-        # time, so the larger program's temporaries count once
-        row["both_states_and_largest_temp"] = together + max(
-            row[n]["temp_bytes"] for n in cell.traffic["arms"])
-        row["fits_16GB"] = row["both_states_and_largest_temp"] < HBM_BYTES
-        assert row["fits_16GB"], row
+        row["needs_bytes"], row["host_bytes"], row["law"] = memory_law(row)
+        row["fits"] = bool(row["needs_bytes"] < HBM_BYTES
+                           and row.get("check_bytes", 0) < HBM_BYTES
+                           and row["host_bytes"] < HOST_BYTES[cell.chips])
         print(json.dumps(row), flush=True)
+        if not row["fits"]:
+            raise SystemExit("refused: " + row["law"])
 
 
 def main(argv):

@@ -1,20 +1,22 @@
-"""One run of one cell: set up, warm up, check, measure, print.
+"""One run of one cell: set up, warm up, measure, check, print.
 
     python3 benchmark/run.py --workload <name> --seed <n> --seconds <s> \
         --trace <0|1>
 
 A fresh process per run. It refuses a backend that is not a TPU, builds the
 cell's arms from the repo's config tree (``benchmark/build.py``), warms up
-exactly the programs the window uses, checks the exchange engine against
-its plain reference and the arms against each other, and then measures
-for ``--seconds`` seconds in rounds: ``round_steps`` donated per-dispatch
+exactly the programs the window uses, and measures for ``--seconds``
+seconds in rounds: ``round_steps`` donated per-dispatch
 steps of one arm, dispatched back to back and ended by one
 ``block_until_ready``, then the same for the other arm, the order of the
 arms alternating from round to round. Nothing may compile inside the
 window; a run in which something does exits non-zero. Once the window has
-closed, the configuration's plain reference of the model follows the first
-steps that set-up drove through the window's own call
-(``benchmark/model_check.py``).
+closed, the peak has been read and the arms' states are freed, the two
+checks run on the device the arms have left: the exchange engine against
+its plain reference (``benchmark/check.py``), and the configuration's plain
+reference of the model over the first steps that set-up drove through the
+window's own call (``benchmark/model_check.py``). So a cell needs of the
+chip what its arms need, and no check needs more than that.
 
 ``--trace 1`` builds the steps with the ``dgcph.*`` markers on (their
 executables have cache entries of their own), runs the same window, then
@@ -42,6 +44,7 @@ import shutil
 import statistics
 import sys
 import tempfile
+from typing import Dict, List
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
@@ -235,12 +238,10 @@ def _measure(cell, seed, seconds, trace, devices):
             jax.block_until_ready(run.state)
             lap("warmup")
             if name == "dgc":
-                # the DGC job's own peak: before another arm's state (or
-                # the check's temporaries) exists on the device
+                # the DGC job's own peak: before another arm's state
+                # exists on the device
                 dgc_peak = hbm_peak_bytes(cell_devices)
                 engine = engine_info(arm)
-                check = exchange_check(arm, seed)
-                lap("check")
         names = list(runs)
         dispatches = traffic["round_steps"]
         # one whole interleaved round, discarded (bench.py: the first
@@ -248,13 +249,6 @@ def _measure(cell, seed, seconds, trace, devices):
         for name in names:
             run_round(runs[name], feed, spans, dispatches)
         lap("warmup")
-
-        losses_ref = list(first_loss.values())
-        step0_ok = all(abs(v - losses_ref[0])
-                       <= STEP0_LOSS_RTOL * abs(losses_ref[0])
-                       for v in losses_ref)
-        log("check", exchange=check, step0_loss=first_loss,
-            step0_loss_rtol=STEP0_LOSS_RTOL, step0_ok=step0_ok)
 
         # ---- the window ------------------------------------------------ #
         for run in runs.values():
@@ -296,11 +290,22 @@ def _measure(cell, seed, seconds, trace, devices):
             traced = _profile(cell, runs, feed, spans, steps_per_round
                               // dispatches)
 
-        # the program's peak, then its state freed, then the reference:
-        # nothing of the model check is in the peak or in setup_s
+        # the program's peak, then its state freed, then the two checks
+        # on the device the arms have left: neither is in the peak or in
+        # setup_s
         memory_peak = int(hbm_peak_bytes(cell_devices))
         for run in runs.values():
             run.state = None
+        if "dgc" in runs:
+            t0 = time.perf_counter()
+            check = exchange_check(runs["dgc"].arm, seed)
+            check["check_s"] = time.perf_counter() - t0
+        losses_ref = list(first_loss.values())
+        step0_gap = max(abs(v - losses_ref[0])
+                        / (abs(losses_ref[0]) or 1.0) for v in losses_ref)
+        step0_ok = bool(step0_gap <= STEP0_LOSS_RTOL)
+        log("check", exchange=check, step0_loss=first_loss,
+            step0_loss_rtol=STEP0_LOSS_RTOL, step0_ok=step0_ok)
         model = model_check.compare(cell, followers, first_batch)
         log("model_check", **model)
     finally:
@@ -313,6 +318,7 @@ def _measure(cell, seed, seconds, trace, devices):
         "rows": rows, "steps_per_round": steps_per_round,
         "window_s": window_s, "attempted": attempted, "failed": failed,
         "check": check, "model_check": model, "step0_ok": step0_ok,
+        "step0_gap": step0_gap,
         "dgc_peak_bytes": dgc_peak,
         "window_spans": window_spans, "traced": traced, "engine": engine,
     }
@@ -450,6 +456,33 @@ def is_correct(m) -> bool:
                 and m["step0_ok"] and m["failed"] == 0)
 
 
+def compared(m) -> Dict[str, List[float]]:
+    """Every number ``is_correct`` rests on, beside its limit: [number,
+    limit]. A count's limit is 0, a floor is met from above (``fill``,
+    ``recall``), every other limit from below."""
+    out = {"step0_loss_gap": [m["step0_gap"], STEP0_LOSS_RTOL],
+           "nonfinite_losses": [m["failed"], 0]}
+    check = m["check"]
+    if "skipped" not in check:
+        for key in ("inexact_residual_coords", "unconserved_coords",
+                    "over_quota_rows", "sent_outside_rows"):
+            out["exchange." + key] = [check[key], 0]
+        out["exchange.fill_floor"] = [check["fill"], check["fill_floor"]]
+        out["exchange.recall_floor"] = [check["recall"],
+                                        check["recall_floor"]]
+        # the bucket nearest its own floor
+        out["exchange.bucket_recall_floor"] = list(min(
+            zip(check["recall_per_bucket"],
+                check["recall_floor_per_bucket"]),
+            key=lambda pair: pair[0] - pair[1]))
+    model = m["model_check"]
+    for arm, numbers in (model.get("arms") or {}).items():
+        for key, limit in model["limits"].items():
+            if key in numbers:
+                out[f"{arm}.{key}"] = [numbers[key]["max"], limit]
+    return out
+
+
 def parse_args(argv):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -524,6 +557,11 @@ def main(argv=None):
     result["metrics"] = {k: {"value": v, "unit": units[k]}
                          for k, v in values.items()}
     result["device"] = device
+    # what was compared, each number beside its limit: last in the line,
+    # and the last lines of standard error
+    result["compared"] = compared(m)
+    for name, (number, limit) in result["compared"].items():
+        print(f"compared {name} {number!r} limit {limit!r}", file=sys.stderr)
     print(json.dumps(result), flush=True)
 
 

@@ -52,6 +52,30 @@ def test_traced_measure_puts_the_harness_spans_on_the_trace():
     assert out["per_layer_read"] == ["input.wait_ms"]
 
 
+def test_the_memory_law_refuses_with_its_numbers():
+    """``rehearse.py aot``'s rule on a compiler's numbers (bytes a chip;
+    these are a 461M-parameter token model's at 16 rows of 256, PR 27):
+    both states and the larger step's temporaries, the check on its own,
+    and a refusal that states every one of them."""
+    row = {"cell": "wide", "chips": 1, "check_bytes": 8884226048,
+           "dgc": {"argument_bytes": 7439714304, "temp_bytes": 4390782976},
+           "dense": {"argument_bytes": 3691021312, "temp_bytes": 6359577600}}
+    needs, host, said = rehearse.memory_law(row)
+    assert needs == 7439714304 + 3691021312 + 6359577600 > rehearse.HBM_BYTES
+    # no reference of the model, nothing copied: the process's baseline
+    assert host == int(rehearse.HOST_BASELINE_BYTES)
+    for number in (needs, 7439714304, 3691021312, 6359577600, 8884226048):
+        assert str(number) in said
+    # with a reference the followers' four copies of both states bind the
+    # host long before the chip: 58.7 GB against 40 GiB
+    needs, host, said = rehearse.memory_law({**row, "follower_copies": 4})
+    assert host == int(rehearse.HOST_BASELINE_BYTES) + 4 * (
+        7439714304 + 3691021312) > rehearse.HOST_BYTES[1]
+    assert str(host) in said and str(rehearse.HOST_BYTES[1]) in said
+    del row["dense"]           # a cell of the dgc arm alone
+    assert rehearse.memory_law(row)[0] == 7439714304 + 4390782976
+
+
 # ---------------------------------------------------------------------- #
 # a token cell of new files only, and the two ways it must come out      #
 # not correct                                                            #
@@ -74,7 +98,8 @@ def loss_and_grads(params, inputs, labels):
 
 
 def _new_token_cell(tmp_path, reference_scale=None, traffic_modules=(),
-                    overrides=None, dgc_module=None):
+                    overrides=None, dgc_module=None,
+                    reference_text=SCALED_REFERENCE):
     """What a later PR adds, all of it in ``tmp_path``: a configuration
     file, its reference, a traffic file, and their entries."""
     with open(os.path.join(rehearse.FIXTURE, "configs", "tiny_lm.json")) as fh:
@@ -86,7 +111,7 @@ def _new_token_cell(tmp_path, reference_scale=None, traffic_modules=(),
             os.path.relpath(tmp_path / "dgc_more.py", cells.ROOT))
     if reference_scale is not None:
         ref = tmp_path / "reference.py"
-        ref.write_text(SCALED_REFERENCE.format(
+        ref.write_text(reference_text.format(
             sound=os.path.join(cells.ROOT, cfg["reference"]),
             scale=reference_scale))
         cfg["reference"] = os.path.relpath(ref, cells.ROOT)
@@ -120,6 +145,19 @@ def test_a_new_token_cell_runs_and_is_correct(tmp_path):
     m = _measure(_new_token_cell(tmp_path))
     assert m["check"]["ok"] and m["model_check"]["ok"] and m["step0_ok"]
     assert m["attempted"] > 0 and run.is_correct(m)
+    # every number the verdict rests on stands beside its limit, and in a
+    # correct run is inside it (a floor is met from above)
+    got = run.compared(m)
+    assert set(got) == {
+        "step0_loss_gap", "nonfinite_losses",
+        "exchange.inexact_residual_coords", "exchange.unconserved_coords",
+        "exchange.over_quota_rows", "exchange.sent_outside_rows",
+        "exchange.fill_floor", "exchange.recall_floor",
+        "exchange.bucket_recall_floor", "dgc.loss_rel_err",
+        "dgc.conserved_rel_err", "dense.loss_rel_err", "dense.grad_rel_err",
+        "dense.update_norm_gap"}
+    assert all(number >= limit if name.endswith("_floor")
+               else number <= limit for name, (number, limit) in got.items())
     # a reference scaled by exactly 1 is the sound one: the wrapper itself
     # changes nothing
     m = _measure(_new_token_cell(tmp_path, reference_scale=1.0))
@@ -232,6 +270,8 @@ def test_a_step_that_returns_its_state_unchanged_is_not_correct(
     assert got["dense", "update_norm_gap"] == pytest.approx(1.0)
     assert got["dense", "grad_rel_err"] == pytest.approx(1.0)
     assert got["dgc", "conserved_rel_err"] == pytest.approx(1.0)
+    number, limit = run.compared(m)["dgc.conserved_rel_err"]
+    assert number == got["dgc", "conserved_rel_err"] > limit
 
 
 def test_a_step_that_trains_on_half_the_batch_is_not_correct(
@@ -253,6 +293,53 @@ def test_a_step_that_trains_on_half_the_batch_is_not_correct(
     got = _maxima(model)
     assert got["dense", "loss_rel_err"] > 1000 * LOSS_RTOL
     assert got["dgc", "loss_rel_err"] > 1000 * LOSS_RTOL
+
+
+ROW_BLOCK_REFERENCE = SCALED_REFERENCE.replace(
+    "def loss_and_grads", "ROW_BLOCK = 1\n\n\ndef loss_and_grads")
+
+
+def test_a_reference_called_a_row_at_a_time_gives_the_whole_batchs_numbers(
+        tmp_path):
+    import jax
+    import numpy as np
+    from benchmark import model_check
+    sound = os.path.join(rehearse.FIXTURE, "references", "tiny_lm.py")
+    (tmp_path / "blocked.py").write_text(
+        ROW_BLOCK_REFERENCE.format(sound=sound, scale=1.0))
+    blocked = cells.load_reference(
+        os.path.relpath(tmp_path / "blocked.py", cells.ROOT))
+    assert blocked.ROW_BLOCK == 1 and not hasattr(_LIMITS, "ROW_BLOCK")
+    (tmp_path / "no_rows.py").write_text(
+        ROW_BLOCK_REFERENCE.replace("ROW_BLOCK = 1", "ROW_BLOCK = 0").format(
+            sound=sound, scale=1.0))
+    with pytest.raises(cells.CellError, match="ROW_BLOCK must be a number "
+                                              "of rows, at least 1, got 0"):
+        cells.load_reference(
+            os.path.relpath(tmp_path / "no_rows.py", cells.ROOT))
+    rng = np.random.default_rng(0)
+    params = {"embed": {"embedding": rng.normal(size=(64, 8))},
+              "gate": {"kernel": rng.normal(size=(8, 16))},
+              "up": {"kernel": rng.normal(size=(8, 16))},
+              "down": {"kernel": rng.normal(size=(16, 8))},
+              "head": {"kernel": rng.normal(size=(8, 64))}}
+    params = jax.tree.map(lambda a: a.astype(np.float32) * 0.3, params)
+    rows, seq = 5, 7
+    tokens = rng.integers(0, 64, size=(rows, seq + 1), dtype=np.int32)
+    batch = tokens[:, :-1], tokens[:, 1:].reshape(-1)
+    with jax.default_matmul_precision("highest"):
+        loss, grads = model_check._loss_and_grads(_LIMITS)(params, *batch)
+        loss_b, grads_b = model_check._loss_and_grads(blocked)(params, *batch)
+    assert float(loss_b) == pytest.approx(float(loss), rel=1e-6)
+    for want, got in zip(jax.tree.leaves(grads), jax.tree.leaves(grads_b)):
+        assert np.linalg.norm(got - want) <= 1e-6 * np.linalg.norm(want)
+    # and under a whole run the four numbers stay inside their limits
+    m = _measure(_new_token_cell(tmp_path, reference_scale=1.0,
+                                 reference_text=ROW_BLOCK_REFERENCE))
+    assert run.is_correct(m), m["model_check"]
+    limits = m["model_check"]["limits"]
+    assert all(value <= limits[key]
+               for (_, key), value in _maxima(m["model_check"]).items())
 
 
 def test_a_nan_in_one_tensor_is_the_worst_and_not_correct():
