@@ -17,8 +17,8 @@ step:
 
 There is no ``synchronize`` step: the reference needs it because Horovod ops
 are async handles drained at ``optimizer.step()``; under XLA the whole step is
-one program and the latency-hiding scheduler overlaps collectives with compute
-automatically.
+one program, and its scheduler runs a collective beside compute where the
+dataflow lets it (``training/step.py``).
 """
 
 from typing import Any, NamedTuple, Optional, Tuple
@@ -75,9 +75,10 @@ class _DenseCompressor(Compressor):
         return grad
 
     def make_flat_exchange(self, layout, plan=None):
-        """Flat-path capability: one psum over the whole gradient buffer.
-        ``plan`` is accepted for interface parity with the DGC engine and
-        ignored — the dense exchange has exactly one regime."""
+        """Flat-path capability: the gradient buffer all-reduced, one psum
+        per layout segment. ``plan`` is accepted for interface parity with
+        the DGC engine and ignored — the dense exchange has exactly one
+        regime."""
         from dgc_tpu.compression.flat import FlatDenseExchange
         return FlatDenseExchange(self, layout)
 

@@ -91,14 +91,15 @@ def _round_up(n: int, align: int) -> int:
     return -(-n // align) * align
 
 
-def _count_collective(kind: str, operand, axis, engine) -> None:
+def _count_collective(kind: str, operand, axis, engine, **where) -> None:
     """One ``exchange.collective`` count where a collective is issued:
     the bytes THIS worker hands it, from the traced operand's shape and
     dtype — what the program puts on the wire, not what the planner
-    modeled (trace time only; returns at once with tracing off)."""
+    modeled (trace time only; returns at once with tracing off).
+    ``where`` names the part of the buffer it carries (``segment=i``)."""
     _trace.count("exchange.collective",
                  int(operand.size) * operand.dtype.itemsize, kind=kind,
-                 axis=str(axis), engine=type(engine).__name__)
+                 axis=str(axis), engine=type(engine).__name__, **where)
 
 
 class _BucketGeom(NamedTuple):
@@ -263,6 +264,24 @@ class ParamLayout:
             parts.append(jnp.zeros((self.total - self.p_data_end,),
                                    self.dtype))
         return jnp.concatenate(parts)
+
+    def pieces(self) -> List[Tuple[int, int, Tuple[str, ...]]]:
+        """The flat buffer cut where its content changes hands: half-open
+        runs ``(start, stop, names)`` that tile ``[0, total)`` once, in
+        storage order — each size bucket's tile, the gap up to
+        ``t_compressed``, each dense-tail tensor, the buffer's padding.
+        A run holds the gradients of ``names`` (none: structural zeros)
+        and of no other tensor, so what is computed from it alone can
+        start when THEY are final."""
+        cuts = [(g.base, g.names) for g in self.buckets]
+        cuts.append((self.t_data, ()))
+        cuts += [(self.offsets[n], (n,)) for n in self.dense_names]
+        cuts.append((self.p_data_end, ()))
+        out = []
+        for (lo, names), (hi, _) in zip(cuts, cuts[1:] + [(self.total, ())]):
+            if hi > lo:
+                out.append((lo, hi, tuple(names)))
+        return out
 
     def unflatten(self, flat: jax.Array, transform=None):
         """Flat [P] -> pytree with the original structure. ``transform``
@@ -3025,14 +3044,80 @@ class FlatDGCEngine:
 
 class FlatDenseExchange:
     """Flat-path counterpart for the dense baseline compressors
-    (``NoneCompressor``/``FP16Compressor``): one psum over the whole flat
-    gradient buffer."""
+    (``NoneCompressor``/``FP16Compressor``): the flat gradient buffer
+    all-reduced, one psum per segment of the layout (``segments``)."""
 
     payload_size = 0
+    #: the step may hand ``exchange`` the order in which the tensors'
+    #: gradients become final (``grad_ready``, training/step.py)
+    takes_grad_ready = True
+    #: slots below which a piece of the buffer does not get a collective
+    #: of its own (``segments``). At 2**20, 4 MiB of float32, VGG-16-BN's
+    #: nine largest tensors go alone and the other 49 ride two collectives:
+    #: eleven on four v5e chips, all but the last (0.04 ms) over before the
+    #: backward pass is (PERF.md section 6, PR 28). No other size was read.
+    MIN_SEGMENT = 1 << 20
 
     def __init__(self, compressor, layout: ParamLayout):
         self.c = compressor
         self.layout = layout
+
+    def segments(self, grad_ready: Optional[Dict[str, int]] = None
+                 ) -> List[List[Tuple[int, int]]]:
+        """What each collective of the exchange carries, in the order the
+        collectives are issued: lists of ``(start, stop)`` runs of the flat
+        buffer that together tile it once. The layout's pieces are taken
+        in the order their gradients become final (``grad_ready``: tensor
+        name -> position in the backward pass; storage order without it).
+        A piece of ``MIN_SEGMENT`` slots goes alone, so that no large
+        tensor is copied to sit beside a small one; smaller pieces ride
+        together and leave once they hold that much."""
+        pieces = self.layout.pieces()
+        if grad_ready is not None:
+            # structural zeros wait for nothing and go last
+            last = max(grad_ready.values())
+            pieces.sort(key=lambda p: max((grad_ready[n] for n in p[2]),
+                                          default=last))
+        out, small, held = [], [], 0
+        for lo, hi, _ in pieces:
+            if hi - lo >= self.MIN_SEGMENT:
+                out.append([(lo, hi)])
+                continue
+            small.append((lo, hi))
+            held += hi - lo
+            if held >= self.MIN_SEGMENT:
+                out.append(small)
+                small, held = [], 0
+        if small:
+            out.append(small)
+        return out
+
+    def _psum_segments(self, wire, axis_name, world_size, grad_ready):
+        """``psum(wire)`` as one collective per segment, joined back in
+        storage order. A segment's collective reads the gradients of its
+        own tensors only, so it can run beside what is left of the backward
+        pass. Each operand is tied to the result of the collective before
+        it by an optimization barrier: until XLA expands the barriers, late
+        in its pipeline, that keeps its combiner from re-joining the
+        collectives, and its scheduler then keeps them in the order they
+        were issued. Over one worker there is nothing to cut: the psum is
+        elided."""
+        segments = self.segments(grad_ready)
+        if world_size == 1 or len(segments) < 2:  # dgclint: ok[tracer-branch] — the world size and the layout's cut are static
+            _count_collective("psum", wire, axis_name, self, segment=0)
+            return jax.lax.psum(wire, axis_name)
+        reduced, before = {}, None
+        for i, runs in enumerate(segments):
+            operand = jnp.concatenate([wire[lo:hi] for lo, hi in runs])
+            if before is not None:
+                operand, _ = jax.lax.optimization_barrier((operand, before))
+            _count_collective("psum", operand, axis_name, self, segment=i)
+            before = jax.lax.psum(operand, axis_name)
+            at = 0
+            for lo, hi in runs:
+                reduced[lo] = before[at:at + hi - lo]
+                at += hi - lo
+        return jnp.concatenate([reduced[lo] for lo in sorted(reduced)])
 
     def init_memory(self) -> Dict:
         return {}
@@ -3040,7 +3125,8 @@ class FlatDenseExchange:
     def exchange(self, flat_grad, mem, key, axis_name, world_size,
                  op: str = "average", local_axis: Optional[str] = None,
                  local_size: int = 1, telemetry: bool = False,
-                 health_out: Optional[Dict] = None, send_frac=None):
+                 health_out: Optional[Dict] = None, send_frac=None,
+                 grad_ready: Optional[Dict[str, int]] = None):
         # health_out/send_frac accepted for signature parity with
         # FlatDGCEngine; the dense psum has no sparse payload to checksum
         # and no per-worker quota for the adaptive policy to shrink
@@ -3083,8 +3169,8 @@ class FlatDenseExchange:
         # the gradient all-reduce DGC exists to replace: its own phase, as
         # the engine's dense tail has, not filed under the step's update
         with _trace.phase("dense"):
-            _count_collective("psum", wire, axis_name, self)
-            total = jax.lax.psum(wire, axis_name)
+            total = self._psum_segments(wire, axis_name, world_size,
+                                        grad_ready)
         total = self.c._unwire(total, flat_grad.dtype)
         out = (total / world_size if op == "average" else total).astype(
             flat_grad.dtype)

@@ -4,10 +4,16 @@ TPU-native equivalent of the reference's patched Horovod
 ``_DistributedOptimizer`` (/root/reference/dgc/horovod/optimizer.py:105-194).
 The reference registers per-parameter autograd hooks that launch async
 collectives during backward and drains them in ``step()``; here the exchange
-is ordinary dataflow inside the jitted step — XLA's latency-hiding scheduler
-overlaps the collectives with the remaining backward compute, which is the
-compiler-managed version of the reference's hook overlap (SURVEY.md §2
-"Async overlap" row).
+is ordinary dataflow inside the jitted step, and XLA's scheduler can run a
+collective beside the remaining backward compute only where the program
+lets it: one psum over the flat [P] buffer depends on every gradient and
+ran with nothing beside it (9.71 ms of a 70.59 ms dense step on four v5e
+chips; ledger, PR 27). Since PR 28 the dense exchange issues one collective
+per layout segment in the order the backward pass finishes them, and the
+step is compiled with XLA:TPU's asynchronous all-reduce on
+(``training/step.py``): on the same chips every all-reduce but the last
+4 MB ends under the backward pass (PERF.md section 6, PR 28). The DGC
+engine's collectives are 0.09 ms there and are left as they were.
 
 The plugin boundary survives intact (optimizer.py:39-40): for every gradient
 the optimizer calls ``compressor.compress → communicate → decompress`` and is
@@ -137,7 +143,8 @@ class DistributedOptimizer:
     def update_flat(self, flat_grads, opt_state, flat_params, mem_state,
                     key, engine, telemetry: bool = False,
                     health_out: Optional[Dict] = None,
-                    send_frac=None):
+                    send_frac=None,
+                    grad_ready: Optional[Dict[str, int]] = None):
         """Flat-path analogue of :meth:`update`: fused exchange over the [P]
         buffer, then the wrapped optimizer on the same buffer.
 
@@ -146,7 +153,10 @@ class DistributedOptimizer:
         extra. ``health_out`` forwards to the engine's exchange (payload-
         checksum mismatch counter, see ``resilience.integrity``);
         ``send_frac`` forwards this worker's adaptive send fraction
-        (``resilience.adaptive``; None is Python-static off)."""
+        (``resilience.adaptive``; None is Python-static off);
+        ``grad_ready`` goes to an engine that ``takes_grad_ready`` (tensor
+        name -> when its gradient is final, ``training/step.py``)."""
+        ready = {} if grad_ready is None else {"grad_ready": grad_ready}
         # parts of the step's ``update`` phase: the engine's own phases
         # nest inside ``exchange``, and what they leave is its glue
         with _trace.phase("update", part="exchange"):
@@ -155,13 +165,13 @@ class DistributedOptimizer:
                     flat_grads, mem_state, key, self.axis_name,
                     self.num_nodes, local_axis=self.local_axis_name,
                     local_size=self.local_size, telemetry=True,
-                    health_out=health_out, send_frac=send_frac)
+                    health_out=health_out, send_frac=send_frac, **ready)
             else:
                 exchanged, mem_state = engine.exchange(
                     flat_grads, mem_state, key, self.axis_name,
                     self.num_nodes, local_axis=self.local_axis_name,
                     local_size=self.local_size, health_out=health_out,
-                    send_frac=send_frac)
+                    send_frac=send_frac, **ready)
         with _trace.phase("update", part="optimizer"):
             updates, opt_state = self.optimizer.update(exchanged, opt_state,
                                                        flat_params)
