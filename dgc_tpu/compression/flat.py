@@ -35,9 +35,11 @@ an identity index map, never shrink them), scatter-add-then-average
 decompress, momentum correction and masking per SURVEY.md §2.3-2.5.
 """
 
+import collections
+import dataclasses
 import math
 import os
-from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -700,6 +702,48 @@ def _batched_adapt(imp_rows, thr, num_selects, adapt_mask, lower, upper,
     return thr
 
 
+class _Lanes(NamedTuple):
+    """The sparse wire: what one worker hands the all-gathers (gathered:
+    the same with a leading [W]), in the order they are issued. A lane the
+    plan does not use is None."""
+    q: Optional[jax.Array] = None       # int8: int8 payload, int4 nibbles
+    f32: Optional[jax.Array] = None     # native values, then both scales
+    f16: Optional[jax.Array] = None
+    words: Optional[jax.Array] = None   # uint32: bit-packed, Elias-Fano
+    plain: Optional[jax.Array] = None   # flat offsets
+
+
+#: one gossip round (compression/gossip.py): the dropped workers ([W] bool,
+#: under fault injection only, else None), is it a full sync, was that forced
+#: by staleness, the new ages, this worker's index, each sender's row weight
+_GossipRound = collections.namedtuple(
+    "_GossipRound", "dropped full forced new_age widx row_w")
+
+
+@dataclasses.dataclass
+class _Exchange:
+    """What one ``FlatDGCEngine.exchange`` call carries from stage to stage
+    (trace-time values; a stage fills what the later ones read)."""
+    grad: Any                       # [P], node-reduced
+    mem: Dict                       # the memory as handed in
+    taps: Any = None                # telemetry.taps with telemetry on
+    grad_norm: Any = None
+    clip_delta: Any = None
+    gd: Any = None                  # grad[T:]
+    mc: Any = None                  # live momentum, velocity of [0, T)
+    vc: Any = None
+    md: Any = None                  # live momentum of the tail
+    mc_prev: Any = None             # mc, vc before this step's compensate
+    vc_prev: Any = None
+    gossip: Optional[_GossipRound] = None
+    sel_stats: Optional[Dict] = None
+    values: Any = None              # this worker's payload
+    indices: Any = None
+    acc: Any = None                 # [T] the sparse tier's contribution
+    new_bits: Any = None            # this step's transmit record
+    inbox: Any = None               # gossip: the round's neighbor mass
+
+
 class FlatDGCEngine:
     """Fused flat-buffer execution of the DGC pipeline for one compressor +
     layout pair. Rebuilt (cheaply, host-side) whenever the warm-up schedule
@@ -847,11 +891,9 @@ class FlatDGCEngine:
                 ck.append((plo, plo + b.payload, blo, blo + nb))
                 plo, blo = plo + b.payload, blo + nb
             self._i4_chunks = tuple(ck)
-            self._i4_bytes = blo
         else:
             self._i4_map = None
             self._i4_chunks = ()
-            self._i4_bytes = 0
         #: static mask of int8 payload slots — only needed when int8
         #: error feedback must coexist with deferred-masking (non-i8)
         #: buckets in one mixed plan; None for every uniform plan
@@ -1024,23 +1066,15 @@ class FlatDGCEngine:
         return base + ("_packed"
                        if getattr(c, "packed_indices", False) else "")
 
-    def _kind_chunks(self, arr: jax.Array, kind: str) -> jax.Array:
-        """Concatenated payload chunks of the sparse buckets whose value
-        kind is ``kind`` — the identity when every sparse bucket shares
+    def _chunks(self, arr: jax.Array, flags, want) -> jax.Array:
+        """Concatenated payload chunks of the sparse buckets whose flag in
+        ``flags`` (``self._kinds``: the value lane; ``self._packed``: the
+        three-valued index lane) is ``want`` — the identity when all share
         it (uniform plans keep their exact pre-planner wire arrays)."""
-        if all(k == kind for k in self._kinds):
+        if all(f == want for f in flags):  # dgclint: ok[tracer-branch] — flags are plan-static regime flags, not a tracer
             return arr
-        parts = [arr[s0:s1] for (s0, s1), k
-                 in zip(self._payload_slices, self._kinds) if k == kind]
-        return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
-
-    def _packed_chunks(self, arr: jax.Array, packed) -> jax.Array:
-        """Same, for the index lanes (packed words / Elias-Fano words /
-        plain offsets — ``packed`` is the three-valued regime flag)."""
-        if all(p == packed for p in self._packed):  # dgclint: ok[tracer-branch] — self._packed is plan-static regime flags, not a tracer
-            return arr
-        parts = [arr[s0:s1] for (s0, s1), p
-                 in zip(self._payload_slices, self._packed) if p == packed]
+        parts = [arr[s0:s1] for (s0, s1), f
+                 in zip(self._payload_slices, flags) if f == want]
         return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
 
     def _sort_delta_payload(self, values: jax.Array, indices: jax.Array
@@ -1072,7 +1106,7 @@ class FlatDGCEngine:
         """Decode the gathered int4 nibble bytes back to values: unpack
         each bucket's byte span (odd payloads drop the zero pad nibble),
         then rescale by that bucket's f32 scale. ``g_q4`` is
-        [W, _i4_bytes] int8, ``g_scale4`` starts with the
+        [W, nibble bytes] int8, ``g_scale4`` starts with the
         [W, _i4_buckets] per-bucket scales; returns [W, i4 payload] in
         ``dt``."""
         from dgc_tpu.compression.wirecodec import unpack_int4
@@ -1087,34 +1121,18 @@ class FlatDGCEngine:
     # -------------------------------------------------------------- #
 
     def wire_bytes_per_worker(self) -> int:
-        """Static per-worker sparse wire bytes per step, lane-exact under
-        the active plan: the value lanes (int8 payload + per-row f32
-        scales / fp16 / native precision) + the index lanes (packed
-        bitstream words / flat offsets). Dense-planned buckets ride the
-        fallback psum and cost 0 here — the psum is the same on both arms
-        of every comparison. Uniform plans report exactly the pre-planner
-        figures."""
+        """Static per-worker sparse wire bytes per step under the active
+        plan: the sizes of the lanes ``_encode_values`` and
+        ``_encode_indices`` lay out (checksum words apart). Dense-planned
+        buckets ride the fallback psum, the same on both arms: 0 here."""
         if not self.payload_size:
             return 0
-        kp = self._kind_payload
-        val = 0
-        if kp.get("i8"):
-            val += kp["i8"] + 4 * self._i8_rows
-        if kp.get("i4"):
-            val += self._i4_bytes + 4 * self._i4_buckets
-        if kp.get("f16"):
-            val += 2 * kp["f16"]
-        if kp.get("f32"):
-            val += kp["f32"] * np.dtype(self.layout.dtype).itemsize
-        idx = 0
-        if self._codec is not None:
-            idx += 4 * self._codec.nwords
-        if self._dcodec is not None:
-            idx += 4 * self._dcodec.nwords
-        if self._plain_payload:
-            idx += (self._plain_payload
-                    * jnp.dtype(self.index_dtype).itemsize)
-        return int(val + idx)
+        lanes = jax.eval_shape(
+            lambda v, i: (self._encode_values(v)[0], self._encode_indices(i)),
+            jax.ShapeDtypeStruct((self.payload_size,), self.layout.dtype),
+            jax.ShapeDtypeStruct((self.payload_size,), self.index_dtype))
+        return sum(lane.size * lane.dtype.itemsize
+                   for lane in jax.tree_util.tree_leaves(lanes))
 
     def bucket_wire_bytes(self) -> List[int]:
         """Per-bucket sparse wire bytes under the active plan (the
@@ -1223,14 +1241,9 @@ class FlatDGCEngine:
         # 0.83 ms/step at ResNet-50 scale on v5e). The record is
         # BIT-PACKED (sent_bits, kernels.pack_sent_bits — one int32 word
         # per 32 coordinates): per-worker payload indices are unique, so
-        # one word-wide scatter of single bits replaces the v0.3 full-[T]
-        # f32 count vector — 32x less HBM on the kernel's mask stream,
-        # the per-step zero-init, and the state carried between steps.
-        # (An int8 byte mask was rejected earlier for its sub-word
-        # scatter, which lowers to a serial while-loop on v5e; the
-        # word-wide bit scatter has no such problem.) The record's shape
-        # is ratio-independent, so checkpoints survive warm-up ratio
-        # changes.
+        # one word-wide scatter of single bits builds it (a sub-word byte
+        # mask would lower to a serial while-loop on v5e). Its shape is
+        # ratio-independent, so checkpoints survive warm-up ratio changes.
         out = {"momentums_c": zc, "velocities_c": zc,
                "momentums_d": zd, "velocities_d": zd,
                "sent_bits": jnp.zeros((kernels.num_sent_words(T) if T else 0,),
@@ -1280,12 +1293,8 @@ class FlatDGCEngine:
         seg_top2_reference downstream) stays equivalent.
 
         With a narrow (bf16) state dtype the compensated gradient is the
-        bf16 velocity and the selection pipeline runs on it directly.
-        (A split-output variant emitting a pre-rounding f32 comp from the
-        same pass was built and measured — it recovered nothing at
-        ResNet-50 (6.53 vs 6.62 ms naive, the bf16 delta lives in the
-        K-loop state carry, not selection) and LOST 4.5 ms/step at VGG;
-        reverted, recorded in docs/RESULTS.md.)"""
+        bf16 velocity and the selection pipeline runs on it directly (a
+        split-output f32 variant lost: docs/RESULTS.md)."""
         m = self._mem
         n = mmt.shape[0] if hasattr(mmt, "shape") else 0
         if m is None:
@@ -1599,11 +1608,8 @@ class FlatDGCEngine:
         per wire entry on CPU — minutes per step at warmup-ratio
         payloads), so at real scale off-TPU the engine silently keeps
         the XLA scatter path."""
-        if not getattr(self.c, "fused_apply", False):
-            return False
-        if kernels._interpret() and self.payload_size > 4096:
-            return False
-        return self._apply_kernel_ok("fused_apply", m, int8_ef, dt)
+        return (getattr(self.c, "fused_apply", False)
+                and self._apply_kernel_ok("fused_apply", m, int8_ef, dt))
 
     def _decline(self, flag: str, why: str) -> bool:
         """A kernel flag the user set that the engine cannot honour for a
@@ -1617,8 +1623,11 @@ class FlatDGCEngine:
         return False
 
     def _apply_kernel_ok(self, flag: str, m, int8_ef: bool, dt) -> bool:
-        """Preconditions the two fused apply kernels share — none of
-        them bucket geometry (see :meth:`_decline`)."""
+        """Preconditions the two fused apply kernels share: the
+        interpreter's payload bound (see :meth:`_use_fused_apply`), then
+        those that are not bucket geometry (see :meth:`_decline`)."""
+        if kernels._interpret() and self.payload_size > 4096:
+            return False
         if (m is not None and not int8_ef and dt == jnp.float32  # dgclint: ok[tracer-branch] — memory/wire dtype/T are plan-static Python values, not tracers
                 and self.T % kernels._LANE == 0):
             return True
@@ -1694,11 +1703,8 @@ class FlatDGCEngine:
         so the divided [W * payload] wire never materializes in HBM.
         Same preconditions as :meth:`_use_fused_apply`, keyed on the
         megakernel opt-in instead of ``fused_apply``."""
-        if not self._megakernel:
-            return False
-        if kernels._interpret() and self.payload_size > 4096:
-            return False
-        return self._apply_kernel_ok("megakernel", m, int8_ef, dt)
+        return (self._megakernel
+                and self._apply_kernel_ok("megakernel", m, int8_ef, dt))
 
     def _compensate_megakernel(self, mmt, vec, grad, sent_bits):
         """Forward-megakernel compensate over [0, T): eligible buckets
@@ -1949,6 +1955,42 @@ class FlatDGCEngine:
             vals = jnp.where(valid, sel_vals, jnp.zeros((), vec_c.dtype))
         return vals, gidx
 
+    def _select(self, bi: int, block, scores, fwd_sel):
+        """A bucket's fixed-size selection over its [R, cols] importance:
+        ``(top_scores, signed payload values or None, columns)``, by
+        any route bitwise the same set."""
+        b = self.buckets[bi]
+        fused = (fwd_sel or {}).get(bi)  # plan-static dict, not a tracer
+        if fused is not None:
+            # already emitted by the forward megakernel's compensate pass
+            # (bitwise select_pack_rows on the same block)
+            return fused
+        if self._use_fused_select(b):
+            # fused threshold->select->pack: the kernel masks by numel and
+            # emits the top set's SIGNED payload values in the same pass —
+            # the [R, cols] importance array and the value gather disappear
+            return kernels.select_pack_rows(
+                block, jnp.asarray(b.numels, jnp.int32), b.max_sel)
+        top_scores, cols = self._select_topk(scores, b.max_sel)
+        return top_scores, None, cols
+
+    def _selection_payload(self, b: "_Bucket", block, row_off, thr,
+                           top_scores, fvals, cols):
+        """A selection as [R, max_sel] ``(values, flat indices)``: slots
+        under the row's ``thr`` (None: importance >= 0, the exact geometry)
+        or past its num_selects are invalid and carry (0.0, sentinel).
+        Values: the kernel's, else a row-local gather (no global one)."""
+        slot = jnp.arange(b.max_sel, dtype=jnp.int32)[None, :]
+        valid = (top_scores >= (0 if thr is None else thr[:, None])) & (
+            slot < jnp.asarray(b.num_selects)[:, None])
+        gidx = jnp.where(valid, row_off + cols.astype(self.index_dtype),
+                         jnp.asarray(self.layout.sentinel, self.index_dtype))
+        vals = jnp.where(valid,
+                         (fvals if fvals is not None else
+                          jnp.take_along_axis(block, cols, axis=1)),
+                         jnp.zeros((), block.dtype))
+        return vals, gidx
+
     def sparsify(self, vec_c: jax.Array, key: jax.Array, seg_cands=None,
                  fwd_sel=None, stats_out: Optional[Dict] = None):
         """Sampled-top-k selection over the compressed block [T].
@@ -2046,38 +2088,10 @@ class FlatDGCEngine:
                 # is exactly top-num_selects by importance — the selection
                 # pass below. Skip the redundant sampling/threshold pass
                 # (adaptation is statically off: numel == num_samples).
-                scores = imp_rows
                 with _trace.phase("select", bi):
-                    fused = (fwd_sel or {}).get(bi)  # plan-static dict, not a tracer
-                    if fused is not None:
-                        # selection already emitted by the forward
-                        # megakernel's compensate pass (bitwise
-                        # select_pack_rows on the same block)
-                        top_scores, fvals, cols = fused
-                    elif self._use_fused_select(b):
-                        # fused threshold->select->pack: the kernel masks
-                        # by numel, extracts the top set, and emits the
-                        # SIGNED payload values in the same pass — the
-                        # [R, cols] importance array and the value gather
-                        # both disappear (bitwise the unfused selection)
-                        top_scores, fvals, cols = kernels.select_pack_rows(
-                            block, jnp.asarray(b.numels, jnp.int32),
-                            b.max_sel)
-                    else:
-                        fvals = None
-                        top_scores, cols = self._select_topk(scores,
-                                                             b.max_sel)
-                    slot = jnp.arange(b.max_sel, dtype=jnp.int32)[None, :]
-                    valid = (top_scores >= 0) & (
-                        slot < jnp.asarray(b.num_selects)[:, None])
-                    gidx = jnp.where(valid,
-                                 row_off + cols.astype(self.index_dtype),
-                                 jnp.asarray(S, self.index_dtype))
-                    vals = jnp.where(valid,
-                                     (fvals if fvals is not None else
-                                      jnp.take_along_axis(block, cols,
-                                                          axis=1)),
-                                     jnp.zeros((), vec_c.dtype))
+                    vals, gidx = self._selection_payload(
+                        b, block, row_off, None,
+                        *self._select(bi, block, imp_rows, fwd_sel))
                 with _trace.phase("pack", bi):
                     emit(vals, gidx, b)
                 continue
@@ -2117,22 +2131,8 @@ class FlatDGCEngine:
             # depend on thr), so the resample ladder can be derived from
             # the top-k values with no extra pass over the block.
             with _trace.phase("select", bi):
-                fused = (fwd_sel or {}).get(bi)  # plan-static dict, not a tracer
-                if fused is not None:
-                    # forward-megakernel selection (see the exact branch
-                    # above); threshold adaptation below still uses
-                    # top_scores
-                    top_scores, fvals, cols = fused
-                elif self._use_fused_select(b):
-                    # fused selection (see the exact branch above): the
-                    # signed payload values ride out of the same pass;
-                    # threshold adaptation below still uses top_scores
-                    top_scores, fvals, cols = kernels.select_pack_rows(
-                        block, jnp.asarray(b.numels, jnp.int32), b.max_sel)
-                else:
-                    fvals = None
-                    top_scores, cols = self._select_topk(imp_rows,
-                                                         b.max_sel)
+                top_scores, fvals, cols = self._select(bi, block, imp_rows,
+                                                       fwd_sel)
 
             # --- bounded threshold adaptation (compression.py:128-149) ---
             if self.c.max_adaptation_iters > 0 and b.adapt.any():
@@ -2157,18 +2157,8 @@ class FlatDGCEngine:
                             self.c.compress_upper_bound,
                             self.c.max_adaptation_iters, self.c.resample)
             with _trace.phase("select", bi):
-                slot = jnp.arange(b.max_sel, dtype=jnp.int32)[None, :]
-                valid = (top_scores >= thr[:, None]) & (
-                    slot < jnp.asarray(b.num_selects)[:, None])
-                gidx = jnp.where(valid,
-                                 row_off + cols.astype(self.index_dtype),
-                                 jnp.asarray(S, self.index_dtype))
-                # values via a row-local gather from the reshape view (no
-                # global gather); invalid slots carry 0.0 like the sentinel
-                vals = jnp.where(valid,
-                                 (fvals if fvals is not None else
-                                  jnp.take_along_axis(block, cols, axis=1)),
-                                 jnp.zeros((), vec_c.dtype))
+                vals, gidx = self._selection_payload(
+                    b, block, row_off, thr, top_scores, fvals, cols)
 
             with _trace.phase("pack", bi):
                 emit(vals, gidx, b)
@@ -2199,7 +2189,7 @@ class FlatDGCEngine:
         return jnp.concatenate(out_v), jnp.concatenate(out_i)
 
     # -------------------------------------------------------------- #
-    # the full exchange                                              #
+    # the full exchange, stage by stage over one _Exchange record    #
     # -------------------------------------------------------------- #
 
     def _dense_combine(self, block: jax.Array, axis_name: str,
@@ -2275,194 +2265,158 @@ class FlatDGCEngine:
         compressor) every parameter falls through to the dense block —
         the same graceful degradation as the per-tensor path's
         ``name in attributes`` guard."""
+        flat_grad = self._node_reduce(flat_grad, op, local_axis, local_size)
+        # dgcver anchors (analysis/verify.py): identity `name` tags that
+        # seed/sink the verifier's static taint passes. Zero HLO ops.
+        st = _Exchange(grad=kernels.vtag(flat_grad, "dgcver.src.grad"),
+                       mem=mem)
+        if telemetry:
+            from dgc_tpu.telemetry import taps
+            st.taps = taps
+            st.grad_norm = taps.l2(st.grad)
+            st.clip_delta = jnp.zeros((), jnp.float32)
+        # ratio >= 1.0, nothing initialized or an all-dense PLAN (the
+        # fast-fabric regime): everything dense, ZERO gathers lowered
+        if (self.T == 0 or self.c.compress_ratio >= 1.0
+                or not self._sparse_ids):
+            return self._exchange_dense(st, axis_name, world_size, op)
+        self._compress(st, key, axis_name, world_size, op, send_frac)
+        g_values, g_indices = self._wire(st, axis_name, health_out)
+        self._apply(st, g_values, g_indices, axis_name, world_size, op)
+        out = self._dense_tail(st, axis_name, world_size, op)
+        return self._finish(st, out)
+
+    def _node_reduce(self, flat_grad, op: str, local_axis: Optional[str],
+                     local_size: int):
+        """The two-tier mode's dense-over-ICI tier: full-precision node
+        aggregation (the fp16 wire option applies to the slow DCN link
+        only). Under "adasum" the NODE MEAN is the logical Adasum
+        participant — the reference's Adasum (optimizer.py:197-367) with
+        each "sparsified node" acting as one worker (as Horovod's own
+        hierarchical Adasum: in-node sum + normalize, Adasum across)."""
         if local_axis is not None and local_size > 1:
-            # dense-over-ICI tier: full-precision node aggregation (the
-            # fp16 wire option applies to the slow DCN link only). Under
-            # "adasum" the NODE MEAN is the logical Adasum participant —
-            # the node-aggregated form of the reference's Adasum
-            # (optimizer.py:197-367) with each "sparsified node" acting as
-            # one worker (Horovod's own hierarchical Adasum does the same:
-            # in-node sum + normalize, Adasum across nodes).
             _count_collective("psum", flat_grad, local_axis, self)
             flat_grad = jax.lax.psum(flat_grad, local_axis)
             if op in ("average", "adasum"):
                 flat_grad = flat_grad / local_size
-        # dgcver anchors (analysis/verify.py): identity `name` tags that
-        # seed/sink the verifier's static taint passes. Zero HLO ops —
-        # every byte-identity and collective-count contract is unchanged.
-        flat_grad = kernels.vtag(flat_grad, "dgcver.src.grad")
-        T, P = self.T, self.layout.total
-        m = self._mem
-        clip = m.gradient_clipping if m is not None else None
-        if telemetry:
-            from dgc_tpu.telemetry import taps
-            grad_norm = taps.l2(flat_grad)
-            clip_delta = jnp.zeros((), jnp.float32)
+        return flat_grad
 
-        # ratio >= 1.0 (or nothing initialized): everything dense, with the
-        # per-tensor path's non-accumulating correction (dgc.py compress
-        # guard `compress_ratio < 1.0 and name in attributes`)
-        if T == 0 or self.c.compress_ratio >= 1.0 or not self._sparse_ids:
-            # ``not self._sparse_ids``: an all-dense PLAN — the planner
-            # decided every bucket rides the psum (fast-fabric regime).
-            # Lowers with ZERO gathers, the plan-matches-collectives
-            # contract's all-dense case.
-            avg = self._dense_combine(flat_grad, axis_name, world_size, op)
-            if m is None:
-                if telemetry:
-                    return avg, mem, self._telemetry_stats(
-                        taps, grad_norm, clip_delta, None, None, None, None)
-                return avg, mem
-            if clip is not None:
-                if telemetry:
-                    pre = taps.l2(avg)
-                avg = self._clip_block(avg, self.layout.names, 0)
-                if telemetry:
-                    clip_delta = ((pre - taps.l2(avg))
-                                  / jnp.maximum(pre, 1e-12))
-            # materialize any pending transmit mask from a previous
-            # compressed step before the non-accumulating correction (the
-            # reference zeroed those coords at the compressed step,
-            # memory.py:72-77), and reset it — carrying it forward would
-            # wrongly zero the dense momentum written below
-            mc = kernels.vtag(mem["momentums_c"], "dgcver.src.momentum")
-            vc = kernels.vtag(mem["velocities_c"], "dgcver.src.residual")
-            bits = mem.get("sent_bits")
-            if m is not None and T and bits is not None:
-                keep = kernels.keep_from_bits(bits, T).astype(vc.dtype)
-                vc = vc * keep
-                if m.momentum_masking:
-                    mc = mc * keep
-            out_c, mc2 = self._compensate_dense(mc, avg[:T])
-            out_d, md2 = self._compensate_dense(mem["momentums_d"], avg[T:])
-            out = (jnp.concatenate([out_c, out_d]) if T and P > T
-                   else (out_c if T else out_d))
-            new_mem = {"momentums_c": mc2, "momentums_d": md2,
-                       "velocities_c": vc,
-                       "velocities_d": mem["velocities_d"],
-                       "sent_bits": jnp.zeros(
-                           (kernels.num_sent_words(T) if T else 0,),
-                           jnp.int32)}
-            if telemetry:
-                return out, new_mem, self._telemetry_stats(
-                    taps, grad_norm, clip_delta, mc2, md2, vc, None)
-            return out, new_mem
+    def _clip_tapped(self, st: _Exchange, block, names):
+        """``_clip_block`` from offset 0 and, with telemetry, the share of
+        the block's norm the clip took (``clip_delta``)."""
+        if st.taps is None:
+            return self._clip_block(block, names, 0)
+        pre = st.taps.l2(block)
+        block = self._clip_block(block, names, 0)
+        st.clip_delta = (pre - st.taps.l2(block)) / jnp.maximum(pre, 1e-12)
+        return block
 
-        gc, gd = flat_grad[:T], flat_grad[T:]
+    def _gossip_round(self, mem, axis_name, world_size: int, op: str):
+        """This round's gossip state (compression/gossip.py): pure
+        functions of replicated memory state, so every worker computes
+        identical values — zero extra collectives."""
+        g_cfg = self._gossip
+        if int(world_size) != g_cfg.world:
+            raise ValueError(
+                f"gossip plan was built for world={g_cfg.world} but "
+                f"exchange runs with world_size={world_size} — "
+                "replan for the current cohort")
+        if op != "average":
+            raise ValueError(
+                "gossip regimes require op='average': the neighbor "
+                f"mixing weights fold into the averaging divide "
+                f"(got op={op!r})")
+        clock = mem["gossip_clock"]
+        dropped = (_faults.gossip_dropped(g_cfg.world, clock)
+                   if _faults.armed() else None)
+        full, forced, new_age = _gossip_sched.round_state(
+            g_cfg, clock, mem["gossip_age"], dropped)
+        widx = jax.lax.axis_index(axis_name)
+        row_w = _gossip_sched.row_weights(g_cfg, clock, widx, full, dropped)
+        return _GossipRound(dropped, full, forced, new_age, widx, row_w)
+
+    def _compress(self, st: _Exchange, key, axis_name, world_size: int,
+                  op: str, send_frac) -> None:
+        """Gradient + memory -> this worker's payload (``st.values``,
+        ``st.indices``): clip, masked compensate (or the forward
+        megakernel), the gossip round and its inbox fold, ``sparsify``, the
+        ``send_frac`` mask, the delta codec's sort. Also leaves the live
+        ``mc``/``vc``/``md``, the PREVIOUS ``mc``/``vc`` for _dense_tail,
+        the gossip round and the selection stats."""
+        T, m = self.T, self._mem
+        mem = st.mem
+        gc, st.gd = st.grad[:T], st.grad[T:]
         if m is not None:
-            mc = kernels.vtag(mem["momentums_c"], "dgcver.src.momentum")
-            vc = kernels.vtag(mem["velocities_c"], "dgcver.src.residual")
-            md = mem["momentums_d"]
-        else:
-            mc = vc = md = None
-        # pre-compensate state: dense-PLANNED slabs inside [0, T) get the
-        # dense (non-accumulating) correction from the PREVIOUS step's
-        # state, overriding whatever the accumulating compensate below
-        # wrote there (it runs over the whole [T] buffer)
-        mc_prev, vc_prev = mc, vc
-        bits_prev = mem.get("sent_bits") if m is not None else None
+            st.mc = kernels.vtag(mem["momentums_c"], "dgcver.src.momentum")
+            st.vc = kernels.vtag(mem["velocities_c"], "dgcver.src.residual")
+            st.md = mem["momentums_d"]
+        # the state BEFORE the accumulating compensate below, which runs
+        # over the whole [T] buffer: _dense_tail corrects dense-PLANNED
+        # slabs from it
+        st.mc_prev, st.vc_prev = st.mc, st.vc
 
-        # --- compressed block: masked compensate -> sparsify -> gather ---
-        cands = None
-        fwd_sel = None
+        # --- compressed block: masked compensate -> sparsify ---
+        cands = fwd_sel = None
         if m is not None:
-            if clip is not None:
+            if m.gradient_clipping is not None:
                 # clipping runs on the LOCAL gradient inside the accumulating
                 # compensate (reference memory.py:52-53)
-                if telemetry:
-                    pre = taps.l2(gc)
-                gc = self._clip_block(gc, self.layout.compressed_names, 0)
-                if telemetry:
-                    clip_delta = ((pre - taps.l2(gc))
-                                  / jnp.maximum(pre, 1e-12))
-                gsrc = gc
+                gsrc = self._clip_tapped(st, gc, self.layout.compressed_names)
             else:
-                # the WHOLE flat buffer: on the fused-candidates TPU path
-                # the kernel reads [0, T) through its index map, so XLA
-                # never materializes the [:T] slice as a Pallas operand
-                # copy (part of the r5 device profile's data-movement-copy
-                # mass at VGG); non-fused paths slice inside
-                # _compensate_acc as before
-                gsrc = flat_grad
+                # the WHOLE flat buffer: no [:T] operand-slice copy on the
+                # fused-candidates TPU path (see _compensate_acc)
+                gsrc = st.grad
             # deferred masking (memory.py:72-77): the PREVIOUS step's
             # transmit record is applied on read inside the compensate
             # pass. x*0 == set-to-0 for finite values, and the sentinel
             # slot is a structural zero, so padded payload slots are no-ops.
             if self._mk_fwd_ids:
-                # forward megakernel (plan-static opt-in): eligible
-                # buckets fuse compensate -> threshold -> select -> pack
-                # into one pass each; sparsify consumes the selections
-                # via fwd_sel below. Seg-kernel buckets (if any coexist)
-                # fall back to the standalone candidates kernel — the
+                # forward megakernel (plan-static opt-in; see
+                # _compensate_megakernel): sparsify consumes its selections
+                # via fwd_sel. Seg-kernel buckets (if any coexist) fall
+                # back to the standalone candidates kernel — the
                 # megakernel path does not thread want_cands.
                 with _trace.phase("forward"):
-                    comp, mc, vc, fwd_sel = self._compensate_megakernel(
-                        mc, vc, gsrc, mem["sent_bits"])
+                    comp, st.mc, st.vc, fwd_sel = self._compensate_megakernel(
+                        st.mc, st.vc, gsrc, mem["sent_bits"])
             else:
                 with _trace.phase("compensate"):
-                    comp, mc, vc, cands = self._compensate_acc(
-                        mc, vc, gsrc, mem["sent_bits"],
+                    comp, st.mc, st.vc, cands = self._compensate_acc(
+                        st.mc, st.vc, gsrc, mem["sent_bits"],
                         want_cands=self._seg_fused)
         else:
             comp = gc
 
-        # --- gossip round state (compression/gossip.py) --- plan-static:
-        # None lowers nothing. The round type, staleness ages and row
-        # weights are pure functions of replicated memory state, so every
-        # worker computes identical values — zero extra collectives.
-        g_cfg = self._gossip
-        if g_cfg is not None:
-            if int(world_size) != g_cfg.world:
-                raise ValueError(
-                    f"gossip plan was built for world={g_cfg.world} but "
-                    f"exchange runs with world_size={world_size} — "
-                    "replan for the current cohort")
-            if op != "average":
-                raise ValueError(
-                    "gossip regimes require op='average': the neighbor "
-                    f"mixing weights fold into the averaging divide "
-                    f"(got op={op!r})")
-            g_clock = mem["gossip_clock"]
-            g_forced0 = mem["gossip_forced"]
-            g_dropped = (_faults.gossip_dropped(g_cfg.world, g_clock)
-                         if _faults.armed() else None)
-            g_full, g_forced, g_new_age = _gossip_sched.round_state(
-                g_cfg, g_clock, mem["gossip_age"], g_dropped)
-            g_widx = jax.lax.axis_index(axis_name)
-            g_row_w = _gossip_sched.row_weights(g_cfg, g_clock, g_widx,
-                                                g_full, g_dropped)
+        if self._gossip is not None:
+            # plan-static: None lowers nothing
+            st.gossip = self._gossip_round(mem, axis_name, world_size, op)
             # fold LAST round's received neighbor mass into the velocity
             # accumulator — AFTER the deferred transmit mask above, so a
             # freshly received value can never be wiped by this worker's
             # own transmit record; and into the VELOCITY only (the
             # sender already ran its momentum), matching the oracle in
             # tests/test_gossip.py. The inbox is consumed exactly once:
-            # it is rewritten from this round's gather below.
-            vc = vc + mem["gossip_inbox"].astype(vc.dtype)
-            comp = vc
+            # it is rewritten from this round's gather in _apply.
+            st.vc = st.vc + mem["gossip_inbox"].astype(st.vc.dtype)
+            comp = st.vc
         if os.environ.get("DGC_VERIFY_MUTATE", "") == "cast_bf16":
             # seeded mutation (tests/test_analysis_verify.py): a silent
             # precision drop on the compensated gradient — the dgcver
             # dtype-flow pass must turn the gate red on this
-            comp = comp.astype(jnp.bfloat16).astype(flat_grad.dtype)
-        sel_stats: Optional[Dict] = {} if telemetry else None
+            comp = comp.astype(jnp.bfloat16).astype(st.grad.dtype)
+        st.sel_stats = {} if st.taps is not None else None
         values, indices = self.sparsify(comp, key, seg_cands=cands,
                                         fwd_sel=fwd_sel,
-                                        stats_out=sel_stats)
+                                        stats_out=st.sel_stats)
         # tag the selection BEFORE the adaptive mask: masked derivations
         # must stay tainted so conservation covers the withheld tail too
         values = kernels.vtag(values, "dgcver.sel_values")
         indices = kernels.vtag(indices, "dgcver.sel_indices")
         if send_frac is not None and self._adaptive_rank is not None:
-            # straggler-adaptive masking (resilience/adaptive.py): keep
-            # only each row's ceil(quota * send_frac) largest selections;
-            # the rest become structural (0.0, sentinel) pads — wire
-            # no-ops everywhere downstream (quantize/checksum/scatter),
-            # and DROPPED from the transmit record, so the withheld mass
-            # stays in the velocity buffer for a later exchange. Shapes
-            # are static: no new collectives, no recompiles. At
-            # send_frac == 1.0 the keep mask covers every valid slot and
-            # the wire is bitwise unchanged.
+            # straggler-adaptive masking (resilience/adaptive.py; see
+            # ``exchange``): the withheld slots become structural
+            # (0.0, sentinel) pads — wire no-ops everywhere downstream
+            # (quantize/checksum/scatter) and absent from the record
             fr = jnp.clip(jnp.asarray(send_frac, jnp.float32), 0.0, 1.0)
             keep = (jnp.asarray(self._adaptive_rank)
                     < jnp.ceil(jnp.asarray(self._adaptive_quota) * fr))
@@ -2470,11 +2424,11 @@ class FlatDGCEngine:
             indices = jnp.where(keep, indices,
                                 jnp.asarray(self.layout.sentinel,
                                             indices.dtype))
-            if sel_stats is not None:
+            if st.sel_stats is not None:
                 # transmitted elements, post-mask (selection stats like
                 # selected_frac/threshold stay pre-mask by design: they
                 # describe the selection, this describes the wire)
-                sel_stats["payload_elems"] = jnp.sum(
+                st.sel_stats["payload_elems"] = jnp.sum(
                     (indices != self.layout.sentinel).astype(jnp.float32))
         if self._dcodec is not None:
             # Elias-Fano precondition: each delta bucket's payload slice
@@ -2482,19 +2436,29 @@ class FlatDGCEngine:
             # the quantized q lane and the index stream stay aligned
             with _trace.phase("pack"):
                 values, indices = self._sort_delta_payload(values, indices)
+        st.values, st.indices = values, indices
 
-        dt = flat_grad.dtype
+    @property
+    def _int8_ef(self) -> bool:
+        """int8 error feedback runs: an int8 lane, a memory for the
+        rounding residual, the compressor's switch."""
+        return bool(self._kind_payload.get("i8") and self._mem is not None
+                    and getattr(self.c, "int8_error_feedback", False))
+
+    def _encode_values(self, values: jax.Array):
+        """Payload values -> the value lanes (q, f32, f16) as they ship,
+        and, under int8 error feedback, the dequantized int8 payload in
+        f32 (what the wire carried of those slots, for the caller to take
+        out of the velocity), else None."""
         kp = self._kind_payload
-        int8_ef = False
-        f32_wire = f16_wire = q_wire = q4_wire = scale = scale4 = None
+        q_wire = q4_wire = scale = scale4 = dequant = None
         if kp.get("i8"):
             # int8 wire lane: symmetric per-TENSOR quantization (one f32
             # scale per row, segment-max over the tight payload) — the
             # reference's stated "no quantization/encoding of payloads"
-            # caveat (README.md:130-138) addressed; dequantize after the
-            # gather, before the scatter-add. The scales ride the f32
-            # value lane (appended after any native-f32 chunks).
-            vals_i8 = self._kind_chunks(values, "i8")
+            # caveat (README.md:130-138) addressed; dequantized after the
+            # gather, before the scatter-add
+            vals_i8 = self._chunks(values, self._kinds, "i8")
             with _trace.phase("pack"):
                 smax = jax.ops.segment_max(jnp.abs(vals_i8), self._row_map,
                                            num_segments=self._i8_rows)
@@ -2502,37 +2466,15 @@ class FlatDGCEngine:
                 safe = jnp.where(scale > 0, scale, 1.0)
                 q_wire = jnp.clip(jnp.round(vals_i8 / safe[self._row_map]),
                                   -127, 127).astype(jnp.int8)
-            int8_ef = (m is not None
-                       and getattr(self.c, "int8_error_feedback", False))
-            if int8_ef:
-                # quantization ERROR FEEDBACK: the wire carried q*scale,
-                # so the velocity keeps the rounding residual
-                # ``values - q*scale`` instead of being zeroed. vc already
-                # holds ``values`` at these coordinates (comp IS the
-                # velocity), so one scatter-subtract of the dequantized
-                # payload leaves exactly the residual there — and the
-                # transmit record stays EMPTY this step for the int8
-                # slots (no deferred zeroing; the residual must survive
-                # the next compensate). Momentum masking (memory.py:72-77)
-                # happens eagerly instead, bitwise the same as the
-                # deferred form since nothing reads mmt in between.
-                # Padded slots carry (sentinel, q=0): a zero subtract at
-                # the structural-zero slot, a no-op.
-                dequant = (q_wire.astype(jnp.float32)
-                           * scale[self._row_map]).astype(vc.dtype)
-                idx_i8 = self._kind_chunks(indices, "i8")
-                vc = vc.at[idx_i8].add(-dequant)
-                if m.momentum_masking:
-                    mc = mc.at[idx_i8].set(jnp.zeros((), mc.dtype))
+            if self._int8_ef:
+                dequant = q_wire.astype(jnp.float32) * scale[self._row_map]
         if kp.get("i4"):
             # int4 wire lane: symmetric per-BUCKET quantization (one f32
             # scale per bucket — the payload is small enough that a
             # coarser scale granularity buys half the value bytes), two
-            # nibbles per byte, riding the i8 q lane after any int8
-            # payload. Per-bucket byte padding keeps the accounting
-            # exact (bucket_wire_bytes).
+            # nibbles per byte, each bucket padded to whole bytes
             from dgc_tpu.compression.wirecodec import pack_int4
-            vals_i4 = self._kind_chunks(values, "i4")
+            vals_i4 = self._chunks(values, self._kinds, "i4")
             with _trace.phase("pack"):
                 smax4 = jax.ops.segment_max(jnp.abs(vals_i4),
                                             self._i4_map,
@@ -2545,416 +2487,471 @@ class FlatDGCEngine:
                 nb = [pack_int4(q4[plo:phi])
                       for plo, phi, _, _ in self._i4_chunks]
                 q4_wire = nb[0] if len(nb) == 1 else jnp.concatenate(nb)
-        # f32 value lane: native-dtype values of the f32-regime buckets,
-        # then the int8 per-row scales, then the int4 per-bucket scales.
-        # A single part ships identity (uniform plans keep their exact
-        # pre-planner wire arrays); multiple parts promote to f32 for
-        # the concat.
-        f32_parts = ([self._kind_chunks(values, "f32")]
-                     if kp.get("f32") else [])
-        if scale is not None:
-            f32_parts.append(scale)
-        if scale4 is not None:
-            f32_parts.append(scale4)
+        # f32 lane: a single part ships identity (uniform plans keep
+        # their exact pre-planner wire arrays); several promote to f32
+        # for the concat
+        f32_parts = [p for p in (
+            self._chunks(values, self._kinds, "f32") if kp.get("f32")
+            else None, scale, scale4) if p is not None]
+        f32_wire = f16_wire = None
         if len(f32_parts) == 1:
             f32_wire = f32_parts[0]
         elif f32_parts:  # dgclint: ok[tracer-branch] — list emptiness is plan-static (kp/scale), not a tracer test
             f32_wire = jnp.concatenate(
                 [p.astype(jnp.float32) for p in f32_parts])
         if kp.get("f16"):
-            f16_wire = self._kind_chunks(values, "f16").astype(jnp.float16)
+            f16_wire = self._chunks(values, self._kinds,
+                                    "f16").astype(jnp.float16)
         if q_wire is not None and q4_wire is not None:
             q_lane = jnp.concatenate([q_wire, q4_wire])
         else:
             q_lane = q_wire if q_wire is not None else q4_wire
-        with _trace.phase("allgather"):
-            for lane in (q_lane, f32_wire, f16_wire):
-                if lane is not None:
-                    _count_collective("all_gather", lane, axis_name, self)
-            g_q = (jax.lax.all_gather(q_lane, axis_name)
-                   if q_lane is not None else None)  # [W, i8+i4 bytes]
-            g_f32 = (jax.lax.all_gather(f32_wire, axis_name)
-                     if f32_wire is not None else None)
-            g_f16 = (jax.lax.all_gather(f16_wire, axis_name)
-                     if f16_wire is not None else None)
-        kinds = set(self._kinds)
-        if kinds == {"f16"}:
-            g_values = g_f16
-        elif kinds == {"f32"}:
-            g_values = g_f32
-        elif kinds == {"i8"}:
-            with _trace.phase("decode"):
-                g_values = g_q.astype(dt) * jnp.take(
-                    g_f32.astype(dt), self._row_map, axis=1)
-        elif kinds == {"i4"}:
-            # uniform int4 plan: the f32 lane is exactly the per-bucket
-            # scale vector
-            with _trace.phase("decode"):
-                g_values = self._decode_i4(g_q, g_f32, dt)
-        else:
-            # mixed plan: stitch the gathered lanes back into payload
-            # order per sparse bucket ([W, payload], wire precision —
-            # the shared .astype(dt) happens at the scatter below)
-            with _trace.phase("decode"):
-                n8 = kp.get("i8", 0)
-                f32_off = kp.get("f32", 0)
-                if n8:
-                    g_i8 = g_q[:, :n8].astype(dt) * jnp.take(
-                        g_f32[:, f32_off:].astype(dt),
-                        self._row_map, axis=1)
-                if kp.get("i4"):
-                    g_i4 = self._decode_i4(
-                        g_q[:, n8:],
-                        g_f32[:, f32_off + self._i8_rows:], dt)
-                parts = []
-                for kk, lo, hi in self._val_chunks:
-                    if kk == "i8":
-                        parts.append(g_i8[:, lo:hi])
-                    elif kk == "i4":
-                        parts.append(g_i4[:, lo:hi])
-                    elif kk == "f16":
-                        parts.append(g_f16[:, lo:hi].astype(dt))
-                    else:
-                        parts.append(g_f32[:, lo:hi].astype(dt))
-                g_values = jnp.concatenate(parts, axis=1)
-        if _faults.armed():
-            # deterministic post-gather corruption (tests only; identity
-            # ops, zero HLO, when DGC_FAULTS is unset)
-            g_values = _faults.corrupt_wire(g_values)
-        checksum = self.checksum and health_out is not None
-        if checksum:
-            # sender-side per-bucket checksum over the exact wire forms:
-            # the value words as shipped, and the indices in the form the
-            # receiver reconstructs (codec slots clip in-row — see
-            # IndexCodec.canonical). Rides the index gather below.
-            with _trace.phase("pack"):
-                # constructor guarantees checksum plans are uniform
-                # non-int8: exactly one value lane carries the payload
-                wire_values = f16_wire if f16_wire is not None else f32_wire
-                idx_canon = (self._codec.canonical(indices)
-                             if self._codec is not None else indices)
-                chk = integrity.payload_checksum(
-                    wire_values, idx_canon, self._seg_ids,
-                    self._num_seg)
-        g_idx_packed = g_idx_plain = g_idx_delta = None
+        return _Lanes(q=q_lane, f32=f32_wire, f16=f16_wire), dequant
+
+    def _encode_indices(self, indices: jax.Array, chk=None) -> _Lanes:
+        """Payload indices -> the index lanes: the shared uint32 word lane
+        (IndexCodec words first, Elias-Fano delta words after) and the
+        plain-offset lane. ``chk`` (the sender's checksum words, or None)
+        rides behind the IndexCodec words where there are any, else behind
+        the plain offsets (never beside delta words: no checksum + int8)."""
+        words = plain = None
         if self._codec is not None or self._dcodec is not None:
-            # packed index wire: gather the bitstream(s), decode per
-            # worker (static gathers + shifts; decoded == original for
-            # every real slot, padded slots land in-row with value 0.0).
-            # Both codecs share ONE uint32 lane: IndexCodec words first
-            # (+ checksum words when on — checksum never co-occurs with
-            # delta buckets, the constructor rejects checksum+int8),
-            # Elias-Fano delta words after.
             with _trace.phase("pack"):
                 wparts = []
                 if self._codec is not None:
                     wparts.append(self._codec.encode(
-                        self._packed_chunks(indices, True)))
-                    if checksum:
-                        # int32 -> uint32 astype is a bit-preserving
-                        # mod-2^32 wrap, undone symmetrically on the
-                        # receiver
+                        self._chunks(indices, self._packed, True)))
+                    if chk is not None:
+                        # int32 -> uint32 astype: a bit-preserving mod-2^32
+                        # wrap, undone symmetrically on the receiver
                         wparts.append(chk.astype(jnp.uint32))
                 if self._dcodec is not None:
                     wparts.append(self._dcodec.encode(
-                        self._packed_chunks(indices, "delta")))
+                        self._chunks(indices, self._packed, "delta")))
                 words = (wparts[0] if len(wparts) == 1
                          else jnp.concatenate(wparts))
-            with _trace.phase("allgather"):
-                _count_collective("all_gather", words, axis_name, self)
-                g_words = jax.lax.all_gather(words, axis_name)
+        if self._plain_payload:
+            with _trace.phase("pack"):
+                plain = self._chunks(indices, self._packed, False)
+                if chk is not None and self._codec is None:
+                    plain = jnp.concatenate(
+                        [plain, chk.astype(self.index_dtype)])
+        return _Lanes(words=words, plain=plain)
+
+    def _gather(self, lanes: _Lanes, axis_name) -> _Lanes:
+        """The engine's ONE all-gather site: every lane that exists, in
+        the order of ``_Lanes``' fields, each counted as it is issued."""
+        out = []
+        with _trace.phase("allgather"):
+            for lane in lanes:
+                if lane is not None:
+                    _count_collective("all_gather", lane, axis_name, self)
+                    lane = jax.lax.all_gather(lane, axis_name)
+                out.append(lane)
+        return _Lanes(*out)
+
+    def _decode_values(self, g: _Lanes, dt) -> jax.Array:
+        """Gathered value lanes -> [W, payload] values in payload order.
+        Uniform fp16/f32 plans hand their lane through at wire precision
+        (the shared ``.astype(dt)`` happens at the scatter)."""
+        kinds = set(self._kinds)
+        if kinds == {"f16"}:
+            return g.f16
+        if kinds == {"f32"}:
+            return g.f32
+        kp = self._kind_payload
+        with _trace.phase("decode"):
+            if kinds == {"i8"}:
+                return g.q.astype(dt) * jnp.take(
+                    g.f32.astype(dt), self._row_map, axis=1)
+            if kinds == {"i4"}:
+                # the f32 lane is exactly the per-bucket scale vector
+                return self._decode_i4(g.q, g.f32, dt)
+            # mixed plan: stitch the lanes back into payload order
+            n8, f32_off = kp.get("i8", 0), kp.get("f32", 0)
+            if n8:
+                g_i8 = g.q[:, :n8].astype(dt) * jnp.take(
+                    g.f32[:, f32_off:].astype(dt), self._row_map, axis=1)
+            if kp.get("i4"):
+                g_i4 = self._decode_i4(
+                    g.q[:, n8:], g.f32[:, f32_off + self._i8_rows:], dt)
+            parts = []
+            for kk, lo, hi in self._val_chunks:
+                if kk == "i8":
+                    parts.append(g_i8[:, lo:hi])
+                elif kk == "i4":
+                    parts.append(g_i4[:, lo:hi])
+                elif kk == "f16":
+                    parts.append(g.f16[:, lo:hi].astype(dt))
+                else:
+                    parts.append(g.f32[:, lo:hi].astype(dt))
+            return jnp.concatenate(parts, axis=1)
+
+    def _decode_indices(self, g: _Lanes, checksum: bool = False):
+        """Gathered index lanes -> ([W, payload] indices in payload order,
+        the gathered checksum words or None): static gathers + shifts,
+        exact for every real slot; padded slots land in-row, value 0.0."""
+        g_chk = None
+        srcs = {True: None, False: None, "delta": None}
+        if g.words is not None:
             with _trace.phase("decode"):
                 nc = self._codec.nwords if self._codec is not None else 0
                 if checksum:
-                    g_chk = g_words[:, nc:].astype(jnp.int32)
+                    g_chk = g.words[:, nc:].astype(jnp.int32)
                 if self._dcodec is not None:
-                    g_idx_delta = self._dcodec.decode(
-                        g_words[:, nc:nc + self._dcodec.nwords],
+                    srcs["delta"] = self._dcodec.decode(
+                        g.words[:, nc:nc + self._dcodec.nwords],
                         self.index_dtype)
                 if self._codec is not None:
-                    g_idx_packed = self._codec.decode(
-                        g_words[:, :nc], self.index_dtype)
-        if self._plain_payload:
-            with _trace.phase("pack"):
-                idx_wire = self._packed_chunks(indices, False)
-                if checksum and self._codec is None:
-                    idx_wire = jnp.concatenate(
-                        [idx_wire, chk.astype(self.index_dtype)])
-            with _trace.phase("allgather"):
-                _count_collective("all_gather", idx_wire, axis_name, self)
-                g_idx_wire = jax.lax.all_gather(idx_wire, axis_name)
+                    srcs[True] = self._codec.decode(
+                        g.words[:, :nc], self.index_dtype)
+        if g.plain is not None:
             with _trace.phase("decode"):
                 if checksum and self._codec is None:
-                    g_chk = g_idx_wire[:, self._plain_payload:].astype(
+                    g_chk = g.plain[:, self._plain_payload:].astype(
                         jnp.int32)
-                    g_idx_plain = g_idx_wire[:, :self._plain_payload]
+                    srcs[False] = g.plain[:, :self._plain_payload]
                 else:
-                    g_idx_plain = g_idx_wire
-        srcs = {True: g_idx_packed, False: g_idx_plain,
-                "delta": g_idx_delta}
-        live = [g for g in srcs.values() if g is not None]
+                    srcs[False] = g.plain
+        live = [s for s in srcs.values() if s is not None]
         if len(live) == 1:
-            g_indices = live[0]
-        else:
-            with _trace.phase("decode"):
-                g_indices = jnp.concatenate(
-                    [srcs[p][:, lo:hi]
-                     for p, lo, hi in self._idx_chunks], axis=1)
+            return live[0], g_chk
+        with _trace.phase("decode"):
+            return jnp.concatenate(
+                [srcs[p][:, lo:hi] for p, lo, hi in self._idx_chunks],
+                axis=1), g_chk
+
+    def _wire(self, st: _Exchange, axis_name, health_out):
+        """Payload -> every worker's, [W, payload] values and indices:
+        encode, gather, decode — the values, then the indices: the value
+        decode and the sender's checksum stay between the two groups of
+        gathers, where the int8, packed and guarded programs have them."""
+        lanes, dequant = self._encode_values(st.values)
+        if dequant is not None:
+            # quantization ERROR FEEDBACK: the wire carried q*scale, so
+            # the velocity keeps the rounding residual ``values - q*scale``
+            # instead of being zeroed. vc already holds ``values`` there
+            # (comp IS the velocity), so one scatter-subtract of the
+            # dequantized payload leaves exactly the residual — and the
+            # int8 slots' transmit record stays EMPTY this step (the
+            # residual must survive the next compensate). Momentum masking
+            # (memory.py:72-77) happens eagerly instead, bitwise the
+            # deferred form since nothing reads mmt in between. Padded
+            # slots carry (sentinel, q=0): a no-op at the structural zero.
+            dequant = dequant.astype(st.vc.dtype)
+            idx_i8 = self._chunks(st.indices, self._kinds, "i8")
+            st.vc = st.vc.at[idx_i8].add(-dequant)
+            if self._mem.momentum_masking:
+                st.mc = st.mc.at[idx_i8].set(jnp.zeros((), st.mc.dtype))
+        g_values = self._decode_values(self._gather(lanes, axis_name),
+                                       st.grad.dtype)
+        if _faults.armed():
+            # deterministic post-gather corruption (tests only)
+            g_values = _faults.corrupt_wire(g_values)
+        checksum = self.checksum and health_out is not None
+        chk = None
+        if checksum:
+            # sender-side per-bucket checksum over the exact wire forms:
+            # the value words as shipped (checksum plans are uniform
+            # non-int8: exactly one value lane), and the indices in the
+            # form the receiver reconstructs (codec slots clip in-row —
+            # see IndexCodec.canonical). Rides the index gather below.
+            with _trace.phase("pack"):
+                wire_values = lanes.f16 if lanes.f16 is not None else lanes.f32
+                idx_canon = (self._codec.canonical(st.indices)
+                             if self._codec is not None else st.indices)
+                chk = integrity.payload_checksum(
+                    wire_values, idx_canon, self._seg_ids, self._num_seg)
+        g_indices, g_chk = self._decode_indices(
+            self._gather(self._encode_indices(st.indices, chk), axis_name),
+            checksum)
         if _faults.armed():
             g_indices = _faults.corrupt_indices(g_indices)
         if checksum:
             health_out["checksum_failures"] = integrity.count_mismatches(
-                g_values, g_indices, g_chk, self._seg_ids,
-                self._num_seg)
+                g_values, g_indices, g_chk, self._seg_ids, self._num_seg)
         # always-on bounds clamp BEFORE the scatter-add: XLA drops >= T
         # indices under jit but wraps NEGATIVE ones python-style, so a
         # corrupted payload word decoding to -5 would silently add
-        # garbage at T-5. Out-of-range indices route to the structural-
-        # zero sentinel slot (scatters there are no-ops by layout
-        # construction); the codec path additionally enforces each
-        # slot's static row bounds — exactly the set an honest encode
-        # can produce. Honest traffic passes through bitwise unchanged.
+        # garbage at T-5 (integrity.clamp_indices routes them to the
+        # sentinel). Honest traffic passes through bitwise unchanged.
         with _trace.phase("decode"):
             g_indices = integrity.clamp_indices(
-                g_indices, T, self.layout.sentinel, *self._clamp_bounds)
-        # Averaging divides the [W, payload] WIRE values BEFORE the
-        # scatter (algebraically identical to the reference's
-        # scatter-then-divide, compression.py:192-193; differs by
-        # float-rounding order only): the full-[T] divide pass disappears
-        # — its read/write cost scales with the model, ~0.8 ms/step at
-        # VGG. The scatter keeps a fresh ZEROS operand + concat,
-        # deliberately: XLA fuses the zero-init INTO the scatter (one [T]
-        # write), while scattering into a non-zero operand (the final [P]
-        # buffer pre-filled with the dense tail — tried both as a
-        # trailing dynamic_update_slice and as a concat-initialized
-        # operand) always COPIES the operand and measured +0.3 ms/step at
-        # ResNet-50. The fused [2T] acc+sent scatter also LOSES (slicing
-        # the halves back out materializes a 0.66 ms loop fusion);
-        # scatter-set into the live mmt/vec buffers (1.8 ms) and sub-word
-        # masks (serial while-loop) stay avoided.
-        if g_cfg is not None:
-            # per-sender row weights realize the round semantics on the
-            # ONE gathered wire (shapes and collectives identical every
-            # round): full rounds weight each live sender 1 (the
-            # ordinary all-gather average after the /W below, a dropped
-            # sender zero-weighted so its mass stays in its residual);
-            # gossip rounds weight this worker's in-neighbors W/outdeg
-            # (-> 1/outdeg after the /W — mixing columns sum to 1, so
-            # global signed mass is conserved, oracle-pinned).
-            g_values = g_values * g_row_w[:, None].astype(g_values.dtype)
+                g_indices, self.T, self.layout.sentinel, *self._clamp_bounds)
+        return g_values, g_indices
+
+    def _sent_flags(self, g_indices, axis_name):
+        """Per gathered entry: THIS worker's and a real slot — the fused
+        apply kernels' transmit record, bitwise ``pack_sent_bits``."""
+        me = jax.lax.axis_index(axis_name)
+        rows = jnp.arange(g_indices.shape[0], dtype=jnp.int32)[:, None]
+        return ((rows == me)
+                & (g_indices != self.layout.sentinel)).reshape(-1)
+
+    def _transmit_record(self, st: _Exchange):
+        """THIS step's transmit record for the next compensate:
+        bit-packed, one word-wide scatter over a 32x smaller buffer
+        (padded slots carry the sentinel and are dropped — their repeated
+        single-bit adds would carry across bits). Under int8 error
+        feedback (see _wire) the int8 slots keep an EMPTY record; in a
+        mixed plan the non-i8 buckets still record theirs."""
+        indices = st.indices
+        with _trace.phase("pack"):
+            if self._int8_ef and self._i8_slot_mask is None:
+                return jnp.zeros_like(st.mem["sent_bits"])
+            if self._int8_ef:
+                rec = jnp.where(
+                    jnp.asarray(self._i8_slot_mask),
+                    jnp.asarray(self.layout.sentinel, indices.dtype),
+                    indices)
+                return kernels.pack_sent_bits(
+                    rec, self.T, sentinel=self.layout.sentinel)
+            if os.environ.get("DGC_VERIFY_MUTATE", "") == "drop_foldback":
+                # seeded mutation: lose the transmit record, so the next
+                # compensate re-sends what the wire already carried — the
+                # dgcver ef-conservation pass must turn the gate red
+                return jnp.zeros_like(st.mem["sent_bits"])
+            return kernels.pack_sent_bits(
+                indices, self.T, sentinel=self.layout.sentinel)
+
+    def _apply(self, st: _Exchange, g_values, g_indices, axis_name,
+               world_size: int, op: str) -> None:
+        """Every worker's payload -> the sparse tier's [T] contribution
+        (``st.acc``), this worker's transmit record (``st.new_bits``)
+        and, on a gossip plan, the round's inbox.
+
+        Averaging divides the [W, payload] WIRE values BEFORE the
+        scatter (algebraically identical to the reference's
+        scatter-then-divide, compression.py:192-193; differs by
+        float-rounding order only): the full-[T] divide pass disappears
+        — its read/write cost scales with the model, ~0.8 ms/step at
+        VGG. The scatter keeps a fresh ZEROS operand + concat,
+        deliberately: XLA fuses the zero-init INTO the scatter (one [T]
+        write), while scattering into a non-zero operand (the final [P]
+        buffer pre-filled with the dense tail — tried both as a
+        trailing dynamic_update_slice and as a concat-initialized
+        operand) always COPIES the operand and measured +0.3 ms/step at
+        ResNet-50. The fused [2T] acc+sent scatter also LOSES (slicing
+        the halves back out materializes a 0.66 ms loop fusion);
+        scatter-set into the live mmt/vec buffers (1.8 ms) and sub-word
+        masks (serial while-loop) stay avoided."""
+        T, m = self.T, self._mem
+        dt = st.grad.dtype
+        gr = st.gossip
+        if gr is not None:
+            # per-sender row weights (gossip.row_weights) realize the
+            # round semantics on the ONE gathered wire, before the /W
+            # below: shapes and collectives are identical every round,
+            # and mixing columns sum to 1, so global signed mass is
+            # conserved (oracle-pinned)
+            g_values = g_values * gr.row_w[:, None].astype(g_values.dtype)
         wire = g_values.reshape(-1).astype(dt)
-        mk_apply = self._use_megakernel_apply(m, int8_ef, dt)
+        mk_apply = self._use_megakernel_apply(m, self._int8_ef, dt)
         if op == "average" and not mk_apply:
             wire = wire / world_size
         if mk_apply:
-            # apply megakernel (kernels.dgc_apply_rows): the fused-apply
-            # epilogue below with the worker-average decompress divide
-            # folded into the kernel body — the divided [W * payload]
-            # wire intermediate never materializes in HBM; each staged
-            # entry divides in-register on its way into the
-            # VMEM-resident output chunk. The per-entry IEEE divide and
-            # the stable staging sort keep duplicate contributions in
-            # payload order, so values AND transmit record stay bitwise
-            # the unfused path's (pinned in tests/test_megakernel.py).
+            # apply megakernel (kernels.dgc_apply_rows; see
+            # _use_megakernel_apply): each staged entry divides
+            # in-register on its way into the VMEM-resident output chunk.
+            # The per-entry IEEE divide and the stable staging sort keep
+            # duplicate contributions in payload order, so values AND
+            # transmit record stay bitwise the unfused path's (pinned in
+            # tests/test_megakernel.py).
             with _trace.phase("apply"):
-                me = jax.lax.axis_index(axis_name)
-                rows = jnp.arange(g_indices.shape[0],
-                                  dtype=jnp.int32)[:, None]
-                flags = ((rows == me)
-                         & (g_indices != self.layout.sentinel)).reshape(-1)
-                acc, new_bits = kernels.dgc_apply_rows(
+                flags = self._sent_flags(g_indices, axis_name)
+                st.acc, st.new_bits = kernels.dgc_apply_rows(
                     wire, g_indices.reshape(-1), flags, T,
-                    bits_donor=mem["sent_bits"],
+                    bits_donor=st.mem["sent_bits"],
                     divisor=(float(world_size) if op == "average"
                              else None))
-        elif self._use_fused_apply(m, int8_ef, dt):
+        elif self._use_fused_apply(m, self._int8_ef, dt):
             # fused apply epilogue (kernels.payload_apply_bits): the
             # decompress scatter-add AND the transmit-record pack ride
             # one streamed Pallas pass over [T] — the payload is
             # pre-bucketed by 2048-row chunk at payload scale, then each
             # VMEM-resident chunk takes its entries' adds and bit sets
-            # and is written once. The LOCAL worker's non-sentinel
-            # entries are flagged inside the gathered stream, so the
-            # record is identical (bitwise) to pack_sent_bits on the
-            # local indices; the dead previous-step record buffer is
-            # donated for the rebuild (input_output_aliases). Values
-            # within f32 scatter-order rounding of the XLA path below.
+            # and is written once (the record from _sent_flags); the dead
+            # previous-step record buffer is donated for the rebuild
+            # (input_output_aliases). Values within f32 scatter-order
+            # rounding of the XLA path below.
             with _trace.phase("apply"):
-                me = jax.lax.axis_index(axis_name)
-                rows = jnp.arange(g_indices.shape[0],
-                                  dtype=jnp.int32)[:, None]
-                flags = ((rows == me)
-                         & (g_indices != self.layout.sentinel)).reshape(-1)
-                acc, new_bits = kernels.payload_apply_bits(
+                flags = self._sent_flags(g_indices, axis_name)
+                st.acc, st.new_bits = kernels.payload_apply_bits(
                     wire, g_indices.reshape(-1), flags, T,
-                    bits_donor=mem["sent_bits"])
+                    bits_donor=st.mem["sent_bits"])
         else:
             with _trace.phase("apply"):
-                acc = jnp.zeros((T,),
-                                dt).at[g_indices.reshape(-1)].add(wire)
+                st.acc = jnp.zeros((T,),
+                                   dt).at[g_indices.reshape(-1)].add(wire)
             if m is not None:
-                # THIS step's transmit record for the next compensate:
-                # bit-packed, one word-wide scatter over a 32x smaller
-                # buffer (padded slots carry the sentinel and are dropped
-                # — their repeated single-bit adds would carry across
-                # bits). Under int8 error feedback the int8 slots keep an
-                # EMPTY record — masking was applied eagerly above and the
-                # velocity keeps the residual; in a mixed plan the non-i8
-                # buckets still record theirs (deferred masking).
-                with _trace.phase("pack"):
-                    if int8_ef and self._i8_slot_mask is None:
-                        new_bits = jnp.zeros_like(mem["sent_bits"])
-                    elif int8_ef:
-                        rec = jnp.where(
-                            jnp.asarray(self._i8_slot_mask),
-                            jnp.asarray(self.layout.sentinel,
-                                        indices.dtype),
-                            indices)
-                        new_bits = kernels.pack_sent_bits(
-                            rec, T, sentinel=self.layout.sentinel)
-                    elif (os.environ.get("DGC_VERIFY_MUTATE", "")
-                          == "drop_foldback"):
-                        # seeded mutation: lose the transmit record, so
-                        # the next compensate re-sends what the wire
-                        # already carried — the dgcver ef-conservation
-                        # pass must turn the gate red on this
-                        new_bits = jnp.zeros_like(mem["sent_bits"])
-                    else:
-                        new_bits = kernels.pack_sent_bits(
-                            indices, T, sentinel=self.layout.sentinel)
-        if g_cfg is not None:
+                st.new_bits = self._transmit_record(st)
+        if gr is not None:
             with _trace.phase("apply"):
-                if g_dropped is not None:
+                if gr.dropped is not None:
                     # a dropped worker's transmit record is voided: the
                     # round carried none of its mass (receivers folded a
                     # zero-weighted row), so the mass must stay in its
                     # error-feedback residual for a later round — the
                     # droplink leg of the conservation oracle
-                    new_bits = jnp.where(g_dropped[g_widx],
-                                         jnp.zeros_like(new_bits),
-                                         new_bits)
-                # split the scattered payload by round type: on a gossip
-                # round it feeds ONLY the neighborhood inbox (folded into
-                # the velocities next round) and the parameters see zeros
-                # from the sparse tier; on a full-sync round it feeds the
-                # parameters and the inbox resets
-                g_inbox = jnp.where(g_full, jnp.zeros_like(acc), acc)
-                acc = jnp.where(g_full, acc, jnp.zeros_like(acc))
+                    st.new_bits = jnp.where(gr.dropped[gr.widx],
+                                            jnp.zeros_like(st.new_bits),
+                                            st.new_bits)
+                # by round type: a gossip round feeds ONLY the
+                # neighborhood inbox (folded into the velocities next
+                # round) and the parameters see zeros from the sparse
+                # tier; a full-sync round feeds them and resets the inbox
+                st.inbox = jnp.where(gr.full, jnp.zeros_like(st.acc), st.acc)
+                st.acc = jnp.where(gr.full, st.acc, jnp.zeros_like(st.acc))
 
-        # --- dense fallback block: one collective + correction ---
-        # dense-PLANNED buckets ride the SAME psum as the dense tail (one
-        # concatenated wire, still exactly one collective), then split
-        # back into per-bucket slabs that get the dense-path semantics:
-        # clip on the averaged gradient, pending transmit mask from the
-        # PREVIOUS state materialized, non-accumulating compensate — the
-        # [0, T) writes the accumulating compensate made there are
-        # overridden from (mc_prev, vc_prev).
+    def _dense_correct(self, avg, mmt, vec, keep, lo: int, hi: int):
+        """The dense (non-accumulating) correction of [lo, hi) of the
+        compressed block from the state BEFORE this step: materialize the
+        pending transmit mask of a previous compressed step (``keep``,
+        [T], or None; the reference zeroed those coordinates at the
+        compressed step, memory.py:72-77), then ``_compensate_dense`` on
+        ``avg``, read from its start. Returns ``(out, mmt', vec')`` for
+        the range; over the whole block every slice is the identity."""
+        mmt, vec = mmt[lo:hi], vec[lo:hi]
+        if keep is not None:
+            k = keep[lo:hi].astype(vec.dtype)
+            vec = vec * k
+            if self._mem.momentum_masking:
+                mmt = mmt * k
+        out, mmt = self._compensate_dense(mmt, avg[:hi - lo])
+        return out, mmt, vec
+
+    def _exchange_dense(self, st: _Exchange, axis_name, world_size: int,
+                        op: str):
+        """The all-dense step: one collective over the whole buffer, then
+        the dense correction of the compressed block and of the tail."""
+        T, P, m = self.T, self.layout.total, self._mem
+        mem = st.mem
+        avg = self._dense_combine(st.grad, axis_name, world_size, op)
+        if m is None:
+            return self._finish(st, avg)
+        if m.gradient_clipping is not None:
+            avg = self._clip_tapped(st, avg, self.layout.names)
+        mc = kernels.vtag(mem["momentums_c"], "dgcver.src.momentum")
+        vc = kernels.vtag(mem["velocities_c"], "dgcver.src.residual")
+        bits = mem.get("sent_bits")
+        keep = (kernels.keep_from_bits(bits, T)
+                if T and bits is not None else None)
+        out_c, st.mc, st.vc = self._dense_correct(avg, mc, vc, keep, 0, T)
+        out_d, st.md = self._compensate_dense(mem["momentums_d"], avg[T:])
+        out = (jnp.concatenate([out_c, out_d]) if T and P > T
+               else (out_c if T else out_d))
+        # the record is reset: carrying it forward would wrongly zero the
+        # dense momentum written above
+        st.new_bits = jnp.zeros((kernels.num_sent_words(T) if T else 0,),
+                                jnp.int32)
+        return self._finish(st, out)
+
+    def _dense_tail(self, st: _Exchange, axis_name, world_size: int,
+                    op: str):
+        """The dense fallback block: one collective + correction.
+        Dense-PLANNED buckets ride the SAME psum as the dense tail (one
+        concatenated wire), then split back into per-bucket slabs that get
+        the dense-path semantics: clip on the averaged gradient, pending
+        transmit mask from the PREVIOUS state materialized,
+        non-accumulating compensate — overriding what the accumulating
+        compensate wrote in [0, T). Returns the step's [P] result."""
+        T, P, m = self.T, self.layout.total, self._mem
+        clip = m.gradient_clipping if m is not None else None
+        acc = st.acc
         dslabs = [(i, self.buckets[i]) for i in self._dense_ids]
-        if P > T or dslabs:
-            with _trace.phase("dense"):
-                dparts = [flat_grad[b.base:b.base + b.rows * b.cols]
-                          for _, b in dslabs]
-                # dparts emptiness is plan-static (dense regime ids)
-                dwire = (jnp.concatenate(dparts + [gd])  # dgclint: ok[tracer-branch]
-                         if dparts else gd)
-                davg = self._dense_combine(dwire, axis_name, world_size,
-                                           op)
-                keep = None
-                off = 0
-                for i, b in dslabs:
-                    n = b.rows * b.cols
-                    slab = davg[off:off + n]
-                    off += n
-                    if clip is not None:
-                        slab = self._clip_block(
-                            slab, self.layout.buckets[i].names, b.base)
-                    if m is None:
-                        acc = acc.at[b.base:b.base + n].set(
-                            slab.astype(acc.dtype))
-                        continue
-                    if keep is None:
-                        keep = kernels.keep_from_bits(bits_prev, T)
-                    kslab = keep[b.base:b.base + n].astype(vc_prev.dtype)
-                    vslab = vc_prev[b.base:b.base + n] * kslab
-                    mslab = mc_prev[b.base:b.base + n]
-                    if m.momentum_masking:
-                        mslab = mslab * kslab
-                    out_slab, mslab2 = self._compensate_dense(mslab, slab)
-                    acc = acc.at[b.base:b.base + n].set(
-                        out_slab.astype(acc.dtype))
-                    mc = mc.at[b.base:b.base + n].set(mslab2)
-                    vc = vc.at[b.base:b.base + n].set(vslab)
-                if P > T:
-                    gd_avg = davg[off:]
-                    if clip is not None:
-                        # the fallback's compensate sees the AVERAGED
-                        # gradient (reference compression.py:198 ->
-                        # memory.py:52-53)
-                        gd_avg = self._clip_block(gd_avg,
-                                                  self.layout.dense_names,
-                                                  T)
-                    out_d, md = self._compensate_dense(md, gd_avg)
-            out = jnp.concatenate([acc, out_d]) if P > T else acc
-        else:
-            out = acc
+        if not (P > T or dslabs):
+            return acc
+        with _trace.phase("dense"):
+            dparts = [st.grad[b.base:b.base + b.rows * b.cols]
+                      for _, b in dslabs]
+            # dparts emptiness is plan-static (dense regime ids)
+            dwire = (jnp.concatenate(dparts + [st.gd])  # dgclint: ok[tracer-branch]
+                     if dparts else st.gd)
+            davg = self._dense_combine(dwire, axis_name, world_size, op)
+            keep, off = None, 0
+            for i, b in dslabs:
+                lo, n = b.base, b.rows * b.cols
+                slab = davg[off:off + n]
+                off += n
+                if clip is not None:
+                    slab = self._clip_block(
+                        slab, self.layout.buckets[i].names, lo)
+                if m is None:
+                    acc = acc.at[lo:lo + n].set(slab.astype(acc.dtype))
+                    continue
+                if keep is None:
+                    keep = kernels.keep_from_bits(st.mem.get("sent_bits"), T)
+                out_slab, mslab, vslab = self._dense_correct(
+                    slab, st.mc_prev, st.vc_prev, keep, lo, lo + n)
+                acc = acc.at[lo:lo + n].set(out_slab.astype(acc.dtype))
+                st.mc = st.mc.at[lo:lo + n].set(mslab)
+                st.vc = st.vc.at[lo:lo + n].set(vslab)
+            if P > T:
+                gd_avg = davg[off:]
+                if clip is not None:
+                    # the fallback's compensate sees the AVERAGED
+                    # gradient (reference compression.py:198 ->
+                    # memory.py:52-53)
+                    gd_avg = self._clip_block(gd_avg,
+                                              self.layout.dense_names, T)
+                out_d, st.md = self._compensate_dense(st.md, gd_avg)
+        return jnp.concatenate([acc, out_d]) if P > T else acc
 
-        if m is not None:
-            mem = {"momentums_c": kernels.vtag(mc, "dgcver.sink.momentum"),
-                   "velocities_c": kernels.vtag(vc, "dgcver.sink.residual"),
-                   "momentums_d": md, "velocities_d": mem["velocities_d"],
-                   "sent_bits": kernels.vtag(new_bits,
-                                             "dgcver.sink.sent_bits")}
-            if g_cfg is not None:
-                mem["gossip_clock"] = g_clock + 1
-                mem["gossip_age"] = g_new_age
-                mem["gossip_inbox"] = g_inbox.astype(vc.dtype)
-                mem["gossip_forced"] = (g_forced0
-                                        + g_forced.astype(jnp.int32))
-        if telemetry:
-            # transmitted energy from the live payload (invalid slots carry
-            # 0.0): under deferred masking vc still holds the transmitted
-            # values, so the untransmitted residual is ||vc||² minus it;
-            # under int8 error feedback vc was already rewritten to the
-            # residual above and is the norm directly. Mixed plans with
-            # int8 EF count only the deferred (non-i8) slots.
-            if m is None:
-                tx_energy = tx_abs = None
-            elif int8_ef and self._i8_slot_mask is not None:
-                keep_tx = jnp.where(jnp.asarray(self._i8_slot_mask), 0.0,
-                                    values.astype(jnp.float32))
-                tx_energy = jnp.sum(keep_tx ** 2)
-                tx_abs = jnp.sum(jnp.abs(keep_tx))
-            elif int8_ef:
-                tx_energy = tx_abs = None
-            else:
-                vf = values.astype(jnp.float32)
-                tx_energy = jnp.sum(vf ** 2)
-                tx_abs = jnp.sum(jnp.abs(vf))
-            return out, mem, self._telemetry_stats(
-                taps, grad_norm, clip_delta, mc, md, vc, sel_stats,
-                tx_energy=tx_energy, tx_abs=tx_abs)
-        return out, mem
+    def _finish(self, st: _Exchange, out):
+        """The step's result, ``(out, memory)`` and with telemetry the
+        stats: the one place the memory dict is assembled."""
+        mem = st.mem
+        if self._mem is not None:
+            # the verifier's sinks pair with the selection's tags: an
+            # all-dense step plants neither
+            tag = kernels.vtag if st.values is not None else (lambda x, _: x)
+            mem = {"momentums_c": tag(st.mc, "dgcver.sink.momentum"),
+                   "velocities_c": tag(st.vc, "dgcver.sink.residual"),
+                   "momentums_d": st.md, "velocities_d": mem["velocities_d"],
+                   "sent_bits": tag(st.new_bits, "dgcver.sink.sent_bits")}
+            gr = st.gossip
+            if gr is not None:
+                mem["gossip_clock"] = st.mem["gossip_clock"] + 1
+                mem["gossip_age"] = gr.new_age
+                mem["gossip_inbox"] = st.inbox.astype(st.vc.dtype)
+                mem["gossip_forced"] = (st.mem["gossip_forced"]
+                                        + gr.forced.astype(jnp.int32))
+        if st.taps is None:
+            return out, mem
+        return out, mem, self._telemetry_stats(st)
 
-    def _telemetry_stats(self, taps, grad_norm, clip_delta, mc, md, vc,
-                         sel, tx_energy=None, tx_abs=None):
-        """Assemble the STEP_METRICS pytree (see telemetry.taps). ``sel``
-        is sparsify's stats_out dict, or None on the dense-only paths
-        (zero payload, zero wire). ``tx_energy`` / ``tx_abs`` — sum of
-        squared / absolute transmitted values for the deferred-masking
-        residual identity; None means vc already IS the residual (dense
-        path / int8 EF). The abs identity is exact for the same reason the
-        energy one is: under deferred masking the transmitted slots of vc
-        hold exactly the transmitted values, and masking zeroes them."""
-        if sel is None:
+    def _telemetry_stats(self, st: _Exchange):
+        """The STEP_METRICS pytree (see telemetry.taps) of the step's
+        record. Without a selection (the all-dense paths) payload and wire
+        are zero and vc IS the residual. Under deferred masking ``vc``
+        still holds exactly the transmitted values, which masking will
+        zero: the untransmitted residual is ||vc||² minus the energy of
+        the live payload (invalid slots carry 0.0), and likewise the mass.
+        Under int8 error feedback vc was already rewritten to the residual
+        and is the norm directly; mixed plans with int8 EF count only the
+        deferred (non-i8) slots."""
+        taps, mc, md, vc = st.taps, st.mc, st.md, st.vc
+        tx = None
+        if st.values is not None and self._mem is not None:
+            if not self._int8_ef:
+                tx = st.values.astype(jnp.float32)
+            elif self._i8_slot_mask is not None:
+                tx = jnp.where(jnp.asarray(self._i8_slot_mask), 0.0,
+                               st.values.astype(jnp.float32))
+        if st.sel_stats is None:
             sel = taps.empty_bucket_stats(len(self.buckets))
             wire = 0.0
         else:
+            sel = st.sel_stats
             wire = float(self.wire_bytes_per_worker())
+        if tx is not None:
+            tx_energy = jnp.sum(tx ** 2)
+            tx_abs = jnp.sum(jnp.abs(tx))
         if mc is None and md is None and vc is None:
             mom = res = mass = jnp.zeros((), jnp.float32)
         else:
             mom = jnp.sqrt(taps.l2(mc) ** 2 + taps.l2(md) ** 2)
-            if tx_energy is None:
+            if tx is None:
                 res = taps.l2(vc)
                 mass = taps.l1(vc)
             else:
@@ -2962,8 +2959,8 @@ class FlatDGCEngine:
                     jnp.sum(vc.astype(jnp.float32) ** 2) - tx_energy, 0.0))
                 mass = jnp.maximum(taps.l1(vc) - tx_abs, 0.0)
         return taps.assemble_step_stats(
-            grad_norm=grad_norm, momentum_norm=mom, residual_norm=res,
-            residual_mass=mass, clip_delta=clip_delta,
+            grad_norm=st.grad_norm, momentum_norm=mom, residual_norm=res,
+            residual_mass=mass, clip_delta=st.clip_delta,
             payload_elems=sel["payload_elems"],
             wire_bytes=jnp.asarray(wire, jnp.float32),
             selected_frac=sel["selected_frac"], threshold=sel["threshold"])

@@ -385,7 +385,7 @@ class DesyncAlert(NamedTuple):
 
 def detect_desync(series: List[Tuple[int, List[float]]],
                   metric: str = "w_residual_mass", *, window: int = 16,
-                  band_scale: float = 4.0, band_floor: float = 0.25,
+                  band_scale: float = 4.0, band_floor: float = 0.75,
                   min_hits: int = 3) -> List[DesyncAlert]:
     """Rolling-band divergence detector over a per-worker series.
 
@@ -398,6 +398,15 @@ def detect_desync(series: List[Tuple[int, List[float]]],
     DGC residual/momentum mass wanders step to step (selection is
     stochastic), but a worker whose error-feedback state corrupted walks
     AWAY from the cohort and stays out.
+
+    ``band_floor`` is what a HEALTHY worker may sit off the median: the
+    residual is an integrator (its excursions last many steps, so
+    ``min_hits`` consecutive steps do not screen them) and the worst of W
+    workers routinely sits 5-7x the cohort's median deviation. Healthy
+    8-worker cohorts of the two-process drill held one worker 0.40-0.63
+    off the median for five and more steps (tests/test_fleet.py has one
+    such run), which a floor of 0.25 reported as a desync; corrupted
+    state shows as multiples, and a collapsed one as a deviation of 1.
     """
     alerts: List[DesyncAlert] = []
     spreads: List[float] = []          # trailing typical deviations

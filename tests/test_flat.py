@@ -21,7 +21,7 @@ from dgc_tpu import (
     dgc_sgd,
     sgd,
 )
-from dgc_tpu.compression.flat import ParamLayout
+from dgc_tpu.compression.flat import _REGIMES, ParamLayout
 from dgc_tpu.utils.pytree import named_flatten
 from dgc_tpu.utils.compat import enable_x64, shard_map
 
@@ -1935,3 +1935,132 @@ def test_flat_mixed_plan_matches_uniform_mixture(mesh8, sparse_regime):
             np.testing.assert_allclose(
                 full_mix[mk][s1], full_dn[mk][s1], rtol=2e-7, atol=1e-7,
                 err_msg=f"step {step} {mk} bucket1")
+
+
+# ------------------------------------------------------------------ #
+# the exchange's stages, alone (no mesh)                             #
+# ------------------------------------------------------------------ #
+
+def _three_bucket_engine(regimes, **mem_kw):
+    """An engine over three size buckets (700k, 360k and 2k elements a
+    row) under ``regimes``: one name for all three, or one a bucket."""
+    from dgc_tpu.compression.flat import FlatDGCEngine
+    from dgc_tpu.compression.planner import BUILTIN_FABRICS, Plan
+
+    shapes = {"a": (1000, 700), "b": (600, 600), "c": (40, 50)}
+    params = {n: {"kernel": jnp.zeros(s, jnp.float32)}
+              for n, s in shapes.items()}
+    params["bias"] = {"b": jnp.zeros((16,), jnp.float32)}
+    named, _ = named_flatten(params)
+    compressed = [n for n, p in named.items() if p.ndim > 1]
+    comp = DGCCompressor(0.05, memory=DGCSGDMemory(momentum=0.9, **mem_kw),
+                         sample_ratio=1.0)
+    comp.initialize((n, named[n]) for n in compressed)
+    engine = FlatDGCEngine(
+        comp, ParamLayout(params, compressed),
+        plan=Plan(regimes if isinstance(regimes, tuple) else (regimes,) * 3,
+                  BUILTIN_FABRICS["32x25GbE"], W))
+    assert len(engine.buckets) == 3
+    return engine
+
+
+_WIRE_PLANS = {r: r for r in _REGIMES if r != "dense"}
+_WIRE_PLANS["mixed_plain_and_words"] = ("int8", "fp16_packed", "int4_packed")
+_WIRE_PLANS["mixed_words_and_dense"] = ("int8_delta_idx", "dense",
+                                        "fp32_packed")
+
+
+@pytest.mark.parametrize("plan", sorted(_WIRE_PLANS))
+def test_wire_roundtrip_without_a_mesh(plan):
+    """The wire alone: ``decode(stack([encode(values, indices)]))`` of one
+    worker's payload, for every sparse regime and two mixed plans. Indices
+    come back exactly (the delta codec's as the sorted permutation it
+    ships), values exactly (fp32), to half precision (fp16) or within one
+    quantisation step of their row's (int8) or bucket's (int4) scale —
+    and the static wire figure is the bytes of the lanes encode made."""
+    from dgc_tpu.compression.flat import _Lanes
+
+    engine = _three_bucket_engine(_WIRE_PLANS[plan])
+    S = engine.layout.sentinel
+    rng = np.random.RandomState(3)
+    vec = np.zeros((engine.T,), np.float32)
+    for b in engine.buckets:
+        for r, n in enumerate(b.numels):
+            lo = b.base + r * b.cols
+            vec[lo:lo + n] = rng.standard_t(3, n)
+    values, indices = engine.sparsify(jnp.asarray(vec),
+                                      jax.random.PRNGKey(0))
+    if engine._dcodec is not None:
+        values, indices = engine._sort_delta_payload(values, indices)
+    assert values.shape == indices.shape == (engine.payload_size,)
+
+    lanes, _ = engine._encode_values(values)
+    index_lanes = engine._encode_indices(indices)
+    lanes = lanes._replace(words=index_lanes.words, plain=index_lanes.plain)
+    assert engine.wire_bytes_per_worker() == sum(
+        lane.nbytes for lane in lanes if lane is not None)
+    stacked = _Lanes(*[None if lane is None else lane[None]
+                       for lane in lanes])
+    g_values = np.asarray(engine._decode_values(stacked, jnp.float32))
+    g_indices, g_chk = engine._decode_indices(stacked)
+    assert g_chk is None
+    assert g_values.shape == g_indices.shape == (1, engine.payload_size)
+
+    v, i = np.asarray(values), np.asarray(indices)
+    real = i != S
+    assert real.sum() > 0.9 * engine.payload_size
+    # a padded slot may decode to any slot of its row: it carries 0.0
+    np.testing.assert_array_equal(np.asarray(g_indices)[0][real], i[real])
+    assert not v[~real].any() and not g_values[0][~real].any()
+    for (s0, s1), kind, b in zip(engine._payload_slices, engine._kinds,
+                                 engine._sparse_buckets):
+        got, want = g_values[0, s0:s1], v[s0:s1]
+        if kind == "f32":
+            np.testing.assert_array_equal(got, want)
+        elif kind == "f16":
+            np.testing.assert_array_equal(
+                got, want.astype(np.float16).astype(np.float32))
+        elif kind == "i8":
+            row = b.tight // b.max_sel
+            scale = np.zeros((b.rows,), np.float32)
+            np.maximum.at(scale, row, np.abs(want))
+            assert (np.abs(got - want) <= scale[row] / 127 + 1e-12).all()
+        else:
+            assert kind == "i4"
+            assert (np.abs(got - want)
+                    <= np.abs(want).max() / 7 + 1e-12).all()
+
+
+@pytest.mark.parametrize("nesterov", [False, True])
+@pytest.mark.parametrize("momentum_masking", [False, True])
+def test_dense_correction_whole_equals_slabs(nesterov, momentum_masking):
+    """The dense correction is one helper for the all-dense step (the
+    whole compressed block) and for dense-planned slabs: over a partition
+    of the block, slab by slab, it is bitwise the whole block's."""
+    from dgc_tpu.ops import kernels
+
+    engine = _three_bucket_engine("fp32", nesterov=nesterov,
+                                  momentum_masking=momentum_masking)
+    T = engine.T
+    rng = np.random.RandomState(11)
+    avg, mmt, vec = (jnp.asarray(rng.randn(T).astype(np.float32))
+                     for _ in range(3))
+    sent = rng.choice(T, T // 50, replace=False).astype(np.int32)
+    keep = kernels.keep_from_bits(
+        kernels.pack_sent_bits(jnp.asarray(sent), T,
+                               sentinel=engine.layout.sentinel), T)
+    assert 0 < int((np.asarray(keep) == 0).sum()) <= sent.size
+    cuts = [0] + [b.base for b in engine.buckets[1:]] + [T]
+    for k in (keep, None):
+        whole = engine._dense_correct(avg, mmt, vec, k, 0, T)
+        slabs = [engine._dense_correct(avg[lo:hi], mmt, vec, k, lo, hi)
+                 for lo, hi in zip(cuts[:-1], cuts[1:])]
+        for got, want in zip(zip(*slabs), whole):
+            np.testing.assert_array_equal(
+                np.concatenate([np.asarray(x) for x in got]),
+                np.asarray(want))
+    # the mask did something, and momentum masking decides where
+    _, m_kept, v_kept = engine._dense_correct(avg, mmt, vec, keep, 0, T)
+    _, m_all, v_all = engine._dense_correct(avg, mmt, vec, None, 0, T)
+    assert (np.asarray(v_kept) != np.asarray(v_all)).any()
+    assert (np.asarray(m_kept) != np.asarray(m_all)).any() == momentum_masking

@@ -224,6 +224,58 @@ def test_desync_detector_quiet_then_fires():
     assert alerts[0].band < 1.0
 
 
+#: w_residual_mass of a HEALTHY 8-worker cohort: the two-process drill
+#: (tests/test_multiprocess.py::test_fleet_two_process_straggler) at 4
+#: images a worker, its 14 steps from a cold start, rounded. Worker 5 sits
+#: 0.47-0.57 off the median from step 9 on while the cohort's typical
+#: deviation is 0.05-0.12, and comes back.
+_HEALTHY_RESIDUAL_MASS = [
+    [4.83, 4.03, 4.70, 4.69, 4.80, 5.27, 4.77, 4.40],
+    [10.52, 6.58, 8.31, 8.99, 7.47, 10.71, 8.16, 9.56],
+    [15.71, 8.68, 11.43, 14.46, 9.72, 15.55, 10.74, 15.35],
+    [19.77, 13.79, 15.79, 19.48, 10.29, 20.34, 15.45, 18.58],
+    [22.17, 19.48, 19.25, 23.90, 12.19, 24.64, 20.84, 20.97],
+    [24.21, 21.64, 22.18, 25.33, 14.39, 29.68, 26.62, 22.53],
+    [25.36, 23.89, 27.45, 27.36, 16.10, 33.69, 28.27, 23.38],
+    [26.81, 25.57, 28.54, 28.75, 17.68, 37.66, 28.43, 24.81],
+    [28.62, 27.24, 29.79, 30.98, 20.30, 40.81, 29.08, 26.77],
+    [30.44, 27.75, 31.12, 32.16, 22.01, 43.19, 28.42, 27.81],
+    [33.03, 28.76, 30.88, 32.01, 22.33, 45.11, 28.51, 26.32],
+    [34.66, 28.27, 29.57, 30.81, 24.02, 45.79, 29.12, 23.36],
+    [34.97, 26.74, 29.23, 29.57, 24.48, 45.20, 28.77, 24.96],
+    [34.25, 26.51, 27.83, 26.77, 26.38, 43.77, 31.05, 27.95],
+]
+
+
+@pytest.mark.fast
+def test_desync_floor_holds_a_healthy_excursion():
+    """The band's floor is what a healthy worker may sit off the cohort
+    median. A recorded healthy cohort stays quiet (the floor of 0.25 that
+    the detector shipped with named worker 5); a worker whose state
+    collapsed, and one that walks away, still alert, alone."""
+    healthy = list(enumerate(_HEALTHY_RESIDUAL_MASS))
+    assert fleet.detect_desync(healthy) == []
+    old = fleet.detect_desync(healthy, band_floor=0.25)
+    assert old and {a.worker for a in old} == {5}
+
+    def spoiled(worker, factor_at):
+        out = []
+        for step, vals in healthy:
+            vals = list(vals)
+            vals[worker] *= factor_at(step)
+            out.append((step, vals))
+        return out
+
+    # error-feedback state lost at step 6: the mass is rebuilt from zero
+    collapsed = fleet.detect_desync(
+        spoiled(3, lambda s: 1.0 if s < 6 else 0.02 * (s - 5)))
+    assert collapsed and {a.worker for a in collapsed} == {3}
+    assert collapsed[0].step == 6 + 2             # min_hits consecutive
+    walked = fleet.detect_desync(
+        spoiled(1, lambda s: 1.0 + 0.9 * max(0, s - 4)))
+    assert walked and {a.worker for a in walked} == {1}
+
+
 @pytest.mark.fast
 def test_straggler_table_and_summary(tmp_path):
     run = _write_run(str(tmp_path), hosts=2, world=4, steps=30,

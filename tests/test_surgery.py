@@ -31,6 +31,7 @@ from dgc_tpu.control.supervisor import Supervisor, parse_env_file
 from dgc_tpu.resilience import faults, surgery
 from dgc_tpu.telemetry import monitor, registry
 
+from surgery_worker import _read_step
 from test_fleet import _write_run
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -536,14 +537,30 @@ def test_monitor_cohort_line_and_gauges(tmp_path):
 # the 3-process excise/readmit drill                                     #
 # --------------------------------------------------------------------- #
 
-def _surgery_rules():
-    # the shipped detectors and action mapping, tuned tick-fast: readmit
-    # holds back long enough for the survivors to run a stretch at W=2
+#: the drill's worker 2 hangs at this step; its readmit waits until the
+#: survivors are this far past it
+_HANG_STEP, _READMIT_AFTER = 5, 20
+
+
+def _surgery_rules(cohort_dir):
+    # the shipped detectors and action mapping, tuned tick-fast. Readmit
+    # holds back until the survivors HAVE run a stretch at W=2 — by the
+    # cohort's own progress, not by the clock: a readmitted life resumes
+    # at the cohort's step, and one that resumed inside worker 2's fault
+    # window would hang again with the readmit budget spent. (A hold-back
+    # in ticks raced the survivors' exit-76 path, which takes the barrier
+    # timeout PLUS a jax import: surgery.write_exit_record publishes
+    # through dgc_tpu.serving.protocol, and dgc_tpu/serving/__init__.py
+    # imports the exporter.)
+    def readmit_after_stretch(snap):
+        step = _read_step(os.path.join(cohort_dir, "progress.json"))
+        return rules.detect_readmit(snap) if step >= _READMIT_AFTER else None
+
     return (
         Rule("hang-excise", rules.detect_excise, "excise",
              min_hits=1, debounce_s=60.0, budget=1),
-        Rule("probe-readmit", rules.detect_readmit, "readmit",
-             min_hits=14, debounce_s=60.0, budget=1),
+        Rule("probe-readmit", readmit_after_stretch, "readmit",
+             min_hits=2, debounce_s=60.0, budget=1),
     )
 
 
@@ -557,7 +574,7 @@ def test_cohort_surgery_drill(tmp_path):
 
     def spec(i, **kw):
         run_dir = os.path.join(root, f"w{i}")
-        env = {"JAX_PROCESS_ID": str(i), "DGC_BOUNDARY_TIMEOUT": "3.5"}
+        env = {"JAX_PROCESS_ID": str(i), "DGC_BOUNDARY_TIMEOUT": "6"}
         env.update(kw.pop("env", {}))
         return RunSpec(
             f"w{i}",
@@ -570,13 +587,18 @@ def test_cohort_surgery_drill(tmp_path):
         spec(0), spec(1),
         # worker 2 hangs at step 5 (exactly once: the readmitted life
         # resumes past the window); its supervisor escalates via the
-        # stale heartbeat, and its probe re-earns the slot
-        spec(2, env={"DGC_FAULTS": "hang@5-5"}, hang_timeout=1.5,
+        # stale heartbeat, and its probe re-earns the slot. The budget
+        # counts from launch, so it also has to cover a worker's start
+        # on a host that runs the suite's other xdist workers (the
+        # barrier timeout stays above it: the hang is escalated before
+        # the survivors give the member up)
+        spec(2, env={"DGC_FAULTS": f"hang@{_HANG_STEP}-{_HANG_STEP}"},
+             hang_timeout=3.0,
              probe_cmd=[sys.executable, WORKER,
                         os.path.join(root, "w2"), "--cohort", cohort_dir,
                         "--probe"]),
     ]
-    plane = ControlPlane(specs, root, rules=_surgery_rules(),
+    plane = ControlPlane(specs, root, rules=_surgery_rules(cohort_dir),
                          interval=0.25)
     final = plane.run(max_ticks=400)
 
