@@ -541,14 +541,14 @@ def run_contract_suite(mesh=None, log: Callable[[str], None] = None,
 
     # two-megakernel hot path (ISSUE 16): megakernel=False must be
     # byte-identical to a build that never mentioned the flag, with
-    # neither fused kernel body (_dgc_forward_kernel / _dgc_apply_kernel)
+    # neither fused kernel body (_dgc_forward_kernel / _payload_apply_kernel)
     # lowered into the step — the gate is Python-static, like telemetry
     _, step_mkoff, _, _ = build_fixture(
         mesh, donate=False, telemetry=False,
         compressor_kwargs={"megakernel": False})
     mkoff = _step_contract(
         "megakernel-off-compiles-away", state, step_mkoff, inputs,
-        forbid_substrings=["_dgc_forward_kernel", "_dgc_apply_kernel"],
+        forbid_substrings=["_dgc_forward_kernel", "_payload_apply_kernel"],
         identical_to=plain)
     run(mkoff.name, mkoff.check)
 

@@ -37,6 +37,7 @@ decompress, momentum correction and masking per SURVEY.md §2.3-2.5.
 
 import collections
 import dataclasses
+import functools
 import math
 import os
 from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
@@ -1622,20 +1623,49 @@ class FlatDGCEngine:
                 f"DGCCompressor({flag}=True) cannot be honoured: {why}")
         return False
 
+    def _apply_kernel_serves(self, m, int8_ef: bool, dt) -> bool:
+        """The static plan properties the apply kernel serves: a transmit
+        record to build (error-feedback memory), a plain f32 value wire
+        with no int8 error feedback, int32 offsets over a lane-aligned
+        T the kernel's chunk arithmetic cannot overflow, and no gossip
+        round (its inbox takes the [T] accumulator itself)."""
+        return (m is not None and not int8_ef and dt == jnp.float32  # dgclint: ok[tracer-branch] — memory/wire dtype/T are plan-static Python values, not tracers
+                and self.T % kernels._LANE == 0
+                and self.T + kernels._APPLY_CHUNK < 2 ** 31
+                and not self._gossip)
+
     def _apply_kernel_ok(self, flag: str, m, int8_ef: bool, dt) -> bool:
-        """Preconditions the two fused apply kernels share: the
-        interpreter's payload bound (see :meth:`_use_fused_apply`), then
-        those that are not bucket geometry (see :meth:`_decline`)."""
+        """Preconditions of a FLAGGED apply kernel: the interpreter's
+        payload bound (see :meth:`_use_fused_apply`), then those that
+        are not bucket geometry (see :meth:`_decline`)."""
         if kernels._interpret() and self.payload_size > 4096:
             return False
-        if (m is not None and not int8_ef and dt == jnp.float32  # dgclint: ok[tracer-branch] — memory/wire dtype/T are plan-static Python values, not tracers
-                and self.T % kernels._LANE == 0):
+        if self._apply_kernel_serves(m, int8_ef, dt):  # dgclint: ok[tracer-branch] — plan-static Python values, not tracers
             return True
         return self._decline(
             flag, "the fused apply kernel needs error-feedback memory "
             "(DGCSGDMemory), an f32 value wire and no int8 error feedback "
             f"— got memory={type(m).__name__}, int8_error_feedback="
             f"{int8_ef}, wire dtype={jnp.dtype(dt).name}")
+
+    #: the geometry rule of :meth:`_apply`, on static numbers only. The
+    #: pairs stream through the apply kernel where the [T] f32
+    #: accumulator (4 T bytes) is larger than the chip's 128 MiB of
+    #: VMEM, in which XLA otherwise keeps it between the scatter and
+    #: the optimizer that reads it (ResNet-50: 108 MB, kept; VGG-16-BN:
+    #: 556 MB, streamed); see :meth:`_apply` for the readings
+    APPLY_STREAM_MIN_BYTES = 128 * 1024 * 1024
+    #: and while the windows' first / last indices (two int32 a 128
+    #: pairs) stay a small part of the scalar memory they are
+    #: prefetched into: 2**21 pairs are 2 x 64 KiB
+    APPLY_STREAM_MAX_PAIRS = 1 << 21
+
+    @classmethod
+    def _apply_streams(cls, T: int, pairs: int) -> bool:
+        """The geometry rule on its two static numbers: the compressed
+        block's length and the gathered pairs ``W * payload``."""
+        return (4 * T > cls.APPLY_STREAM_MIN_BYTES
+                and pairs <= cls.APPLY_STREAM_MAX_PAIRS)
 
     def _use_fused_select(self, b: "_Bucket") -> bool:
         """Whether a bucket's selection runs the fused
@@ -2725,16 +2755,54 @@ class FlatDGCEngine:
         scatter-then-divide, compression.py:192-193; differs by
         float-rounding order only): the full-[T] divide pass disappears
         — its read/write cost scales with the model, ~0.8 ms/step at
-        VGG. The scatter keeps a fresh ZEROS operand + concat,
-        deliberately: XLA fuses the zero-init INTO the scatter (one [T]
-        write), while scattering into a non-zero operand (the final [P]
-        buffer pre-filled with the dense tail — tried both as a
-        trailing dynamic_update_slice and as a concat-initialized
-        operand) always COPIES the operand and measured +0.3 ms/step at
-        ResNet-50. The fused [2T] acc+sent scatter also LOSES (slicing
-        the halves back out materializes a 0.66 ms loop fusion);
-        scatter-set into the live mmt/vec buffers (1.8 ms) and sub-word
-        masks (serial while-loop) stay avoided."""
+        VGG.
+
+        **Two forms of one step, chosen from T and W * payload**
+        (:meth:`_apply_streams`; ``exchange.apply`` counts the pairs
+        and names the form under ``step.trace``). What the chip says
+        (v5e; PR 30's probes and PR 31's, `PERF.md` §6):
+
+        * ``scatter``: ``zeros[T].at[idx].add(wire)``, then
+          ``pack_sent_bits`` over the local indices. XLA:TPU's scatter
+          ALIASES its operand and is a PASS over it (an earlier
+          installation read "always copies": disproved, PR 30): into
+          zeros the pass is a fused fill, 0.85 ms at VGG's T, plus 9.1
+          ns a pair wherever the pairs fall (2.07 ms at 138,360 pairs,
+          5.86 at 553,440); into a live buffer (the optimizer's output,
+          the [P] result with its tail) it is a [T] read + write, which
+          is why "update without the dense gradient" gave back what it
+          saved. The bit scatter is a second per-pair pass: 1.39 ms.
+          The fused [2T] acc+sent scatter, scatter-set into the live
+          mmt/vec buffers and sub-word masks all lose as well.
+        * ``stream``: one stable sort of the pairs and ONE pass over
+          the buffer (``kernels.payload_apply_bits``), which expands
+          128 pairs at a time into one-hot matrix products and writes
+          every output tile and the transmit record once, into a [P]
+          buffer whose tail :meth:`_dense_tail` fills in place. 1.78 ms
+          against 3.46 at VGG's T with 138,360 pairs, 3.90 against 7.25
+          with 553,440 (sort 0.45 / 1.09 of it). The staging the two
+          opt-in kernels had before (argsort + take + three payload-
+          sized scatter-sets, ``_stage_payload``) cost 7.05 / 27.70 ms
+          alone: every payload-sized XLA scatter, gather or argsort is
+          6-10 ns an element here.
+
+        The rule: stream where the accumulator cannot stay on the chip,
+        ``4 T > 128 MiB`` (and the pairs' window maps fit scalar
+        memory). At ResNet-50 (T 27,068,416: 108 MB) XLA keeps the
+        scatter's [T] in VMEM until the optimizer has read it, so its
+        fill and re-read are free and a kernel that writes HBM gives
+        that up: forced to stream, the step's ``dgc_overhead_ms`` read
+        2.215, 2.239 against 2.185, 2.180 with the scatter, although
+        alone the pass is the faster of the two there (0.43 against
+        0.60 ms). The measured crossover lies between the two T the
+        benchmark has (108 MB loses by 0.03-0.06 ms, 556 MB wins by
+        1.8); W * payload did not move it (1x and 4x at VGG).
+        Static plan properties the kernel does not serve keep the
+        scatter: no error-feedback memory, a non-f32 value wire, int8
+        error feedback, an int64 wire, a gossip plan; off the TPU
+        backend the scatter stays unless ``fused_apply`` / ``megakernel``
+        force the kernel (interpreted, small payloads: the parity
+        tests)."""
         T, m = self.T, self._mem
         dt = st.grad.dtype
         gr = st.gossip
@@ -2747,38 +2815,40 @@ class FlatDGCEngine:
             g_values = g_values * gr.row_w[:, None].astype(g_values.dtype)
         wire = g_values.reshape(-1).astype(dt)
         mk_apply = self._use_megakernel_apply(m, self._int8_ef, dt)
+        stream = (mk_apply or self._use_fused_apply(m, self._int8_ef, dt)
+                  or (kernels.use_pallas()
+                      and self._apply_kernel_serves(m, self._int8_ef, dt)
+                      and self._apply_streams(T, wire.shape[0])))
+        _trace.count("exchange.apply", wire.shape[0],
+                     path="stream" if stream else "scatter")
         if op == "average" and not mk_apply:
             wire = wire / world_size
-        if mk_apply:
-            # apply megakernel (kernels.dgc_apply_rows; see
-            # _use_megakernel_apply): each staged entry divides
-            # in-register on its way into the VMEM-resident output chunk.
-            # The per-entry IEEE divide and the stable staging sort keep
-            # duplicate contributions in payload order, so values AND
-            # transmit record stay bitwise the unfused path's (pinned in
-            # tests/test_megakernel.py).
+        if stream:
+            # one pass over the buffer (kernels.payload_apply_bits): a
+            # stable sort of the pairs, then each VMEM-resident chunk
+            # takes its pairs as one-hot matrix products and its
+            # transmit bits (from _sent_flags) in the same visit, and is
+            # written once, into a [P] buffer whose tail _dense_tail
+            # fills in place; the dead previous-step record is donated
+            # for the rebuild. Duplicates sum in payload order, so
+            # values and record are bitwise the XLA path's on the CPU
+            # (tests/test_flat.py, tests/test_megakernel.py). The
+            # megakernel flag folds the worker average into the staging
+            # (kernels.dgc_apply_rows): the same per-entry IEEE divide.
             with _trace.phase("apply"):
                 flags = self._sent_flags(g_indices, axis_name)
-                st.acc, st.new_bits = kernels.dgc_apply_rows(
-                    wire, g_indices.reshape(-1), flags, T,
-                    bits_donor=st.mem["sent_bits"],
+                apply = (functools.partial(
+                    kernels.dgc_apply_rows,
                     divisor=(float(world_size) if op == "average"
                              else None))
-        elif self._use_fused_apply(m, self._int8_ef, dt):
-            # fused apply epilogue (kernels.payload_apply_bits): the
-            # decompress scatter-add AND the transmit-record pack ride
-            # one streamed Pallas pass over [T] — the payload is
-            # pre-bucketed by 2048-row chunk at payload scale, then each
-            # VMEM-resident chunk takes its entries' adds and bit sets
-            # and is written once (the record from _sent_flags); the dead
-            # previous-step record buffer is donated for the rebuild
-            # (input_output_aliases). Values within f32 scatter-order
-            # rounding of the XLA path below.
-            with _trace.phase("apply"):
-                flags = self._sent_flags(g_indices, axis_name)
-                st.acc, st.new_bits = kernels.payload_apply_bits(
+                    if mk_apply else kernels.payload_apply_bits)
+                st.acc, st.new_bits = apply(
                     wire, g_indices.reshape(-1), flags, T,
-                    bits_donor=st.mem["sent_bits"])
+                    bits_donor=st.mem["sent_bits"],
+                    out_total=self.layout.total,
+                    # a worker sends a coordinate once (disjoint rows,
+                    # distinct top-k): W bounds a run of equal indices
+                    max_dup=g_indices.shape[0])
         else:
             with _trace.phase("apply"):
                 st.acc = jnp.zeros((T,),
@@ -2895,6 +2965,9 @@ class FlatDGCEngine:
                     gd_avg = self._clip_block(gd_avg,
                                               self.layout.dense_names, T)
                 out_d, st.md = self._compensate_dense(st.md, gd_avg)
+        if P > T and acc.shape[0] == P:  # the apply kernel's [P]: in place
+            return jax.lax.dynamic_update_slice(
+                acc, out_d.astype(acc.dtype), (T,))
         return jnp.concatenate([acc, out_d]) if P > T else acc
 
     def _finish(self, st: _Exchange, out):

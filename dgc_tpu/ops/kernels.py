@@ -35,18 +35,19 @@ bitwise — tested in tests/test_kernels.py):
                      (threshold → select → pack, values never respill)
           ──DMA──▶ HBM mmt' / vec' + (scores, values, cols) payload
 
-      apply (one pass over the flat [T] buffer, grid = payload pages)
-          staged payload page ──scalar prefetch──▶ SMEM
-            └▶ unpack → decompress (divide) → scatter-apply
-               └▶ sent-bits record, same VMEM-resident output block
+      apply (one pass over the flat [T] buffer, grid = chunk pages)
+          sorted pairs, one block a page ──BlockSpec by prefetch──▶ VMEM
+            └▶ 128 pairs a window → one-hot row / lane factors
+               └▶ MXU products: values (three bf16 parts) + sent bits
+                  into the VMEM-resident output chunk, written once
           ──DMA──▶ HBM dense grad + packed transmit record
 
   Double-buffered streaming: both kernels run their HBM operands through
   the Pallas grid pipeline (the next block's DMA issues while the current
   block computes; the apply pass additionally scalar-prefetches its
-  page→chunk maps so the output-block revisit pattern is known ahead of
-  the DMAs), so per-bucket cost is bandwidth-bound rather than
-  launch-bound. Between them the unfused path's intermediate HBM
+  page→chunk and page→block maps so the output-block revisit pattern and
+  the pairs' blocks are known ahead of the DMAs), so per-bucket cost is
+  bandwidth-bound rather than launch-bound. Between them the unfused path's intermediate HBM
   round-trips (compensated velocity re-read, candidate buffers, staged
   importance) disappear.
 
@@ -1524,12 +1525,16 @@ def dgc_forward_rows(grad: jax.Array, mmt: jax.Array, vec: jax.Array,
 # fused payload-apply epilogue                                       #
 # ------------------------------------------------------------------ #
 
-#: gathered-payload entries staged per grid page of the apply pass
-#: (4 KB per SMEM operand; one grid step applies one page)
-_APPLY_PAGE = 1024
 #: flat elements covered by one apply chunk — one VMEM-resident
 #: [_CHUNK_ROWS, 128] output block of the fused pass
 _APPLY_CHUNK = _CHUNK_ROWS * _LANE
+#: rows of one apply sub-block, the M of one one-hot matmul (a power of
+#: two, a multiple of 256; 512 against 256 on the chip, PR 31: 1.78 |
+#: 2.08 ms a pass at 138,360 pairs, 3.90 | 3.87 at 553,440)
+_APPLY_SUB = 512
+#: 128-pair windows (lane rows of the sorted arrays) per pipelined input
+#: block; a chunk holding more pairs than one block takes further pages
+_APPLY_WPB = 32
 
 
 def payload_apply_bits_reference(values, indices, flags, total: int):
@@ -1543,118 +1548,223 @@ def payload_apply_bits_reference(values, indices, flags, total: int):
     return acc, bits
 
 
-def _payload_apply_body(pc_ref, first_ref, cnt_ref, pv_ref, po_ref,
-                        pf_ref, bits_donor_ref, acc_ref, bits_ref,
-                        divisor):
-    """One grid step applies one staged payload page into its chunk's
-    VMEM-resident output block. Pages of the same chunk are consecutive
-    (the staging sort guarantees it), so the output block revisits are
-    consecutive and the accumulation stays in VMEM between pages; the
-    first page of each chunk zero-initializes both blocks (every chunk
-    owns at least one page, so every block is fully defined).
+def _one_hot(mask):
+    """A 32-bit compare's mask as bf16 0/1 (selected in f32, then cast:
+    Mosaic will not relayout an i1 mask onto packed bf16 operands)."""
+    return jnp.where(mask, 1.0, 0.0).astype(jnp.bfloat16)  # dgcver: ok[dtype-flow] — 0.0 and 1.0 are exact in bf16
 
-    ``divisor`` is a PYTHON-static optional: None traces no divide (the
-    body stays op-for-op what it always was — the megakernel-off
-    byte-identity contract); a float folds the worker average into the
-    same pass (per-entry IEEE divide by the same operand the unfused
-    path uses on the wire, so values stay bitwise)."""
+
+def _dot_nt(a, b):
+    """``a [M, K] @ b [N, K]^T`` on the MXU, f32 accumulate."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _payload_apply_kernel(pc_ref, pb_ref, first_ref, cw0_ref, cw1_ref,
+                          kmin_ref, kmax_ref, k_ref, v_ref, f_ref,
+                          bits_donor_ref, acc_ref, bits_ref):
+    """One grid step applies one block of the SORTED pairs to its
+    chunk's VMEM-resident output block. Pages of one chunk are
+    consecutive, so the block stays in VMEM between them and reaches
+    HBM once; the chunk's first page zero-fills it (every chunk owns a
+    page, so every block is defined whatever the donor held).
+
+    No pair is touched alone. A window is 128 consecutive sorted pairs,
+    one lane row; a sub-block is :data:`_APPLY_SUB` rows of the output.
+    A window's first and last index (prefetched) say which sub-blocks
+    it reaches; for each of them the sub-block's values are
+
+        OneHotRow [SUB, 128 pairs] @ (OneHotLane [128 lanes, 128 pairs]
+                                      * value)^T
+
+    on the MXU: both factors are vector compares of an iota against the
+    window's row / lane numbers, pairs of other sub-blocks match no row
+    and fall out by themselves, and the f32 value travels as three bf16
+    parts (hi + mid + lo, 8 mantissa bits each) through three passes
+    whose f32 sum is the value again, bit for bit. The transmit bits
+    are a fourth product, ``2 * SUB / 32`` rows tall: the flagged
+    pairs' word rows against ``2**(bit % 16)`` in their lanes, the low
+    and the high half word kept in separate rows so that every sum
+    stays under 2**16 and exact. The lane factors are built once a
+    window, the row factors once a (window, sub-block)."""
     del bits_donor_ref  # alias donor: never dereferenced
     p = pl.program_id(0)
+    c, b = pc_ref[p], pb_ref[p]
+    sub, wr = _APPLY_SUB, _APPLY_SUB // 32
+    nsb = _CHUNK_ROWS // sub
+    shift = (sub * _LANE).bit_length() - 1
+    bf16, f32 = jnp.bfloat16, jnp.float32
 
     @pl.when(first_ref[p] == 1)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         bits_ref[...] = jnp.zeros_like(bits_ref)
 
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, _LANE), 1)
+    row_iota = jax.lax.broadcasted_iota(jnp.int32, (sub, _LANE), 0)
+    lane_iota = jax.lax.broadcasted_iota(jnp.int32, (_LANE, _LANE), 0)
+    half_iota = jax.lax.broadcasted_iota(jnp.int32, (2 * wr, _LANE), 0)
 
-    def body(j, carry):
-        off = po_ref[j]              # in-chunk offset, [0, _APPLY_CHUNK)
-        v = pv_ref[j]
-        if divisor is not None:
-            v = v / divisor          # fused worker average (decompress)
-        f = pf_ref[j]
-        r = off // _LANE
-        c = off % _LANE
-        # value add: one dynamic-sublane row RMW; duplicates within a
-        # chunk serialize through the loop in sorted-index order
-        onehot = jnp.where(lane == c, v, jnp.zeros((), v.dtype))
-        acc_ref[pl.ds(r, 1), :] = acc_ref[pl.ds(r, 1), :] + onehot
-        # transmit bit (word layout of pack_sent_bits): word row
-        # off//4096, word lane off%128, bit (off//128)%32 — the chunk
-        # base contributes 0 to each (a multiple of 4096*32 rows)
-        wrow = off // (32 * _LANE)
-        bvec = jnp.where(lane == c, f << (r % 32), jnp.zeros((), jnp.int32))
-        bits_ref[pl.ds(wrow, 1), :] = bits_ref[pl.ds(wrow, 1), :] | bvec
+    def window(w, carry):
+        wl = w - b * _APPLY_WPB
+        k = k_ref[pl.ds(wl, 1), :]                        # [1, 128]
+        v = v_ref[pl.ds(wl, 1), :]
+        f = f_ref[pl.ds(wl, 1), :]
+        in_lane = lane_iota == (k & (_LANE - 1))          # [128, 128]
+
+        def lanes(x):                        # x of a pair in its lane
+            return jnp.where(in_lane, x, 0.0).astype(bf16)  # dgcver: ok[dtype-flow] — x is one of three bf16-exact parts whose f32 sum is the value
+
+        # v = hi + mid + lo, each exact in bf16 (f32 here: the selects
+        # run on 32-bit masks, the casts after them)
+        hi_p = v.astype(bf16).astype(f32)  # dgcver: ok[dtype-flow] — the remainder travels in mid and lo
+        mid_p = (v - hi_p).astype(bf16).astype(f32)  # dgcver: ok[dtype-flow] — the remainder travels in lo
+        c_hi, c_mid, c_lo = lanes(hi_p), lanes(mid_p), lanes(
+            (v - hi_p) - mid_p)
+        # transmit bits (word layout of pack_sent_bits): word row
+        # p // 4096, word lane p % 128, bit (p // 128) % 32; rows
+        # [0, wr) of the product take bits 0..15, rows [wr, 2 wr)
+        # bits 16..31
+        row = k >> 7
+        bit = row & 31
+        c_bit = lanes(jnp.left_shift(1, bit & 15).astype(f32))
+        wrow = jnp.where(f != 0, k >> 12, -1)    # unflagged: no word row
+        up = jnp.where(bit >= 16, wr, 0)
+
+        def sub_block(s, carry):
+            r0 = pl.multiple_of((s - c * nsb) * sub, sub)
+            b0 = pl.multiple_of((s - c * nsb) * wr, wr)
+            a = _one_hot(row_iota == row - s * sub)       # [sub, 128]
+            out = (_dot_nt(a, c_hi) + _dot_nt(a, c_mid)) + _dot_nt(a, c_lo)
+            acc_ref[pl.ds(r0, sub), :] = acc_ref[pl.ds(r0, sub), :] + out
+            local = wrow - s * wr
+            half = jnp.where((local >= 0) & (local < wr), local + up, -1)
+            words = _dot_nt(
+                _one_hot(half_iota == half),
+                c_bit).astype(jnp.int32)  # dgcver: ok[dtype-flow] — sums of distinct powers of two under 2**16: integers, exact in f32
+            bits_ref[pl.ds(b0, wr), :] = (
+                bits_ref[pl.ds(b0, wr), :]
+                | words[:wr] | jnp.left_shift(words[wr:], 16))
+            return carry
+
+        # the sub-blocks of THIS chunk the window reaches (a pad
+        # window's first index lies past every chunk: none)
+        jax.lax.fori_loop(
+            jnp.maximum(kmin_ref[w] >> shift, c * nsb),
+            jnp.minimum(kmax_ref[w] >> shift, c * nsb + nsb - 1) + 1,
+            sub_block, 0)
         return carry
 
-    jax.lax.fori_loop(0, cnt_ref[p], body, 0)
+    jax.lax.fori_loop(jnp.maximum(cw0_ref[c], b * _APPLY_WPB),
+                      jnp.minimum(cw1_ref[c], (b + 1) * _APPLY_WPB),
+                      window, 0)
 
 
-def _payload_apply_kernel(pc_ref, first_ref, cnt_ref, pv_ref, po_ref,
-                          pf_ref, bits_donor_ref, acc_ref, bits_ref):
-    _payload_apply_body(pc_ref, first_ref, cnt_ref, pv_ref, po_ref,
-                        pf_ref, bits_donor_ref, acc_ref, bits_ref, None)
+def _count_below(samples, queries):
+    """``searchsorted(samples, queries, side="left")`` for the staging's
+    short sorted arrays: one fused compare-and-count where that is
+    small (a binary search is ~20 dependent steps of launch latency,
+    0.3–0.7 ms on the chip whatever the sizes), the unrolled search
+    above it."""
+    small = samples.shape[0] * queries.shape[0] <= 1 << 26
+    return jnp.searchsorted(
+        samples, queries, side="left",
+        method="compare_all" if small else "scan_unrolled"
+    ).astype(jnp.int32)
 
 
-def _dgc_apply_kernel(pc_ref, first_ref, cnt_ref, pv_ref, po_ref,
-                      pf_ref, bits_donor_ref, acc_ref, bits_ref, *,
-                      divisor):
-    _payload_apply_body(pc_ref, first_ref, cnt_ref, pv_ref, po_ref,
-                        pf_ref, bits_donor_ref, acc_ref, bits_ref, divisor)
+def _sorted_pairs(values, indices, flags, total: int, divisor, max_dup):
+    """Pair-scale staging of the apply kernels: ONE ``lax.sort`` of
+    (index, position | flag, value), then elementwise work only. Returns
+    the sorted arrays as [windows, 128] lane rows, padded by whole
+    blocks of (INT32_MAX, 0.0, 0) — pads sort last and match no row —
+    and the scalar-prefetch maps, all window- or chunk-sized:
 
+    ``kmin`` / ``kmax`` [windows] — a window's first and last index;
+    ``cw0`` / ``cw1`` [nchunks] — the windows that hold a chunk's pairs
+    (counted against ``kmax`` / ``kmin``); ``page_chunk`` /
+    ``page_block`` / ``first`` [npages] — grid step -> output chunk,
+    sorted-array block, and whether the step opens its chunk. A chunk
+    owns the blocks its windows lie in and at least one page; steps
+    past the last used one revisit the last chunk with the all-pad
+    block, which holds nothing.
 
-def _stage_payload(values, indices, flags, total: int):
-    """Payload-scale pre-bucketing shared by :func:`payload_apply_bits`
-    and :func:`dgc_apply_rows` (plain XLA: one sort + cumsum + one
-    payload-sized staging scatter — op-for-op the original epilogue
-    staging, so the unfused program stays byte-identical). Returns the
-    scalar-prefetch maps, the staged flat [npages * _APPLY_PAGE] operands, and
-    ``npages``."""
+    The position rides the sort as its second key, so equal indices
+    keep payload order (what a stable sort gives, with one operand
+    less). Cross-worker duplicates of one coordinate are then adjacent,
+    and are summed HERE, left to right in f32 as a scatter-add in
+    payload order would: ``max_dup - 1`` shifted adds where the caller
+    bounds the run length (the engine: one pair a worker, so W; 1 skips
+    the fold), else rounds until nothing changes. The run's last pair
+    carries the sum and the others 0.0, so the kernel adds one non-zero
+    term a coordinate and its result does not depend on the MXU's
+    accumulation order."""
     n = values.shape[0]
+    bp = _APPLY_WPB * _LANE
+    nblocks = -(-n // bp) + 1                   # + one all-pad block
+    pad = nblocks * bp - n
+    i32 = jnp.int32
+    assert 2 * nblocks * bp < 2 ** 31, n
+    if divisor is not None:
+        values = values / divisor   # worker average, per entry (IEEE)
+    order = (jnp.arange(n, dtype=i32) << 1) | flags.astype(i32)
+    si, so, sv = jax.lax.sort(
+        (jnp.concatenate([indices.astype(i32),
+                          jnp.full((pad,), jnp.iinfo(i32).max, i32)]),
+         jnp.concatenate([order, jnp.zeros((pad,), i32)]),
+         jnp.concatenate([values, jnp.zeros((pad,), values.dtype)])),
+        num_keys=2, is_stable=False)
+    if max_dup != 1:  # dgclint: ok[tracer-branch] — static by contract (the engine passes the Python world size)
+        same = jnp.concatenate([jnp.zeros((1,), bool), si[1:] == si[:-1]])
+
+        def fold(run):
+            return jnp.where(same, jnp.concatenate(
+                [jnp.zeros((1,), sv.dtype), run[:-1]]) + sv, sv)
+
+        def fold_changed(state):
+            nxt = fold(state[0])
+            # bit compare: a NaN must not keep the loop alive
+            return nxt, jnp.any(
+                jax.lax.bitcast_convert_type(nxt, i32)
+                != jax.lax.bitcast_convert_type(state[0], i32))
+
+        if max_dup is None:
+            run, _ = jax.lax.while_loop(lambda s: s[1], fold_changed,
+                                        (sv, jnp.ones((), bool)))
+        else:
+            run = sv
+            for _ in range(max_dup - 1):
+                run = fold(run)
+        last = jnp.concatenate([~same[1:], jnp.ones((1,), bool)])
+        sv = jnp.where(last, run, jnp.zeros((), sv.dtype))
+
+    rows = (nblocks * _APPLY_WPB, _LANE)
+    sk = si.reshape(rows)
+    kmin, kmax = sk[:, 0], sk[:, _LANE - 1]
     nchunks = -(-total // _APPLY_CHUNK)
-    pg = _APPLY_PAGE
-    npages_data = -(-n // pg)
-    npages = npages_data + nchunks          # static capacity bound
-    order = jnp.argsort(indices)
-    si = jnp.take(indices, order)
-    sv = jnp.take(values, order)
-    sf = jnp.take(flags, order).astype(jnp.int32)
-    ch = (si // _APPLY_CHUNK).astype(jnp.int32)
-    off = (si - ch.astype(si.dtype) * _APPLY_CHUNK).astype(jnp.int32)
-    starts = jnp.searchsorted(
-        ch, jnp.arange(nchunks, dtype=jnp.int32), side="left"
-    ).astype(jnp.int32)                                     # [nchunks]
-    counts = jnp.diff(jnp.concatenate(
-        [starts, jnp.full((1,), n, jnp.int32)]))
-    # every chunk owns >= 1 page (possibly empty) so every output block
-    # is visited and zero-initialized — correctness does not depend on
-    # the donor's contents
-    pages_per = jnp.maximum(-(-counts // pg), 1)
+    starts = jnp.arange(nchunks + 1, dtype=i32) * _APPLY_CHUNK
+    cw0 = _count_below(kmax, starts[:-1])
+    cw1 = _count_below(kmin, starts[1:])
+    b0 = cw0 // _APPLY_WPB
+    pages_per = jnp.maximum((cw1 - 1) // _APPLY_WPB, b0) - b0 + 1
     page_start = jnp.concatenate(
-        [jnp.zeros((1,), jnp.int32), jnp.cumsum(pages_per)])  # pages
-    pos = page_start[ch] * pg + (jnp.arange(n, dtype=jnp.int32)
-                                 - starts[ch])
-    cap = npages * pg
-    stage_v = jnp.zeros((cap,), values.dtype).at[pos].set(sv)
-    stage_o = jnp.zeros((cap,), jnp.int32).at[pos].set(off)
-    stage_f = jnp.zeros((cap,), jnp.int32).at[pos].set(sf)
-    pageid = jnp.arange(npages, dtype=jnp.int32)
+        [jnp.zeros((1,), i32), jnp.cumsum(pages_per).astype(i32)])
+    npages = nchunks + nblocks                   # static capacity bound
+    pageid = jnp.arange(npages, dtype=i32)
     page_chunk = jnp.clip(
-        jnp.searchsorted(page_start, pageid, side="right").astype(
-            jnp.int32) - 1, 0, nchunks - 1)
-    first = jnp.concatenate(
-        [jnp.ones((1,), jnp.int32),
-         (page_chunk[1:] != page_chunk[:-1]).astype(jnp.int32)])
-    pcount = jnp.clip(
-        counts[page_chunk] - (pageid - page_start[page_chunk]) * pg,
-        0, pg)
-    return page_chunk, first, pcount, stage_v, stage_o, stage_f, npages
+        jnp.searchsorted(page_start, pageid, side="right",
+                         method="compare_all").astype(i32) - 1,
+        0, nchunks - 1)
+    within = pageid - page_start[page_chunk]
+    used = pageid < page_start[-1]
+    page_block = jnp.where(used, b0[page_chunk] + within, nblocks - 1)
+    first = (used & (within == 0)).astype(i32)
+    return (page_chunk, page_block, first, cw0, cw1, kmin, kmax,
+            sk, sv.reshape(rows), (so & 1).reshape(rows), npages)
 
 
 @_trace.phased("apply")
 def payload_apply_bits(values, indices, flags, total: int,
-                       bits_donor=None):
+                       bits_donor=None, out_total=None, max_dup=None):
     """Fused apply epilogue: decompress scatter-add + transmit-record
     pack in ONE streamed pass over the flat [total] buffer.
 
@@ -1664,45 +1774,54 @@ def payload_apply_bits(values, indices, flags, total: int,
     ``flags`` nonzero (the engine flags the LOCAL worker's non-sentinel
     entries, reproducing :func:`pack_sent_bits` on the local indices).
 
-    The payload is pre-bucketed at payload scale (one sort + cumsum +
-    one payload-sized staging scatter): entries sort by 2048-row chunk
-    and stage into whole :data:`_APPLY_PAGE`-entry pages per chunk, so a
-    single grid pass over the pages can map each page to its chunk's
-    [_CHUNK_ROWS, 128] output block via scalar-prefetched page->chunk
-    indices. Unlike the XLA path's four separate [T]-scale streams
-    (zeros init, value scatter, bit scatter, and the next consumer's
-    re-read), the flat buffer is written exactly once, chunk by chunk,
-    while the chunk is VMEM-resident. ``bits_donor`` (the PREVIOUS
-    step's dead ``sent_bits`` buffer) is donated via
-    ``input_output_aliases`` so the record is rebuilt in place — no
-    fresh [total/32] allocation; the kernel never reads it (every block
-    zero-initializes on its first page).
+    Pair-scale work is one ``lax.sort`` and elementwise passes
+    (:func:`_sorted_pairs`): no payload-sized scatter, gather or
+    argsort. The kernel walks the buffer chunk by chunk, takes each
+    chunk's pairs straight from the sorted arrays (a BlockSpec indexed
+    by a scalar-prefetched block number) and expands them 128 at a time
+    by vector compares and one-hot matrix products
+    (:func:`_payload_apply_kernel`), so the flat buffer is written
+    exactly once, with no zero-fill before it, and the transmit record
+    is built in the same visit. ``bits_donor`` (the PREVIOUS step's dead
+    ``sent_bits`` buffer) is donated via ``input_output_aliases`` so the
+    record is rebuilt in place; the kernel never reads it.
+
+    ``out_total`` (>= ``total``, lane-aligned) sizes ``acc`` for a
+    caller that places more behind the [total] it asked for (the
+    engine's dense tail): ``acc[total:]`` is NOT defined — the caller
+    overwrites it in place, which costs no buffer-sized copy where a
+    ``concatenate`` behind a custom call's output would. ``max_dup``
+    (static) bounds how often one coordinate may occur with a non-zero
+    value; None makes no assumption.
 
     Numerics: bitwise :func:`payload_apply_bits_reference` for unique
-    real indices (any scatter order agrees); with cross-worker duplicate
-    coordinates the add order is sorted-index (stable) rather than XLA's
-    unspecified scatter order — equal to f32 rounding. f32 values only
-    (the engine gates). Returns ``(acc [total], bits
+    real indices; cross-worker duplicates sum left to right in payload
+    order (the order of a sequential scatter-add; XLA:TPU's own order is
+    unspecified, so there the two agree to f32 rounding). A non-finite
+    value poisons its sub-block's lane (0 * inf in the products), not
+    its coordinate alone. f32 values and int32 indices only (the engine
+    gates). Returns ``(acc [out_total or total], bits
     [num_sent_words(total)])``."""
-    return _payload_apply_call(_payload_apply_kernel, "payload_apply_bits",
-                               values, indices, flags, total, bits_donor)
+    return _payload_apply_call("payload_apply_bits", values, indices, flags,
+                               total, bits_donor, None, out_total, max_dup)
 
 
-def _payload_apply_call(kernel, name: str, values, indices, flags,
-                        total: int, bits_donor):
+def _payload_apply_call(name: str, values, indices, flags, total: int,
+                        bits_donor, divisor, out_total, max_dup):
     """Shared staging + launch of the apply-epilogue kernels
     (:func:`payload_apply_bits` and :func:`dgc_apply_rows` differ only
-    in the kernel body's static divisor and the ``name`` their device
+    in the static divisor of the staging and the ``name`` their device
     events carry)."""
     n = values.shape[0]
-    assert total % _LANE == 0, total
+    out_total = total if out_total is None else out_total
+    assert total % _LANE == 0 and out_total % _LANE == 0, (total, out_total)  # dgclint: ok[tracer-branch] — buffer lengths are static
+    assert total <= out_total and total + _APPLY_CHUNK < 2 ** 31, total  # dgclint: ok[tracer-branch] — buffer lengths are static
     assert indices.shape == (n,) and flags.shape == (n,)
     assert values.dtype == jnp.float32, values.dtype
-    pg = _APPLY_PAGE
     brows = num_sent_words(total) // _LANE
 
-    (page_chunk, first, pcount, stage_v, stage_o, stage_f,
-     npages) = _stage_payload(values, indices, flags, total)
+    *maps, sk, sv, sf, npages = _sorted_pairs(
+        values, indices, flags, total, divisor, max_dup)
 
     if bits_donor is None:
         bits_donor = jnp.zeros((brows, _LANE), jnp.int32)
@@ -1710,12 +1829,11 @@ def _payload_apply_call(kernel, name: str, values, indices, flags,
         assert bits_donor.shape == (brows * _LANE,), bits_donor.shape
         bits_donor = bits_donor.reshape(brows, _LANE)
 
-    # flat [npages * pg] staged operands, one 1-D page per grid step (a
-    # (1, pg) block of a 2-D array is not a legal Mosaic block shape)
-    pspec = pl.BlockSpec((pg,), lambda p, pc, fr, ct: (p,),
-                         memory_space=pltpu.SMEM)
+    pspec = pl.BlockSpec((_APPLY_WPB, _LANE),
+                         lambda p, pc, pb, *_: (pb[p], 0),
+                         memory_space=pltpu.VMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=len(maps),
         grid=(npages,),
         in_specs=[
             pspec, pspec, pspec,
@@ -1723,24 +1841,24 @@ def _payload_apply_call(kernel, name: str, values, indices, flags,
         ],
         out_specs=(
             pl.BlockSpec((_CHUNK_ROWS, _LANE),
-                         lambda p, pc, fr, ct: (pc[p], 0),
+                         lambda p, pc, *_: (pc[p], 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((_CHUNK_ROWS // 32, _LANE),
-                         lambda p, pc, fr, ct: (pc[p], 0),
+                         lambda p, pc, *_: (pc[p], 0),
                          memory_space=pltpu.VMEM),
         ),
     )
     acc, bits = pl.pallas_call(
-        kernel,
+        _payload_apply_kernel,
         grid_spec=grid_spec,
-        out_shape=(jax.ShapeDtypeStruct((total // _LANE, _LANE),
+        out_shape=(jax.ShapeDtypeStruct((out_total // _LANE, _LANE),
                                         values.dtype),
                    jax.ShapeDtypeStruct((brows, _LANE), jnp.int32)),
         # the dead previous-step record is rebuilt in place
-        input_output_aliases={6: 1},
+        input_output_aliases={len(maps) + 3: 1},
         interpret=_interpret(),
         name=name,
-    )(page_chunk, first, pcount, stage_v, stage_o, stage_f, bits_donor)
+    )(*maps, sk, sv, sf, bits_donor)
     return acc.reshape(-1), bits.reshape(-1)
 
 
@@ -1756,28 +1874,24 @@ def dgc_apply_rows_reference(values, indices, flags, total: int,
 
 @_trace.phased("apply")
 def dgc_apply_rows(values, indices, flags, total: int, bits_donor=None,
-                   divisor=None):
+                   divisor=None, out_total=None, max_dup=None):
     """Apply megakernel: unpack → decompress → scatter-apply → sent-bits
     record in ONE streamed pass — :func:`payload_apply_bits` with the
-    worker-average divide folded into the kernel body, finishing what
-    that epilogue started. The unfused path materializes the divided
-    wire (`wire / world_size`, a [W * payload] intermediate) before the
-    scatter; here each staged entry divides in SMEM-register on its way
-    into the VMEM-resident output block, so the divided wire never
-    exists in HBM.
+    worker-average divide folded into its pair-scale staging (one
+    elementwise pass with the duplicate fold, :func:`_sorted_pairs`),
+    so the divided [W * payload] wire is never a step of its own.
 
     ``divisor`` is static (None = sum semantics, no divide traced —
-    byte-identical to :func:`payload_apply_bits`). Per-entry IEEE
+    the program of :func:`payload_apply_bits`). Per-entry IEEE
     division by the same f32 operand makes the applied values bitwise
-    the unfused path's. Same staging, same double-buffered
-    scalar-prefetch streaming, same donor aliasing; returns ``(acc
-    [total], bits [num_sent_words(total)])`` bitwise
+    the unfused path's. Same staging, same kernel, same donor aliasing;
+    returns ``(acc, bits [num_sent_words(total)])`` bitwise
     :func:`dgc_apply_rows_reference` under unique real indices."""
     if divisor is not None:
         divisor = float(divisor)  # dgclint: ok[host-sync] — static by contract (the engine passes the Python world size), never a tracer
-    return _payload_apply_call(
-        functools.partial(_dgc_apply_kernel, divisor=divisor),
-        "dgc_apply_rows", values, indices, flags, total, bits_donor)
+    return _payload_apply_call("dgc_apply_rows", values, indices, flags,
+                               total, bits_donor, divisor, out_total,
+                               max_dup)
 
 
 # ------------------------------------------------------------------ #

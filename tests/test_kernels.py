@@ -643,6 +643,108 @@ def test_payload_apply_bits_matches_reference(total):
     np.testing.assert_array_equal(np.asarray(bits_s), np.asarray(bits_r))
 
 
+_CHUNK = 2048 * 128
+
+
+def _apply_pairs(case, W, rng):
+    """[W, n] indices (unique within a worker), values, the sentinel and
+    the buffer length of one case of the parity matrix."""
+    total = 3 * _CHUNK + (2048 if case == "ragged" else 0)
+    n, sentinel = 700, total - 1
+    if case == "duplicates":
+        # half of every worker's coordinates are worker 0's
+        shared = rng.choice(total - 1, n, replace=False)
+        idx = np.stack([np.where(
+            rng.rand(n) < 0.5, shared,
+            rng.choice(total - 1, n, replace=False)) for _ in range(W)])
+        idx[0] = shared
+        idx = np.stack([np.unique(r, return_index=True)[0][:600]
+                        for r in idx])
+    elif case == "empty_chunks":
+        # everything lands in the LAST chunk: the others take no pair
+        idx = (2 * _CHUNK + rng.choice(_CHUNK - 1, W * n, replace=False)
+               ).reshape(W, n)
+    elif case == "overfull_chunk":
+        # the middle chunk holds more pairs than one 4096-pair block
+        n = 4800 // W + 300
+        idx = (_CHUNK + rng.choice(_CHUNK, W * n, replace=False)
+               ).reshape(W, n)
+    else:
+        idx = rng.choice(total - 1, W * n, replace=False).reshape(W, n)
+    vals = rng.randn(*idx.shape).astype(np.float32)
+    if case == "sentinel":
+        pad = rng.rand(*idx.shape) < 0.3
+        idx, vals = np.where(pad, sentinel, idx), np.where(pad, 0, vals)
+    return idx.astype(np.int32), vals.astype(np.float32), sentinel, total
+
+
+@pytest.mark.parametrize("W", [1, 4])
+@pytest.mark.parametrize("case", ["unique", "duplicates", "empty_chunks",
+                                  "overfull_chunk", "sentinel", "ragged"])
+def test_apply_pass_parity(case, W):
+    """The one-pass apply (sorted pairs -> one-hot products, values and
+    transmit bits in one visit) against the XLA scatter pair, over the
+    worker count and the shapes a payload takes. Values: bitwise for
+    unique coordinates; duplicates are folded left to right in payload
+    order, which is what ``np.add.at`` does (bitwise) and what the
+    reference scatter gives to f32 rounding. Bits: bitwise
+    ``pack_sent_bits`` of the local worker's indices, always. The
+    donated record is garbage and is never read."""
+    from dgc_tpu.ops import kernels
+
+    rng = np.random.RandomState(31 + W)
+    idx, vals, sentinel, total = _apply_pairs(case, W, rng)
+    assert total % 4096 == (2048 if case == "ragged" else 0)
+    me = W - 1
+    flags = np.zeros(idx.shape, bool)
+    flags[me] = idx[me] != sentinel
+    donor = jnp.asarray(rng.randint(
+        -2**31, 2**31 - 1, size=kernels.num_sent_words(total),
+        dtype=np.int64).astype(np.int32))
+    acc_k, bits_k = jax.jit(
+        lambda v, i, f, d: kernels.payload_apply_bits(
+            v, i, f, total, bits_donor=d))(
+        jnp.asarray(vals.reshape(-1)), jnp.asarray(idx.reshape(-1)),
+        jnp.asarray(flags.reshape(-1)), donor)
+    acc_r, _ = kernels.payload_apply_bits_reference(
+        jnp.asarray(vals.reshape(-1)), jnp.asarray(idx.reshape(-1)),
+        jnp.asarray(flags.reshape(-1)), total)
+    bits_r = kernels.pack_sent_bits(jnp.asarray(idx[me]), total,
+                                    sentinel=sentinel)
+    np.testing.assert_array_equal(np.asarray(bits_k), np.asarray(bits_r))
+    if case == "duplicates":
+        assert len(np.unique(idx)) < idx.size or W == 1
+        np.testing.assert_allclose(np.asarray(acc_k), np.asarray(acc_r),
+                                   rtol=1e-6, atol=1e-7)
+        acc_r = np.zeros(total, np.float32)
+        np.add.at(acc_r, idx.reshape(-1), vals.reshape(-1))
+    np.testing.assert_array_equal(np.asarray(acc_k), np.asarray(acc_r))
+    if case == "empty_chunks":
+        assert not np.asarray(acc_k[:2 * _CHUNK]).any()
+        assert not np.asarray(bits_k[:2 * _CHUNK // 32]).any()
+
+
+def test_apply_pass_places_a_tail_in_a_longer_buffer():
+    """``out_total`` sizes the accumulator for a caller that writes its
+    own tail behind [total]: the first ``total`` entries are the
+    reference's whatever the length."""
+    from dgc_tpu.ops import kernels
+
+    rng = np.random.RandomState(5)
+    total, out_total = _CHUNK + 6144, _CHUNK + 6144 + 2048
+    idx = jnp.asarray(rng.choice(total, 900, replace=False).astype(np.int32))
+    vals = jnp.asarray(rng.randn(900).astype(np.float32))
+    flags = jnp.asarray(rng.rand(900) < 0.5)
+    acc_k, bits_k = kernels.payload_apply_bits(vals, idx, flags, total,
+                                               out_total=out_total)
+    acc_r, bits_r = kernels.payload_apply_bits_reference(vals, idx, flags,
+                                                         total)
+    assert acc_k.shape == (out_total,)
+    np.testing.assert_array_equal(np.asarray(acc_k[:total]),
+                                  np.asarray(acc_r))
+    np.testing.assert_array_equal(np.asarray(bits_k), np.asarray(bits_r))
+
+
 def test_payload_apply_bits_duplicates_and_empty_chunks():
     """Cross-worker duplicate coordinates: the staged adds run in
     stable sorted order (payload order within a coordinate), summing the

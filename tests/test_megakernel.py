@@ -162,6 +162,28 @@ def test_apply_kernel_no_divisor_matches_fused_epilogue():
     np.testing.assert_array_equal(np.asarray(b1), np.asarray(b2))
 
 
+@pytest.mark.parametrize("W", [1, 4])
+@pytest.mark.parametrize("name, T, payload, streams", [
+    ("resnet50", 27_068_416, 25_583, False),
+    ("vgg16_bn", 139_028_480, 138_360, True),
+])
+def test_apply_geometry_rule_on_the_benchmark_geometries(name, T, payload,
+                                                         streams, W):
+    """The rule `_apply`'s docstring states, on the static numbers of
+    the two benchmark configurations (PERF.md §4) and both world sizes,
+    without building a model: ResNet-50 keeps the zeros-scatter (its
+    [T] accumulator stays on chip), VGG-16-BN streams."""
+    from dgc_tpu.compression.flat import FlatDGCEngine
+    assert FlatDGCEngine._apply_streams(T, W * payload) is streams, name
+    # the boundary is the accumulator's bytes, nothing else
+    edge = FlatDGCEngine.APPLY_STREAM_MIN_BYTES // 4
+    assert not FlatDGCEngine._apply_streams(edge, W * payload)
+    assert FlatDGCEngine._apply_streams(edge + 128, W * payload)
+    # and the pairs' window maps have to fit the scalar memory
+    assert not FlatDGCEngine._apply_streams(
+        edge + 128, FlatDGCEngine.APPLY_STREAM_MAX_PAIRS + 1)
+
+
 @pytest.mark.parametrize("k", [257, 1024])
 def test_select_pack_rows_no_delegation_past_128(k):
     """The VGG-16 fc regime (k in (128, 1024]) must run the multi-round

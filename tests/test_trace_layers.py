@@ -230,6 +230,25 @@ def test_collective_bytes_are_the_traced_operands_bytes(rec, mesh8, kind,
         assert sum(c["value"] for c in counts) == layout.total * item
 
 
+@pytest.mark.parametrize("flag, path", [(False, "scatter"),
+                                        (True, "stream")])
+def test_apply_path_is_counted_once_a_trace(rec, mesh8, flag, path):
+    """``exchange.apply``: one count a trace, under ``step.trace``
+    beside the collectives, its value the gathered pairs and ``path``
+    what `_apply` chose (off the chip: the XLA scatters unless a flag
+    forces the kernel)."""
+    layout, engine = _engine("dgc")
+    engine.c.fused_apply = flag
+    _trace_exchange(layout, engine, mesh8)
+    count, = _named(rec.records(), "exchange.apply", "count")
+    trace, = _named(rec.records(), "step.trace")
+    assert count["parent"] == trace["id"]
+    assert count["value"] == W * engine.payload_size
+    assert count["args"] == {"path": path}
+    assert (engine._use_fused_apply(engine._mem, False, jnp.float32)
+            is flag)
+
+
 def test_dgc_wire_is_some_hundred_times_smaller_at_ratio_0001(rec, mesh8):
     """PERF.md §1's "~1000x": 0.001 of the coordinates, each sent as a
     value AND an index, so ~500x on a model whose 1-D tail is small."""
@@ -323,7 +342,7 @@ def test_every_pallas_call_site_passes_a_unique_name():
     assert len(names) == 13 and None not in names, names
     # the one shared site takes its name from its two callers
     assert names.count("name") == 1
-    shared = [call.args[1].value
+    shared = [call.args[0].value
               for call in _kernel_calls("_payload_apply_call")]
     assert sorted(shared) == ["dgc_apply_rows", "payload_apply_bits"]
     every = [n for n in names if n != "name"] + shared
