@@ -30,6 +30,11 @@ BENCHMARK_JSON = os.path.join(ROOT, "BENCHMARK.json")
 ARMS = ("dgc", "dense")
 INPUTS = ("pipeline", "resident")
 LOOPS = ("dispatch", "scan")
+#: whether a cell's arms are on the chip together, interleaved round by
+#: round, or one after the other, each gone before the next is built; a
+#: traffic file states ``one`` only where ``rehearse.memory_law`` shows
+#: that ``both`` does not fit (``rehearse.py aot`` refuses it otherwise)
+RESIDENCIES = ("both", "one")
 #: what a configuration's data is, and the sizes its file states for it
 #: (held to the built model's ``configs.dataset`` in ``build.py``)
 DATA_KINDS = {"images": ("image_size", "num_classes"),
@@ -122,7 +127,8 @@ def load_benchmark(path: str = BENCHMARK_JSON) -> Dict[str, Any]:
 
 _TRAFFIC_KEYS = {"per_chip_batch", "arms", "input", "round_steps",
                  "trace_steps", "loop", "k", "modules", "dgc_modules",
-                 "compress_ratio", "pool_batches", "why", *TOKEN_KEYS}
+                 "compress_ratio", "pool_batches", "residency", "why",
+                 *TOKEN_KEYS}
 
 
 def load_traffic(name: str, traffic_dir: Optional[str] = None
@@ -149,6 +155,8 @@ def load_traffic(name: str, traffic_dir: Optional[str] = None
         "compress_ratio": raw.get("compress_ratio"),
         "pool_batches": _want(raw, "pool_batches", int, where, default=16,
                               required=False),
+        "residency": _want(raw, "residency", str, where, default="both",
+                           required=False),
         **{key: _number_or_null(raw, key, where) for key in TOKEN_KEYS},
     }
     for key in ("per_chip_batch", "round_steps", "trace_steps",
@@ -163,6 +171,9 @@ def load_traffic(name: str, traffic_dir: Optional[str] = None
         raise CellError(f"{where}: 'input' must be one of {list(INPUTS)}")
     if t["loop"] not in LOOPS:
         raise CellError(f"{where}: 'loop' must be one of {list(LOOPS)}")
+    if t["residency"] not in RESIDENCIES:
+        raise CellError(f"{where}: 'residency' must be one of "
+                        f"{list(RESIDENCIES)}, got {t['residency']!r}")
     if t["loop"] == "scan":
         if t["k"] is None or t["k"] < 1:
             raise CellError(f"{where}: loop 'scan' needs 'k' >= 1")

@@ -44,10 +44,25 @@ from benchmark import cells
 FIXTURE = os.path.join(ROOT, "benchmark", "tests", "fixtures", "rehearsal")
 HBM_BYTES = 16e9
 #: the host beside one chip and beside the 2x2, and what a process of the
-#: harness held there before any state was made (14.2e9 at a 461M-parameter
-#: token model, one v5e, PR 27; PERF.md section 7.6)
+#: harness holds there when the reference's follow begins (17.7e9 at a
+#: 504M-parameter token model on one v5e, PR 32; 14.2e9 of it is the TPU
+#: runtime's from the moment the client exists: two 4 GiB windows of the
+#: chip, a 4 GiB staging buffer, its code; PERF.md section 7.6)
 HOST_BYTES = {1: 40 * 2 ** 30, 4: 140 * 2 ** 30}
-HOST_BASELINE_BYTES = 14.2e9
+HOST_BASELINE_BYTES = 17.7e9
+
+
+def host_follow_bytes(chips: int) -> int:
+    """What the follow holds of the host for each byte of the parameters:
+    two snapshots' momentum and velocity as each worker's engine gives
+    them, the reference's gradient, the parameters on their way to the
+    device, dgc_sgd's buffer (read on one chip: 13.7e9 at 2.02e9, PR 32;
+    the workers' share is arithmetic, not read)."""
+    return 4 * chips + 3
+
+
+#: free under the chip machine's temporary directory (my probe, PR 32)
+TMP_BYTES = 80e9
 
 
 def fixture_cell(name):
@@ -71,24 +86,36 @@ def _run_tiny(name, trace):
     assert m["check"]["ok"], m["check"]
     assert m["model_check"]["ok"], m["model_check"]
     assert m["step0_ok"]
-    assert len(m["rows"]) % 2 == 0 and set(m["rows"][0]) == {"dgc", "dense"}
+    one = cell.traffic["residency"] == "one"
+    order = [sorted(row) for row in m["rows"]]
+    if one:
+        # each arm has its own rounds, all of dgc's before the first of
+        # dense's
+        n_dgc = order.count(["dgc"])
+        assert 0 < n_dgc < len(order)
+        assert order == [["dgc"]] * n_dgc + [["dense"]] * (len(order) - n_dgc)
+    else:
+        assert len(order) % 2 == 0 and order == [["dense", "dgc"]] * len(order)
     assert set(run.end_to_end_values(m, paired)) >= {
         "setup_s", "step_ms", "dense_step_ms", "dgc_overhead_ms"}
     out = {"cell": name, "trace": trace, "rounds": len(m["rows"]),
            "steps": m["attempted"], "check": m["check"],
            "model_check": m["model_check"], "compiles": m["compiles"]}
     if trace:
-        events = m["traced"]["events"]
+        # a profiler session for the arms that share the chip
+        assert len(m["traced"]) == (2 if one else 1)
+        events = [ev for session in m["traced"] for ev in session["events"]]
         names = [n for n, _, _ in trace_reduce.host_annotations(events)]
         for arm in ("dgc", "dense"):
             assert f"{arm}:segment" in names, names
             assert f"{arm}:dispatch" in names, names
-        try:
-            trace_reduce.split_arms(events, m["traced"]["steps"])
-        except trace_reduce.TraceError as e:
-            out["no_device_ops"] = str(e)[:60]     # the CPU has no device lane
-        else:
-            raise AssertionError("a CPU trace yielded device ops")
+        for session in m["traced"]:
+            try:
+                trace_reduce.split_arms(session["events"], session["steps"])
+            except trace_reduce.TraceError as e:
+                out["no_device_ops"] = str(e)[:60]  # the CPU has no lane
+            else:
+                raise AssertionError("a CPU trace yielded device ops")
         values = run.per_layer_values(
             cell, {"paired": paired, "tables": {}, "arms": {},
                    "engine": None, "peaks": {}}, m["window_spans"])
@@ -100,52 +127,127 @@ def _run_tiny(name, trace):
 
 def rehearse_cpu():
     for name in ("tiny.steady", "tiny.resident", "tiny.scan",
-                 "tiny_lm.resident", "tiny_lm.scan"):
+                 "tiny_lm.resident", "tiny_lm.scan", "tiny_lm.one"):
         print(json.dumps(_run_tiny(name, trace=False)), flush=True)
     print(json.dumps(_run_tiny("tiny.steady", trace=True)), flush=True)
 
 
 def rehearse_mesh():
-    for name in ("tiny.steady.x4", "tiny_lm.resident.x4"):
+    for name in ("tiny.steady.x4", "tiny_lm.resident.x4", "tiny_lm.one.x4"):
         print(json.dumps(_run_tiny(name, trace=False)), flush=True)
 
 
 def memory_law(row):
     """What a cell needs, from the compiler's numbers in ``row`` (bytes a
-    chip). Of one chip: the arms' states together and the larger step's
-    temporaries (both states are resident, the steps run one at a time);
-    the exchange check runs on a device the arms have left, so it is held
-    to the chip on its own. Of the host, where the configuration has a
-    reference of its model: ``follower_copies`` copies of a chip's states
-    (``model_check.Follower``), which stay until the reference has
-    followed, on top of the process's baseline; the other chips' shares of
-    what is sharded and the follow's own float64 working copies come on
-    top and are not counted, so this refuses what cannot fit and admits
-    nothing for certain. Returns (chip bytes, host
-    bytes, the sentence that says so)."""
+    chip), and whether it may run. Of one chip, by the ``residency`` its
+    traffic file states: ``both``, the arms' states together and the
+    larger step's temporaries (both states are resident, the steps run
+    one at a time); ``one``, the larger arm's state and temporaries (the
+    arms come one after the other). The key is no choice: ``one`` is
+    refused where ``both`` fits. The exchange check runs on a device the
+    arms have left, so it is held to the chip on its own. Where the
+    configuration has a reference of its model, what the followers write
+    (``model_check.Follower.kept_bytes``) is held to the temporary
+    directory's disk, and the reference's follow, which reads it back a
+    piece at a time, to the host's memory: ``host_follow_bytes`` for each
+    byte of the parameters on top of the process's baseline. Returns the
+    row's ``needs_bytes``, ``host_bytes``, ``disk_bytes``, ``fits`` and
+    ``law``, the sentence that says so."""
     arms = [n for n in cells.ARMS if n in row]
     states = [row[n]["argument_bytes"] for n in arms]
-    temp = max(row[n]["temp_bytes"] for n in arms)
-    needs = sum(states) + temp
+    temps = [row[n]["temp_bytes"] for n in arms]
+    both = sum(states) + max(temps)
+    one = max(s + t for s, t in zip(states, temps))
+    residency = row.get("residency", "both")
+    needs = both if residency == "both" else one
     check = row.get("check_bytes", 0)
-    copies = row.get("follower_copies", 0)
-    host = int(HOST_BASELINE_BYTES) + copies * sum(states)
-    return needs, host, (
-        f"{row['cell']}: needs {needs} B a chip (states "
-        f"{' + '.join(map(str, states))} together + the larger step's "
-        f"temporaries {temp}) and the exchange check {check} B on its own, "
-        f"of {int(HBM_BYTES)}; at least {host} B of the host ({copies} "
-        f"copies of the states for the model reference + "
-        f"{int(HOST_BASELINE_BYTES)}), of {HOST_BYTES[row['chips']]}")
+    disk = sum(row[n].get("follower_bytes", 0) for n in arms)
+    host = int(HOST_BASELINE_BYTES + (
+        host_follow_bytes(row["chips"]) * row["param_bytes"] if disk else 0))
+    law = (
+        f"{row['cell']}: residency '{residency}' needs {needs} B a chip "
+        f"(states {' + '.join(map(str, states))}, step temporaries "
+        f"{' | '.join(map(str, temps))}: together {both}, one arm at a time "
+        f"{one}) and the exchange check {check} B on its own, of "
+        f"{int(HBM_BYTES)}; the followers write {disk} B under the "
+        f"temporary directory, of {int(TMP_BYTES)}, and the host holds at "
+        f"least {host} B ({int(HOST_BASELINE_BYTES)} + the reference's "
+        f"follow), of {HOST_BYTES[row['chips']]}")
+    fits = bool(needs < HBM_BYTES and check < HBM_BYTES
+                and disk < TMP_BYTES and host < HOST_BYTES[row["chips"]])
+    if residency == "one" and both < HBM_BYTES:
+        fits = False
+        law += ("; 'one' is stated where both arms fit the chip together: "
+                "the key is for a cell the law forces it on")
+    return {"needs_bytes": needs, "host_bytes": host, "disk_bytes": disk,
+            "fits": fits, "law": law}
 
 
-def rehearse_aot(only=()):
+def aot_row(cell, topo):
+    """Both arms of ``cell`` and its exchange check's programs compiled
+    for the described chips of ``topo``: the compiler's bytes, what the
+    followers would write, and ``memory_law``'s verdict."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import topologies
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from benchmark import build, check, inputs, run
+    from benchmark import build, check, inputs, model_check, run
+
+    mesh = build.make_mesh(cell, devices=topo.devices)
+    gb = cell.chips * cell.traffic["per_chip_batch"]
+    batch = NamedSharding(mesh, P(tuple(mesh.axis_names)))
+    row = {"cell": cell.name, "chips": cell.chips,
+           "residency": cell.traffic["residency"]}
+    for name in cell.traffic["arms"]:
+        arm = build.build_arm(cell, name, mesh)
+        key = jax.ShapeDtypeStruct((2,), jnp.uint32,
+                                   sharding=NamedSharding(mesh, P()))
+        arm.init.lower(key).compile()
+        state = jax.eval_shape(arm.init, jax.random.PRNGKey(0))
+        examples, labels = inputs.example_shapes(arm.dataset, gb)
+        with build.matmul_precision(cell):
+            compiled = arm.step.lower(
+                state,
+                jax.ShapeDtypeStruct(*examples, sharding=batch),
+                jax.ShapeDtypeStruct(*labels, sharding=batch),
+                key).compile()
+        program = check.check_program(arm) if name == "dgc" else None
+        if program is not None:
+            stages = check.stage_bytes(program)
+            row["check_stage_bytes"] = stages
+            row["check_bytes"] = max(stages.values())
+            row["check_bytes_per_T"] = (row["check_bytes"]
+                                        / arm.setup.engine.T)
+        mem = compiled.memory_analysis()
+        hlo = compiled.as_text()
+        row["param_bytes"] = (cell.config["sizes"]["num_parameters"]
+                              * state.params.dtype.itemsize)
+        row[name] = {
+            "argument_bytes": mem.argument_size_in_bytes,
+            "temp_bytes": mem.temp_size_in_bytes,
+            # a snapshot before the first dispatch and after each
+            # followed one
+            "follower_bytes": model_check.Follower(
+                cell, arm, snapshots=2 + run.SOLO_WARMUP_STEPS
+            ).kept_bytes(state),
+            # donated state: the outputs alias the arguments
+            "program_bytes_per_chip": check.program_bytes(compiled),
+            "mosaic_calls": hlo.count("tpu_custom_call"),
+            "collectives": sorted({
+                op for op in ("all-reduce", "all-gather", "all-to-all",
+                              "collective-permute", "reduce-scatter")
+                if f" {op}(" in hlo or f" {op}-start(" in hlo}),
+        }
+    row.update(memory_law(row))
+    return row
+
+
+def describe_v5e():
+    """The described 2x2 of v5e chips, with the process set up to compile
+    for it: no device is attached."""
+    import jax
+    from jax.experimental import topologies
+
     from dgc_tpu.ops import kernels
 
     # the engine asks the default backend (the CPU, here) which route to
@@ -154,57 +256,17 @@ def rehearse_aot(only=()):
     # a compile for a described chip is written to the cache but cannot be
     # read back without one
     jax.config.update("jax_enable_compilation_cache", False)
-    topo = topologies.get_topology_desc(platform="tpu",
+    return topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
+
+
+def rehearse_aot(only=()):
+    topo = describe_v5e()
     bench = cells.load_benchmark()
     for w in bench["workloads"]:
         if only and w["name"] not in only:
             continue
-        cell = cells.load_cell(w["name"], bench=bench)
-        mesh = build.make_mesh(cell, devices=topo.devices)
-        gb = cell.chips * cell.traffic["per_chip_batch"]
-        batch = NamedSharding(mesh, P(tuple(mesh.axis_names)))
-        # a snapshot before the first dispatch and after each followed one
-        row = {"cell": cell.name, "chips": cell.chips,
-               "follower_copies": (2 + run.SOLO_WARMUP_STEPS
-                                   if cell.config["reference"] else 0)}
-        for name in cell.traffic["arms"]:
-            arm = build.build_arm(cell, name, mesh)
-            key = jax.ShapeDtypeStruct((2,), jnp.uint32,
-                                       sharding=NamedSharding(mesh, P()))
-            arm.init.lower(key).compile()
-            state = jax.eval_shape(arm.init, jax.random.PRNGKey(0))
-            examples, labels = inputs.example_shapes(arm.dataset, gb)
-            with build.matmul_precision(cell):
-                compiled = arm.step.lower(
-                    state,
-                    jax.ShapeDtypeStruct(*examples, sharding=batch),
-                    jax.ShapeDtypeStruct(*labels, sharding=batch),
-                    key).compile()
-            program = check.check_program(arm) if name == "dgc" else None
-            if program is not None:
-                stages = check.stage_bytes(program)
-                row["check_stage_bytes"] = stages
-                row["check_bytes"] = max(stages.values())
-                row["check_bytes_per_T"] = (row["check_bytes"]
-                                            / arm.setup.engine.T)
-            mem = compiled.memory_analysis()
-            hlo = compiled.as_text()
-            row[name] = {
-                "argument_bytes": mem.argument_size_in_bytes,
-                "temp_bytes": mem.temp_size_in_bytes,
-                # donated state: the outputs alias the arguments
-                "program_bytes_per_chip": check.program_bytes(compiled),
-                "mosaic_calls": hlo.count("tpu_custom_call"),
-                "collectives": sorted({
-                    op for op in ("all-reduce", "all-gather", "all-to-all",
-                                  "collective-permute", "reduce-scatter")
-                    if f" {op}(" in hlo or f" {op}-start(" in hlo}),
-            }
-        row["needs_bytes"], row["host_bytes"], row["law"] = memory_law(row)
-        row["fits"] = bool(row["needs_bytes"] < HBM_BYTES
-                           and row.get("check_bytes", 0) < HBM_BYTES
-                           and row["host_bytes"] < HOST_BYTES[cell.chips])
+        row = aot_row(cells.load_cell(w["name"], bench=bench), topo)
         print(json.dumps(row), flush=True)
         if not row["fits"]:
             raise SystemExit("refused: " + row["law"])

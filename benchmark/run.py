@@ -9,8 +9,13 @@ exactly the programs the window uses, and measures for ``--seconds``
 seconds in rounds: ``round_steps`` donated per-dispatch
 steps of one arm, dispatched back to back and ended by one
 ``block_until_ready``, then the same for the other arm, the order of the
-arms alternating from round to round. Nothing may compile inside the
-window; a run in which something does exits non-zero. Once the window has
+arms alternating from round to round. Where the traffic file states
+``residency: one`` (a cell whose arms do not fit the chip together), the
+arms come one after the other, dgc first: each is built, driven through its
+first steps, warmed up and measured for its half of the window, and leaves
+the chip before the next is built. Nothing may compile inside the
+window, or inside either half; a run in which something does exits
+non-zero. Once the window has
 closed, the peak has been read and the arms' states are freed, the two
 checks run on the device the arms have left: the exchange engine against
 its plain reference (``benchmark/check.py``), and the configuration's plain
@@ -178,11 +183,15 @@ def _measure(cell, seed, seconds, trace, devices):
     split = {}
     mark = time.perf_counter()
 
-    def lap(name):
+    def lap(name=None):
+        """The seconds since the last lap go to ``name`` of the set-up's
+        split (to nothing without a name: a window's own); returns now."""
         nonlocal mark
         now = time.perf_counter()
-        split[name] = split.get(name, 0.0) + now - mark
+        if name is not None:
+            split[name] = split.get(name, 0.0) + now - mark
         mark = now
+        return now
 
     counter = CompileCounter()
     traffic = cell.traffic
@@ -195,100 +204,125 @@ def _measure(cell, seed, seconds, trace, devices):
     cell_devices = list(mesh.devices.flat)
     spans = Spans()
     scan = traffic["loop"] == "scan"
+    names = traffic["arms"]
+    # the arms that are on the chip together and share a window, round by
+    # round: all of them, or (``residency: one``) one after the other,
+    # each with its share of the window and gone before the next is built
+    groups = ([names] if traffic["residency"] == "both"
+              else [[name] for name in names])
+    dispatches = traffic["round_steps"]
     runs, feed, first_batch = {}, None, None
     first_loss, dgc_peak, engine = {}, None, None
     check = {"ok": True, "skipped": "no dgc arm in this traffic"}
     followers = {}
+    rows, steps_per_round, losses, window_spans, traced = [], None, {}, {}, []
+    setup_s = window_s = 0.0
+    since = _T0               # where the set-up now running began
     lap("backend_and_mesh")
 
     try:
-        for name in traffic["arms"]:
-            arm = build.build_arm(cell, name, mesh)
-            lap("build_" + name)
-            if feed is None:
-                gb = arm.world * traffic["per_chip_batch"]
-                if traffic["input"] == "pipeline":
-                    feed = inputs.pipeline_feed(
-                        seed, gb, traffic["pool_batches"], arm.dataset, mesh)
-                else:
-                    n = traffic["pool_batches" if scan else "round_steps"]
-                    resident = inputs.resident_batches(
-                        seed, gb, n, arm.dataset, traffic, mesh)
-                    feed = (inputs.scan_feed(resident, mesh) if scan
-                            else inputs.resident_feed(resident))
-                first_batch = next(feed)
-                jax.block_until_ready(first_batch)
-                lap("data")
-            run = runs[name] = ArmRun(arm, build.init_state(arm, seed), seed)
-            jax.block_until_ready(run.state)
-            lap("init_" + name)
-            # where the configuration has a reference of its model: host
-            # copies of the state round each of these dispatches
-            follow = followers[name] = model_check.Follower(cell, arm)
-            follow.snapshot(run)
-            # the first call compiles (or loads) the one program this arm
-            # uses; same weights, same batch, same key for every arm
-            loss = run.dispatch(*first_batch)
-            first_loss[name] = float(np.ravel(jax.device_get(loss))[0])
-            follow.snapshot(run)
-            lap("first_step_" + name)
-            for _ in range(SOLO_WARMUP_STEPS):
-                run.dispatch(*first_batch)
+        for group in groups:
+            for name in group:
+                arm = build.build_arm(cell, name, mesh)
+                lap("build_" + name)
+                if feed is None:
+                    gb = arm.world * traffic["per_chip_batch"]
+                    if traffic["input"] == "pipeline":
+                        feed = inputs.pipeline_feed(
+                            seed, gb, traffic["pool_batches"], arm.dataset,
+                            mesh)
+                    else:
+                        n = traffic["pool_batches" if scan
+                                    else "round_steps"]
+                        resident = inputs.resident_batches(
+                            seed, gb, n, arm.dataset, traffic, mesh)
+                        feed = (inputs.scan_feed(resident, mesh) if scan
+                                else inputs.resident_feed(resident))
+                    first_batch = next(feed)
+                    jax.block_until_ready(first_batch)
+                    lap("data")
+                run = runs[name] = ArmRun(arm, build.init_state(arm, seed),
+                                          seed)
+                jax.block_until_ready(run.state)
+                lap("init_" + name)
+                # where the configuration has a reference of its model:
+                # what its follow reads of the state round each of these
+                # dispatches, in files
+                follow = followers[name] = model_check.Follower(
+                    cell, arm, snapshots=2 + SOLO_WARMUP_STEPS)
                 follow.snapshot(run)
-            jax.block_until_ready(run.state)
+                # the first call compiles (or loads) the one program this
+                # arm uses; same weights, same batch, same key for every arm
+                loss = run.dispatch(*first_batch)
+                first_loss[name] = float(np.ravel(jax.device_get(loss))[0])
+                follow.snapshot(run)
+                lap("first_step_" + name)
+                for _ in range(SOLO_WARMUP_STEPS):
+                    run.dispatch(*first_batch)
+                    follow.snapshot(run)
+                jax.block_until_ready(run.state)
+                lap("warmup")
+                if name == "dgc":
+                    # the DGC job's own peak: before another arm's state
+                    # exists on the device
+                    dgc_peak = hbm_peak_bytes(cell_devices)
+                    engine = engine_info(arm)
+            # one whole interleaved round, discarded (bench.py: the first
+            # round after compile runs slow); it also fills the pipeline
+            for name in group:
+                run_round(runs[name], feed, spans, dispatches)
             lap("warmup")
-            if name == "dgc":
-                # the DGC job's own peak: before another arm's state
-                # exists on the device
-                dgc_peak = hbm_peak_bytes(cell_devices)
-                engine = engine_info(arm)
-        names = list(runs)
-        dispatches = traffic["round_steps"]
-        # one whole interleaved round, discarded (bench.py: the first
-        # round after compile runs slow); it also fills the pipeline
-        for name in names:
-            run_round(runs[name], feed, spans, dispatches)
-        lap("warmup")
 
-        # ---- the window ------------------------------------------------ #
-        for run in runs.values():
-            run.losses.clear()
-        span_mark = spans.mark()
-        before = counter.snapshot()
-        rows, steps_per_round = [], None
-        gc.collect()
-        gc.disable()
-        t_start = time.perf_counter()
-        setup_s = t_start - _T0
-        while True:
-            row = {}
-            for name in rounds.arm_order(names, len(rows)):
-                row[name], steps_per_round = run_round(
-                    runs[name], feed, spans, dispatches)
-            rows.append(row)
-            if (time.perf_counter() - t_start >= seconds
-                    and len(rows) % len(names) == 0):
-                break
-        window_s = time.perf_counter() - t_start
-        gc.enable()
-        after = counter.snapshot()
-        if after != before:
-            raise SystemExit(
-                f"benchmark: something compiled inside the measured window "
-                f"({before} -> {after}); the run is void")
+            # ---- the window, or this group's share of it ---------------- #
+            for name in group:
+                runs[name].losses.clear()
+            span_mark = spans.mark()
+            before = counter.snapshot()
+            share = seconds * len(group) / len(names)
+            first_row = len(rows)
+            gc.collect()
+            gc.disable()
+            t_start = time.perf_counter()
+            setup_s += t_start - since
+            while True:
+                row = {}
+                for name in rounds.arm_order(group, len(rows) - first_row):
+                    row[name], steps_per_round = run_round(
+                        runs[name], feed, spans, dispatches)
+                rows.append(row)
+                if (time.perf_counter() - t_start >= share
+                        and (len(rows) - first_row) % len(group) == 0):
+                    break
+            window_s += time.perf_counter() - t_start
+            gc.enable()
+            after = counter.snapshot()
+            if after != before:
+                raise SystemExit(
+                    f"benchmark: something compiled inside the measured "
+                    f"window ({before} -> {after}); the run is void")
+            for name in group:
+                losses[name] = np.concatenate([
+                    np.ravel(x) for x in jax.device_get(runs[name].losses)])
+            for name, by_span in spans.seconds(span_mark).items():
+                for key, secs in by_span.items():
+                    window_spans.setdefault(name, {}).setdefault(
+                        key, []).extend(secs)
+            if trace:
+                traced.append(_profile(
+                    cell, {name: runs[name] for name in group}, feed, spans,
+                    steps_per_round // dispatches))
+            since = lap()
+            if group is not groups[-1]:
+                # the chip is the next group's: nothing of this one stays
+                # but its programs and the cell's resident batches
+                for name in group:
+                    runs[name].state = None
+                    runs[name].losses.clear()
+                gc.collect()
 
-        losses = {name: np.concatenate([np.ravel(x) for x in
-                                        jax.device_get(run.losses)])
-                  for name, run in runs.items()}
         attempted = int(sum(len(v) for v in losses.values()))
         failed = int(sum(int(np.sum(~np.isfinite(v)))
                          for v in losses.values()))
-        window_spans = spans.seconds(span_mark)
-
-        traced = None
-        if trace:
-            traced = _profile(cell, runs, feed, spans, steps_per_round
-                              // dispatches)
 
         # the program's peak, then its state freed, then the two checks
         # on the device the arms have left: neither is in the peak or in
@@ -306,13 +340,18 @@ def _measure(cell, seed, seconds, trace, devices):
         step0_ok = bool(step0_gap <= STEP0_LOSS_RTOL)
         log("check", exchange=check, step0_loss=first_loss,
             step0_loss_rtol=STEP0_LOSS_RTOL, step0_ok=step0_ok)
+        t0 = time.perf_counter()
         model = model_check.compare(cell, followers, first_batch)
+        model["check_s"] = time.perf_counter() - t0
         log("model_check", **model)
     finally:
         if feed is not None:
             feed.close()
+        for follow in followers.values():
+            follow.close()
 
     return {
+        "residency": traffic["residency"],
         "memory_peak_bytes": memory_peak,
         "setup_s": setup_s, "split": split, "compiles": counter.snapshot(),
         "rows": rows, "steps_per_round": steps_per_round,
@@ -367,17 +406,28 @@ def _profile(cell, runs, feed, spans, steps_per_dispatch):
 # ---------------------------------------------------------------------- #
 
 def paired_summary(m):
-    """Medians and quartiles of the window, per arm and paired."""
+    """Medians and quartiles of the window, per arm and paired. Arms that
+    ran one after the other (``residency: one``) have no round in common:
+    their difference is that of their medians, and has no quartiles."""
     rows, steps = m["rows"], m["steps_per_round"]
+    rounds_of = {}                            # each arm's own rounds
+    for row in rows:
+        for name in row:
+            rounds_of.setdefault(name, []).append(row)
     out = {"rounds": len(rows), "steps_per_round": steps,
            "window_s": m["window_s"], "arms": {}}
-    for name in rows[0]:
-        q = rounds.quartiles(rounds.per_step_ms(rows, name, steps))
+    for name, own in rounds_of.items():
+        q = rounds.quartiles(rounds.per_step_ms(own, name, steps))
         out["arms"][name] = {"q1": q[0], "median": q[1], "q3": q[2]}
-    if "dgc" in rows[0] and "dense" in rows[0]:
-        q = rounds.quartiles(rounds.paired_diff_ms(rows, "dgc", "dense",
-                                                   steps))
-        out["dgc_minus_dense_ms"] = {"q1": q[0], "median": q[1], "q3": q[2]}
+    if "dgc" in rounds_of and "dense" in rounds_of:
+        if m["residency"] == "both":
+            q = rounds.quartiles(rounds.paired_diff_ms(rows, "dgc", "dense",
+                                                       steps))
+            out["dgc_minus_dense_ms"] = {"q1": q[0], "median": q[1],
+                                         "q3": q[2]}
+        else:
+            out["dgc_minus_dense_ms"] = {"median": rounds.median_diff_ms(
+                rounds_of["dgc"], "dgc", rounds_of["dense"], "dense", steps)}
     return out
 
 
@@ -398,8 +448,11 @@ def trace_view(m, paired, device_kind):
     """What the per-layer readers get as ``trace``."""
     from benchmark import trace_reduce
 
-    traced = m["traced"]
-    arms = trace_reduce.split_arms(traced["events"], traced["steps"])
+    # a profiler session for the arms that were on the chip together
+    arms = {}
+    for session in m["traced"]:
+        arms.update(trace_reduce.split_arms(session["events"],
+                                            session["steps"]))
     return {
         "arms": arms,
         "tables": {name: trace_reduce.phase_table(a)

@@ -34,6 +34,8 @@ def test_every_traffic_file_loads():
     for name in sorted(os.listdir(folder)):
         t = cells.load_traffic(name[:-len(".json")])
         assert t["input"] in cells.INPUTS and t["loop"] in cells.LOOPS
+        # none states ``residency``: every cell's arms share the chip
+        assert t["residency"] == "both"
 
 
 def test_unknown_workload_names_the_known_ones():
@@ -66,6 +68,9 @@ GOOD_TRAFFIC = {"per_chip_batch": 4, "arms": ["dgc", "dense"],
     ({"k": 4}, "'k' belongs to loop 'scan'"),
     ({"compress_ratio": 2}, "'compress_ratio' must be null or in"),
     ({"rate": 5}, r"unknown key\(s\) \['rate'\]"),
+    ({"residency": "two"},
+     r"'residency' must be one of \['both', 'one'\], got 'two'"),
+    ({"residency": 1}, "'residency' must be str"),
     ({"zipf_s": -0.5}, "'zipf_s' must be at least 0"),
     ({"zipf_s": "one"}, "'zipf_s' must be a number"),
     ({"zipf_s": 1.0, "doc_len_median": 0, "doc_len_sigma": 1.0},

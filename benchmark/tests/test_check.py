@@ -56,16 +56,19 @@ def _sound_but_for_the_check(m):
             and not m["check"]["ok"] and not run.is_correct(m))
 
 
-def test_error_feedback_kept_in_bfloat16_is_not_correct(tmp_path):
+@pytest.mark.parametrize("residency", cells.RESIDENCIES)
+def test_error_feedback_kept_in_bfloat16_is_not_correct(tmp_path, residency):
     """The control: the nearest precision below the float32 the engine's
     state is stated in, through the program's own path to it
     (``configs/dgc/bf16mem.py``) composed by the traffic file, the step a
     later PR might take. Every residual coordinate then differs bitwise
-    from the reference's float32 value."""
+    from the reference's float32 value. With the arms on the chip
+    together or one after the other."""
     with open(os.path.join(rehearse.FIXTURE, "traffic",
                            "tiny.resident.json")) as fh:
         traffic = json.load(fh)
-    traffic["dgc_modules"] = ["configs/dgc/bf16mem.py"]
+    traffic.update(dgc_modules=["configs/dgc/bf16mem.py"],
+                   residency=residency)
     (tmp_path / "bf16mem.json").write_text(json.dumps(traffic))
     bench = cells.load_benchmark(
         os.path.join(rehearse.FIXTURE, "BENCHMARK.json"))

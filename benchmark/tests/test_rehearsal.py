@@ -12,11 +12,13 @@ import pytest
 from benchmark import cells, rehearse, run
 
 TRAFFIC = os.path.join(rehearse.FIXTURE, "traffic")
+RESIDENCIES = pytest.mark.parametrize("residency", cells.RESIDENCIES)
 
 
 @pytest.mark.parametrize("cell", ["tiny.steady", "tiny.resident", "tiny.scan",
                                   "tiny.steady.x4", "tiny_lm.resident",
-                                  "tiny_lm.scan", "tiny_lm.resident.x4"])
+                                  "tiny_lm.scan", "tiny_lm.resident.x4",
+                                  "tiny_lm.one", "tiny_lm.one.x4"])
 def test_measure_runs_the_cell(cell):
     out = rehearse._run_tiny(cell, trace=False)
     assert out["rounds"] >= 2 and out["steps"] > 0
@@ -58,22 +60,153 @@ def test_the_memory_law_refuses_with_its_numbers():
     both states and the larger step's temporaries, the check on its own,
     and a refusal that states every one of them."""
     row = {"cell": "wide", "chips": 1, "check_bytes": 8884226048,
+           "param_bytes": 1845493760,
            "dgc": {"argument_bytes": 7439714304, "temp_bytes": 4390782976},
            "dense": {"argument_bytes": 3691021312, "temp_bytes": 6359577600}}
-    needs, host, said = rehearse.memory_law(row)
-    assert needs == 7439714304 + 3691021312 + 6359577600 > rehearse.HBM_BYTES
-    # no reference of the model, nothing copied: the process's baseline
-    assert host == int(rehearse.HOST_BASELINE_BYTES)
-    for number in (needs, 7439714304, 3691021312, 6359577600, 8884226048):
-        assert str(number) in said
-    # with a reference the followers' four copies of both states bind the
-    # host long before the chip: 58.7 GB against 40 GiB
-    needs, host, said = rehearse.memory_law({**row, "follower_copies": 4})
-    assert host == int(rehearse.HOST_BASELINE_BYTES) + 4 * (
-        7439714304 + 3691021312) > rehearse.HOST_BYTES[1]
-    assert str(host) in said and str(rehearse.HOST_BYTES[1]) in said
+    law = rehearse.memory_law(row)
+    assert law["needs_bytes"] == 7439714304 + 3691021312 + 6359577600
+    assert law["needs_bytes"] > rehearse.HBM_BYTES and not law["fits"]
+    # no reference of the model, nothing written: the process's baseline
+    assert law["host_bytes"] == int(rehearse.HOST_BASELINE_BYTES)
+    assert law["disk_bytes"] == 0
+    for number in (law["needs_bytes"], 7439714304, 3691021312, 6359577600,
+                   4390782976, 8884226048):
+        assert str(number) in law["law"]
     del row["dense"]           # a cell of the dgc arm alone
-    assert rehearse.memory_law(row)[0] == 7439714304 + 4390782976
+    law = rehearse.memory_law(row)
+    assert law["needs_bytes"] == 7439714304 + 4390782976 and law["fits"]
+
+
+def test_the_memory_law_reads_the_residency():
+    """The same numbers under ``one``: the larger of (state +
+    temporaries) over the arms, which fits; and a cell that states ``one``
+    where its arms fit together is refused, with the law's numbers."""
+    row = {"cell": "wide", "chips": 1, "check_bytes": 8884226048,
+           "param_bytes": 1845493760, "residency": "one",
+           "dgc": {"argument_bytes": 7439714304, "temp_bytes": 4390782976},
+           "dense": {"argument_bytes": 3691021312, "temp_bytes": 6359577600}}
+    law = rehearse.memory_law(row)
+    assert law["needs_bytes"] == 7439714304 + 4390782976 and law["fits"]
+    assert "residency 'one'" in law["law"]
+    assert str(7439714304 + 3691021312 + 6359577600) in law["law"]
+    # absent, the key reads ``both``
+    assert rehearse.memory_law({**row, "residency": "both"}) \
+        == rehearse.memory_law({k: v for k, v in row.items()
+                                if k != "residency"})
+    small = {**row, "dgc": {"argument_bytes": 2264258048,
+                            "temp_bytes": 4226406912},
+             "dense": {"argument_bytes": 1128998912,
+                       "temp_bytes": 4412540928}}          # vgg16_bn.steady
+    assert rehearse.memory_law({**small, "residency": "both"})["fits"]
+    law = rehearse.memory_law(small)
+    assert not law["fits"] and "where both arms fit" in law["law"]
+    assert str(2264258048 + 1128998912 + 4412540928) in law["law"]
+    # and one that fits neither way
+    big = {**row, "dgc": {"argument_bytes": 12 * 10 ** 9,
+                          "temp_bytes": 5 * 10 ** 9}}
+    assert not rehearse.memory_law(big)["fits"]
+
+
+#: ``wide_lm`` (503,971,840 parameters, a row of 2048 tokens) compiled for
+#: a described v5e: ``rehearse.aot_row``'s numbers (PR 32)
+WIDE_LM = {"cell": "wide_lm", "chips": 1, "check_bytes": 9944003072,
+           "param_bytes": 2015887360,
+           "dgc": {"argument_bytes": 8126597120, "temp_bytes": 4131106816,
+                   "follower_bytes": 24442734592},
+           "dense": {"argument_bytes": 4031792128, "temp_bytes": 5103667200,
+                     "follower_bytes": 6047662080}}
+
+
+def test_the_wide_fixture_is_forced_to_one_arm_at_a_time():
+    """The fixture that re-reads the law on the chip: with both arms
+    resident it is refused, with the numbers; one at a time it fits the
+    chip, its followers' files the disk and its follow the host."""
+    both = rehearse.memory_law({**WIDE_LM, "residency": "both"})
+    assert both["needs_bytes"] == 17262056448 > rehearse.HBM_BYTES
+    assert not both["fits"] and "17262056448" in both["law"]
+    one = rehearse.memory_law({**WIDE_LM, "residency": "one"})
+    assert one["needs_bytes"] == 8126597120 + 4131106816 and one["fits"]
+    assert one["disk_bytes"] == 24442734592 + 6047662080 < rehearse.TMP_BYTES
+    assert one["host_bytes"] == int(
+        rehearse.HOST_BASELINE_BYTES
+        + 7 * 2015887360) < rehearse.HOST_BYTES[1]
+    # read on the chip: 31.4e9 at the peak of the follow (PR 32)
+    assert 31.4e9 < one["host_bytes"] < 32e9
+    for number in (one["disk_bytes"], one["host_bytes"],
+                   rehearse.HOST_BYTES[1], int(rehearse.TMP_BYTES)):
+        assert str(number) in one["law"]
+    # files that do not fit the disk refuse the cell
+    full = {**WIDE_LM, "residency": "one",
+            "dgc": {**WIDE_LM["dgc"],
+                    "follower_bytes": int(rehearse.TMP_BYTES)}}
+    assert not rehearse.memory_law(full)["fits"]
+    # and the files are what the fixture's followers would write
+    import jax
+    from benchmark import build, model_check
+    for residency in cells.RESIDENCIES:
+        cell = rehearse.fixture_cell("wide_lm." + residency)
+        assert cell.traffic["residency"] == residency
+        mesh = build.make_mesh(cell, jax.devices("cpu"))
+        for name in ("dgc", "dense"):
+            arm = build.build_arm(cell, name, mesh)
+            state = jax.eval_shape(arm.init, jax.random.PRNGKey(0))
+            assert model_check.Follower(cell, arm, 4).kept_bytes(state) \
+                == WIDE_LM[name]["follower_bytes"]
+        assert cell.config["sizes"]["num_parameters"] * 4 \
+            == WIDE_LM["param_bytes"]
+
+
+def test_a_follower_keeps_what_its_follow_reads(tmp_path, monkeypatch):
+    """Which parts of which snapshot, by arm and loop; their bytes; and
+    that the host holds paths, not arrays, until the files are removed."""
+    import jax
+    import numpy as np
+    from benchmark import build, model_check
+    monkeypatch.setattr(model_check.tempfile, "tempdir", str(tmp_path))
+    cell = rehearse.fixture_cell("tiny_lm.resident")
+    scan = rehearse.fixture_cell("tiny_lm.scan")
+    mesh = build.make_mesh(cell, jax.devices("cpu"))
+    reads = {}
+    for name in ("dgc", "dense"):
+        arm = build.build_arm(cell, name, mesh)
+        f = model_check.Follower(cell, arm, snapshots=4)
+        reads[name] = [f.reads(i) for i in range(4)]
+        reads[name + ".scan"] = [
+            model_check.Follower(scan, arm, snapshots=4).reads(i)
+            for i in range(4)]
+        state = build.init_state(arm, 0)
+        shapes = jax.eval_shape(arm.init, jax.random.PRNGKey(0))
+        run_ = run.ArmRun(arm, state, 0)
+        for _ in range(4):
+            f.snapshot(run_)
+        files = sorted(os.listdir(tmp_path / os.listdir(tmp_path)[0]))
+        assert sum(os.path.getsize(os.path.join(f._dir, x))
+                   for x in files) == f.kept_bytes(shapes)
+        assert all(isinstance(v, model_check._File) for snap in f.snaps
+                   for k, v in snap.items() if k != "losses")
+        np.testing.assert_array_equal(
+            f.snaps[0]["params"].whole(), np.asarray(state.params))
+        np.testing.assert_array_equal(
+            f.snaps[0]["params"].read(7, 19), np.asarray(state.params)[7:19])
+        if name == "dgc":
+            np.testing.assert_array_equal(
+                f.snaps[0]["memory.sent_bits"].row(0),
+                np.asarray(state.memory["sent_bits"])[0])
+        f.close()
+        assert os.listdir(tmp_path) == []
+    every = ("params", "memory")
+    assert reads["dgc"] == [every] * 4
+    assert reads["dgc.scan"] == [("params",)] * 3 + [()]
+    assert reads["dense"] == [("params",), ("momentum",), (), ("params",)]
+    assert reads["dense.scan"] == [("params",), (), (), ("params",)]
+    # a configuration without a reference keeps nothing
+    tiny = rehearse.fixture_cell("tiny.resident")
+    arm = build.build_arm(tiny, "dgc", build.make_mesh(
+        tiny, jax.devices("cpu")))
+    f = model_check.Follower(tiny, arm, snapshots=4)
+    assert f.kept_bytes(jax.eval_shape(arm.init, jax.random.PRNGKey(0))) == 0
+    f.snapshot(None)
+    assert f.snaps == [] and os.listdir(tmp_path) == []
 
 
 # ---------------------------------------------------------------------- #
@@ -99,7 +232,7 @@ def loss_and_grads(params, inputs, labels):
 
 def _new_token_cell(tmp_path, reference_scale=None, traffic_modules=(),
                     overrides=None, dgc_module=None,
-                    reference_text=SCALED_REFERENCE):
+                    reference_text=SCALED_REFERENCE, residency="both"):
     """What a later PR adds, all of it in ``tmp_path``: a configuration
     file, its reference, a traffic file, and their entries."""
     with open(os.path.join(rehearse.FIXTURE, "configs", "tiny_lm.json")) as fh:
@@ -119,7 +252,7 @@ def _new_token_cell(tmp_path, reference_scale=None, traffic_modules=(),
     with open(os.path.join(TRAFFIC, "tiny_lm.resident.json")) as fh:
         traffic = json.load(fh)
     traffic.update(zipf_s=1.1, doc_len_median=5, doc_len_sigma=1.0,
-                   modules=list(traffic_modules))
+                   modules=list(traffic_modules), residency=residency)
     (tmp_path / "skewed.json").write_text(json.dumps(traffic))
     bench = copy.deepcopy(cells.load_benchmark(
         os.path.join(rehearse.FIXTURE, "BENCHMARK.json")))
@@ -164,6 +297,61 @@ def test_a_new_token_cell_runs_and_is_correct(tmp_path):
     assert run.is_correct(m)
 
 
+def test_arms_resident_one_at_a_time_keep_the_metrics_names(tmp_path):
+    """``residency: one``: all of the dgc arm's rounds, then all of the
+    dense arm's, each arm for half the window; the end-to-end metrics keep
+    their names, the overhead being the difference of the arms' medians;
+    the verdict rests on the same numbers as under ``both``."""
+    m = _measure(_new_token_cell(tmp_path, residency="one"))
+    assert run.is_correct(m) and m["residency"] == "one"
+    arms = [next(iter(row)) for row in m["rows"]]
+    assert all(len(row) == 1 for row in m["rows"])
+    n_dgc = arms.count("dgc")
+    assert arms == ["dgc"] * n_dgc + ["dense"] * (len(arms) - n_dgc)
+    assert n_dgc >= 1 and len(arms) - n_dgc >= 1
+    # 0.2 s of window: each arm ran for its half, and no longer than a
+    # round more
+    per_arm = {a: sum(row[a] for row in m["rows"] if a in row)
+               for a in ("dgc", "dense")}
+    assert all(0.09 <= v < 0.2 for v in per_arm.values()), per_arm
+    assert m["window_s"] == pytest.approx(sum(per_arm.values()), rel=0.05)
+    paired = run.paired_summary(m)
+    values = run.end_to_end_values(m, paired)
+    assert set(values) == {"setup_s", "step_ms", "dense_step_ms",
+                           "dgc_overhead_ms"}
+    assert values["dgc_overhead_ms"] == pytest.approx(
+        values["step_ms"] - values["dense_step_ms"])
+    assert set(paired["dgc_minus_dense_ms"]) == {"median"}
+    assert m["attempted"] == len(arms) * m["steps_per_round"]
+    both = _measure(_new_token_cell(tmp_path))
+    assert set(run.compared(m)) == set(run.compared(both))
+    assert m["model_check"]["arms"] == both["model_check"]["arms"]
+    assert {k: v for k, v in m["check"].items() if k != "check_s"} == {
+        k: v for k, v in both["check"].items() if k != "check_s"}
+    assert set(run.paired_summary(both)["dgc_minus_dense_ms"]) == {
+        "q1", "median", "q3"}
+
+
+@pytest.mark.parametrize("arm", ["dgc", "dense"])
+def test_a_compile_in_either_half_of_the_window_voids_the_run(
+        tmp_path, monkeypatch, arm):
+    """Nothing compiles in the window: held per half where the arms come
+    one after the other. Each arm's first round is its discarded warm-up;
+    a program built in its second is built inside its half."""
+    import jax
+    real, calls = run.run_round, {"dgc": 0, "dense": 0}
+
+    def run_round(arm_run, *args):
+        calls[arm_run.name] += 1
+        if arm_run.name == arm and calls[arm] == 2:
+            jax.jit(lambda x: x * 3 + len(arm))(1.0)
+        return real(arm_run, *args)
+
+    monkeypatch.setattr(run, "run_round", run_round)
+    with pytest.raises(SystemExit, match="compiled inside the measured"):
+        _measure(_new_token_cell(tmp_path, residency="one"))
+
+
 _LIMITS = cells.load_reference(os.path.relpath(os.path.join(
     rehearse.FIXTURE, "references", "tiny_lm.py"), cells.ROOT))
 LOSS_RTOL, GRAD_RTOL = _LIMITS.LOSS_RTOL, _LIMITS.GRAD_RTOL
@@ -180,9 +368,12 @@ NESTEROV_MEMORY = ("from dgc_tpu.utils.config import configs\n"
     ({"train.optimizer.weight_decay": 0.01}, None),
 ], ids=["decay-nesterov", "decay"])
 def test_the_reference_follows_the_optimizer_as_configured(
-        tmp_path, overrides, dgc_module):
+        tmp_path, monkeypatch, overrides, dgc_module):
     """The plain SGD beside the reference reads the recipe the arm was
-    built with: weight decay, nesterov; DGC's memory too."""
+    built with: weight decay, nesterov; DGC's memory too. With every
+    tensor cut into several pieces, as a large model's are."""
+    from benchmark import model_check
+    monkeypatch.setattr(model_check, "PIECE", 1000)
     m = _measure(_new_token_cell(tmp_path, overrides=overrides,
                                  dgc_module=dgc_module))
     assert run.is_correct(m), m["model_check"]
@@ -211,8 +402,11 @@ def _maxima(model):
             for key, value in numbers.items() if isinstance(value, dict)}
 
 
-def test_a_reference_with_one_gradient_scaled_is_not_correct(tmp_path):
-    m = _measure(_new_token_cell(tmp_path, reference_scale=1.01))
+@RESIDENCIES
+def test_a_reference_with_one_gradient_scaled_is_not_correct(
+        tmp_path, residency):
+    m = _measure(_new_token_cell(tmp_path, reference_scale=1.01,
+                                 residency=residency))
     model = m["model_check"]
     assert _others_sound(m) and not model["ok"] and not run.is_correct(m)
     for arm, key in (("dense", "grad_rel_err"), ("dgc", "conserved_rel_err")):
@@ -224,10 +418,12 @@ def test_a_reference_with_one_gradient_scaled_is_not_correct(tmp_path):
     assert model["arms"]["dense"]["loss_rel_err"]["max"] <= LOSS_RTOL
 
 
-def test_a_bfloat16_model_against_a_float32_file_is_not_correct(tmp_path):
+@RESIDENCIES
+def test_a_bfloat16_model_against_a_float32_file_is_not_correct(
+        tmp_path, residency):
     """The control: the configuration file says float32, and the traffic
     composes ``configs/bf16.py`` after it, the step a later PR might take."""
-    m = _measure(_new_token_cell(tmp_path,
+    m = _measure(_new_token_cell(tmp_path, residency=residency,
                                  traffic_modules=["configs/bf16.py"]))
     model = m["model_check"]
     assert _others_sound(m) and not model["ok"] and not run.is_correct(m)
@@ -249,8 +445,9 @@ def _break_the_step(monkeypatch, broken_step):
     monkeypatch.setattr(build, "build_arm", build_arm)
 
 
+@RESIDENCIES
 def test_a_step_that_returns_its_state_unchanged_is_not_correct(
-        tmp_path, monkeypatch):
+        tmp_path, monkeypatch, residency):
     import jax
     import jax.numpy as jnp
 
@@ -261,7 +458,7 @@ def test_a_step_that_returns_its_state_unchanged_is_not_correct(
         return broken
 
     _break_the_step(monkeypatch, unchanged)
-    m = _measure(_new_token_cell(tmp_path))
+    m = _measure(_new_token_cell(tmp_path, residency=residency))
     model = m["model_check"]
     # every loss is finite, the exchange engine is sound, the arms start
     # from the same loss: only the model check sees it
@@ -274,8 +471,9 @@ def test_a_step_that_returns_its_state_unchanged_is_not_correct(
     assert number == got["dgc", "conserved_rel_err"] > limit
 
 
+@RESIDENCIES
 def test_a_step_that_trains_on_half_the_batch_is_not_correct(
-        tmp_path, monkeypatch):
+        tmp_path, monkeypatch, residency):
     import jax.numpy as jnp
 
     def half(step):
@@ -286,7 +484,7 @@ def test_a_step_that_trains_on_half_the_batch_is_not_correct(
         return broken
 
     _break_the_step(monkeypatch, half)
-    m = _measure(_new_token_cell(tmp_path))
+    m = _measure(_new_token_cell(tmp_path, residency=residency))
     model = m["model_check"]
     # both arms are broken alike, so their first losses still agree
     assert _others_sound(m) and not model["ok"] and not run.is_correct(m)
@@ -346,10 +544,21 @@ def test_a_nan_in_one_tensor_is_the_worst_and_not_correct():
     from benchmark import model_check
     ref = {"a": [3.0, 4.0], "b": [1.0, 0.0], "c": [0.0, 2.0]}
     prog = {"a": [3.0, 4.0], "b": [float("nan"), 0.0], "c": [0.0, 2.0]}
-    worst = model_check._worst(prog, ref)
+
+    def triples(prog, ref):
+        return ((n, prog[n], ref[n]) for n in ref)
+
+    worst = model_check._worst(triples(prog, ref))
     assert worst["worst_tensor"] == "b" and worst["max"] != worst["max"]
-    assert model_check._worst(ref, ref)["max"] == 0.0
+    assert model_check._worst(triples(ref, ref))["max"] == 0.0
     # an all-but-zero tensor is measured against the median tensor's norm
-    tiny = model_check._leafwise({**ref, "b": [1e-9, 0.0]},
-                                 {**ref, "b": [0.0, 0.0]})
+    tiny = model_check._leafwise(triples({**ref, "b": [1e-9, 0.0]},
+                                         {**ref, "b": [0.0, 0.0]}))
     assert tiny["rel_err"]["b"] == pytest.approx(1e-9 / 2.0)
+    # a tensor given in pieces reads as it does whole
+    whole = model_check._leafwise([("a", [3.0, 4.5, 1.0], [3.0, 4.0, 2.0]),
+                                   ("b", [1.0], [7.0])])
+    cut = model_check._leafwise([(("a", 0, 2), [3.0, 4.5], [3.0, 4.0]),
+                                 (("a", 2, 3), [1.0], [2.0]),
+                                 (("b", 0, 1), [1.0], [7.0])])
+    assert cut == whole

@@ -70,3 +70,20 @@ def test_one_disturbed_round_does_not_move_the_median():
     rows.append({"dgc": 5.0, "dense": 0.50})
     assert paired_median(rows, steps) \
         == pytest.approx(2.0)
+
+
+def test_arms_that_ran_one_after_the_other_differ_by_their_medians():
+    """``residency: one``: each arm's rounds are its own, so there is no
+    within-round difference to take; the arms may have run a different
+    number of rounds, and a disturbed round moves neither median."""
+    steps = 4
+    dgc = [{"dgc": 27e-3 * steps} for _ in range(7)] + [{"dgc": 1.0}]
+    dense = [{"dense": 25e-3 * steps} for _ in range(5)]
+    assert rounds.median_diff_ms(dgc, "dgc", dense, "dense", steps) \
+        == pytest.approx(2.0)
+    # on interleaved rounds it is the difference of the arms' medians,
+    # which drifts with the machine where the paired median does not
+    rows = [{"dense": 0.25 * (1 + 0.3 * r / 19),
+             "dgc": 0.25 * (1 + 0.3 * r / 19) + 0.02} for r in range(20)]
+    assert rounds.median_diff_ms(rows, "dgc", rows, "dense", 10) \
+        == pytest.approx(2.0)

@@ -204,11 +204,18 @@ def test_async_collective_counts_from_start_to_done():
 def test_result_assembly_from_a_traced_measurement():
     """run.py's path from loaded events to the last line's parts."""
     from benchmark import run
-    m = {"traced": {"events": small_trace(),
-                    "steps": {"dgc": 1, "dense": 1}}, "engine": None}
+    # a profiler session for the arms that shared the chip: here one
+    m = {"traced": [{"events": small_trace(),
+                     "steps": {"dgc": 1, "dense": 1}}], "engine": None}
     view = run.trace_view(m, {"dgc_minus_dense_ms": {"median": 0.5}},
                           "TPU v5 lite")
     assert view["engine"] is None and set(view["arms"]) == {"dgc", "dense"}
+    # and one each where the arms came one after the other
+    apart = run.trace_view(
+        {"traced": [{"events": small_trace(), "steps": {arm: 1}}
+                    for arm in ("dgc", "dense")], "engine": None},
+        {}, "TPU v5 lite")
+    assert apart["tables"] == view["tables"]
     busy, window = run.device_busy(view)
     assert busy == pytest.approx(950e-6 + 500e-6)
     assert window == pytest.approx(1150e-6 + 500e-6)
