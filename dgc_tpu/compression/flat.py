@@ -40,7 +40,8 @@ import dataclasses
 import functools
 import math
 import os
-from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
+from typing import (Any, Callable, Dict, List, NamedTuple, Optional, Sequence,
+                    Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -54,7 +55,8 @@ from dgc_tpu.resilience import integrity
 from dgc_tpu.telemetry import trace as _trace
 from dgc_tpu.utils.pytree import named_flatten, named_unflatten
 
-__all__ = ["ParamLayout", "LayoutMask", "FlatDGCEngine", "FlatDenseExchange"]
+__all__ = ["ParamLayout", "LayoutMask", "InPlaceUpdate", "FlatDGCEngine",
+           "FlatDenseExchange"]
 
 #: block alignment (elements) of the compressed-block boundary and the buffer
 #: tail — multiples of the Pallas tile for BOTH supported state dtypes
@@ -417,10 +419,15 @@ class LayoutMask:
                      runs=len(self.runs))
         if self.form == "vector":
             return jnp.asarray(np.asarray(self))
-        idx = jax.lax.iota(self.index_dtype, self.total)
-        mask = jnp.zeros((self.total,), bool)
+        return self.at(jax.lax.iota(self.index_dtype, self.total))
+
+    def at(self, idx) -> jax.Array:
+        """The ``runs`` form at the flat coordinates ``idx`` (any shape):
+        what the call builds over the whole buffer, for a reader that
+        holds a block of it (``optim/sgd.py::ElementwiseRule``)."""
+        mask = jnp.zeros(idx.shape, bool)
         for start, stop in self.runs:
-            inside = jnp.ones((self.total,), bool)
+            inside = jnp.ones(idx.shape, bool)
             if start > 0:
                 inside &= idx >= start
             if stop < self.total:
@@ -722,11 +729,30 @@ _GossipRound = collections.namedtuple(
 
 
 @dataclasses.dataclass
+class InPlaceUpdate:
+    """A step's offer to :meth:`FlatDGCEngine.exchange`: run the
+    elementwise update of my flat [P] ``state`` where the sparse tier's
+    gradient is made, instead of handing me a [P] gradient to read.
+    ``rule(g, idx, scalars, *blocks) -> new blocks`` and ``scalars()``
+    are those of ``kernels.payload_update_bits`` (``scalars`` is traced
+    only when the offer is taken: a step that keeps today's form
+    compiles today's program). An engine that takes the offer replaces
+    ``state`` by buffers whose compressed block [0, T) is updated and
+    whose tail [T, P) is as it was, sets ``taken``, and returns the
+    tail's gradient alone."""
+    state: Tuple[jax.Array, ...]
+    rule: Callable
+    scalars: Callable[[], Tuple[jax.Array, ...]]
+    taken: bool = False
+
+
+@dataclasses.dataclass
 class _Exchange:
     """What one ``FlatDGCEngine.exchange`` call carries from stage to stage
     (trace-time values; a stage fills what the later ones read)."""
     grad: Any                       # [P], node-reduced
     mem: Dict                       # the memory as handed in
+    update: Optional[InPlaceUpdate] = None    # the step's offer
     taps: Any = None                # telemetry.taps with telemetry on
     grad_norm: Any = None
     clip_delta: Any = None
@@ -741,6 +767,7 @@ class _Exchange:
     values: Any = None              # this worker's payload
     indices: Any = None
     acc: Any = None                 # [T] the sparse tier's contribution
+                                    # (None: it went into the offer's state)
     new_bits: Any = None            # this step's transmit record
     inbox: Any = None               # gossip: the round's neighbor mass
 
@@ -749,6 +776,9 @@ class FlatDGCEngine:
     """Fused flat-buffer execution of the DGC pipeline for one compressor +
     layout pair. Rebuilt (cheaply, host-side) whenever the warm-up schedule
     changes the compress ratio (reference compression.py:91-107)."""
+
+    #: ``exchange`` takes the step's :class:`InPlaceUpdate` offer
+    takes_update = True
 
     def __init__(self, compressor, layout: ParamLayout, plan=None):
         self.c = compressor
@@ -2241,9 +2271,18 @@ class FlatDGCEngine:
                  local_axis: Optional[str] = None, local_size: int = 1,
                  telemetry: bool = False,
                  health_out: Optional[Dict] = None,
-                 send_frac=None):
+                 send_frac=None,
+                 update: Optional[InPlaceUpdate] = None):
         """compress -> communicate -> decompress over the whole model:
         two ``all_gather`` + one ``psum`` per step, total.
+
+        ``update`` — the step's :class:`InPlaceUpdate` offer. Where the
+        apply pass streams the buffer (:meth:`_apply`) it is taken: the
+        pass writes the updated compressed block of the offer's state
+        and the transmit record, no [T] gradient exists, and the first
+        element returned is the dense tail's [P - T] gradient. Anywhere
+        else the offer is left untouched and the program is the one
+        ``update=None`` compiles.
 
         ``send_frac`` — straggler-adaptive exchange (docs/RESILIENCE.md
         §Adaptive exchange): a traced f32 scalar in [0, 1], THIS worker's
@@ -2299,7 +2338,7 @@ class FlatDGCEngine:
         # dgcver anchors (analysis/verify.py): identity `name` tags that
         # seed/sink the verifier's static taint passes. Zero HLO ops.
         st = _Exchange(grad=kernels.vtag(flat_grad, "dgcver.src.grad"),
-                       mem=mem)
+                       mem=mem, update=update)
         if telemetry:
             from dgc_tpu.telemetry import taps
             st.taps = taps
@@ -2774,6 +2813,16 @@ class FlatDGCEngine:
           saved. The bit scatter is a second per-pair pass: 1.39 ms.
           The fused [2T] acc+sent scatter, scatter-set into the live
           mmt/vec buffers and sub-word masks all lose as well.
+        * ``update``: ``stream``, taken one step further where the
+          step offers its optimizer's elementwise rule
+          (:class:`InPlaceUpdate`): the same pass reads the chunk's p
+          and buf blocks, runs the rule on the chunk's gradient while
+          it is in VMEM and writes p', buf' and the record
+          (``kernels.payload_update_bits``). The [T] gradient is never
+          written and the optimizer's five-stream fusion (4.13 ms at
+          VGG) shrinks to the dense tail: alone the pass takes 3.39 ms
+          at 138,360 pairs against 1.49 + 4.12 before, 3.74 at 553,440
+          against 2.87 + 4.15 (step 0 of PR 35, `PERF.md` §6).
         * ``stream``: one stable sort of the pairs and ONE pass over
           the buffer (``kernels.payload_apply_bits``), which expands
           128 pairs at a time into one-hot matrix products and writes
@@ -2815,15 +2864,37 @@ class FlatDGCEngine:
             g_values = g_values * gr.row_w[:, None].astype(g_values.dtype)
         wire = g_values.reshape(-1).astype(dt)
         mk_apply = self._use_megakernel_apply(m, self._int8_ef, dt)
-        stream = (mk_apply or self._use_fused_apply(m, self._int8_ef, dt)
-                  or (kernels.use_pallas()
-                      and self._apply_kernel_serves(m, self._int8_ef, dt)
-                      and self._apply_streams(T, wire.shape[0])))
+        flagged = mk_apply or self._use_fused_apply(m, self._int8_ef, dt)
+        stream = flagged or (
+            kernels.use_pallas()
+            and self._apply_kernel_serves(m, self._int8_ef, dt)
+            and self._apply_streams(T, wire.shape[0]))
+        # the step's offer is taken where the geometry rule streams (the
+        # opt-in kernels keep their own form) and nothing else writes
+        # the compressed block (a dense-planned bucket does)
+        take = (stream and not flagged and st.update is not None
+                and not self._dense_ids)
         _trace.count("exchange.apply", wire.shape[0],
-                     path="stream" if stream else "scatter")
+                     path=("update" if take else "stream" if stream
+                           else "scatter"))
         if op == "average" and not mk_apply:
             wire = wire / world_size
-        if stream:
+        if take:
+            # the streamed pass below with the offer's rule inside it
+            # (kernels.payload_update_bits): each chunk's gradient stays
+            # in VMEM, where the rule reads it beside the chunk's state
+            # blocks and writes them back in place. Four [T] streams
+            # where the pass and the optimizer's fusion moved six
+            with _trace.phase("apply"):
+                offer = st.update
+                offer.state, st.new_bits = kernels.payload_update_bits(
+                    wire, g_indices.reshape(-1),
+                    self._sent_flags(g_indices, axis_name), T,
+                    offer.state, offer.rule, offer.scalars(),
+                    bits_donor=st.mem["sent_bits"],
+                    max_dup=g_indices.shape[0])
+                offer.taken = True
+        elif stream:
             # one pass over the buffer (kernels.payload_apply_bits): a
             # stable sort of the pairs, then each VMEM-resident chunk
             # takes its pairs as one-hot matrix products and its
@@ -2924,13 +2995,14 @@ class FlatDGCEngine:
         the dense-path semantics: clip on the averaged gradient, pending
         transmit mask from the PREVIOUS state materialized,
         non-accumulating compensate — overriding what the accumulating
-        compensate wrote in [0, T). Returns the step's [P] result."""
+        compensate wrote in [0, T). Returns the step's [P] result, or
+        its [P - T] tail where the apply pass took the step's update."""
         T, P, m = self.T, self.layout.total, self._mem
         clip = m.gradient_clipping if m is not None else None
         acc = st.acc
         dslabs = [(i, self.buckets[i]) for i in self._dense_ids]
         if not (P > T or dslabs):
-            return acc
+            return jnp.zeros((0,), st.grad.dtype) if acc is None else acc
         with _trace.phase("dense"):
             dparts = [st.grad[b.base:b.base + b.rows * b.cols]
                       for _, b in dslabs]
@@ -2965,6 +3037,8 @@ class FlatDGCEngine:
                     gd_avg = self._clip_block(gd_avg,
                                               self.layout.dense_names, T)
                 out_d, st.md = self._compensate_dense(st.md, gd_avg)
+        if acc is None:     # taken by the step's update: the tail alone
+            return out_d.astype(st.grad.dtype)
         if P > T and acc.shape[0] == P:  # the apply kernel's [P]: in place
             return jax.lax.dynamic_update_slice(
                 acc, out_d.astype(acc.dtype), (T,))

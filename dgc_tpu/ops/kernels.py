@@ -92,6 +92,7 @@ __all__ = ["fused_compensate", "fused_compensate_reference",
            "seg_top2_candidates", "seg_top2_reference",
            "seg_top2_eligible", "opaque_view", "use_pallas",
            "payload_apply_bits", "payload_apply_bits_reference",
+           "payload_update_bits",
            "dgc_forward_rows", "dgc_forward_rows_reference",
            "dgc_apply_rows", "dgc_apply_rows_reference", "vtag"]
 
@@ -1555,19 +1556,23 @@ def _one_hot(mask):
 
 
 def _dot_nt(a, b):
-    """``a [M, K] @ b [N, K]^T`` on the MXU, f32 accumulate."""
+    """``a [M, K] @ b [N, K]^T`` on the MXU, f32 accumulate. The bf16
+    operands are exact parts and one-hot factors: one MXU pass is the
+    whole product, whatever ``jax.default_matmul_precision`` the caller
+    runs under (under ``highest`` Mosaic refuses a bf16 product that
+    states none)."""
     return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               precision=jax.lax.Precision.DEFAULT,
                                preferred_element_type=jnp.float32)
 
 
-def _payload_apply_kernel(pc_ref, pb_ref, first_ref, cw0_ref, cw1_ref,
-                          kmin_ref, kmax_ref, k_ref, v_ref, f_ref,
-                          bits_donor_ref, acc_ref, bits_ref):
+def _expand_pairs(pc_ref, pb_ref, first_ref, cw0_ref, cw1_ref, kmin_ref,
+                  kmax_ref, k_ref, v_ref, f_ref, acc_ref, bits_ref):
     """One grid step applies one block of the SORTED pairs to its
-    chunk's VMEM-resident output block. Pages of one chunk are
-    consecutive, so the block stays in VMEM between them and reaches
-    HBM once; the chunk's first page zero-fills it (every chunk owns a
-    page, so every block is defined whatever the donor held).
+    chunk's VMEM-resident block ``acc_ref`` (and ``bits_ref``). Pages of
+    one chunk are consecutive, so the block stays in VMEM between them;
+    the chunk's first page zero-fills it (every chunk owns a page, so
+    every block is defined whatever it held).
 
     No pair is touched alone. A window is 128 consecutive sorted pairs,
     one lane row; a sub-block is :data:`_APPLY_SUB` rows of the output.
@@ -1587,7 +1592,6 @@ def _payload_apply_kernel(pc_ref, pb_ref, first_ref, cw0_ref, cw1_ref,
     and the high half word kept in separate rows so that every sum
     stays under 2**16 and exact. The lane factors are built once a
     window, the row factors once a (window, sub-block)."""
-    del bits_donor_ref  # alias donor: never dereferenced
     p = pl.program_id(0)
     c, b = pc_ref[p], pb_ref[p]
     sub, wr = _APPLY_SUB, _APPLY_SUB // 32
@@ -1657,6 +1661,61 @@ def _payload_apply_kernel(pc_ref, pb_ref, first_ref, cw0_ref, cw1_ref,
     jax.lax.fori_loop(jnp.maximum(cw0_ref[c], b * _APPLY_WPB),
                       jnp.minimum(cw1_ref[c], (b + 1) * _APPLY_WPB),
                       window, 0)
+
+
+def _payload_apply_kernel(*refs):
+    """:func:`_expand_pairs` into the output block itself: the chunk
+    reaches HBM once, when its last page is done."""
+    *maps_and_pairs, bits_donor_ref, acc_ref, bits_ref = refs
+    del bits_donor_ref  # alias donor: never dereferenced
+    _expand_pairs(*maps_and_pairs, acc_ref, bits_ref)
+
+
+#: rows of one slab of the update kernel's rule: the chunk's gradient,
+#: its state blocks and the rule's temporaries at 8 vregs an array
+_UPDATE_ROWS = 64
+
+
+def _payload_update_kernel(rule, total: int, nstate: int, *refs):
+    """:func:`_expand_pairs` into a VMEM scratch block, and on the
+    chunk's LAST page ``rule`` over that block and the chunk's state
+    blocks, slab by slab: the gradient never reaches HBM, and each state
+    stream is read once and written once, in place. Coordinates at or
+    past ``total`` (the caller's tail behind the block, the last
+    chunk's ragged end) keep what they held."""
+    # prefetched: the seven window maps, ``last``, the caller's scalars;
+    # then the pairs, the donor, the state in and out, the bits, the
+    # scratch block
+    *maps, last_ref = refs[:8]
+    fixed = 6 + 2 * nstate
+    scalar_refs = refs[8:-fixed]
+    k_ref, v_ref, f_ref, bits_donor_ref, *state_refs, bits_ref, acc_ref = (
+        refs[-fixed:])
+    del bits_donor_ref  # alias donor: never dereferenced
+    old_refs, new_refs = state_refs[:nstate], state_refs[nstate:]
+    _expand_pairs(*maps, k_ref, v_ref, f_ref, acc_ref, bits_ref)
+    p = pl.program_id(0)
+    base = maps[0][p] * _APPLY_CHUNK
+    rows = _UPDATE_ROWS
+
+    @pl.when(last_ref[p] == 1)
+    def _update():
+        scalars = tuple(s[0] for s in scalar_refs)
+        offset = (jax.lax.broadcasted_iota(jnp.int32, (rows, _LANE), 0)
+                  * _LANE
+                  + jax.lax.broadcasted_iota(jnp.int32, (rows, _LANE), 1))
+
+        def slab(j, carry):
+            r0 = pl.multiple_of(j * rows, rows)
+            idx = base + r0 * _LANE + offset
+            old = tuple(s[pl.ds(r0, rows), :] for s in old_refs)
+            new = rule(acc_ref[pl.ds(r0, rows), :], idx, scalars, *old)
+            inside = idx < total
+            for o, n, s in zip(new_refs, new, old):
+                o[pl.ds(r0, rows), :] = jnp.where(inside, n, s)
+            return carry
+
+        jax.lax.fori_loop(0, _CHUNK_ROWS // rows, slab, 0)
 
 
 def _count_below(samples, queries):
@@ -1806,32 +1865,47 @@ def payload_apply_bits(values, indices, flags, total: int,
                                total, bits_donor, None, out_total, max_dup)
 
 
+def _apply_staging(values, indices, flags, total: int, bits_donor,
+                   divisor, max_dup):
+    """What the apply-pass kernels share before their launch: the
+    contract's asserts, :func:`_sorted_pairs`, the donated record as
+    lane rows, and the BlockSpecs of a block of sorted pairs, of a
+    chunk of the flat buffer and of its transmit words (index maps over
+    the grid step and the prefetched ``page_chunk`` / ``page_block``)."""
+    n = values.shape[0]
+    assert total % _LANE == 0 and total + _APPLY_CHUNK < 2 ** 31, total  # dgclint: ok[tracer-branch] — buffer lengths are static
+    assert indices.shape == (n,) and flags.shape == (n,)
+    assert values.dtype == jnp.float32, values.dtype
+    brows = num_sent_words(total) // _LANE
+    *maps, sk, sv, sf, npages = _sorted_pairs(
+        values, indices, flags, total, divisor, max_dup)
+    if bits_donor is None:
+        bits_donor = jnp.zeros((brows, _LANE), jnp.int32)
+    else:
+        assert bits_donor.shape == (brows * _LANE,), bits_donor.shape
+        bits_donor = bits_donor.reshape(brows, _LANE)
+    pspec = pl.BlockSpec((_APPLY_WPB, _LANE),
+                         lambda p, pc, pb, *_: (pb[p], 0),
+                         memory_space=pltpu.VMEM)
+    cspec = pl.BlockSpec((_CHUNK_ROWS, _LANE),
+                         lambda p, pc, *_: (pc[p], 0),
+                         memory_space=pltpu.VMEM)
+    bspec = pl.BlockSpec((_CHUNK_ROWS // 32, _LANE),
+                         lambda p, pc, *_: (pc[p], 0),
+                         memory_space=pltpu.VMEM)
+    return maps, (sk, sv, sf), npages, bits_donor, (pspec, cspec, bspec)
+
+
 def _payload_apply_call(name: str, values, indices, flags, total: int,
                         bits_donor, divisor, out_total, max_dup):
     """Shared staging + launch of the apply-epilogue kernels
     (:func:`payload_apply_bits` and :func:`dgc_apply_rows` differ only
     in the static divisor of the staging and the ``name`` their device
     events carry)."""
-    n = values.shape[0]
     out_total = total if out_total is None else out_total
-    assert total % _LANE == 0 and out_total % _LANE == 0, (total, out_total)  # dgclint: ok[tracer-branch] — buffer lengths are static
-    assert total <= out_total and total + _APPLY_CHUNK < 2 ** 31, total  # dgclint: ok[tracer-branch] — buffer lengths are static
-    assert indices.shape == (n,) and flags.shape == (n,)
-    assert values.dtype == jnp.float32, values.dtype
-    brows = num_sent_words(total) // _LANE
-
-    *maps, sk, sv, sf, npages = _sorted_pairs(
-        values, indices, flags, total, divisor, max_dup)
-
-    if bits_donor is None:
-        bits_donor = jnp.zeros((brows, _LANE), jnp.int32)
-    else:
-        assert bits_donor.shape == (brows * _LANE,), bits_donor.shape
-        bits_donor = bits_donor.reshape(brows, _LANE)
-
-    pspec = pl.BlockSpec((_APPLY_WPB, _LANE),
-                         lambda p, pc, pb, *_: (pb[p], 0),
-                         memory_space=pltpu.VMEM)
+    assert total <= out_total and out_total % _LANE == 0, (total, out_total)  # dgclint: ok[tracer-branch] — buffer lengths are static
+    maps, pairs, npages, bits_donor, (pspec, cspec, bspec) = _apply_staging(
+        values, indices, flags, total, bits_donor, divisor, max_dup)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(maps),
         grid=(npages,),
@@ -1839,27 +1913,81 @@ def _payload_apply_call(name: str, values, indices, flags, total: int,
             pspec, pspec, pspec,
             pl.BlockSpec(memory_space=pl.ANY),        # bits donor
         ],
-        out_specs=(
-            pl.BlockSpec((_CHUNK_ROWS, _LANE),
-                         lambda p, pc, *_: (pc[p], 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((_CHUNK_ROWS // 32, _LANE),
-                         lambda p, pc, *_: (pc[p], 0),
-                         memory_space=pltpu.VMEM),
-        ),
+        out_specs=(cspec, bspec),
     )
     acc, bits = pl.pallas_call(
         _payload_apply_kernel,
         grid_spec=grid_spec,
         out_shape=(jax.ShapeDtypeStruct((out_total // _LANE, _LANE),
                                         values.dtype),
-                   jax.ShapeDtypeStruct((brows, _LANE), jnp.int32)),
+                   jax.ShapeDtypeStruct(bits_donor.shape, jnp.int32)),
         # the dead previous-step record is rebuilt in place
         input_output_aliases={len(maps) + 3: 1},
         interpret=_interpret(),
         name=name,
-    )(*maps, sk, sv, sf, bits_donor)
+    )(*maps, *pairs, bits_donor)
     return acc.reshape(-1), bits.reshape(-1)
+
+
+@_trace.phased("apply")
+def payload_update_bits(values, indices, flags, total: int, state, rule,
+                        scalars=(), bits_donor=None, max_dup=None):
+    """:func:`payload_apply_bits` for a caller whose next step is an
+    elementwise rule over the gradient and its own flat state: the rule
+    runs INSIDE the pass, on each chunk while it is in VMEM, so the
+    ``[total]`` gradient is never written and never read back.
+
+    ``state`` is a tuple of f32 flat buffers of one length ``>= total``
+    (lane-aligned), each an input ALIASED to the output of the same
+    position: donated, read once and written once, in place.
+    ``rule(g, idx, scalars, *blocks) -> new blocks`` is traced into the
+    kernel and sees VMEM values only: ``g`` the chunk's gradient as
+    :func:`payload_apply_bits` would have written it (same staging,
+    same one-hot products, same left-to-right duplicate fold), ``idx``
+    the int32 flat coordinates of the slab, ``scalars`` the 0-d
+    ``scalars`` (f32 or int32, prefetched with the window maps) and
+    ``blocks`` the state at those coordinates. Whatever lies at or past
+    ``total`` keeps its values: a tail the caller updates itself.
+
+    Returns ``(new_state, bits [num_sent_words(total)])``; the bits are
+    those of :func:`payload_apply_bits`."""
+    nstate = len(state)
+    size = state[0].shape[0]
+    assert total <= size and size % _LANE == 0, (total, size)  # dgclint: ok[tracer-branch] — buffer lengths are static
+    assert all(s.shape == (size,) and s.dtype == jnp.float32 for s in state)  # dgclint: ok[tracer-branch] — shapes and dtypes are static
+    maps, pairs, npages, bits_donor, (pspec, cspec, bspec) = _apply_staging(
+        values, indices, flags, total, bits_donor, None, max_dup)
+    # a chunk's rule runs on its last page: the step before another
+    # chunk opens, and the grid's last (pad pages revisit the last chunk)
+    last = jnp.concatenate([maps[2][1:], jnp.ones((1,), jnp.int32)])
+    prefetch = (*maps, last, *(jnp.reshape(s, (1,)) for s in scalars))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(npages,),
+        in_specs=[
+            pspec, pspec, pspec,
+            pl.BlockSpec(memory_space=pl.ANY),        # bits donor
+            *[cspec] * nstate,
+        ],
+        out_specs=(*[cspec] * nstate, bspec),
+        scratch_shapes=[pltpu.VMEM((_CHUNK_ROWS, _LANE), jnp.float32)],
+    )
+    *new, bits = pl.pallas_call(
+        functools.partial(_payload_update_kernel, rule, total, nstate),
+        grid_spec=grid_spec,
+        out_shape=(*[jax.ShapeDtypeStruct((size // _LANE, _LANE),
+                                          jnp.float32)] * nstate,
+                   jax.ShapeDtypeStruct(bits_donor.shape, jnp.int32)),
+        # the state moves in place; the dead previous-step record is
+        # rebuilt in place
+        input_output_aliases={
+            len(prefetch) + 3: nstate,
+            **{len(prefetch) + 4 + i: i for i in range(nstate)}},
+        interpret=_interpret(),
+        name="payload_update_bits",
+    )(*prefetch, *pairs, bits_donor,
+      *(s.reshape(size // _LANE, _LANE) for s in state))
+    return tuple(x.reshape(-1) for x in new), bits.reshape(-1)
 
 
 def dgc_apply_rows_reference(values, indices, flags, total: int,

@@ -27,6 +27,7 @@ reference's per-tensor named-handle fusion and to its stated thresholding
 overhead caveat (README.md:130-138).
 """
 
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -156,28 +157,106 @@ class DistributedOptimizer:
         (``resilience.adaptive``; None is Python-static off);
         ``grad_ready`` goes to an engine that ``takes_grad_ready`` (tensor
         name -> when its gradient is final, ``training/step.py``)."""
-        ready = {} if grad_ready is None else {"grad_ready": grad_ready}
+        exchanged, mem_state, tstats = self._exchange_flat(
+            flat_grads, mem_state, key, engine, telemetry, health_out,
+            send_frac, grad_ready)
+        updates, opt_state = self._optimize(exchanged, opt_state,
+                                            flat_params)
+        return (updates, opt_state, mem_state) + tstats
+
+    def _exchange_flat(self, flat_grads, mem_state, key, engine,
+                       telemetry: bool, health_out, send_frac, grad_ready,
+                       update=None):
+        """The engine's exchange as the flat step calls it: ``(exchanged,
+        memory, (tstats,) or ())``."""
+        more = {} if grad_ready is None else {"grad_ready": grad_ready}
+        if update is not None:
+            more["update"] = update
+        if telemetry:
+            more["telemetry"] = True
         # parts of the step's ``update`` phase: the engine's own phases
         # nest inside ``exchange``, and what they leave is its glue
         with _trace.phase("update", part="exchange"):
-            if telemetry:
-                exchanged, mem_state, tstats = engine.exchange(
-                    flat_grads, mem_state, key, self.axis_name,
-                    self.num_nodes, local_axis=self.local_axis_name,
-                    local_size=self.local_size, telemetry=True,
-                    health_out=health_out, send_frac=send_frac, **ready)
-            else:
-                exchanged, mem_state = engine.exchange(
-                    flat_grads, mem_state, key, self.axis_name,
-                    self.num_nodes, local_axis=self.local_axis_name,
-                    local_size=self.local_size, health_out=health_out,
-                    send_frac=send_frac, **ready)
+            exchanged, mem_state, *tstats = engine.exchange(
+                flat_grads, mem_state, key, self.axis_name,
+                self.num_nodes, local_axis=self.local_axis_name,
+                local_size=self.local_size, health_out=health_out,
+                send_frac=send_frac, **more)
+        return exchanged, mem_state, tuple(tstats)
+
+    def _optimize(self, exchanged, opt_state, params):
         with _trace.phase("update", part="optimizer"):
-            updates, opt_state = self.optimizer.update(exchanged, opt_state,
-                                                       flat_params)
-        if telemetry:
-            return updates, opt_state, mem_state, tstats
-        return updates, opt_state, mem_state
+            return self.optimizer.update(exchanged, opt_state, params)
+
+    def step_flat(self, flat_grads, opt_state, flat_params, mem_state,
+                  key, engine, telemetry: bool = False,
+                  health_out: Optional[Dict] = None, send_frac=None,
+                  grad_ready: Optional[Dict[str, int]] = None,
+                  in_place: bool = True):
+        """:meth:`update_flat` and the parameter add: ``(new_params,
+        opt_state, mem_state[, tstats])``.
+
+        Where the optimizer offers its elementwise rule (``optim/sgd.py::
+        ElementwiseRule``) and the engine's apply pass streams the
+        buffer, the two are ONE pass: the engine updates the compressed
+        block [0, T) of the parameters and the momentum buffer where it
+        makes their gradient (``flat.InPlaceUpdate``), and what is left
+        here is the rule over the dense tail [T, P), written into the
+        same buffers in place. ``in_place=False`` (a step that still
+        needs the buffers it came with: no donation, the guards' atomic
+        skip) makes no offer; an offer that is not taken leaves the
+        program :meth:`update_flat` and an add compile."""
+        rule = getattr(self.optimizer, "rule", None)
+        offer = None
+        if (rule is not None and in_place and not self.per_worker_opt_state
+                and getattr(engine, "takes_update", False)):
+            from dgc_tpu.compression.flat import InPlaceUpdate
+            offer = InPlaceUpdate(
+                rule.blocks(opt_state, flat_params), rule.step,
+                functools.cache(lambda: rule.scalars(opt_state)))
+        if offer is None:
+            # through the overridable entry (Adasum's own update_flat)
+            more = {} if grad_ready is None else {"grad_ready": grad_ready}
+            updates, opt_state, mem_state, *tstats = self.update_flat(
+                flat_grads, opt_state, flat_params, mem_state, key, engine,
+                telemetry=telemetry, health_out=health_out,
+                send_frac=send_frac, **more)
+        else:
+            exchanged, mem_state, tstats = self._exchange_flat(
+                flat_grads, mem_state, key, engine, telemetry, health_out,
+                send_frac, grad_ready, update=offer)
+            if offer.taken:  # dgclint: ok[tracer-branch] — a Python bool the engine sets while it is traced
+                new_params, opt_state = self._update_tail(
+                    rule, offer, exchanged, opt_state, engine)
+                return (new_params, opt_state, mem_state, *tstats)
+            updates, opt_state = self._optimize(exchanged, opt_state,
+                                                flat_params)
+        return (self._add(flat_params, updates), opt_state, mem_state,
+                *tstats)
+
+    @staticmethod
+    def _update_tail(rule, offer, tail_grad, opt_state, engine):
+        """After an offer was taken: the same rule over the dense tail
+        [T, P), read from and written into the buffers the pass left (the
+        tail keeps the XLA optimizer), and the optimizer's next state."""
+        T = engine.T
+        blocks = offer.state
+        with _trace.phase("update", part="optimizer"):
+            if tail_grad.shape[0]:
+                idx = T + jax.lax.iota(engine.layout.index_dtype,
+                                       tail_grad.shape[0])
+                tail = rule.step(tail_grad, idx, offer.scalars(),
+                                 *(b[T:] for b in blocks))
+                blocks = tuple(jax.lax.dynamic_update_slice(b, t, (T,))
+                               for b, t in zip(blocks, tail))
+            return blocks[0], rule.advance(opt_state, blocks)
+
+    @staticmethod
+    def _add(params, updates):
+        # the add is the root of the optimizer's fusion, and a fusion
+        # carries its root's scope: without it the part reads nothing
+        with _trace.phase("update", part="optimizer"):
+            return params + updates
 
     # ------------------------------------------------------------------ #
 

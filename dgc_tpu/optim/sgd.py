@@ -41,7 +41,7 @@ import jax
 import jax.numpy as jnp
 import optax
 
-__all__ = ["dgc_sgd", "sgd", "SGDState"]
+__all__ = ["dgc_sgd", "sgd", "SGDState", "ElementwiseRule"]
 
 ScalarOrSchedule = Union[float, Callable[[jax.Array], jax.Array]]
 
@@ -53,6 +53,65 @@ class SGDState(NamedTuple):
 
 def _lr_at(lr: ScalarOrSchedule, count):
     return lr(count) if callable(lr) else lr
+
+
+def _advance(state, new_buf):
+    return SGDState(count=state.count + 1, momentum_buffer=new_buf)
+
+
+class ElementwiseRule(NamedTuple):
+    """What ``dgc_sgd`` and ``sgd`` offer (``.rule`` on the transformation
+    they return) to a caller that can run the update where the gradient
+    is made: their ``per_param``, which is elementwise over (g, p, buf),
+    with what it closes over in ``update``. The flat DGC step hands it to
+    the engine's apply pass (``kernels.payload_update_bits``), which then
+    writes p' and buf' of the compressed block instead of a [T] gradient
+    that is zero nearly everywhere; the same ``per_param`` is traced
+    there, so the mathematics has one copy, here.
+
+    Offered only with a weight-decay mask that can be built for any
+    coordinates from static geometry: none, or ``flat.LayoutMask`` in its
+    ``runs`` form."""
+    per_param: Callable
+    lr: ScalarOrSchedule
+    weight_decay_mask: Any
+    use_buf: bool
+
+    def blocks(self, state, params):
+        """The flat buffers :meth:`step` reads and writes, in its order."""
+        return (params, state.momentum_buffer) if self.use_buf else (params,)
+
+    def scalars(self, state):
+        """``(lr_t, first)`` of the step ``update`` would make from this
+        state, as an f32 and an int32 scalar."""
+        return (jnp.asarray(_lr_at(self.lr, state.count), jnp.float32),
+                (state.count == 0).astype(jnp.int32))
+
+    def advance(self, state, blocks):
+        """The state ``update`` would return, from :meth:`step`'s blocks."""
+        return _advance(state, blocks[1] if self.use_buf else None)
+
+    def step(self, g, idx, scalars, p, buf=None):
+        """``(p', buf')`` (``(p',)`` without a buffer) at the flat
+        coordinates ``idx``: ``update``'s arithmetic and the add."""
+        lr_t, first = scalars
+        # a vector predicate: the rule is also traced into a kernel
+        first = jnp.full(p.shape, first) != 0
+        m_wd = True
+        if self.weight_decay_mask is not None:
+            m_wd = jnp.where(self.weight_decay_mask.at(idx),
+                             jnp.ones((), p.dtype), jnp.zeros((), p.dtype))
+        upd, new_buf = self.per_param(g, p, buf, m_wd, lr_t, first)
+        return (p + upd, new_buf) if self.use_buf else (p + upd,)
+
+
+class _RuledTransformation(optax.GradientTransformation):
+    """A ``GradientTransformation`` that also carries ``rule``."""
+
+    def __new__(cls, init, update, rule):
+        self = super().__new__(cls, init, update)
+        self.rule = rule
+        return self
 
 
 def _wd_mask_flat(weight_decay_mask, params, treedef):
@@ -90,9 +149,12 @@ def _make_sgd(per_param_fn, lr, weight_decay_mask, use_buf):
         updates = jax.tree.unflatten(treedef, flat_updates)
         new_buf = (jax.tree.unflatten(treedef, flat_new_buf)
                    if use_buf else None)
-        return updates, SGDState(count=state.count + 1,
-                                 momentum_buffer=new_buf)
+        return updates, _advance(state, new_buf)
 
+    mask = weight_decay_mask
+    if mask is None or getattr(mask, "form", None) == "runs":
+        return _RuledTransformation(init, update, ElementwiseRule(
+            per_param_fn, lr, mask, use_buf))
     return optax.GradientTransformation(init, update)
 
 
@@ -126,8 +188,8 @@ def dgc_sgd(lr: ScalarOrSchedule, momentum: float = 0.9,
             else:
                 new_buf = buf
             return -lr_t * (mv * d_p + g), new_buf
-        wd = weight_decay if m_wd else 0.0
-        if wd != 0:
+        wd = weight_decay if m_wd else 0.0  # dgclint: ok[tracer-branch] — a Python bool on this branch (the isinstance test above)
+        if wd != 0:  # dgclint: ok[tracer-branch] — a Python float
             d_p = wd * p
             if momentum != 0:
                 new_buf = jnp.where(first, d_p,
@@ -163,7 +225,7 @@ def sgd(lr: ScalarOrSchedule, momentum: float = 0.0, dampening: float = 0.0,
             # applies to every coordinate (stock torch SGD group semantics)
             d_p = g + weight_decay * jnp.asarray(m_wd, p.dtype) * p
         else:
-            d_p = g + (weight_decay * p
+            d_p = g + (weight_decay * p  # dgclint: ok[tracer-branch] — a Python bool on this branch (the isinstance test above)
                        if (weight_decay != 0 and m_wd) else 0.0)
         if momentum != 0:
             new_buf = jnp.where(first, d_p,

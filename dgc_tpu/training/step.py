@@ -283,22 +283,21 @@ def build_train_step(apply_fn: Callable, dist_opt: DistributedOptimizer,
         def do_update(grads, params, opt_state, memory, key,
                       send_frac=None):
             health = {} if want_health else None
-            tstats = None
             # filled while the backward pass was traced, before this runs
             ready = {"grad_ready": grad_ready} if grad_ready else {}  # dgclint: ok[tracer-branch] — a dict of Python ints
-            if telemetry:
-                upd, opt_state, memory, tstats = dist_opt.update_flat(
-                    grads, opt_state, params, memory, key, engine,
-                    telemetry=True, health_out=health,
-                    send_frac=send_frac, **ready)
-            else:
-                upd, opt_state, memory = dist_opt.update_flat(
-                    grads, opt_state, params, memory, key, engine,
-                    health_out=health, send_frac=send_frac, **ready)
-            # the add is the root of the optimizer's fusion, and a fusion
-            # carries its root's scope: without it the part reads nothing
-            with _trace.phase("update", part="optimizer"):
-                return params + upd, opt_state, memory, tstats, health
+            # in place only where the step gives its buffers up. A caller
+            # that keeps its state, and the guards' atomic skip (a select
+            # between the parameters the step came with and the new
+            # ones), still read the old buffers after the pass: they keep
+            # today's program. A conservative choice; the in-place form
+            # was not timed there
+            new_params, opt_state, memory, *tstats = dist_opt.step_flat(
+                grads, opt_state, params, memory, key, engine,
+                telemetry=telemetry, health_out=health,
+                send_frac=send_frac,
+                in_place=donate and guards is None, **ready)
+            return (new_params, opt_state, memory,
+                    tstats[0] if tstats else None, health)  # dgclint: ok[tracer-branch] — a Python list's length
     else:
         unpack_params = unpack_stats = pack_grads = pack_stats = (
             lambda x: x)

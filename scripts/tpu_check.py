@@ -11,7 +11,8 @@ recall regression. This script runs ON THE REAL CHIP and asserts:
    ladder_counts, topk_rows, the segment-top-2 candidate kernels, and the
    four opt-in kernels (select_pack_rows incl. its multi-round form,
    payload_apply_bits, dgc_forward_rows, dgc_apply_rows) at the engine's
-   ResNet-50 operating shapes;
+   ResNet-50 operating shapes, and payload_update_bits with the
+   optimizer's rule == payload_apply_bits + the optimizer at VGG-16-BN's T;
 2. approx-selection recall >= 0.95 at every ResNet-50 approx bucket
    (exact top-k reference computed on the same device).
 
@@ -243,6 +244,76 @@ def check_kernels():
                 pval, pidx, pflag, bits50),
         jax.jit(lambda v_, i_, f_: kernels.dgc_apply_rows_reference(
             v_, i_, f_, T50, divisor=4.0))(pval, pidx, pflag))
+    out.update(check_update_pass())
+    return out
+
+
+def check_update_pass(T: int = 139_028_480, per: int = 138_360):
+    """``kernels.payload_update_bits`` with ``dgc_sgd``'s own rule against
+    the two passes it replaces (``payload_apply_bits``, then the
+    optimizer's ``update`` and the add, both compiled) at VGG-16-BN's T
+    with the benchmark's constants, a weight-decay mask whose spans cut a
+    chunk, one worker's pairs and four workers' with cross-worker
+    duplicates: p', buf' and the transmit bits bitwise, the tail [T, P)
+    as it came. The benchmark's exchange check does not run this form
+    (``PERF.md`` §7.1c), and XLA:CPU contracts the rule's FMAs
+    differently in the two programs, so the chip is where this
+    comparison holds with real constants. Returns {name: bool}."""
+    from dgc_tpu.compression.flat import LayoutMask
+    from dgc_tpu.ops import kernels
+    from dgc_tpu.optim import dgc_sgd
+    from dgc_tpu.optim.sgd import SGDState
+
+    P = T + 69_632
+    mask = LayoutMask(P, jnp.int32, [(0, 1_000_000, True),
+                                     (1_000_000, 1_000_512, False),
+                                     (1_000_512, T, True), (T, P, False)])
+    opt = dgc_sgd(lambda c: 0.0125 * (1 + c.astype(jnp.float32) * 1e-3),
+                  momentum=0.9, weight_decay=5e-5, weight_decay_mask=mask)
+    rule = opt.rule
+    rng = np.random.RandomState(35)
+    out = {}
+    for W in (1, 4):
+        def two_passes(v, i, f, p, b, bits, count):
+            acc, nbits = kernels.payload_apply_bits(
+                v, i, f, T, bits_donor=bits, out_total=P, max_dup=W)
+            inside = jnp.arange(P) < T          # the tail is undefined
+            upd, state = opt.update(jnp.where(inside, acc, 0.0),
+                                    SGDState(count, b), p)
+            return (jnp.where(inside, p + upd, p),
+                    jnp.where(inside, state.momentum_buffer, b), nbits)
+
+        def one_pass(v, i, f, p, b, bits, count):
+            state = SGDState(count, b)
+            (new_p, new_b), nbits = kernels.payload_update_bits(
+                v, i, f, T, rule.blocks(state, p), rule.step,
+                rule.scalars(state), bits_donor=bits, max_dup=W)
+            return new_p, new_b, nbits
+
+        idx = np.stack([np.unique(rng.randint(0, T, 2 * per))[:per]
+                        for _ in range(W)])
+        for w in range(W):
+            rng.shuffle(idx[w])
+        idx[1:, :per // 10] = idx[0, :per // 10]     # cross-worker duplicates
+        flags = np.zeros((W, per), bool)
+        flags[W - 1] = True
+        pairs = (jnp.asarray(rng.randn(W * per).astype(np.float32) / W),
+                 jnp.asarray(idx.reshape(-1).astype(np.int32)),
+                 jnp.asarray(flags.reshape(-1)))
+        k = jax.random.split(jax.random.PRNGKey(W), 2)
+        p = jax.random.normal(k[0], (P,), jnp.float32)
+        b = jax.random.normal(k[1], (P,), jnp.float32) * 1e-3
+        bits = jnp.zeros((kernels.num_sent_words(T),), jnp.int32)
+        two_passes, one_pass = jax.jit(two_passes), jax.jit(one_pass)
+        same = jax.jit(lambda want, got, p0: jnp.stack(
+            [jnp.array_equal(x, y) for x, y in zip(want, got)]
+            + [jnp.array_equal(got[0][T:], p0[T:])]).all())
+        for count in (0, 3):                        # ``first`` and not
+            want = two_passes(*pairs, p, b, bits, jnp.int32(count))
+            got = one_pass(*pairs, p, b, bits, jnp.int32(count))
+            out[f"payload_update_bits_vggT_w{W}_count{count}"] = bool(
+                same(want, got, p))
+            del want, got
     return out
 
 

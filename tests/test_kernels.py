@@ -778,3 +778,162 @@ def test_payload_apply_bits_duplicates_and_empty_chunks():
     # empty middle chunk: all-zero despite never receiving an entry
     mid = np.asarray(acc_k[2048 * 128:2 * 2048 * 128])
     assert not mid.any()
+
+
+# ------------------------------------------------------------------ #
+# the optimizer's rule inside the apply pass                         #
+# ------------------------------------------------------------------ #
+
+#: powers of two: a product by one is exact, so nothing here depends on
+#: which multiply XLA:CPU's LLVM contracts into an FMA (it chose
+#: fma(wd, p, m * buf) in the interpreted kernel and fma(m, buf, wd * p)
+#: in the optimizer's fusion; tests/test_update_in_apply.py says more)
+_EXACT = dict(momentum=0.5, weight_decay=2.0 ** -7)
+
+
+def _exact_lr(count):
+    return 0.125 * 0.5 ** count.astype(jnp.float32)
+
+
+def _cutting_mask(total, size):
+    """A ``flat.LayoutMask`` whose runs cut a slab, a chunk and the
+    block's end, and reach into the tail."""
+    from dgc_tpu.compression.flat import LayoutMask
+    cuts = [0, 1000, 4096 + 77, _CHUNK + 64 * 128 + 5, 2 * _CHUNK - 3,
+            total - 129, total + 100, size]
+    mask = LayoutMask(size, jnp.int32,
+                      [(a, b, k % 2 == 0)
+                       for k, (a, b) in enumerate(zip(cuts, cuts[1:]))])
+    assert mask.form == "runs" and len(mask.runs) == 4
+    return mask
+
+
+def _update_case(case, W, rng):
+    """Pairs of one parity case and a [size] state behind them: a block
+    of ``total`` coordinates that is not a whole number of chunks, and a
+    tail the pass must leave alone."""
+    idx, vals, sentinel, total = _apply_pairs(case, W, rng)
+    total += 37 * 4096 + 2048
+    size = total + 4096 + 128
+    flags = np.zeros(idx.shape, bool)
+    flags[W - 1] = idx[W - 1] != sentinel
+    p = rng.randn(size).astype(np.float32)
+    buf = rng.randn(size).astype(np.float32)
+    return (jnp.asarray(vals.reshape(-1)), jnp.asarray(idx.reshape(-1)),
+            jnp.asarray(flags.reshape(-1)), jnp.asarray(p),
+            jnp.asarray(buf), total, size)
+
+
+def _fused_and_unfused(opt, total, size, W, count):
+    """The fused pass, and ``payload_apply_bits`` followed by the
+    optimizer's own ``update`` and the add (today's two passes)."""
+    from dgc_tpu.optim.sgd import SGDState
+
+    rule = opt.rule
+
+    @jax.jit
+    def fused(v, i, f, p, b, donor):
+        state = SGDState(count, b)
+        return kernels.payload_update_bits(
+            v, i, f, total, rule.blocks(state, p), rule.step,
+            rule.scalars(state), bits_donor=donor, max_dup=W)
+
+    @jax.jit
+    def unfused(v, i, f, p, b, donor):
+        acc, bits = kernels.payload_apply_bits(
+            v, i, f, total, bits_donor=donor, out_total=size, max_dup=W)
+        inside = jnp.arange(size) < total
+        acc = jnp.where(inside, acc, 0.0)           # the tail is undefined
+        upd, state = opt.update(acc, SGDState(count, b), p)
+        return ((jnp.where(inside, p + upd, p),
+                 jnp.where(inside, state.momentum_buffer, b)), bits)
+
+    return fused, unfused
+
+
+@pytest.mark.parametrize("W, case", [(1, "unique"), (4, "duplicates"),
+                                     (4, "sentinel"), (1, "overfull_chunk"),
+                                     (4, "empty_chunks")])
+@pytest.mark.parametrize("opt_name, nesterov, masked, count", [
+    ("dgc_sgd", False, False, 0),
+    ("dgc_sgd", True, True, 3),
+    ("dgc_sgd", False, True, 0),
+    ("sgd", True, False, 3),
+    ("sgd", False, True, 2),
+])
+def test_update_pass_is_apply_then_the_optimizer(opt_name, nesterov, masked,
+                                                 count, W, case):
+    """``payload_update_bits`` with ``dgc_sgd``'s / ``sgd``'s rule against
+    ``payload_apply_bits`` followed by the optimizer's ``update`` and the
+    add, BITWISE: parameters, momentum buffer and transmit bits, over
+    nesterov, a weight-decay mask whose spans cut slabs and chunks,
+    ``first`` true and false under a scheduled ``lr``, one worker and
+    four with cross-worker duplicates, sentinel pairs, a chunk that
+    takes several pages, chunks that take none, and a block that is not
+    a whole number of chunks with a tail behind it that keeps its
+    values. The donated record is garbage and is never read."""
+    from dgc_tpu.optim import dgc_sgd, sgd
+
+    rng = np.random.RandomState(41 + W)
+    v, i, f, p, b, total, size = _update_case(case, W, rng)
+    assert total % _CHUNK and size > total
+    mask = _cutting_mask(total, size) if masked else None
+    opt = {"dgc_sgd": dgc_sgd, "sgd": sgd}[opt_name](
+        _exact_lr, nesterov=nesterov, weight_decay_mask=mask, **_EXACT)
+    donor = jnp.asarray(rng.randint(
+        -2**31, 2**31 - 1, size=kernels.num_sent_words(total),
+        dtype=np.int64).astype(np.int32))
+    fused, unfused = _fused_and_unfused(opt, total, size, W,
+                                        jnp.asarray(count, jnp.int32))
+    (p_k, b_k), bits_k = fused(v, i, f, p, b, donor)
+    (p_r, b_r), bits_r = unfused(v, i, f, p, b, donor)
+    np.testing.assert_array_equal(np.asarray(bits_k), np.asarray(bits_r))
+    np.testing.assert_array_equal(np.asarray(p_k), np.asarray(p_r))
+    np.testing.assert_array_equal(np.asarray(b_k), np.asarray(b_r))
+    # the tail behind the block is the caller's
+    np.testing.assert_array_equal(np.asarray(p_k[total:]),
+                                  np.asarray(p[total:]))
+    np.testing.assert_array_equal(np.asarray(b_k[total:]),
+                                  np.asarray(b[total:]))
+    # and the pass did the optimizer's work on the block
+    assert not np.array_equal(np.asarray(p_k[:total]), np.asarray(p[:total]))
+
+
+def test_update_pass_with_the_benchmarks_constants():
+    """VGG-16-BN's recipe (momentum 0.9, weight decay 5e-5, no mask):
+    parameters bitwise; the buffer to the last bit of one FMA, which is
+    XLA:CPU's to place (see ``_EXACT``)."""
+    from dgc_tpu.optim import dgc_sgd
+
+    rng = np.random.RandomState(3)
+    v, i, f, p, b, total, size = _update_case("unique", 1, rng)
+    opt = dgc_sgd(0.0125, momentum=0.9, weight_decay=5e-5)
+    fused, unfused = _fused_and_unfused(opt, total, size, 1,
+                                        jnp.asarray(5, jnp.int32))
+    (p_k, b_k), bits_k = fused(v, i, f, p, b, None)
+    (p_r, b_r), bits_r = unfused(v, i, f, p, b, None)
+    np.testing.assert_array_equal(np.asarray(bits_k), np.asarray(bits_r))
+    np.testing.assert_array_equal(np.asarray(p_k), np.asarray(p_r))
+    np.testing.assert_array_max_ulp(np.asarray(b_k), np.asarray(b_r),
+                                    maxulp=1)
+
+
+def test_update_pass_without_a_buffer():
+    """An optimizer with no momentum buffer (``dgc_sgd`` without weight
+    decay) offers a rule over the parameters alone: one state stream."""
+    from dgc_tpu.optim import dgc_sgd
+    from dgc_tpu.optim.sgd import SGDState
+
+    rng = np.random.RandomState(9)
+    v, i, f, p, _, total, size = _update_case("unique", 1, rng)
+    opt = dgc_sgd(0.125)
+    assert not opt.rule.use_buf
+    state = SGDState(jnp.zeros((), jnp.int32), None)
+    (p_k,), bits_k = kernels.payload_update_bits(
+        v, i, f, total, opt.rule.blocks(state, p), opt.rule.step,
+        opt.rule.scalars(state))
+    acc, bits_r = kernels.payload_apply_bits_reference(v, i, f, total)
+    want = np.asarray(p).copy()
+    want[:total] = want[:total] + np.float32(-0.125) * np.asarray(acc)
+    np.testing.assert_array_equal(np.asarray(p_k), want)
+    np.testing.assert_array_equal(np.asarray(bits_k), np.asarray(bits_r))

@@ -272,6 +272,83 @@ def test_on_the_v5e_the_update_is_one_fusion_over_p_buf_g(
     assert [op for _, op in args] == ["parameter"] * 3, big
 
 
+#: VGG-16-BN's compressed block, its flat buffer and one worker's payload
+#: (benchmark/configs/vgg16_bn.json)
+_VGG_T, _VGG_P, _VGG_PAYLOAD = 139_028_480, 139_051_008, 138_360
+
+
+def _apply_pass_args(one_chip, W):
+    from dgc_tpu.ops import kernels
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    n = W * _VGG_PAYLOAD
+    return (arg((n,), jnp.float32), arg((n,), jnp.int32), arg((n,), bool),
+            arg((kernels.num_sent_words(_VGG_T),), jnp.int32))
+
+
+@pytest.mark.parametrize("opt_name, nesterov, masked, W", [
+    ("dgc_sgd", False, False, 1),          # the benchmark's one-chip cell
+    ("dgc_sgd", True, True, 4),
+    ("sgd", True, True, 4)])
+def test_on_the_v5e_the_update_pass_compiles_at_vggs_width(
+        one_chip, no_compile_cache, monkeypatch, opt_name, nesterov, masked,
+        W):
+    """``kernels.payload_update_bits`` with the optimizers' own rule
+    through the TPU's compiler at VGG-16-BN's T, for one worker's pairs
+    and four, under ``jax.default_matmul_precision("highest")`` (what a
+    configuration with a model reference states; PERF.md §7.0): block
+    shapes, scalar memory, VMEM and the rule's operations are all
+    Mosaic's to refuse. The state moves in place: the program holds no
+    [T]-sized temporary."""
+    from dgc_tpu.ops import kernels
+    from dgc_tpu.optim.sgd import SGDState
+    monkeypatch.setattr(kernels, "use_pallas", lambda: True)
+    mask = None
+    if masked:
+        mask = LayoutMask(_VGG_P, jnp.int32,
+                          [(0, 1_000_000, True), (1_000_000, 1_000_512,
+                                                  False),
+                           (1_000_512, _VGG_T, True), (_VGG_T, _VGG_P,
+                                                       False)])
+        assert mask.form == "runs" and len(mask.runs) == 2
+    rule = OPTIMIZERS[opt_name](
+        lambda c: 0.1 / (1.0 + c), momentum=0.9, weight_decay=5e-5,
+        nesterov=nesterov, weight_decay_mask=mask).rule
+
+    def fused(v, i, f, bits, p, buf, count):
+        state = SGDState(count, buf)
+        return kernels.payload_update_bits(
+            v, i, f, _VGG_T, rule.blocks(state, p), rule.step,
+            rule.scalars(state), bits_donor=bits, max_dup=W)
+
+    flat = jax.ShapeDtypeStruct((_VGG_P,), jnp.float32, sharding=one_chip)
+    count = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    with jax.default_matmul_precision("highest"):
+        compiled = jax.jit(fused, donate_argnums=(3, 4, 5)).lower(
+            *_apply_pass_args(one_chip, W), flat, flat, count).compile()
+    assert "payload_update_bits" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * _VGG_T // 16
+
+
+def test_on_the_v5e_the_apply_pass_compiles_under_highest(
+        one_chip, no_compile_cache, monkeypatch):
+    """PERF.md §7.0: ``kernels._dot_nt`` states its precision, so the
+    streamed apply compiles whatever matmul precision the step states."""
+    from dgc_tpu.ops import kernels
+    monkeypatch.setattr(kernels, "use_pallas", lambda: True)
+
+    def apply(v, i, f, bits):
+        return kernels.payload_apply_bits(v, i, f, _VGG_T, bits_donor=bits,
+                                          out_total=_VGG_P, max_dup=1)
+
+    with jax.default_matmul_precision("highest"):
+        compiled = jax.jit(apply, donate_argnums=(3,)).lower(
+            *_apply_pass_args(one_chip, 1)).compile()
+    assert "payload_apply_bits" in compiled.as_text()
+
+
 # --------------------------------------------------------------------- #
 # numerics                                                               #
 # --------------------------------------------------------------------- #

@@ -339,14 +339,15 @@ def test_every_pallas_call_site_passes_a_unique_name():
         value = {k.arg: k.value for k in call.keywords}.get("name")
         names.append(value.value if isinstance(value, ast.Constant)
                      else getattr(value, "id", None))
-    assert len(names) == 13 and None not in names, names
+    assert len(names) == 14 and None not in names, names
     # the one shared site takes its name from its two callers
     assert names.count("name") == 1
     shared = [call.args[0].value
               for call in _kernel_calls("_payload_apply_call")]
     assert sorted(shared) == ["dgc_apply_rows", "payload_apply_bits"]
     every = [n for n in names if n != "name"] + shared
-    assert len(set(every)) == len(every) == 14
+    assert len(set(every)) == len(every) == 15
+    assert "payload_update_bits" in every
     # a kernel carries the name of the jitted function that launches it
     for name in every:
         assert callable(getattr(kernels, name, None)) or callable(
