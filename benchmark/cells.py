@@ -15,6 +15,11 @@ adding files and entries and edits nothing that is here:
 * ``benchmark/traffic/<traffic>.json``
 * ``benchmark/layer_metrics/<metric>.py`` with ``read(trace, spans, cell)``
 
+A per-layer metric whose entry has no ``workloads`` list is owed by EVERY
+cell, those of later PRs too: a traced run whose reader returns None for it
+prints no line (``run.refuse_a_short_line``). A reader returns None only
+for a cell that its entry's list leaves out.
+
 A missing or malformed file is an error that names the file and the key.
 """
 
@@ -367,7 +372,10 @@ def load_cell(name: str, bench: Optional[Dict[str, Any]] = None,
 def load_reader(metric: str, readers_dir: Optional[str] = None) -> Callable:
     """The reader of one per-layer metric: ``read(trace, spans, cell)``
     in ``benchmark/layer_metrics/<metric>.py``. It returns a number, or
-    None when it finds nothing to read (the metric is then left out)."""
+    None when it finds nothing to read: never 0 for a share. A cell owes
+    every metric of its ``per_layer`` (``_metrics_of``: every entry
+    without a ``workloads`` list, and those whose list names it), so a
+    None there costs the traced run its line."""
     path = os.path.join(readers_dir
                         or os.path.join(BENCH_DIR, "layer_metrics"),
                         metric + ".py")

@@ -29,12 +29,21 @@ near 1) and mapped to ids by a permutation made from the seed; document
 lengths are log-normal round ``doc_len_median`` with ``doc_len_sigma``,
 clipped to [1, seq_len] (null median: one document per row). ``input:
 resident`` only: the program has no token split for ``pipeline`` to drive.
+
+``host_batches`` records the harness's own spans round the making of each
+global batch (arm ``setup``): ``input.batch`` round ``split.get_batch``
+or round a token batch's cut, and for tokens ``input.pool`` round the one
+``make_tokens`` draw its batches share. ``input.produce_ms`` reads them
+where the program recorded no ``input.get_batch`` span of its own
+(``program_records.batch_seconds``).
 """
 
 import itertools
 from typing import Any, Dict, Iterator, List, Tuple
 
 import numpy as np
+
+from benchmark.spans import Spans
 
 
 def example_shapes(dataset: Dict[str, Any], n: int):
@@ -144,29 +153,37 @@ def pipeline_feed(seed, global_batch, pool_batches, dataset, mesh) -> Feed:
     return Feed(staged, close=batches.close)
 
 
-def host_batches(seed, global_batch, n, dataset, traffic) -> Iterator[Tuple]:
+def host_batches(seed, global_batch, n, dataset, traffic, spans=None
+                 ) -> Iterator[Tuple]:
     """``n`` global batches on the host, as the step receives them, one at
-    a time."""
+    a time; the making of each is a span of ``spans``."""
+    span = (spans or Spans()).span
     if dataset["kind"] == "images":
         split = _split(seed, global_batch, n, dataset)
         index_iter = _endless_batches(len(split), global_batch, seed)
         for _ in range(n):
-            yield split.get_batch(next(index_iter))
+            indices = next(index_iter)
+            with span("setup", "input.batch"):
+                batch = split.get_batch(indices)
+            yield batch
         return
-    rows = make_tokens(seed, n * global_batch, dataset["seq_len"],
-                       dataset["vocab_size"], dataset["eos_id"],
-                       traffic["zipf_s"], traffic["doc_len_median"],
-                       traffic["doc_len_sigma"])
+    with span("setup", "input.pool"):
+        rows = make_tokens(seed, n * global_batch, dataset["seq_len"],
+                           dataset["vocab_size"], dataset["eos_id"],
+                           traffic["zipf_s"], traffic["doc_len_median"],
+                           traffic["doc_len_sigma"])
     for r in rows.reshape(n, global_batch, -1):
-        yield (np.ascontiguousarray(r[:, :-1]),
-               np.ascontiguousarray(r[:, 1:]).reshape(-1))
+        with span("setup", "input.batch"):
+            batch = (np.ascontiguousarray(r[:, :-1]),
+                     np.ascontiguousarray(r[:, 1:]).reshape(-1))
+        yield batch
 
 
-def resident_batches(seed, global_batch, n, dataset, traffic, mesh
-                     ) -> List[Tuple]:
+def resident_batches(seed, global_batch, n, dataset, traffic, mesh,
+                     spans=None) -> List[Tuple]:
     """``n`` global batches on the device, made once."""
-    return [_to_mesh(b, mesh)
-            for b in host_batches(seed, global_batch, n, dataset, traffic)]
+    return [_to_mesh(b, mesh) for b in host_batches(
+        seed, global_batch, n, dataset, traffic, spans)]
 
 
 def resident_feed(batches: List[Tuple]) -> Feed:

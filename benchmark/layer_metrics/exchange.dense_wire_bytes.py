@@ -6,6 +6,6 @@ from benchmark.program_records import collective_bytes
 
 
 def read(trace, spans, cell):
-    if "dense" not in trace["arms"]:
+    if "dense" not in trace["steps"]:
         return None
     return collective_bytes("FlatDenseExchange")

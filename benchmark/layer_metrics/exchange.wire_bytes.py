@@ -6,6 +6,6 @@ from benchmark.program_records import collective_bytes
 
 
 def read(trace, spans, cell):
-    if "dgc" not in trace["arms"]:
+    if "dgc" not in trace["steps"]:
         return None
     return collective_bytes("FlatDGCEngine")

@@ -7,5 +7,5 @@ from benchmark.program_records import span_seconds
 
 
 def read(trace, spans, cell):
-    traced = span_seconds("step.trace") if trace["arms"] else []
+    traced = span_seconds("step.trace") if trace["steps"] else []
     return sum(traced) if traced else None

@@ -4,7 +4,7 @@ the harness calls ``enable(True)`` before it builds under ``--trace 1``).
 A program without such a recorder (the parent of the PR that added it)
 yields no record, and the readers built on this return nothing."""
 
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 
 def records() -> List[Dict[str, Any]]:
@@ -20,6 +20,25 @@ def span_seconds(name: str) -> List[float]:
     """Durations of the spans called ``name``, in the order they closed."""
     return [(r["t1_ns"] - r["t0_ns"]) * 1e-9 for r in records()
             if r.get("kind") == "span" and r.get("name") == name]
+
+
+def batch_seconds(setup_spans: Dict[str, List[float]]
+                  ) -> Tuple[Optional[str], List[float]]:
+    """(whose spans, seconds each global batch took to make). The
+    program's ``input.get_batch`` wherever it recorded any (its image
+    splits; a ``pipeline`` cell's batches are made on its producer thread,
+    where the harness cannot stand); else the harness's own round the same
+    work (``inputs.host_batches``: a ``tokens`` configuration's batches,
+    which go through no split of the program's), the pool's one draw
+    shared over its batches; (None, []) where neither recorded one."""
+    made = span_seconds("input.get_batch")
+    if made:
+        return "program:input.get_batch", made
+    made = setup_spans.get("input.batch", [])
+    if not made:
+        return None, []
+    pool = sum(setup_spans.get("input.pool", [])) / len(made)
+    return "harness:input.batch", [s + pool for s in made]
 
 
 def collective_bytes(engine: str) -> Optional[int]:

@@ -71,12 +71,29 @@ def fixture_cell(name):
                            traffic_dir=os.path.join(FIXTURE, "traffic"))
 
 
+#: where a reader's number comes from when no op ran on a device: the
+#: per-layer metrics of these sources read on the CPU too
+HOST_SOURCES = ("program_span", "program_counter", "host_clock")
+
+
 def _run_tiny(name, trace):
     """One tiny cell through ``measure``; checks what a run must satisfy
     and returns counts only."""
+    from dgc_tpu.telemetry import trace as program_trace
+
+    # a run is a process of its own: its recorder starts empty, and the
+    # next run's steps carry no marker of this one's
+    program_trace.enable(False)
+    try:
+        return _run_tiny_fresh(name, trace)
+    finally:
+        program_trace.enable(False)
+
+
+def _run_tiny_fresh(name, trace):
     import jax
 
-    from benchmark import run, trace_reduce
+    from benchmark import program_records, run, trace_reduce
 
     cell = fixture_cell(name)
     m = run.measure(cell, seed=3, seconds=0.5, trace=trace,
@@ -116,10 +133,16 @@ def _run_tiny(name, trace):
                 out["no_device_ops"] = str(e)[:60]  # the CPU has no lane
             else:
                 raise AssertionError("a CPU trace yielded device ops")
-        values = run.per_layer_values(
-            cell, {"paired": paired, "tables": {}, "arms": {},
-                   "engine": None, "peaks": {}}, m["window_spans"])
+        # the repo's readers on this cell: each that needs no device lane
+        # returns a number (the rest have nothing to read on the CPU)
+        values = run.per_layer_values(cell, run.host_view(m, paired),
+                                      m["window_spans"])
+        assert sorted(values) == sorted(
+            e["name"] for e in cell.per_layer
+            if e["source"] in HOST_SOURCES), sorted(values)
         out["per_layer_read"] = sorted(values)
+        out["input_produce_source"] = program_records.batch_seconds(
+            m["setup_spans"])[0]
         out["annotations"] = len(names)
         out["trace_events"] = len(events)
     return out
@@ -129,7 +152,8 @@ def rehearse_cpu():
     for name in ("tiny.steady", "tiny.resident", "tiny.scan",
                  "tiny_lm.resident", "tiny_lm.scan", "tiny_lm.one"):
         print(json.dumps(_run_tiny(name, trace=False)), flush=True)
-    print(json.dumps(_run_tiny("tiny.steady", trace=True)), flush=True)
+    for name in ("tiny.steady", "tiny_lm.resident", "tiny_lm.one"):
+        print(json.dumps(_run_tiny(name, trace=True)), flush=True)
 
 
 def rehearse_mesh():

@@ -32,7 +32,11 @@ With ``DGC_BENCH_KEEP_TRACE=<dir>`` in the environment the profiler's
 directory is copied there before it is reduced (to look at a trace a reader
 finds nothing in).
 
-The last line of standard output is the result, one JSON object. Earlier
+The last line of standard output is the result, one JSON object, whole or
+not printed: a run that lacks a metric its cell owes (untraced, an
+end-to-end one; traced, a per-layer one, and a per-layer metric without a
+``workloads`` list is owed by every cell) exits non-zero and names it
+(``refuse_a_short_line``). Earlier
 lines (JSON objects with an ``event`` key) carry the set-up split, the
 check's numbers, the per-round rows with quartiles, and the phase tables.
 """
@@ -44,6 +48,7 @@ _T0 = time.perf_counter()        # process start, give or take the interpreter
 import argparse
 import gc
 import json
+import math
 import os
 import shutil
 import statistics
@@ -55,7 +60,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from benchmark import cells, rounds
+from benchmark import cells, program_records, rounds
 from benchmark.spans import Spans
 
 #: both arms see the same weights and the same first batch, so their
@@ -162,17 +167,19 @@ def engine_info(arm):
                                if state_dtype is not None else item)}
 
 
-def measure(cell, seed, seconds, trace, devices=None):
+def measure(cell, seed, seconds, trace, devices=None, client_s=0.0):
     """Everything between "backend up" and "result": returns the result's
     parts as a dict. ``devices`` is for the rehearsals (virtual CPU
     devices); on the chip it stays None. Every program of the cell is
-    traced and called at the matmul precision its configuration states."""
+    traced and called at the matmul precision its configuration states.
+    ``client_s``: the seconds the runtime took to create its TPU client,
+    which are the machine's and not set-up's (``run_cell``)."""
     from benchmark import build
     with build.matmul_precision(cell):
-        return _measure(cell, seed, seconds, trace, devices)
+        return _measure(cell, seed, seconds, trace, devices, client_s)
 
 
-def _measure(cell, seed, seconds, trace, devices):
+def _measure(cell, seed, seconds, trace, devices, client_s):
     import jax
     import numpy as np
 
@@ -217,7 +224,9 @@ def _measure(cell, seed, seconds, trace, devices):
     followers = {}
     rows, steps_per_round, losses, window_spans, traced = [], None, {}, {}, []
     setup_s = window_s = 0.0
-    since = _T0               # where the set-up now running began
+    # where the set-up now running began: process start, less the
+    # runtime's own client creation
+    since = _T0 + client_s
     lap("backend_and_mesh")
 
     try:
@@ -235,7 +244,7 @@ def _measure(cell, seed, seconds, trace, devices):
                         n = traffic["pool_batches" if scan
                                     else "round_steps"]
                         resident = inputs.resident_batches(
-                            seed, gb, n, arm.dataset, traffic, mesh)
+                            seed, gb, n, arm.dataset, traffic, mesh, spans)
                         feed = (inputs.scan_feed(resident, mesh) if scan
                                 else inputs.resident_feed(resident))
                     first_batch = next(feed)
@@ -360,6 +369,7 @@ def _measure(cell, seed, seconds, trace, devices):
         "step0_gap": step0_gap,
         "dgc_peak_bytes": dgc_peak,
         "window_spans": window_spans, "traced": traced, "engine": engine,
+        "setup_spans": spans.seconds().get("setup", {}),
     }
 
 
@@ -444,6 +454,19 @@ def end_to_end_values(m, paired):
     return values
 
 
+def host_view(m, paired):
+    """What the per-layer readers get as ``trace`` of a run whose trace
+    has no device lane (all a rehearsal on the CPU has): the steps each
+    arm ran in a profiler session, the window's pairing, the engine's
+    sizes and the harness's set-up spans."""
+    steps = {}
+    for session in m["traced"]:
+        steps.update(session["steps"])
+    return {"arms": {}, "tables": {}, "peaks": {}, "steps": steps,
+            "paired": paired, "engine": m["engine"],
+            "setup_spans": m["setup_spans"]}
+
+
 def trace_view(m, paired, device_kind):
     """What the per-layer readers get as ``trace``."""
     from benchmark import trace_reduce
@@ -454,22 +477,54 @@ def trace_view(m, paired, device_kind):
         arms.update(trace_reduce.split_arms(session["events"],
                                             session["steps"]))
     return {
+        **host_view(m, paired),
         "arms": arms,
         "tables": {name: trace_reduce.phase_table(a)
                    for name, a in arms.items()},
-        "paired": paired,
-        "engine": m["engine"],
         "peaks": cells.load_peaks(device_kind),
     }
 
 
 def per_layer_values(cell, view, spans_seconds):
+    """What each reader of the cell's per-layer metrics returns, but for
+    None: ``refuse_a_short_line`` names those."""
     values = {}
     for entry in cell.per_layer:
         value = cells.load_reader(entry["name"])(view, spans_seconds, cell)
         if value is not None:
             values[entry["name"]] = float(value)
     return values
+
+
+#: what the driver reads of ``device`` in every line
+DEVICE_KEYS = ("platform", "kind", "count", "memory_peak_bytes")
+
+
+def refuse_a_short_line(cell, values, device, traced):
+    """The line is whole or it is not printed: every metric the cell owes
+    (traced, its per-layer metrics: one without a ``workloads`` list is
+    owed by EVERY cell; untraced, its end-to-end ones) has a finite value,
+    and ``device`` says what the driver reads of it. Otherwise
+    ``SystemExit`` that names the workload and all that is missing, so the
+    first to see a short line is the one who made it."""
+    owed = cell.per_layer if traced else cell.end_to_end
+    missing = [e["name"] for e in owed
+               if not math.isfinite(values.get(e["name"], math.nan))]
+    faults = []
+    if missing:
+        faults.append(f"did not produce {missing}")
+    lacking = [k for k in DEVICE_KEYS if device.get(k) is None]
+    if lacking:
+        faults.append(f"its device lacks {lacking}")
+    if traced and not 0 < device.get("busy_s", 0) <= device.get(
+            "window_s", 0):
+        faults.append(f"its trace reads busy_s {device.get('busy_s')!r} of "
+                      f"window_s {device.get('window_s')!r}, not 0 < "
+                      "busy_s <= window_s")
+    if faults:
+        kind = "traced" if traced else "untraced"
+        raise SystemExit(f"benchmark: workload '{cell.name}', {kind} run, "
+                         + "; ".join(faults))
 
 
 def breakdown(view):
@@ -551,6 +606,11 @@ def main(argv=None):
         cell = cells.load_cell(args.workload)
     except cells.CellError as e:
         raise SystemExit(f"benchmark: {e}")
+    run_cell(cell, args.seed, args.seconds, bool(args.trace))
+
+
+def run_cell(cell, seed, seconds, trace):
+    """One run of ``cell`` on the chip, to its result line."""
     try:
         import jax
 
@@ -563,18 +623,30 @@ def main(argv=None):
     # every program, however quick to compile, comes from the cache in a
     # warm run (JAX's default keeps only those that took over a second)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    # the runtime creates its TPU client here (libtpu's start, each chip's
+    # two 4 GiB windows, a 4 GiB pinned staging buffer): 6.1 s in a
+    # machine's first process, 10.6 in its thirteenth, 15-17 on four chips
+    # (PERF.md 6, PR 38). They hold no work of the program or the harness,
+    # and no PR can move work into them: logged (``client_s``) and left
+    # out of ``setup_s``
+    t_client = time.perf_counter()
     require_tpu("benchmark/run.py")
-    log("start", workload=cell.name, seed=args.seed, seconds=args.seconds,
-        trace=args.trace, chips=cell.chips, config=cell.config_name,
+    client_s = time.perf_counter() - t_client
+    log("start", workload=cell.name, seed=seed, seconds=seconds,
+        trace=int(trace), chips=cell.chips, config=cell.config_name,
         traffic=cell.traffic_name, cache_dir=cache_dir,
         cache_entries=compile_cache.entries(cache_dir),
-        import_s=time.perf_counter() - _T0)
+        import_s=time.perf_counter() - _T0, client_s=client_s)
 
-    m = measure(cell, args.seed, args.seconds, bool(args.trace))
+    m = measure(cell, seed, seconds, trace, client_s=client_s)
     paired = paired_summary(m)
     log("setup", setup_s=m["setup_s"], split=m["split"],
         compiles=m["compiles"],
-        cache_entries=compile_cache.entries(cache_dir))
+        cache_entries=compile_cache.entries(cache_dir),
+        # whose spans ``input.produce_ms`` reads (a traced run's metric:
+        # the program records only then)
+        input_produce_source=program_records.batch_seconds(
+            m["setup_spans"])[0] if trace else None)
     log("memory", memory_peak_bytes=m["memory_peak_bytes"],
         dgc_peak_bytes=m["dgc_peak_bytes"],
         runtime_stats=jax.devices()[0].memory_stats())
@@ -585,30 +657,24 @@ def main(argv=None):
                                for k, v in by.items()}
                          for arm, by in m["window_spans"].items()})
 
-    units = {e["name"]: e["unit"]
-             for e in cell.end_to_end + cell.per_layer}
     d0 = jax.devices()[0]
     device = {"platform": d0.platform, "kind": d0.device_kind,
               "count": jax.device_count(),
               "memory_peak_bytes": m["memory_peak_bytes"]}
     result = {"correct": is_correct(m), "attempted": m["attempted"],
               "failed": m["failed"]}
-    if args.trace:
+    if trace:
         view = trace_view(m, paired, device["kind"])
         values = per_layer_values(cell, view, m["window_spans"])
         log("phase_tables", **view["tables"])
         device["busy_s"], device["window_s"] = device_busy(view)
         result["breakdown"] = breakdown(view)
     else:
-        wanted = {e["name"] for e in cell.end_to_end}
-        values = {k: v for k, v in end_to_end_values(m, paired).items()
-                  if k in wanted}
-        missing = sorted(wanted - set(values))
-        if missing:
-            raise SystemExit(f"benchmark: workload '{cell.name}' did not "
-                             f"produce {missing}")
-    result["metrics"] = {k: {"value": v, "unit": units[k]}
-                         for k, v in values.items()}
+        values = end_to_end_values(m, paired)
+    refuse_a_short_line(cell, values, device, trace)
+    result["metrics"] = {
+        e["name"]: {"value": values[e["name"]], "unit": e["unit"]}
+        for e in (cell.per_layer if trace else cell.end_to_end)}
     result["device"] = device
     # what was compared, each number beside its limit: last in the line,
     # and the last lines of standard error

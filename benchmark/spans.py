@@ -4,7 +4,9 @@ Three spans per step, recorded from the benchmark's side of the calls into
 the program's layers: ``input.next`` (the ``next()`` on the staged
 iterator: time the loop waits for data), ``dispatch`` (the call of the
 jitted step, which returns when the step is enqueued) and one ``wait`` per
-round (``block_until_ready``). While a profiler session is live each span
+round (``block_until_ready``), under the arm's name; and under ``setup``
+the making of each resident global batch (``inputs.host_batches``). While a
+profiler session is live each span
 also opens a ``jax.profiler.TraceAnnotation`` named ``bench:<arm>:<span>``,
 which puts it on the device trace's clock.
 """

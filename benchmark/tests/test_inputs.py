@@ -108,3 +108,25 @@ def test_image_batches_are_what_the_program_normalises():
         assert x.shape == (4, 8, 8, 3) and x.dtype == np.float32
         assert y.shape == (4,) and y.max() < 5
         assert np.array_equal(x, x2) and np.array_equal(y, y2)
+
+
+@pytest.mark.parametrize("dataset, traffic, want", [
+    ({"kind": "images", "image_size": 8, "num_classes": 5}, {},
+     {"input.batch": 3}),
+    (DATASET, TRAFFIC, {"input.pool": 1, "input.batch": 3}),
+], ids=["images", "tokens"])
+def test_the_making_of_each_batch_is_a_span_of_the_harness(
+        dataset, traffic, want):
+    """Under the arm ``setup``: round ``get_batch`` for images; for tokens
+    round the pool's one draw and round each batch's cut. The batches are
+    what they are without a recorder."""
+    from benchmark.spans import Spans
+    spans = Spans()
+    timed = list(inputs.host_batches(5, 4, 3, dataset, traffic, spans))
+    plain = list(inputs.host_batches(5, 4, 3, dataset, traffic))
+    assert all(np.array_equal(a, b) for pair, again in zip(timed, plain)
+               for a, b in zip(pair, again))
+    got = spans.seconds()
+    assert set(got) == {"setup"}
+    assert {name: len(secs) for name, secs in got["setup"].items()} == want
+    assert all(s > 0 for secs in got["setup"].values() for s in secs)

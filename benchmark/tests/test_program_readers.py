@@ -48,8 +48,9 @@ def _read(view, name):
     return cells.load_reader(name)(view, {}, None)
 
 
-#: the record readers ask the view only which arms the traced run had
-ARMS = {"arms": {"dgc": object(), "dense": object()}}
+#: the record readers ask the view which arms a profiler session ran, and
+#: ``input.produce_ms`` for the harness's own set-up spans
+ARMS = {"steps": {"dgc": 8, "dense": 8}, "setup_spans": {}}
 
 
 @pytest.mark.parametrize("metric, want", [
@@ -119,7 +120,38 @@ def test_record_readers_on_hand_made_records(monkeypatch, metric, want):
     monkeypatch.setattr(program_records, "records", lambda: list(RECORDS))
     assert _read(ARMS, metric) == pytest.approx(want)
     # a view with no arm is not a traced run of this process
-    assert _read({"arms": {}}, metric) is None
+    assert _read({"steps": {}, "setup_spans": {}}, metric) is None
+
+
+#: ten token batches as ``inputs.host_batches`` times them: the pool's one
+#: draw, shared, and each batch's cut
+HARNESS = {"input.pool": [0.040], "input.batch": [0.001] * 10}
+
+
+@pytest.mark.parametrize("records, setup_spans, want", [
+    (RECORDS, {}, 225.0),
+    (RECORDS, HARNESS, 225.0),              # the program's, where it has any
+    ([r for r in RECORDS if r["name"] != "input.get_batch"], HARNESS,
+     4.0 + 1.0),                            # else the harness's
+    ([], {"input.batch": [0.002, 0.004]}, 3.0),     # images: no pool span
+    ([], {}, None),
+    ([], {"input.pool": [0.040]}, None),    # a pool and no batch: nothing
+], ids=["program", "program-first", "harness", "harness-no-pool", "neither",
+        "pool-alone"])
+def test_produce_ms_reads_the_programs_spans_else_the_harnesss(
+        monkeypatch, records, setup_spans, want):
+    monkeypatch.setattr(program_records, "records", lambda: list(records))
+    view = {**ARMS, "setup_spans": setup_spans}
+    got = _read(view, "input.produce_ms")
+    assert got == (None if want is None else pytest.approx(want))
+    source, made = program_records.batch_seconds(setup_spans)
+    assert source == (
+        None if want is None else "program:input.get_batch"
+        if any(r["name"] == "input.get_batch" for r in records)
+        else "harness:input.batch")
+    assert len(made) == {None: 0, 225.0: 2, 5.0: 10, 3.0: 2}[want]
+    # and never outside a traced run
+    assert _read({**view, "steps": {}}, "input.produce_ms") is None
 
 
 def test_record_readers_on_the_live_recorder_and_on_a_program_without():

@@ -48,10 +48,119 @@ def test_measure_runs_the_cell(cell):
         assert model["ok"] and "batch statistics" in model["skipped"]
 
 
-def test_traced_measure_puts_the_harness_spans_on_the_trace():
-    out = rehearse._run_tiny("tiny.resident", trace=True)
+@pytest.mark.parametrize("cell, source", [
+    ("tiny.resident", "program:input.get_batch"),
+    ("tiny_lm.resident", "harness:input.batch"),
+    ("tiny_lm.one", "harness:input.batch"),
+])
+def test_traced_measure_runs_the_repos_readers_on_the_cell(cell, source):
+    """The harness's spans are on the trace, and every reader of the
+    repo's list that needs no device lane returns a number for the cell:
+    an image cell's batches are timed by the program's split, a token
+    cell's by the harness, which makes them."""
+    out = rehearse._run_tiny(cell, trace=True)
     assert out["annotations"] >= 6 and "no_device_ops" in out
-    assert out["per_layer_read"] == ["input.wait_ms"]
+    assert out["per_layer_read"] == [
+        "exchange.dense_wire_bytes", "exchange.wire_bytes",
+        "input.produce_ms", "input.wait_ms", "step.trace_s"]
+    assert out["input_produce_source"] == source
+
+
+def test_the_fixtures_per_layer_metrics_are_the_repos():
+    """Same names in the same order, units, sources, layers and ``moves``;
+    a list of the repo's one-chip (four-chip) cells reads as the list of
+    the fixtures' one-chip (four-chip) cells."""
+    repo = cells.load_benchmark()
+    fixture = cells.load_benchmark(
+        os.path.join(rehearse.FIXTURE, "BENCHMARK.json"))
+
+    def normal(bench):
+        chips = {w["name"]: w["chips"] for w in bench["workloads"]}
+        whole = {n: sorted(name for name, c in chips.items() if c == n)
+                 for n in (1, 4)}
+        out = []
+        for entry in bench["per_layer"]:
+            entry = dict(entry)
+            listed = entry.pop("workloads", None)
+            if listed is not None:
+                (n,) = {chips[name] for name in listed}
+                assert sorted(listed) == whole[n], entry["name"]
+                entry["chips"] = n
+            out.append(entry)
+        return out
+
+    assert normal(fixture) == normal(repo)
+
+
+def _owed_values(cell):
+    return {e["name"]: 1.0 for e in cell.per_layer}
+
+
+DEVICE = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1,
+          "memory_peak_bytes": 1, "busy_s": 0.5, "window_s": 1.0}
+
+
+@pytest.mark.parametrize("gone", [
+    ["input.produce_ms"], ["input.produce_ms", "step.optimizer_ms"]])
+def test_a_traced_line_that_lacks_a_metric_of_its_cell_is_refused(gone):
+    """A token cell owes every per-layer metric without a ``workloads``
+    list, and the one-chip lists': the refusal names the workload and
+    ALL that is missing; the whole line passes."""
+    cell = rehearse.fixture_cell("tiny_lm.one")
+    values = _owed_values(cell)
+    assert len(values) == 17
+    run.refuse_a_short_line(cell, values, DEVICE, traced=True)
+    for name in gone:
+        del values[name]
+    with pytest.raises(SystemExit) as refusal:
+        run.refuse_a_short_line(cell, values, DEVICE, traced=True)
+    said = str(refusal.value)
+    assert "'tiny_lm.one', traced run" in said and str(gone) in said
+    assert not any(name in said for name in values)
+    # a value that is no number is no value
+    with pytest.raises(SystemExit, match=r"\['kernels.pallas_ms'\]"):
+        run.refuse_a_short_line(
+            cell, {**_owed_values(cell), "kernels.pallas_ms": float("nan")},
+            DEVICE, traced=True)
+
+
+def test_an_untraced_line_owes_the_end_to_end_metrics_and_its_device():
+    cell = rehearse.fixture_cell("tiny_lm.one.x4")
+    values = {"step_ms": 1.0, "dense_step_ms": 1.0, "setup_s": 1.0,
+              "dgc_overhead_ms": 0.1}       # the last is not this cell's
+    device = {k: v for k, v in DEVICE.items()
+              if k not in ("busy_s", "window_s")}
+    run.refuse_a_short_line(cell, values, device, traced=False)
+    with pytest.raises(SystemExit,
+                       match=r"'tiny_lm.one.x4', untraced run, did not "
+                             r"produce \['dense_step_ms', 'setup_s'\]"):
+        run.refuse_a_short_line(cell, {"step_ms": 1.0}, device, traced=False)
+    with pytest.raises(SystemExit, match=r"device lacks \['kind'\]"):
+        run.refuse_a_short_line(
+            cell, values, {**device, "kind": None}, traced=False)
+    # traced, the device also says how busy it was, in the driver's words
+    traced = _owed_values(cell)
+    for busy in ({}, {"busy_s": 0.0, "window_s": 1.0},
+                 {"busy_s": 1.5, "window_s": 1.0}):
+        with pytest.raises(SystemExit, match="not 0 < busy_s <= window_s"):
+            run.refuse_a_short_line(cell, traced, {**device, **busy},
+                                    traced=True)
+
+
+def test_the_clients_creation_is_left_out_of_setup(monkeypatch):
+    """``setup_s`` runs from process start to the first timed round, less
+    the seconds the runtime took to create its client (PR 38: the
+    machine's, 6-17 s by the machine, its chips and its age)."""
+    import time
+
+    import jax
+    monkeypatch.setattr(run, "_T0", time.perf_counter())
+    m = run.measure(rehearse.fixture_cell("tiny.resident"), seed=5,
+                    seconds=0.2, trace=False, devices=jax.devices("cpu"),
+                    client_s=100.0)
+    # every lap of a cell whose arms share the chip lies before its window
+    assert m["setup_s"] + 100.0 == pytest.approx(
+        sum(m["split"].values()), abs=0.5)
 
 
 def test_the_memory_law_refuses_with_its_numbers():

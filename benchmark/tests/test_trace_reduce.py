@@ -61,6 +61,44 @@ def small_trace():
     ]
 
 
+def _session_of(arm, device_shift_us=0):
+    """``small_trace`` cut to the profiler session of one arm (the dense
+    arm's events are those from 3000 us on), its device lane moved by
+    ``device_shift_us`` against the host's annotations."""
+    mine = [e for e in small_trace() if e["ph"] == "M"
+            or (e["ts"] >= 3000) == (arm == "dense")]
+    return [dict(e, ts=e["ts"] + device_shift_us)
+            if e["ph"] == "X" and e["pid"] == 1 else e for e in mine]
+
+
+def test_a_session_of_one_arm_gives_it_every_device_op():
+    """Read on the chip (PR 38): the device lane 0.78 s ahead of the host
+    annotations in an arm's own session. The segment's bounds then hold
+    part of the ops; the arm is alone in its session and takes them all."""
+    want = tr.split_arms(small_trace(), {"dgc": 1, "dense": 1})
+    for arm, shift in (("dgc", -400), ("dense", -400), ("dgc", 1500)):
+        got = tr.split_arms(_session_of(arm, shift), {arm: 1})[arm]
+        assert tr.phase_table(got) == tr.phase_table(want[arm])
+        assert got.chips[0].busy_s == pytest.approx(want[arm].chips[0].busy_s)
+        assert tr.idle_share(got.chips[0]) == pytest.approx(
+            tr.idle_share(want[arm].chips[0]))
+
+
+def test_a_session_of_two_arms_off_the_hosts_clock_is_an_error():
+    """Two arms are told apart by the host's segments alone: ops that
+    start outside both, with more than a hundredth of the device time, are
+    refused, not dropped; a stray sliver is not."""
+    shifted = [dict(e, ts=e["ts"] - 400)
+               if e["ph"] == "X" and e["pid"] == 1 else e
+               for e in small_trace()]
+    with pytest.raises(tr.TraceError, match="not on the host annotations' "
+                                            "clock"):
+        tr.split_arms(shifted, {"dgc": 1, "dense": 1})
+    sliver = small_trace() + [_op(1, "copy.0", 2500, 10, "x", "copy")]
+    arms = tr.split_arms(sliver, {"dgc": 1, "dense": 1})
+    assert tr.phase_table(arms["dgc"])["total_ms"] == pytest.approx(1.05)
+
+
 def test_self_time_takes_nested_ops_out():
     arms = tr.split_arms(small_trace(), {"dgc": 1, "dense": 1})
     ops = {o.name: o for o in arms["dgc"].chips[0].ops}
@@ -127,7 +165,7 @@ def test_export_at_the_cap_is_an_error(tmp_path, monkeypatch):
 
 def _view(events, steps, paired=None):
     arms = tr.split_arms(events, steps)
-    return {"arms": arms,
+    return {"arms": arms, "steps": dict(steps), "setup_spans": {},
             "tables": {n: tr.phase_table(a) for n, a in arms.items()},
             "paired": paired or {},
             "engine": {"T": 819_000, "total": 900_000, "payload_size": 800,
@@ -160,8 +198,8 @@ def test_readers_on_the_small_trace():
 
 
 def test_readers_return_nothing_when_there_is_nothing_to_read():
-    view = {"arms": {}, "tables": {}, "paired": {}, "engine": None,
-            "peaks": {}}
+    view = {"arms": {}, "steps": {}, "setup_spans": {}, "tables": {},
+            "paired": {}, "engine": None, "peaks": {}}
     for entry in cells.load_benchmark()["per_layer"]:
         assert cells.load_reader(entry["name"])(view, {}, None) is None
 
@@ -206,16 +244,23 @@ def test_result_assembly_from_a_traced_measurement():
     from benchmark import run
     # a profiler session for the arms that shared the chip: here one
     m = {"traced": [{"events": small_trace(),
-                     "steps": {"dgc": 1, "dense": 1}}], "engine": None}
+                     "steps": {"dgc": 1, "dense": 1}}], "engine": None,
+         "setup_spans": {"input.batch": [0.004]}}
     view = run.trace_view(m, {"dgc_minus_dense_ms": {"median": 0.5}},
                           "TPU v5 lite")
     assert view["engine"] is None and set(view["arms"]) == {"dgc", "dense"}
-    # and one each where the arms came one after the other
+    # and one each where the arms came one after the other: a session of
+    # one arm holds that arm's device ops only
     apart = run.trace_view(
-        {"traced": [{"events": small_trace(), "steps": {arm: 1}}
-                    for arm in ("dgc", "dense")], "engine": None},
+        {"traced": [{"events": _session_of(arm), "steps": {arm: 1}}
+                    for arm in ("dgc", "dense")], "engine": None,
+         "setup_spans": {}},
         {}, "TPU v5 lite")
     assert apart["tables"] == view["tables"]
+    assert apart["steps"] == view["steps"] == {"dgc": 1, "dense": 1}
+    # what needs no device lane is the same view, less the lanes
+    assert run.host_view(m, {}) == {
+        **view, "arms": {}, "tables": {}, "peaks": {}, "paired": {}}
     busy, window = run.device_busy(view)
     assert busy == pytest.approx(950e-6 + 500e-6)
     assert window == pytest.approx(1150e-6 + 500e-6)
@@ -231,8 +276,17 @@ def test_result_assembly_from_a_traced_measurement():
         bench=cells.load_benchmark(os.path.join(fixture, "BENCHMARK.json")),
         traffic_dir=os.path.join(fixture, "traffic"))
     values = run.per_layer_values(cell, view, {"dgc": {"input.next": [0.002]}})
-    assert values == {"input.wait_ms": pytest.approx(2.0),
-                      "exchange.dgc_minus_dense_ms": 0.5}
+    assert values["input.wait_ms"] == pytest.approx(2.0)
+    assert values["exchange.dgc_minus_dense_ms"] == 0.5
+    assert values["input.produce_ms"] == pytest.approx(4.0)
+    # of the repo's metrics this one-chip trace with no engine and no
+    # program record leaves some unread, and the line is refused for them
+    device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 4,
+              "memory_peak_bytes": 1, "busy_s": busy, "window_s": window}
+    with pytest.raises(SystemExit) as refusal:
+        run.refuse_a_short_line(cell, values, device, traced=True)
+    missing = [e["name"] for e in cell.per_layer if e["name"] not in values]
+    assert str(missing) in str(refusal.value) and "step.trace_s" in missing
 
 
 # ---------------------------------------------------------------------- #
