@@ -44,8 +44,8 @@ RESIDENCIES = ("both", "one")
 #: (held to the built model's ``configs.dataset`` in ``build.py``)
 DATA_KINDS = {"images": ("image_size", "num_classes"),
               "tokens": ("seq_len", "vocab_size")}
-#: what a reference module states beside ``loss_and_grads``: the limit of
-#: each number ``benchmark/model_check.py`` compares
+#: what a reference module states beside ``loss_and_grads``: the limits
+#: of ``benchmark/model_check.py``'s numbers that differ by model
 REFERENCE_LIMITS = ("LOSS_RTOL", "GRAD_RTOL", "UPDATE_RTOL",
                     "CONSERVED_RTOL")
 #: what a configuration file may state as ``matmul_precision``: the names
@@ -305,7 +305,7 @@ def load_reference(path: str):
     """The plain reference of a configuration's model: the module at
     ``path`` (relative to the repo's root) with ``loss_and_grads(params,
     inputs, labels) -> (loss, grads)`` and the limits of
-    ``benchmark/model_check.py``'s four numbers; ``ROW_BLOCK`` where its
+    ``benchmark/model_check.py``'s numbers; ``ROW_BLOCK`` where its
     loss is a mean over rows and it is to be called on that many at a
     time."""
     full = os.path.join(ROOT, path)

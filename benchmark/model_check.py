@@ -5,45 +5,100 @@ What is compared is what the window then drives: set-up builds each arm's
 compiled step and state once, drives them from the seed through three
 dispatches (``run.py``: the first and its ``SOLO_WARMUP_STEPS``) of the
 window's own call (``ArmRun.dispatch``) on the cell's own batch under the
-cell's mesh, and hands the same objects to
-the window. A ``Follower`` copies what the follow will read of the arm's
-state (and no more) before the first dispatch and after each one, into
-files under the process's temporary directory, so that the host holds
-none of it through the window; nothing else of the program is read.
-After the window has closed, the device peak has been read and the arms'
-states are freed, the reference follows, and reads the files back a piece
-of a tensor at a time:
+cell's mesh, and hands the same objects to the window. A ``Follower``
+copies what the follow will read of the arm's state (and no more) before
+the first dispatch and after each of the first ``FOLLOWED`` (two: the first
+runs from empty memory and an empty buffer, the second with them, and a
+third is of the second's kind), into files under the process's temporary
+directory, so that the host holds none of it through the window; nothing
+else of the program is read. After the window has closed, the device peak
+has been read and the arms' states are freed, the reference follows, and
+reads the files back a piece of a tensor at a time.
 
-``dense`` arm — an independent trajectory: the configuration's
-``loss_and_grads`` (plain ``jax.numpy``, float32, matmuls at ``highest``)
-and a plain SGD update written here (torch semantics: ``d = g + wd*p``,
-``buf = m*buf + d``, nesterov ``d + m*buf``), from the seed's weights
-through every followed step. Compared: every step's loss; the first
-gradient as the optimizer got it, worked out from the state after one
-step (its momentum buffer, or the parameters' change where there is no
-momentum); the norm of the parameters' change after all followed steps.
+EVERY followed step of EITHER arm is taken from the program's own state
+before it: the reference's ``loss_and_grads`` (plain ``jax.numpy``,
+float32, matmuls at ``highest``) is only ever called at parameters the
+program held. An independent trajectory from the seed's weights would
+carry one float32 rounding of its first step through every later forward
+and backward pass, and a discontinuous layer (a top-k router) or a step
+that is not yet stable multiplies it: a limit on such a number is loose
+enough to mean nothing or refuses sound runs on some seeds (PERF.md
+section 6, PR 40).
 
-``dgc`` arm — which coordinates DGC sends is the engine's (approximate)
-choice, so no independent trajectory exists. Each followed step is held
-to DGC's conservation instead, anchored at the program's own state before
-that step: what reached the parameters (their change over the learning
-rate, less the weight-decay term) plus what stayed behind (the workers'
-mean residual velocity) must equal the reference's momentum correction
-(``benchmark/reference.py``) of the workers' mean memory with the
-reference's gradient at those parameters. At the first step that is the
-gradient itself. Compared: the loss at the first step of every dispatch,
-and the conserved velocity of every step.
+``dense`` arm, every followed step ``d``: the reference's loss and
+gradient ``g`` at the program's parameters ``p_d``, and a plain SGD rule
+written here (torch semantics: ``s = g + wd*p``, ``buf = m*buf + s``,
+nesterov ``s + m*buf``; the first step's buffer is ``s`` itself) fed the
+program's own momentum buffer ``b_d``, in float32 as an SGD forms it, so
+that the rounding of the sum is common to both. Compared, worst step and
+worst tensor: the loss; the gradient as the optimizer got it, worked out
+from the buffers round the step (``b_{d+1} - m*b_d - wd*p_d``; from the
+parameters' change where there is no momentum); the norm of the
+parameters' change ``p_{d+1} - p_d`` against the rule's.
+
+``dgc`` arm, every followed step: which coordinates DGC sends is the
+engine's (approximate) choice, so the step is held to DGC's conservation,
+split by the transmit record the step leaves (``memory.sent_bits``, a
+worker's): the reference's momentum correction
+(``benchmark/reference.py``) ``c`` of the workers' mean memory before the
+step with the reference's gradient at those parameters is, coordinate by
+coordinate, what stayed behind (the workers' mean velocity after the step)
+plus what reached the parameters.
+
+* *what stayed* (``conserved_rel_err``): on the coordinates NO worker
+  sent, the workers' mean velocity after the step against ``c``. Both
+  are state; nothing is divided by the learning rate, so the number
+  reads the step's precision as the dense arm's gradient does.
+* *what reached the parameters* (``unexplained_coords``, a count, limit
+  0): over EVERY coordinate, the float64 prediction ``p - lr * ((c - the
+  velocity left) + dgc_sgd's weight-decay term)`` against the program's
+  next parameters. A coordinate is explained when the two differ by no
+  more than ``APPLIED_ULPS`` float32 ulps of the parameter (the final
+  add rounds to half of one) plus ``lr * COORD_FACTOR * GRAD_RTOL *
+  max(|c|, the largest coordinate of the tensor's gradient)``: room for
+  the float32 gradient's own error at ONE coordinate. A coordinate is a
+  sum of many terms that may cancel (an embedding row's over a frequent
+  token's occurrences), and its error is of the size of the terms, which
+  its own value does not show and the tensor's largest coordinate does.
+  WHAT THE COUNT SEES: a payload entry ``v`` (a worker's, so ``v /
+  world`` of the mean) that is dropped, doubled or applied beside its
+  index leaves the parameter ``lr * |v| / world`` from the prediction,
+  and is counted where that exceeds the allowance: ``|v| / world >
+  APPLIED_ULPS * ulp(p) / lr + COORD_FACTOR * GRAD_RTOL * max(|c|,
+  largest)``. At a language model's learning rate the first term alone
+  is 3.7e-7 (2 ulps of a parameter of 0.02 over 1e-2). The smallest entry
+  the two followed steps send read 1.0e-4 and 2.7e-4 at ``wide_moe``
+  (505M parameters), 270 times that, and zeroed before apply it was
+  counted; a later step of a trained model, or a model whose gradients
+  are smaller, sends entries under it. So the count sees a step's large
+  entries (and any unsent coordinate that moved by more than its weight
+  decay) and is not held to see its small ones; those are the exchange
+  check's, whose ``exchange.unconserved_coords`` compares the exchange's
+  own output exactly, entry by entry (``benchmark/check.py``).
+  ``most_share`` in the event is the most of the gradient's share any
+  coordinate used, in the units of ``COORD_FACTOR`` (a sound run stays
+  under it: 0.017 at ``wide_lm``, 0.23 at ``wide_moe``; with no share at
+  all the farthest coordinate of a 505M-parameter run lies 8e32 ulps out,
+  a parameter at 0 having no ulp to speak of), beside ``most_ulps``, the
+  farthest coordinate's ulps with its share taken off.
+
+Compared as well: the loss at the first step of every followed dispatch.
 
 A loop of kind ``scan`` runs ``k`` steps in a dispatch and leaves no
-state after one step: there the losses and the dense arm's parameter
-change are compared, and no gradient.
+state after one step: there the reference runs the ``k`` steps of a
+dispatch from the program's state at its start, and the losses and the
+dense arm's parameter change over the dispatch are compared, no gradient.
 
 Tensor by tensor, an error is the norm of the difference over the larger
 of the reference's norm of that tensor and of the median tensor (some
-gradients are all but zero); the gap between the two norms can be no
-larger. The reference module states the four limits with the readings
-each was set from: ``LOSS_RTOL``, ``GRAD_RTOL``, ``UPDATE_RTOL``,
-``CONSERVED_RTOL``.
+gradients are all but zero, an unreached expert's exactly); the gap
+between the two norms can be no larger. The reference module states the
+limits that differ by model, with the readings each was set from:
+``LOSS_RTOL``, ``GRAD_RTOL`` and ``CONSERVED_RTOL`` (the step's
+precision), ``UPDATE_RTOL`` (the optimizer's rule). The count's allowance
+(the exchange's bookkeeping) is ``APPLIED_ULPS`` and ``COORD_FACTOR``
+here: the first is the add's and no model's, the second scales the
+module's own ``GRAD_RTOL``.
 """
 
 import math
@@ -84,16 +139,45 @@ class _File(NamedTuple):
         return self.read(w * n, (w + 1) * n).reshape(self.shape[1:])
 
 
+#: dispatches of an arm a follow covers: the first, from empty memory and an
+#: empty momentum buffer, and the second, with them; a third is of the
+#: second's kind, and each costs the followers' files 20 B a parameter
+FOLLOWED = 2
+#: float32 ulps of a parameter its next value may lie from the float64
+#: prediction before the coordinate counts as unexplained: the step's final
+#: add rounds to half an ulp, and the prediction's own terms (float32
+#: products by the learning rate and the weight decay) to less than
+#: another; every sound run of the four fixtures reads 0.30 to 0.50 for the
+#: farthest coordinate. The same for every model: it is the add's
+APPLIED_ULPS = 2.0
+#: the gradient's share of that allowance at one coordinate, in units of
+#: ``GRAD_RTOL`` times the larger of the coordinate and the tensor's
+#: largest. No derivation gives the 8. ``GRAD_RTOL`` bounds the NORM of a
+#: tensor's error and says nothing of one coordinate: as first written (6,
+#: the largest of 10^8 normal errors in standard deviations, times the
+#: tensor's ROOT MEAN SQUARE) two of the first three sound seeds at
+#: ``wide_moe`` counted embedding coordinates 2.1 and 4.8 ulps outside,
+#: because an embedding's mean square is its unused rows' and its errors
+#: sit in the used ones. With the tensor's largest coordinate in its place
+#: (323 times the rms there) and 8 for 6, every sound run since counts 0:
+#: those two seeds again and thirteen fresh ones on the chip, 38 on the
+#: CPU. The form was fitted to two failures, so only the fresh seeds speak
+#: for it. What it buys is paid in sight: see the module docstring, "what
+#: the count sees"
+COORD_FACTOR = 8.0
+
+
 class Follower:
     """What a follow reads of one arm's state round its first dispatches,
     in files of its own directory under the temporary one until
     ``compare`` reads them back. ``snapshots``: how many there will be,
-    one before the first dispatch and one after each."""
+    one before the first dispatch and one after each followed one;
+    further calls of ``snapshot`` keep nothing."""
 
-    def __init__(self, cell, arm, snapshots: int):
+    def __init__(self, cell, arm):
         ref = cell.config["reference"]
         self.reference = None if ref is None else cells.load_reference(ref)
-        self.arm, self.snapshots = arm, snapshots
+        self.arm, self.snapshots = arm, FOLLOWED + 1
         # steps in a dispatch: a loop of kind ``scan`` leaves no state
         # after one step, so no gradient is read there
         self.k = cell.traffic["k"] if cell.traffic["loop"] == "scan" else 1
@@ -104,20 +188,19 @@ class Follower:
         """The parts of the state the follow reads of snapshot ``index``.
         ``_follow_dgc``: the parameters and the engine's memory round
         every step (the parameters before each dispatch where ``k`` steps
-        run in one). ``_follow_dense``: the parameters before the first
-        dispatch and after the last, and after the first the momentum
-        buffer, which holds the first gradient (without momentum the
-        parameters do)."""
+        run in one). ``_follow_dense``: the parameters round every
+        dispatch; the momentum buffer before every dispatch but the first
+        (it is empty there) and, where a dispatch is one step, after the
+        last, which holds that step's gradient."""
         last = self.snapshots - 1
         if self.arm.name == "dgc":
             if self.k == 1:
                 return ("params", "memory")
             return ("params",) if index < last else ()
-        parts = ["params"] if index in (0, last) else []
-        if self.k == 1 and index == 1:
-            parts.append("momentum" if self.arm.recipe["momentum"]
-                         else "params")
-        return tuple(dict.fromkeys(parts))
+        if (self.arm.recipe["momentum"] and index > 0
+                and (self.k == 1 or index < last)):
+            return ("params", "momentum")
+        return ("params",)
 
     @staticmethod
     def _arrays(state, part: str) -> Dict[str, Any]:
@@ -141,8 +224,9 @@ class Follower:
     def snapshot(self, run):
         """Write what the follow reads of ``run``'s state to files, an
         array at a time, and keep its last dispatch's losses; a
-        configuration without a reference copies nothing."""
-        if self.reference is None:
+        configuration without a reference copies nothing, and neither
+        does a dispatch after the last followed one."""
+        if self.reference is None or len(self.snaps) == self.snapshots:
             return
         if self._dir is None:
             self._dir = tempfile.mkdtemp(prefix="dgc_bench_follow_")
@@ -156,6 +240,14 @@ class Follower:
         snap["losses"] = (jax.device_get(run.losses[-1]) if run.losses
                           else None)
         self.snaps.append(snap)
+
+    def written_bytes(self) -> int:
+        """Bytes of the files that are there: what ``kept_bytes`` foretold,
+        read back from the directory (the ``model_check`` event's
+        ``followers_bytes``)."""
+        if self._dir is None:
+            return 0
+        return sum(entry.stat().st_size for entry in os.scandir(self._dir))
 
     def close(self):
         """Remove the files."""
@@ -185,7 +277,8 @@ def compare(cell, followers: Dict[str, Follower], batch) -> Dict[str, Any]:
     ref = next(iter(followers.values())).reference
     limits = {"loss_rel_err": ref.LOSS_RTOL, "grad_rel_err": ref.GRAD_RTOL,
               "update_norm_gap": ref.UPDATE_RTOL,
-              "conserved_rel_err": ref.CONSERVED_RTOL}
+              "conserved_rel_err": ref.CONSERVED_RTOL,
+              "unexplained_coords": 0}
     ok = all(np.isfinite(arm[key]["max"]) and arm[key]["max"] <= limit
              for arm in arms.values() for key, limit in limits.items()
              if key in arm)
@@ -231,103 +324,163 @@ def _loss_and_grads(reference):
 #: copies are of a piece (16 MB), not of a tensor (1 GB of a 504M-parameter
 #: model's) and never of the whole model
 PIECE = 1 << 21
+#: elements of a piece the arithmetic runs over at a time. A follow makes
+#: some forty numpy passes over every coordinate, each into a temporary of
+#: its own; a temporary of a whole piece (8 or 16 MB) is fresh memory from
+#: the system every time, and its page faults cost 3.6 times the arithmetic
+#: on the chip's host (57 ns a coordinate against 15.7 in blocks, my host
+#: probe, PR 40), where a block's (256 or 512 KB) comes off the heap's free
+#: list and stays in the core's cache. A piece stays what is READ at a
+#: time, from a file or off the device
+BLOCK = 1 << 16
 
 
-def _by_tensor(tree) -> Dict[str, np.ndarray]:
-    """The tree's arrays on the host, by name, each in C order: the TPU
-    hands a matrix back column-major, and to flatten that is a copy of
-    the whole of it for every piece."""
-    return {name: np.ascontiguousarray(a) for name, a
-            in named_flatten(jax.device_get(tree))[0].items()}
+def _flat(lay, tree) -> np.ndarray:
+    """The tree as the layout's flat [P] array on the host, packed on the
+    device by the program's own ``ParamLayout.flatten``: the TPU hands a
+    MATRIX back column-major, and to bring a model's back tensor by tensor
+    is a copy of each into C order (27 s of the 160 a 504M-parameter
+    follow took, PERF.md section 6, PR 40)."""
+    return np.asarray(jax.device_get(jax.jit(lay.flatten)(tree)))
 
 
 def _pieces(lay) -> List[Tuple[str, int, int]]:
-    """(tensor's name, lo, hi): every tensor of the layout, in the order
-    ``_by_tensor`` names them, its elements cut into runs of ``PIECE``."""
+    """(tensor's name, lo, hi): every tensor of the layout, in the tree's
+    order, its elements cut into runs of ``PIECE``."""
     names = named_flatten(jax.eval_shape(
         lay.unflatten, jax.ShapeDtypeStruct((lay.total,), np.float32)))[0]
     return [(n, lo, min(lo + PIECE, lay.sizes[n])) for n in names
             for lo in range(0, max(lay.sizes[n], 1), PIECE)]
 
 
-def _cut(by_name, piece) -> np.ndarray:
-    """``piece`` of the flattened tensor it names."""
-    name, lo, hi = piece
-    return np.reshape(by_name[name], -1)[lo:hi]
+def _stored(lay, flat) -> Callable[[Tuple[str, int, int]], np.ndarray]:
+    """Reads a piece of a flat [P] array: one on the host, or the
+    ``_File`` a ``Follower`` wrote, read when it is asked for."""
+    cut = flat.read if isinstance(flat, _File) else (
+        lambda lo, hi: flat[lo:hi])
 
-
-def _stored(lay, file: _File) -> Callable[[Tuple[str, int, int]], np.ndarray]:
-    """Reads a piece of the flat array a ``Follower`` wrote to ``file``,
-    when it is asked for."""
     def read(piece):
         name, lo, hi = piece
-        return file.read(lay.offsets[name] + lo, lay.offsets[name] + hi)
+        return cut(lay.offsets[name] + lo, lay.offsets[name] + hi)
 
     return read
 
 
 def _same_tensors(grads, pieces):
+    """``grads``: the reference's gradients, a tree like the parameters'."""
+    got = set(named_flatten(grads)[0])
     names = {name for name, _, _ in pieces}
-    if set(grads) != names:
+    if got != names:
         raise cells.CellError(
-            f"the reference returns gradients for {sorted(grads)}, the model "
+            f"the reference returns gradients for {sorted(got)}, the model "
             f"has {sorted(names)}")
+
+
+def _worst_step(steps: List[Dict[str, Any]]) -> Dict[str, Any]:
+    """Of one number read at every followed step, the step that reads
+    worst, with every step's reading beside it."""
+    worst = max(range(len(steps)), key=lambda d: _nan_first(steps[d]["max"]))
+    return {**steps[worst], "step": worst,
+            "by_step": [step["max"] for step in steps]}
 
 
 def _follow_dense(f: Follower, batch_at):
     lay, recipe, snaps, k = f.arm.setup.layout, f.arm.recipe, f.snaps, f.k
-    steps = k * (len(snaps) - 1)
     pieces = _pieces(lay)
-    p0 = _stored(lay, snaps[0]["params"])
     decayed = _decayed(recipe, [name for name, _, _ in pieces])
-    wd, m = recipe["weight_decay"], recipe["momentum"]
+    wd, m, damp = (recipe["weight_decay"], recipe["momentum"],
+                   recipe["dampening"])
     grad = _loss_and_grads(f.reference)
 
-    # the reference's own trajectory, float32 on the default device
-    params = lay.unflatten(snaps[0]["params"].whole())
-    buf, ref_losses, g0 = None, [], None
-    for t in range(steps):
-        loss, g = grad(params, *batch_at(t % k))
-        ref_losses.append(float(loss))
-        if t == 0:
-            g0 = _by_tensor(g)
-            _same_tensors(g0, pieces)
-        d = _named_map(lambda n, g_, p: g_ + wd * p if decayed[n] else g_,
-                       g, params)
-        if m:
-            buf = d if buf is None else jax.tree.map(
-                lambda b, d_: m * b + (1 - recipe["dampening"]) * d_, buf, d)
-            d = (jax.tree.map(lambda d_, b: d_ + m * b, d, buf)
-                 if recipe["nesterov"] else buf)
-        lr = float(recipe["lr"](t))
-        params = jax.tree.map(lambda p, d_: p - lr * d_, params, d)
-    p_ref = _by_tensor(params)
+    ref_losses, gradients, changes = [], [], []
+    for d in range(len(snaps) - 1):
+        before, after = snaps[d], snaps[d + 1]
+        # the rule from the program's own state before the dispatch,
+        # float32 on the default device. The first step's buffer is the
+        # step itself (torch's clone), not a decayed zero. What was read
+        # whole for it stays on the host for the pieces below: a file is
+        # read once (the host holds 8 B a parameter less here than in the
+        # dgc arm's follow)
+        p_host = before["params"].whole()
+        b_host = before["momentum"].whole() if m and d else None
+        params = lay.unflatten(p_host)
+        buf = None if b_host is None else lay.unflatten(b_host)
+        for i in range(k):
+            loss, g = grad(params, *batch_at(i))
+            ref_losses.append(float(loss))
+            s = _named_map(lambda n, g_, p: g_ + wd * p if decayed[n] else g_,
+                           g, params)
+            if m:
+                buf = s if buf is None else jax.tree.map(
+                    lambda b, s_: m * b + (1 - damp) * s_, buf, s)
+                s = (jax.tree.map(lambda s_, b: s_ + m * b, s, buf)
+                     if recipe["nesterov"] else buf)
+            lr = float(recipe["lr"](d * k + i))
+            params = jax.tree.map(lambda p, s_: p - lr * s_, params, s)
+        p_ref = _stored(lay, _flat(lay, params))
+        if k == 1:
+            _same_tensors(g, pieces)
+            g = _stored(lay, _flat(lay, g))
+        del params, buf, s
+        p, p_next = _stored(lay, p_host), _stored(lay, after["params"])
+        b = None if b_host is None else _stored(lay, b_host)
+        b_next = _stored(lay, after["momentum"]) if m and k == 1 else None
+
+        def gradient_and_change(piece):
+            """The ``_squares`` of the step's gradient as the optimizer
+            got it (from the momentum buffers round the step, or from
+            the parameters' change where there is no momentum; none
+            where ``k`` steps ran) and of the parameters' change, each
+            against the reference's, over one reading of the piece."""
+            here, after_, want = p(piece), p_next(piece), p_ref(piece)
+            buf_ = None if b is None else b(piece)
+            buf_next = None if b_next is None else b_next(piece)
+            g_ = g(piece) if k == 1 else None
+            grads, moved = _Squares(), _Squares()
+            for at in _blocks(here.size):
+                h = here[at].astype(np.float64)
+                moved.add(after_[at] - h, want[at] - h)
+                if k != 1:
+                    continue
+                if not m:
+                    s = (h - after_[at]) / lr
+                elif buf_ is None:
+                    s = buf_next[at].astype(np.float64)
+                else:
+                    s = buf_next[at] - m * buf_[at].astype(np.float64)
+                    if damp:
+                        s /= 1 - damp
+                if decayed[piece[0]]:
+                    h *= wd
+                    s -= h
+                grads.add(s, g_[at])
+            return piece, grads.sums, moved.sums
+
+        both = list(map(gradient_and_change, pieces))
+        if k == 1:
+            gradients.append(_worst((piece, grads)
+                                    for piece, grads, _ in both))
+        changes.append(_summary(_leafwise(
+            (piece, moved) for piece, _, moved in both)["norm_gap"]))
 
     losses = np.concatenate([np.ravel(s["losses"]) for s in snaps[1:]])
-    out = {"steps": steps, "loss_rel_err": _losses(losses, ref_losses)}
-    if k == 1:
-        # the first gradient as the optimizer got it: its momentum buffer
-        # after one step, or the parameters' change where there is none
-        after_one = _stored(lay, snaps[1]["momentum" if m else "params"])
-        lr0 = float(recipe["lr"](0))
-
-        def first_gradient():
-            for piece in pieces:
-                p = p0(piece)
-                d1 = after_one(piece) if m else (p - after_one(piece)) / lr0
-                yield (piece, d1 - (wd * p if decayed[piece[0]] else 0.0),
-                       _cut(g0, piece))
-
-        out["grad_rel_err"] = _worst(first_gradient())
-    p_end = _stored(lay, snaps[-1]["params"])
-
-    def change():
-        for piece in pieces:
-            p = p0(piece)
-            yield piece, p_end(piece) - p, _cut(p_ref, piece) - p
-
-    out["update_norm_gap"] = _summary(_leafwise(change())["norm_gap"])
+    out = {"steps": k * (len(snaps) - 1),
+           "loss_rel_err": _losses(losses, ref_losses),
+           "update_norm_gap": _worst_step(changes)}
+    if gradients:
+        out["grad_rel_err"] = _worst_step(gradients)
     return out
+
+
+def sent_coordinates(bits: np.ndarray, total: int) -> np.ndarray:
+    """[total] bool from a worker's packed transmit record (the engine's
+    ``memory.sent_bits``, int32 words): coordinate ``c`` is bit ``(c //
+    128) % 32`` of word ``(c // 4096) * 128 + c % 128``, set where the
+    step sent it."""
+    words = np.ascontiguousarray(bits).astype("<i4").view(np.uint8)
+    by_lane = np.unpackbits(words.reshape(-1, 128, 4), axis=-1,
+                            bitorder="little")          # [groups, lane, bit]
+    return by_lane.transpose(0, 2, 1).reshape(-1)[:total].astype(bool)
 
 
 def _follow_dgc(f: Follower, batch_at):
@@ -343,32 +496,41 @@ def _follow_dgc(f: Follower, batch_at):
     wd, m_opt = recipe["weight_decay"], recipe["momentum"]
     pieces = _pieces(lay)
     decayed = _decayed(recipe, [name for name, _, _ in pieces])
+    grad_rtol = f.reference.GRAD_RTOL
+    T = int(engine.T)
 
-    def mean_memory(snap):
-        """Reads a piece of the workers' mean canonical momentum or
-        velocity: each worker's view in float32 as the engine gives it,
-        the mean taken in float64 when a piece is asked for."""
+    def memory_views(snap):
+        """Reads a piece of every worker's canonical momentum or velocity,
+        float32 as the engine gives them: a list, a worker each."""
         fulls = []
         for w in range(arm.world):
             full = jax.device_get(engine.memory_full({
                 name[len("memory."):]: file.row(w)
                 for name, file in snap.items()
                 if name.startswith("memory.")}))
-            fulls.append({key: _by_tensor(lay.unflatten(v))
-                          for key, v in full.items()})
+            fulls.append({key: _stored(lay, np.asarray(flat))
+                          for key, flat in full.items()})
+        return lambda key, piece: [full[key](piece) for full in fulls]
 
-        def mean(key, piece):
-            total = 0.0
-            for full in fulls:
-                total = total + np.asarray(_cut(full[key], piece),
-                                           np.float64)
-            return total / arm.world
+    def exchanged_by_any(snap):
+        """Reads a piece of: which coordinates the step that left ``snap``
+        exchanged, on any worker. Sparsely, by the workers' transmit
+        records; the dense tail [T, P) and a bucket planned dense are
+        all-reduced whole, and leave no velocity behind."""
+        sent = np.ones((lay.total,), bool)
+        sent[:T] = False
+        for w in range(arm.world if T else 0):
+            sent[:T] |= sent_coordinates(snap["memory.sent_bits"].row(w), T)
+        for bucket, regime in zip(engine.buckets, engine.regimes):
+            if regime == "dense":
+                sent[bucket.base:bucket.base + bucket.rows * bucket.cols] \
+                    = True
+        return lambda piece: sent[lay.offsets[piece[0]] + piece[1]:
+                                  lay.offsets[piece[0]] + piece[2]]
 
-        return mean
-
-    losses, ref_losses, conserved = [], [], []
+    losses, ref_losses, stayed, unexplained = [], [], [], []
     buf = {}     # dgc_sgd's momentum buffer: all a follow carries onward
-    mem_next = mean_memory(snaps[0]) if k == 1 else None
+    mem_next = memory_views(snaps[0]) if k == 1 else None
     for d in range(len(snaps) - 1):
         before, after = snaps[d], snaps[d + 1]
         loss, g = grad(lay.unflatten(before["params"].whole()),
@@ -377,41 +539,113 @@ def _follow_dgc(f: Follower, batch_at):
         ref_losses.append(float(loss))
         if k != 1:
             continue
-        g = _by_tensor(g)
         _same_tensors(g, pieces)
+        g = _flat(lay, g)
+        largest = {name: _largest(
+            g[lay.offsets[name]:lay.offsets[name] + lay.sizes[name]])
+            for name in dict.fromkeys(n for n, _, _ in pieces)}
+        g = _stored(lay, g)
         p, p_next = (_stored(lay, before["params"]),
                      _stored(lay, after["params"]))
         # the step before's is dropped before this step's is made: three
         # of them at once are 24 bytes a parameter
         mem, mem_next = mem_next, None
-        mem_next = mean_memory(after)
+        mem_next = memory_views(after)
+        sent = exchanged_by_any(after)
         lr = float(recipe["lr"](d))
+        # per tensor: the coordinates outside the allowance, how many
+        # ulps the farthest one lies beyond the gradient's share of it,
+        # the most of that share any coordinate used, in the units of
+        # ``COORD_FACTOR``, and the coordinates that stayed
+        outside, farthest, shares, stays = (
+            dict.fromkeys(largest, 0), dict.fromkeys(largest, 0.0),
+            dict.fromkeys(largest, 0.0), dict.fromkeys(largest, 0))
+        share = lr * COORD_FACTOR * grad_rtol
+        first = d == 0
 
-        def reached_or_stayed_and_compensated():
-            for piece in pieces:
-                held, here = decayed[piece[0]], p(piece)
+        def stayed_and_reached(piece):
+            name = piece[0]
+            held, here, after_ = decayed[name], p(piece), p_next(piece)
+            g_, unsent = g(piece), ~sent(piece)
+            u, v = mem("momentums", piece), mem("velocities", piece)
+            v_next = mem_next("velocities", piece)
+            if wd and m_opt and first:
+                buf[piece] = np.empty(here.shape, np.float32)
+            squares = _Squares()
+            for at in _blocks(here.size):
+                h, a = here[at], after_[at]
                 # dgc_sgd: momentum runs over the weight-decay term alone
-                term = wd * here if held else 0.0 * here
+                term = wd * h if held else 0.0 * h
                 if wd and m_opt:
-                    buf[piece] = term if piece not in buf else (
-                        m_opt * buf[piece]
-                        + (1 - recipe["dampening"]) * term)
+                    kept = buf[piece][at]
+                    kept[:] = term if first else (
+                        m_opt * kept + (1 - recipe["dampening"]) * term)
                     if held:
-                        term = (term + m_opt * buf[piece]
-                                if recipe["nesterov"] else buf[piece])
-                applied = (here.astype(np.float64) - p_next(piece)) / lr \
-                    - term
+                        term = (term + m_opt * kept
+                                if recipe["nesterov"] else kept)
                 _, want = exchange_reference.momentum_correction(
-                    mem("momentums", piece), mem("velocities", piece),
-                    _cut(g, piece), mem_cfg.momentum, mem_cfg.nesterov)
-                yield piece, applied + mem_next("velocities", piece), want
+                    _mean([x[at] for x in u]), _mean([x[at] for x in v]),
+                    g_[at], mem_cfg.momentum, mem_cfg.nesterov)
+                left = _mean([x[at] for x in v_next])
+                # what stayed, where no worker sent
+                stay = unsent[at]
+                stays[name] += int(np.sum(stay))
+                squares.add(left * stay, want * stay)
+                # what reached the parameters, in parameter space
+                over = np.subtract(want, left, dtype=np.float64)
+                over += term
+                over *= lr
+                np.subtract(h, over, out=over)              # the prediction
+                np.subtract(over, a, out=over)
+                np.abs(over, out=over)
+                ulp = np.spacing(np.maximum(np.abs(h), np.abs(a)))
+                allowed = share * np.maximum(np.abs(want), largest[name])
+                used = np.divide(over - APPLIED_ULPS * ulp, allowed,
+                                 out=np.full(over.shape, -np.inf),
+                                 where=allowed > 0)
+                shares[name] = max(shares[name],
+                                   COORD_FACTOR * float(np.max(used)),
+                                   key=_nan_first)
+                over -= allowed
+                over /= ulp
+                # a NaN is outside
+                outside[name] += int(np.sum(~(over <= APPLIED_ULPS)))
+                farthest[name] = max(farthest[name], float(np.max(over)),
+                                     key=_nan_first)
+            return piece, squares.sums
 
-        conserved.append(_worst(reached_or_stayed_and_compensated()))
+        stayed.append({**_worst(map(stayed_and_reached, pieces)),
+                       "coords": sum(stays.values())})
+        if T and not stayed[-1]["coords"]:
+            raise cells.CellError(
+                f"model check: of the {T} coordinates of the dgc arm's "
+                f"sparse tier none stayed unsent at followed step {d}, so "
+                f"conserved_rel_err compared nothing")
+        far = max(farthest, key=lambda n: _nan_first(farthest[n]))
+        unexplained.append({"max": sum(outside.values()),
+                            "worst_tensor": max(outside, key=outside.get),
+                            "by_tensor": outside, "most_ulps": farthest[far],
+                            "most_ulps_tensor": far,
+                            "most_share": max(shares.values(),
+                                              key=_nan_first)})
     out = {"steps": k * (len(snaps) - 1),
            "loss_rel_err": _losses(losses, ref_losses)}
-    if conserved:
-        out["conserved_rel_err"] = max(
-            conserved, key=lambda c: _nan_first(c["max"]))
+    if stayed:
+        # over how many coordinates each step's was taken
+        out["conserved_rel_err"] = {
+            **_worst_step(stayed),
+            "coords": [step["coords"] for step in stayed]}
+        # a count: all the followed steps' together; the farthest
+        # coordinate of any step
+        far = max(unexplained, key=lambda step: _nan_first(step["most_ulps"]))
+        out["unexplained_coords"] = {
+            **_worst_step(unexplained),
+            "max": sum(step["max"] for step in unexplained),
+            "most_ulps": far["most_ulps"],
+            "most_ulps_tensor": far["most_ulps_tensor"],
+            "most_share": max(
+                (step["most_share"] for step in unexplained),
+                key=_nan_first)}
     return out
 
 
@@ -445,20 +679,60 @@ def _summary(by_name: Dict[str, float]) -> Dict[str, Any]:
             "by_tensor": by_name}
 
 
+def _squares(got, want) -> Tuple[float, float, float]:
+    """The squared norms of the reference's array, of the program's and
+    of their difference, in float64."""
+    want = np.asarray(want, np.float64).ravel()
+    got = np.asarray(got, np.float64).ravel()
+    return tuple(float(x.dot(x)) for x in (want, got, got - want))
+
+
+def _blocks(n: int) -> List[slice]:
+    """[0, n) in runs of ``BLOCK``."""
+    return [slice(lo, min(lo + BLOCK, n)) for lo in range(0, n, BLOCK)]
+
+
+class _Squares:
+    """The ``_squares`` of a piece, summed over its blocks."""
+
+    def __init__(self):
+        self.sums = (0.0, 0.0, 0.0)
+
+    def add(self, got, want):
+        self.sums = tuple(a + b for a, b in zip(self.sums,
+                                                _squares(got, want)))
+
+
+def _mean(arrays: List[np.ndarray]) -> np.ndarray:
+    """The workers' mean in float64, of one worker's as of four's."""
+    total = np.zeros(arrays[0].shape, np.float64)
+    for array in arrays:
+        total += array
+    total /= len(arrays)
+    return total
+
+
+def _largest(x: np.ndarray) -> float:
+    """max |x| with no copy of ``x``; 0 of an empty array, NaN of one
+    that holds a NaN."""
+    if not x.size:
+        return 0.0
+    return max(float(np.max(x)), -float(np.min(x)))
+
+
 def _leafwise(triples) -> Dict[str, Dict[str, float]]:
     """Per tensor: the norm of the difference, and the gap between the
     norms, over max(the reference's norm, its median tensor's norm).
     ``triples``: (a tensor's name or a (name, lo, hi) piece of it, the
-    program's array, the reference's), a tensor's pieces one after the
-    other; the float64 copies are of one triple at a time."""
+    program's array, the reference's) or, in place of the arrays, their
+    ``_squares``; a tensor's pieces one after the other."""
     squares: Dict[str, List[float]] = {}
-    for key, got, want in triples:
-        want = np.asarray(want, np.float64).ravel()
-        got = np.asarray(got, np.float64).ravel()
+    for key, *arrays in triples:
         sums = squares.setdefault(key[0] if isinstance(key, tuple) else key,
                                   [0.0, 0.0, 0.0])
-        for i, x in enumerate((want, got, got - want)):
-            sums[i] += float(x.dot(x))
+        for i, x in enumerate(arrays[0] if len(arrays) == 1
+                              else _squares(*arrays)):
+            sums[i] += x
     norms = {n: math.sqrt(sums[0]) for n, sums in squares.items()}
     floor = statistics.median(norms.values())
     err, gap = {}, {}

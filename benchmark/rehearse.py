@@ -6,8 +6,9 @@
 Workload names restrict ``aot`` to those cells (default: every cell).
 
 ``cpu``   tiny shapes on one CPU device through the harness's own
-          ``measure`` (an image and a token configuration; pipeline,
-          resident and scan traffic, untraced and traced): wrong paths,
+          ``measure`` (an image, a token and a routed token
+          configuration; pipeline, resident and scan traffic, untraced
+          and traced): wrong paths,
           arguments and control flow show here.
 ``mesh``  the same on a mesh of four virtual CPU devices: wrong meshes and
           sharding rules show here.
@@ -150,9 +151,11 @@ def _run_tiny_fresh(name, trace):
 
 def rehearse_cpu():
     for name in ("tiny.steady", "tiny.resident", "tiny.scan",
-                 "tiny_lm.resident", "tiny_lm.scan", "tiny_lm.one"):
+                 "tiny_lm.resident", "tiny_lm.scan", "tiny_lm.one",
+                 "tiny_moe.resident", "tiny_moe.one"):
         print(json.dumps(_run_tiny(name, trace=False)), flush=True)
-    for name in ("tiny.steady", "tiny_lm.resident", "tiny_lm.one"):
+    for name in ("tiny.steady", "tiny_lm.resident", "tiny_lm.one",
+                 "tiny_moe.resident"):
         print(json.dumps(_run_tiny(name, trace=True)), flush=True)
 
 
@@ -215,7 +218,7 @@ def aot_row(cell, topo):
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from benchmark import build, check, inputs, model_check, run
+    from benchmark import build, check, inputs, model_check
 
     mesh = build.make_mesh(cell, devices=topo.devices)
     gb = cell.chips * cell.traffic["per_chip_batch"]
@@ -251,9 +254,8 @@ def aot_row(cell, topo):
             "temp_bytes": mem.temp_size_in_bytes,
             # a snapshot before the first dispatch and after each
             # followed one
-            "follower_bytes": model_check.Follower(
-                cell, arm, snapshots=2 + run.SOLO_WARMUP_STEPS
-            ).kept_bytes(state),
+            "follower_bytes": model_check.Follower(cell, arm).kept_bytes(
+                state),
             # donated state: the outputs alias the arguments
             "program_bytes_per_chip": check.program_bytes(compiled),
             "mosaic_calls": hlo.count("tpu_custom_call"),

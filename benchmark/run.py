@@ -69,7 +69,9 @@ from benchmark.spans import Spans
 #: seeds, PR 22). Computing in bfloat16 would show as some 1e-3.
 STEP0_LOSS_RTOL = 1e-4
 #: dispatches each arm runs alone, after its first (compiling) call, before
-#: its memory peak is read; with the first, what the model check follows
+#: its memory peak is read; the model check follows the first
+#: ``model_check.FOLLOWED`` of them all, and a follower keeps nothing of a
+#: later one
 SOLO_WARMUP_STEPS = 2
 KEEP_TRACE_ENV = "DGC_BENCH_KEEP_TRACE"
 
@@ -257,8 +259,7 @@ def _measure(cell, seed, seconds, trace, devices, client_s):
                 # where the configuration has a reference of its model:
                 # what its follow reads of the state round each of these
                 # dispatches, in files
-                follow = followers[name] = model_check.Follower(
-                    cell, arm, snapshots=2 + SOLO_WARMUP_STEPS)
+                follow = followers[name] = model_check.Follower(cell, arm)
                 follow.snapshot(run)
                 # the first call compiles (or loads) the one program this
                 # arm uses; same weights, same batch, same key for every arm
@@ -291,7 +292,9 @@ def _measure(cell, seed, seconds, trace, devices, client_s):
             first_row = len(rows)
             gc.collect()
             gc.disable()
-            t_start = time.perf_counter()
+            # the collector's pass is set-up's, and has a lap like the
+            # rest of it: the laps add up to ``setup_s``
+            t_start = lap("collect")
             setup_s += t_start - since
             while True:
                 row = {}
@@ -352,6 +355,8 @@ def _measure(cell, seed, seconds, trace, devices, client_s):
         t0 = time.perf_counter()
         model = model_check.compare(cell, followers, first_batch)
         model["check_s"] = time.perf_counter() - t0
+        model["followers_bytes"] = sum(follow.written_bytes()
+                                       for follow in followers.values())
         log("model_check", **model)
     finally:
         if feed is not None:
@@ -564,10 +569,25 @@ def is_correct(m) -> bool:
                 and m["step0_ok"] and m["failed"] == 0)
 
 
+def nearness(name: str, number: float, limit: float) -> float:
+    """How near ``number`` is to ``limit``, over 1 where it is outside: a
+    floor (a name that ends in ``_floor``) is met from above, a count's
+    limit is 0, every other limit is met from below. A number that is no
+    number is outside every limit."""
+    if math.isnan(number):
+        return math.inf
+    if name.endswith("_floor"):
+        return limit / number if number > 0 else math.inf
+    if limit == 0:
+        return math.inf if number > 0 else 0.0
+    return number / limit
+
+
 def compared(m) -> Dict[str, List[float]]:
     """Every number ``is_correct`` rests on, beside its limit: [number,
-    limit]. A count's limit is 0, a floor is met from above (``fill``,
-    ``recall``), every other limit from below."""
+    limit], the number nearest its limit first (``nearness``), so one
+    outside its limit before all others: a record that keeps only the
+    first or the last so many of them keeps the one that decided."""
     out = {"step0_loss_gap": [m["step0_gap"], STEP0_LOSS_RTOL],
            "nonfinite_losses": [m["failed"], 0]}
     check = m["check"]
@@ -588,7 +608,8 @@ def compared(m) -> Dict[str, List[float]]:
         for key, limit in model["limits"].items():
             if key in numbers:
                 out[f"{arm}.{key}"] = [numbers[key]["max"], limit]
-    return out
+    return dict(sorted(out.items(),
+                       key=lambda item: -nearness(item[0], *item[1])))
 
 
 def parse_args(argv):

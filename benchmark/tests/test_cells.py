@@ -218,6 +218,11 @@ GOOD_REFERENCE = (LIMITS + "def loss_and_grads(params, inputs, labels):\n"
      "UPDATE_RTOL must be a positive float"),
     (GOOD_REFERENCE.replace("1e-3", "1"),
      "CONSERVED_RTOL must be a positive float"),
+    # what stayed is a number of the step's precision since PR 40, and a
+    # module that lacks its limit is named with the key
+    (GOOD_REFERENCE.replace("CONSERVED_RTOL = 1e-3\n", ""),
+     r"reference '.*ref\.py': CONSERVED_RTOL must be a positive float, "
+     "got None"),
 ])
 def test_reference_module_without_its_parts(tmp_path, text, message):
     with pytest.raises(cells.CellError, match=message):

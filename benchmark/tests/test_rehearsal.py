@@ -9,7 +9,7 @@ import os
 
 import pytest
 
-from benchmark import cells, rehearse, run
+from benchmark import cells, model_check, rehearse, run
 
 TRAFFIC = os.path.join(rehearse.FIXTURE, "traffic")
 RESIDENCIES = pytest.mark.parametrize("residency", cells.RESIDENCIES)
@@ -18,32 +18,49 @@ RESIDENCIES = pytest.mark.parametrize("residency", cells.RESIDENCIES)
 @pytest.mark.parametrize("cell", ["tiny.steady", "tiny.resident", "tiny.scan",
                                   "tiny.steady.x4", "tiny_lm.resident",
                                   "tiny_lm.scan", "tiny_lm.resident.x4",
-                                  "tiny_lm.one", "tiny_lm.one.x4"])
+                                  "tiny_lm.one", "tiny_lm.one.x4",
+                                  "tiny_moe.resident", "tiny_moe.one"])
 def test_measure_runs_the_cell(cell):
     out = rehearse._run_tiny(cell, trace=False)
     assert out["rounds"] >= 2 and out["steps"] > 0
     assert out["check"]["ok"] and out["check"]["world"] == (
         4 if cell.endswith(".x4") else 1)
     model = out["model_check"]
-    if cell.startswith("tiny_lm"):
-        # the timed step of both arms is held to the model's reference
+    if not cell.startswith("tiny."):
+        # the timed step of both arms is held to the model's reference,
+        # over the first two dispatches
         assert model["ok"] and set(model["arms"]) == {"dgc", "dense"}
         scan = cell == "tiny_lm.scan"
         for arm in model["arms"].values():
-            assert arm["steps"] == (9 if scan else 3)
+            assert arm["steps"] == (6 if scan else 2)
             assert arm["loss_rel_err"]["max"] <= model["limits"][
                 "loss_rel_err"]
         dgc, dense = model["arms"]["dgc"], model["arms"]["dense"]
         assert dense["update_norm_gap"]["max"] <= model["limits"][
             "update_norm_gap"]
+        assert len(dense["update_norm_gap"]["by_step"]) == 2
         # k steps in a dispatch leave no state after one step to read a
         # gradient from
         assert ("grad_rel_err" in dense) == ("conserved_rel_err" in dgc) \
-            == (not scan)
+            == ("unexplained_coords" in dgc) == (not scan)
+        # the followers' files, read back from their directory
+        assert model["followers_bytes"] > 0
         if not scan:
-            assert set(dense["grad_rel_err"]["by_tensor"]) == {
-                "embed/embedding", "gate/kernel", "up/kernel",
-                "down/kernel", "head/kernel"}
+            assert dgc["unexplained_coords"]["max"] == 0
+            assert dgc["unexplained_coords"]["most_share"] \
+                < model_check.COORD_FACTOR
+            # what stayed says over how many coordinates it was taken
+            assert min(dgc["conserved_rel_err"]["coords"]) > 0
+            # every followed step's gradient, not the first alone
+            assert len(dense["grad_rel_err"]["by_step"]) == 2
+            tensors = set(dense["grad_rel_err"]["by_tensor"])
+            if cell.startswith("tiny_lm"):
+                assert tensors == {"embed/embedding", "gate/kernel",
+                                   "up/kernel", "down/kernel", "head/kernel"}
+            else:
+                # 20 experts of three tensors, and the norm's scale: the
+                # one tensor of the engine's dense tail
+                assert len(tensors) == 64 and "norm/scale" in tensors
     else:
         assert model["ok"] and "batch statistics" in model["skipped"]
 
@@ -52,6 +69,8 @@ def test_measure_runs_the_cell(cell):
     ("tiny.resident", "program:input.get_batch"),
     ("tiny_lm.resident", "harness:input.batch"),
     ("tiny_lm.one", "harness:input.batch"),
+    ("tiny_moe.resident", "harness:input.batch"),
+    ("tiny_moe.one", "harness:input.batch"),
 ])
 def test_traced_measure_runs_the_repos_readers_on_the_cell(cell, source):
     """The harness's spans are on the trace, and every reader of the
@@ -158,9 +177,12 @@ def test_the_clients_creation_is_left_out_of_setup(monkeypatch):
     m = run.measure(rehearse.fixture_cell("tiny.resident"), seed=5,
                     seconds=0.2, trace=False, devices=jax.devices("cpu"),
                     client_s=100.0)
-    # every lap of a cell whose arms share the chip lies before its window
+    # every lap of a cell whose arms share the chip lies before its window,
+    # the collector's pass before the window among them (``collect``: it
+    # grows with the process, 0.55 s late in a whole run of these tests)
     assert m["setup_s"] + 100.0 == pytest.approx(
         sum(m["split"].values()), abs=0.5)
+    assert m["split"]["collect"] > 0.0
 
 
 def test_the_memory_law_refuses_with_its_numbers():
@@ -217,13 +239,15 @@ def test_the_memory_law_reads_the_residency():
 
 
 #: ``wide_lm`` (503,971,840 parameters, a row of 2048 tokens) compiled for
-#: a described v5e: ``rehearse.aot_row``'s numbers (PR 32)
+#: a described v5e: ``rehearse.aot_row``'s numbers (PR 32); the followers'
+#: files since PR 40, two followed dispatches: dgc 3 x (p + momentum +
+#: velocity + bits), dense p | p, buf | p, buf: 56.4 B a parameter
 WIDE_LM = {"cell": "wide_lm", "chips": 1, "check_bytes": 9944003072,
            "param_bytes": 2015887360,
            "dgc": {"argument_bytes": 8126597120, "temp_bytes": 4131106816,
-                   "follower_bytes": 24442734592},
+                   "follower_bytes": 18332050944},
            "dense": {"argument_bytes": 4031792128, "temp_bytes": 5103667200,
-                     "follower_bytes": 6047662080}}
+                     "follower_bytes": 10079436800}}
 
 
 def test_the_wide_fixture_is_forced_to_one_arm_at_a_time():
@@ -235,7 +259,8 @@ def test_the_wide_fixture_is_forced_to_one_arm_at_a_time():
     assert not both["fits"] and "17262056448" in both["law"]
     one = rehearse.memory_law({**WIDE_LM, "residency": "one"})
     assert one["needs_bytes"] == 8126597120 + 4131106816 and one["fits"]
-    assert one["disk_bytes"] == 24442734592 + 6047662080 < rehearse.TMP_BYTES
+    assert one["disk_bytes"] == 18332050944 + 10079436800 < rehearse.TMP_BYTES
+    assert one["disk_bytes"] / 503971840 == pytest.approx(56.4, abs=0.05)
     assert one["host_bytes"] == int(
         rehearse.HOST_BASELINE_BYTES
         + 7 * 2015887360) < rehearse.HOST_BYTES[1]
@@ -251,7 +276,7 @@ def test_the_wide_fixture_is_forced_to_one_arm_at_a_time():
     assert not rehearse.memory_law(full)["fits"]
     # and the files are what the fixture's followers would write
     import jax
-    from benchmark import build, model_check
+    from benchmark import build
     for residency in cells.RESIDENCIES:
         cell = rehearse.fixture_cell("wide_lm." + residency)
         assert cell.traffic["residency"] == residency
@@ -259,7 +284,7 @@ def test_the_wide_fixture_is_forced_to_one_arm_at_a_time():
         for name in ("dgc", "dense"):
             arm = build.build_arm(cell, name, mesh)
             state = jax.eval_shape(arm.init, jax.random.PRNGKey(0))
-            assert model_check.Follower(cell, arm, 4).kept_bytes(state) \
+            assert model_check.Follower(cell, arm).kept_bytes(state) \
                 == WIDE_LM[name]["follower_bytes"]
         assert cell.config["sizes"]["num_parameters"] * 4 \
             == WIDE_LM["param_bytes"]
@@ -270,7 +295,7 @@ def test_a_follower_keeps_what_its_follow_reads(tmp_path, monkeypatch):
     that the host holds paths, not arrays, until the files are removed."""
     import jax
     import numpy as np
-    from benchmark import build, model_check
+    from benchmark import build
     monkeypatch.setattr(model_check.tempfile, "tempdir", str(tmp_path))
     cell = rehearse.fixture_cell("tiny_lm.resident")
     scan = rehearse.fixture_cell("tiny_lm.scan")
@@ -278,19 +303,21 @@ def test_a_follower_keeps_what_its_follow_reads(tmp_path, monkeypatch):
     reads = {}
     for name in ("dgc", "dense"):
         arm = build.build_arm(cell, name, mesh)
-        f = model_check.Follower(cell, arm, snapshots=4)
-        reads[name] = [f.reads(i) for i in range(4)]
-        reads[name + ".scan"] = [
-            model_check.Follower(scan, arm, snapshots=4).reads(i)
-            for i in range(4)]
+        f = model_check.Follower(cell, arm)
+        assert f.snapshots == 3
+        reads[name] = [f.reads(i) for i in range(3)]
+        reads[name + ".scan"] = [model_check.Follower(scan, arm).reads(i)
+                                 for i in range(3)]
         state = build.init_state(arm, 0)
         shapes = jax.eval_shape(arm.init, jax.random.PRNGKey(0))
         run_ = run.ArmRun(arm, state, 0)
-        for _ in range(4):
+        for _ in range(4):         # the fourth is past the followed ones
             f.snapshot(run_)
+        assert len(f.snaps) == 3
         files = sorted(os.listdir(tmp_path / os.listdir(tmp_path)[0]))
         assert sum(os.path.getsize(os.path.join(f._dir, x))
-                   for x in files) == f.kept_bytes(shapes)
+                   for x in files) == f.kept_bytes(shapes) \
+            == f.written_bytes()
         assert all(isinstance(v, model_check._File) for snap in f.snaps
                    for k, v in snap.items() if k != "losses")
         np.testing.assert_array_equal(
@@ -303,16 +330,19 @@ def test_a_follower_keeps_what_its_follow_reads(tmp_path, monkeypatch):
                 np.asarray(state.memory["sent_bits"])[0])
         f.close()
         assert os.listdir(tmp_path) == []
-    every = ("params", "memory")
-    assert reads["dgc"] == [every] * 4
-    assert reads["dgc.scan"] == [("params",)] * 3 + [()]
-    assert reads["dense"] == [("params",), ("momentum",), (), ("params",)]
-    assert reads["dense.scan"] == [("params",), (), (), ("params",)]
+    every, both = ("params", "memory"), ("params", "momentum")
+    assert reads["dgc"] == [every] * 3
+    assert reads["dgc.scan"] == [("params",)] * 2 + [()]
+    # the dense arm's every step is followed from the program's own
+    # parameters and buffer (empty before the first), and the buffer
+    # after a step holds that step's gradient
+    assert reads["dense"] == [("params",), both, both]
+    assert reads["dense.scan"] == [("params",), both, ("params",)]
     # a configuration without a reference keeps nothing
     tiny = rehearse.fixture_cell("tiny.resident")
     arm = build.build_arm(tiny, "dgc", build.make_mesh(
         tiny, jax.devices("cpu")))
-    f = model_check.Follower(tiny, arm, snapshots=4)
+    f = model_check.Follower(tiny, arm)
     assert f.kept_bytes(jax.eval_shape(arm.init, jax.random.PRNGKey(0))) == 0
     f.snapshot(None)
     assert f.snaps == [] and os.listdir(tmp_path) == []
@@ -396,14 +426,67 @@ def test_a_new_token_cell_runs_and_is_correct(tmp_path):
         "exchange.over_quota_rows", "exchange.sent_outside_rows",
         "exchange.fill_floor", "exchange.recall_floor",
         "exchange.bucket_recall_floor", "dgc.loss_rel_err",
-        "dgc.conserved_rel_err", "dense.loss_rel_err", "dense.grad_rel_err",
-        "dense.update_norm_gap"}
+        "dgc.conserved_rel_err", "dgc.unexplained_coords",
+        "dense.loss_rel_err", "dense.grad_rel_err", "dense.update_norm_gap"}
     assert all(number >= limit if name.endswith("_floor")
                else number <= limit for name, (number, limit) in got.items())
     # a reference scaled by exactly 1 is the sound one: the wrapper itself
     # changes nothing
     m = _measure(_new_token_cell(tmp_path, reference_scale=1.0))
     assert run.is_correct(m)
+
+
+#: what ``compared`` holds in a cell whose configuration has no reference
+#: of its model: the benchmark's three cells (since PR 32)
+CELLS_COMPARED = [
+    "step0_loss_gap", "nonfinite_losses", "exchange.inexact_residual_coords",
+    "exchange.unconserved_coords", "exchange.over_quota_rows",
+    "exchange.sent_outside_rows", "exchange.fill_floor",
+    "exchange.recall_floor", "exchange.bucket_recall_floor"]
+
+
+def _a_cells_measurement(**check):
+    return {"step0_gap": 2e-6, "failed": 0,
+            "model_check": {"ok": True, "skipped": "no reference"},
+            "check": {"inexact_residual_coords": 0, "unconserved_coords": 0,
+                      "over_quota_rows": 0, "sent_outside_rows": 0,
+                      "fill": 0.93, "fill_floor": 0.8, "recall": 0.99,
+                      "recall_floor": 0.95, "recall_per_bucket": [0.99, 0.96],
+                      "recall_floor_per_bucket": [0.948, 0.93], **check}}
+
+
+def test_compared_lists_the_number_nearest_its_limit_first():
+    """The cells' ``compared`` holds the entries it held, every one; the
+    order is by nearness to the limit, a number outside its limit (a
+    count over 0, a floor missed, a NaN) before all others, so a record
+    that keeps the first few keeps the one that decided."""
+    got = run.compared(_a_cells_measurement())
+    assert sorted(got) == sorted(CELLS_COMPARED)
+    # floors: 0.93 / 0.96 (the bucket nearest ITS floor), 0.95 / 0.99,
+    # 0.8 / 0.93; then 2e-6 of 1e-4; the counts, all 0, as they were listed
+    assert list(got) == [
+        "exchange.bucket_recall_floor", "exchange.recall_floor",
+        "exchange.fill_floor", "step0_loss_gap", "nonfinite_losses",
+        "exchange.inexact_residual_coords", "exchange.unconserved_coords",
+        "exchange.over_quota_rows", "exchange.sent_outside_rows"]
+    assert got["exchange.bucket_recall_floor"] == [0.96, 0.93]
+    assert list(run.compared(_a_cells_measurement(unconserved_coords=3)))[0] \
+        == "exchange.unconserved_coords"
+    assert list(run.compared(_a_cells_measurement(fill=0.7)))[0] \
+        == "exchange.fill_floor"
+    m = _a_cells_measurement()
+    m["model_check"] = {"ok": False, "limits": {"grad_rel_err": 3e-6,
+                                                "unexplained_coords": 0},
+                        "arms": {"dense": {"grad_rel_err": {
+                            "max": float("nan")}},
+                            "dgc": {"unexplained_coords": {"max": 0}}}}
+    got = run.compared(m)
+    assert list(got)[0] == "dense.grad_rel_err"
+    assert set(got) == set(CELLS_COMPARED) | {"dense.grad_rel_err",
+                                              "dgc.unexplained_coords"}
+    assert run.nearness("x", 1.5e-6, 3e-6) == 0.5
+    assert run.nearness("x_floor", 0.5, 0.8) == 1.6
+    assert run.nearness("count", 0, 0) == 0.0
 
 
 def test_arms_resident_one_at_a_time_keep_the_metrics_names(tmp_path):
@@ -481,7 +564,6 @@ def test_the_reference_follows_the_optimizer_as_configured(
     """The plain SGD beside the reference reads the recipe the arm was
     built with: weight decay, nesterov; DGC's memory too. With every
     tensor cut into several pieces, as a large model's are."""
-    from benchmark import model_check
     monkeypatch.setattr(model_check, "PIECE", 1000)
     m = _measure(_new_token_cell(tmp_path, overrides=overrides,
                                  dgc_module=dgc_module))
@@ -610,7 +692,6 @@ def test_a_reference_called_a_row_at_a_time_gives_the_whole_batchs_numbers(
         tmp_path):
     import jax
     import numpy as np
-    from benchmark import model_check
     sound = os.path.join(rehearse.FIXTURE, "references", "tiny_lm.py")
     (tmp_path / "blocked.py").write_text(
         ROW_BLOCK_REFERENCE.format(sound=sound, scale=1.0))
@@ -650,7 +731,6 @@ def test_a_reference_called_a_row_at_a_time_gives_the_whole_batchs_numbers(
 
 
 def test_a_nan_in_one_tensor_is_the_worst_and_not_correct():
-    from benchmark import model_check
     ref = {"a": [3.0, 4.0], "b": [1.0, 0.0], "c": [0.0, 2.0]}
     prog = {"a": [3.0, 4.0], "b": [float("nan"), 0.0], "c": [0.0, 2.0]}
 
