@@ -1678,24 +1678,46 @@ class FlatDGCEngine:
             f"— got memory={type(m).__name__}, int8_error_feedback="
             f"{int8_ef}, wire dtype={jnp.dtype(dt).name}")
 
-    #: the geometry rule of :meth:`_apply`, on static numbers only. The
-    #: pairs stream through the apply kernel where the [T] f32
-    #: accumulator (4 T bytes) is larger than the chip's 128 MiB of
-    #: VMEM, in which XLA otherwise keeps it between the scatter and
-    #: the optimizer that reads it (ResNet-50: 108 MB, kept; VGG-16-BN:
-    #: 556 MB, streamed); see :meth:`_apply` for the readings
+    #: the geometry rule of :meth:`_apply`, on static numbers only.
+    #: WITHOUT the step's offer (the pass writes a [T] gradient for the
+    #: optimizer to read) the pairs stream through the apply kernel
+    #: where that f32 accumulator (4 T bytes) is larger than the chip's
+    #: 128 MiB of VMEM, in which XLA otherwise keeps it between the
+    #: scatter and the optimizer's fusion (ResNet-50: 108 MB, kept;
+    #: VGG-16-BN: 556 MB, streamed). PR 31 read it: ResNet-50 forced to
+    #: ``stream`` lost 0.03-0.06 ms of ``dgc_overhead_ms`` to the
+    #: scatter; see :meth:`_apply`
     APPLY_STREAM_MIN_BYTES = 128 * 1024 * 1024
-    #: and while the windows' first / last indices (two int32 a 128
-    #: pairs) stay a small part of the scalar memory they are
-    #: prefetched into: 2**21 pairs are 2 x 64 KiB
+    #: WITH the offer there is no accumulator to keep anywhere (form
+    #: ``update``: the [T] gradient is never written), so that bound
+    #: does not apply, and this one is where the pass's fixed cost (the
+    #: sort, the staging's small ops, the chunk walk) meets what it
+    #: saves: 8 MB, just under the smallest T that read a win. Step 0
+    #: of PR 41 (v5e, bare programs, 25 calls inside one program, ms a
+    #: call, the pass + the tail's rule | scatter + bit scatter +
+    #: ``dgc_sgd``'s fusion; one worker's pairs, then four workers'):
+    #: T 27,068,416 (ResNet-50) 0.717 | 1.083, 0.902 | 1.765;
+    #: 13,105,152 (ResNet-18) 0.356 | 0.371, 0.437 | 0.677; 6,553,600
+    #: 0.194 | 0.206, 0.231 | 0.373; 2,029,568 (ResNet-110) 0.080 |
+    #: 0.096, 0.091 | 0.132; 370,688 (ResNet-20, 1.5 MB) 0.040 | 0.035,
+    #: 0.042 | 0.037: a LOSS, so ResNet-20 keeps the scatter. Nothing
+    #: was read between 370,688 and 2,029,568 elements
+    APPLY_UPDATE_MIN_BYTES = 8_000_000
+    #: and, either way, while the windows' first / last indices (two
+    #: int32 a 128 pairs) stay a small part of the scalar memory they
+    #: are prefetched into: 2**21 pairs are 2 x 64 KiB
     APPLY_STREAM_MAX_PAIRS = 1 << 21
 
     @classmethod
-    def _apply_streams(cls, T: int, pairs: int) -> bool:
-        """The geometry rule on its two static numbers: the compressed
-        block's length and the gathered pairs ``W * payload``."""
-        return (4 * T > cls.APPLY_STREAM_MIN_BYTES
-                and pairs <= cls.APPLY_STREAM_MAX_PAIRS)
+    def _apply_streams(cls, T: int, pairs: int,
+                       offered: bool = False) -> bool:
+        """The geometry rule on what is static at trace time: the
+        compressed block's length, the gathered pairs ``W * payload``
+        and whether the step offers its optimizer's rule for the block
+        (:class:`InPlaceUpdate`, and nothing else writes the block)."""
+        floor = (cls.APPLY_UPDATE_MIN_BYTES if offered
+                 else cls.APPLY_STREAM_MIN_BYTES)
+        return 4 * T > floor and pairs <= cls.APPLY_STREAM_MAX_PAIRS
 
     def _use_fused_select(self, b: "_Bucket") -> bool:
         """Whether a bucket's selection runs the fused
@@ -2796,10 +2818,12 @@ class FlatDGCEngine:
         — its read/write cost scales with the model, ~0.8 ms/step at
         VGG.
 
-        **Two forms of one step, chosen from T and W * payload**
+        **Three forms of one step, chosen from T, W * payload and
+        whether the step offers its optimizer's rule**
         (:meth:`_apply_streams`; ``exchange.apply`` counts the pairs
         and names the form under ``step.trace``). What the chip says
-        (v5e; PR 30's probes and PR 31's, `PERF.md` §6):
+        (v5e; PR 30's probes, PR 31's, PR 35's and PR 41's, `PERF.md`
+        §6):
 
         * ``scatter``: ``zeros[T].at[idx].add(wire)``, then
           ``pack_sent_bits`` over the local indices. XLA:TPU's scatter
@@ -2835,17 +2859,32 @@ class FlatDGCEngine:
           alone: every payload-sized XLA scatter, gather or argsort is
           6-10 ns an element here.
 
-        The rule: stream where the accumulator cannot stay on the chip,
-        ``4 T > 128 MiB`` (and the pairs' window maps fit scalar
-        memory). At ResNet-50 (T 27,068,416: 108 MB) XLA keeps the
-        scatter's [T] in VMEM until the optimizer has read it, so its
-        fill and re-read are free and a kernel that writes HBM gives
-        that up: forced to stream, the step's ``dgc_overhead_ms`` read
-        2.215, 2.239 against 2.185, 2.180 with the scatter, although
-        alone the pass is the faster of the two there (0.43 against
-        0.60 ms). The measured crossover lies between the two T the
-        benchmark has (108 MB loses by 0.03-0.06 ms, 556 MB wins by
-        1.8); W * payload did not move it (1x and 4x at VGG).
+        The rule has a case for each answer to "is a [T] gradient
+        written?" (and, either way, the pairs' window maps fit scalar
+        memory). WITHOUT the step's offer (guards, no donation,
+        per-worker optimizer state, a chained transformation, a
+        vector-form mask, a dense-planned bucket) one is: stream where
+        that accumulator cannot stay on the chip, ``4 T > 128 MiB``.
+        At ResNet-50 (T 27,068,416: 108 MB) XLA keeps the scatter's
+        [T] in VMEM until the optimizer has read it, so its fill and
+        re-read are free and a kernel that writes HBM gives that up:
+        forced to ``stream``, the step's ``dgc_overhead_ms`` read
+        2.215, 2.239 against 2.185, 2.180 with the scatter (PR 31),
+        although alone the pass is the faster of the two there (0.43
+        against 0.60 ms); 556 MB wins by 1.8, and W * payload did not
+        move it (1x and 4x at VGG). WITH the offer (form ``update``)
+        none is, there is nothing to keep, and that bound does not
+        apply: the pass replaces the scatter, the bit scatter AND the
+        optimizer's five-stream fusion, and wins down to the
+        staging's fixed cost, ``4 T > 8 MB``. Step 0 of PR 41 (bare
+        programs at five T, :attr:`APPLY_UPDATE_MIN_BYTES`' comment):
+        at ResNet-50's T 0.717 ms against 1.083, a win down to
+        ResNet-110's 2,029,568 and a loss at ResNet-20's 370,688.
+        In the whole step (``resnet50.steady``, parent | change on one
+        machine, P C C P): ``dgc_overhead_ms`` 2.188, 2.188 | 1.889,
+        1.879; traced, phase ``apply`` 0.234 -> 0.722 (the pass 0.665,
+        sort + staging 0.057), ``pack`` 0.300 -> 0.125 (the bit
+        scatter was 0.175 of it), the optimizer's 0.659 -> 0.002.
         Static plan properties the kernel does not serve keep the
         scatter: no error-feedback memory, a non-f32 value wire, int8
         error feedback, an int64 wire, a gossip plan; off the TPU
@@ -2865,15 +2904,16 @@ class FlatDGCEngine:
         wire = g_values.reshape(-1).astype(dt)
         mk_apply = self._use_megakernel_apply(m, self._int8_ef, dt)
         flagged = mk_apply or self._use_fused_apply(m, self._int8_ef, dt)
+        # the step's offer counts where nothing else writes the
+        # compressed block (a dense-planned bucket does), and is taken
+        # where the geometry rule then streams (the opt-in kernels keep
+        # their own form)
+        offered = st.update is not None and not self._dense_ids
         stream = flagged or (
             kernels.use_pallas()
             and self._apply_kernel_serves(m, self._int8_ef, dt)
-            and self._apply_streams(T, wire.shape[0]))
-        # the step's offer is taken where the geometry rule streams (the
-        # opt-in kernels keep their own form) and nothing else writes
-        # the compressed block (a dense-planned bucket does)
-        take = (stream and not flagged and st.update is not None
-                and not self._dense_ids)
+            and self._apply_streams(T, wire.shape[0], offered))
+        take = stream and not flagged and offered
         _trace.count("exchange.apply", wire.shape[0],
                      path=("update" if take else "stream" if stream
                            else "scatter"))

@@ -12,7 +12,8 @@ recall regression. This script runs ON THE REAL CHIP and asserts:
    four opt-in kernels (select_pack_rows incl. its multi-round form,
    payload_apply_bits, dgc_forward_rows, dgc_apply_rows) at the engine's
    ResNet-50 operating shapes, and payload_update_bits with the
-   optimizer's rule == payload_apply_bits + the optimizer at VGG-16-BN's T;
+   optimizer's rule == payload_apply_bits + the optimizer at VGG-16-BN's
+   and at ResNet-50's T, each with its configuration's constants;
 2. approx-selection recall >= 0.95 at every ResNet-50 approx bucket
    (exact top-k reference computed on the same device).
 
@@ -248,72 +249,97 @@ def check_kernels():
     return out
 
 
-def check_update_pass(T: int = 139_028_480, per: int = 138_360):
+#: what :func:`check_update_pass` runs: (name, T, one worker's pairs,
+#: the dense tail behind the block, nesterov, weight decay): the two
+#: benchmark configurations' geometry (``PERF.md`` §4) and optimizer
+#: constants (``configs/imagenet/*.py``; lr 0.0125 scheduled and
+#: momentum 0.9 at both). Since PR 41 both compile ``path=update``.
+UPDATE_PASS_CASES = (
+    ("vggT", 139_028_480, 138_360, 69_632, False, 5e-5),
+    ("resnet50T", 27_068_416, 25_583, 55_296, True, 1e-4),
+)
+
+
+def check_update_pass(cases=UPDATE_PASS_CASES):
     """``kernels.payload_update_bits`` with ``dgc_sgd``'s own rule against
     the two passes it replaces (``payload_apply_bits``, then the
-    optimizer's ``update`` and the add, both compiled) at VGG-16-BN's T
-    with the benchmark's constants, a weight-decay mask whose spans cut a
-    chunk, one worker's pairs and four workers' with cross-worker
-    duplicates: p', buf' and the transmit bits bitwise, the tail [T, P)
-    as it came. The benchmark's exchange check does not run this form
-    (``PERF.md`` §7.1c), and XLA:CPU contracts the rule's FMAs
-    differently in the two programs, so the chip is where this
-    comparison holds with real constants. Returns {name: bool}."""
+    optimizer's ``update`` and the add, both compiled) at each of
+    ``cases``: VGG-16-BN's and ResNet-50's T with the benchmark's
+    constants, a weight-decay mask whose spans cut a chunk, one worker's
+    pairs and four workers' with cross-worker duplicates: p', buf' and
+    the transmit bits bitwise, the tail [T, P) as it came. The
+    benchmark's exchange check does not run this form (``PERF.md``
+    §7.1c), and XLA:CPU contracts the rule's FMAs differently in the two
+    programs, so the chip is where this comparison holds with real
+    constants. Returns {name: bool}."""
     from dgc_tpu.compression.flat import LayoutMask
-    from dgc_tpu.ops import kernels
     from dgc_tpu.optim import dgc_sgd
+
+    out = {}
+    for name, T, per, tail, nesterov, weight_decay in cases:
+        P = T + tail
+        mask = LayoutMask(P, jnp.int32, [(0, 1_000_000, True),
+                                         (1_000_000, 1_000_512, False),
+                                         (1_000_512, T, True), (T, P, False)])
+        opt = dgc_sgd(lambda c: 0.0125 * (1 + c.astype(jnp.float32) * 1e-3),
+                      momentum=0.9, nesterov=nesterov,
+                      weight_decay=weight_decay, weight_decay_mask=mask)
+        rng = np.random.RandomState(35)
+        for W in (1, 4):
+            out.update(_update_pass_case(
+                f"payload_update_bits_{name}_w{W}", opt, T, P, per, W, rng))
+    return out
+
+
+def _update_pass_case(name, opt, T, P, per, W, rng):
+    """One geometry and world size of :func:`check_update_pass`, with
+    ``first`` on (count 0) and off."""
+    from dgc_tpu.ops import kernels
     from dgc_tpu.optim.sgd import SGDState
 
-    P = T + 69_632
-    mask = LayoutMask(P, jnp.int32, [(0, 1_000_000, True),
-                                     (1_000_000, 1_000_512, False),
-                                     (1_000_512, T, True), (T, P, False)])
-    opt = dgc_sgd(lambda c: 0.0125 * (1 + c.astype(jnp.float32) * 1e-3),
-                  momentum=0.9, weight_decay=5e-5, weight_decay_mask=mask)
     rule = opt.rule
-    rng = np.random.RandomState(35)
+
+    def two_passes(v, i, f, p, b, bits, count):
+        acc, nbits = kernels.payload_apply_bits(
+            v, i, f, T, bits_donor=bits, out_total=P, max_dup=W)
+        inside = jnp.arange(P) < T          # the tail is undefined
+        upd, state = opt.update(jnp.where(inside, acc, 0.0),
+                                SGDState(count, b), p)
+        return (jnp.where(inside, p + upd, p),
+                jnp.where(inside, state.momentum_buffer, b), nbits)
+
+    def one_pass(v, i, f, p, b, bits, count):
+        state = SGDState(count, b)
+        (new_p, new_b), nbits = kernels.payload_update_bits(
+            v, i, f, T, rule.blocks(state, p), rule.step,
+            rule.scalars(state), bits_donor=bits, max_dup=W)
+        return new_p, new_b, nbits
+
+    idx = np.stack([rng.permutation(np.unique(rng.randint(0, T, 2 * per)))
+                    [:per] for _ in range(W)])
+    shared = idx[0, :per // 10]                  # cross-worker duplicates
+    for w in range(1, W):    # each once a worker: what ``max_dup`` is owed
+        own = idx[w][~np.isin(idx[w], shared)]
+        idx[w] = np.concatenate([shared, own])[:per]
+    flags = np.zeros((W, per), bool)
+    flags[W - 1] = True
+    pairs = (jnp.asarray(rng.randn(W * per).astype(np.float32) / W),
+             jnp.asarray(idx.reshape(-1).astype(np.int32)),
+             jnp.asarray(flags.reshape(-1)))
+    k = jax.random.split(jax.random.PRNGKey(W), 2)
+    p = jax.random.normal(k[0], (P,), jnp.float32)
+    b = jax.random.normal(k[1], (P,), jnp.float32) * 1e-3
+    bits = jnp.zeros((kernels.num_sent_words(T),), jnp.int32)
+    two_passes, one_pass = jax.jit(two_passes), jax.jit(one_pass)
+    same = jax.jit(lambda want, got, p0: jnp.stack(
+        [jnp.array_equal(x, y) for x, y in zip(want, got)]
+        + [jnp.array_equal(got[0][T:], p0[T:])]).all())
     out = {}
-    for W in (1, 4):
-        def two_passes(v, i, f, p, b, bits, count):
-            acc, nbits = kernels.payload_apply_bits(
-                v, i, f, T, bits_donor=bits, out_total=P, max_dup=W)
-            inside = jnp.arange(P) < T          # the tail is undefined
-            upd, state = opt.update(jnp.where(inside, acc, 0.0),
-                                    SGDState(count, b), p)
-            return (jnp.where(inside, p + upd, p),
-                    jnp.where(inside, state.momentum_buffer, b), nbits)
-
-        def one_pass(v, i, f, p, b, bits, count):
-            state = SGDState(count, b)
-            (new_p, new_b), nbits = kernels.payload_update_bits(
-                v, i, f, T, rule.blocks(state, p), rule.step,
-                rule.scalars(state), bits_donor=bits, max_dup=W)
-            return new_p, new_b, nbits
-
-        idx = np.stack([np.unique(rng.randint(0, T, 2 * per))[:per]
-                        for _ in range(W)])
-        for w in range(W):
-            rng.shuffle(idx[w])
-        idx[1:, :per // 10] = idx[0, :per // 10]     # cross-worker duplicates
-        flags = np.zeros((W, per), bool)
-        flags[W - 1] = True
-        pairs = (jnp.asarray(rng.randn(W * per).astype(np.float32) / W),
-                 jnp.asarray(idx.reshape(-1).astype(np.int32)),
-                 jnp.asarray(flags.reshape(-1)))
-        k = jax.random.split(jax.random.PRNGKey(W), 2)
-        p = jax.random.normal(k[0], (P,), jnp.float32)
-        b = jax.random.normal(k[1], (P,), jnp.float32) * 1e-3
-        bits = jnp.zeros((kernels.num_sent_words(T),), jnp.int32)
-        two_passes, one_pass = jax.jit(two_passes), jax.jit(one_pass)
-        same = jax.jit(lambda want, got, p0: jnp.stack(
-            [jnp.array_equal(x, y) for x, y in zip(want, got)]
-            + [jnp.array_equal(got[0][T:], p0[T:])]).all())
-        for count in (0, 3):                        # ``first`` and not
-            want = two_passes(*pairs, p, b, bits, jnp.int32(count))
-            got = one_pass(*pairs, p, b, bits, jnp.int32(count))
-            out[f"payload_update_bits_vggT_w{W}_count{count}"] = bool(
-                same(want, got, p))
-            del want, got
+    for count in (0, 3):                        # ``first`` and not
+        want = two_passes(*pairs, p, b, bits, jnp.int32(count))
+        got = one_pass(*pairs, p, b, bits, jnp.int32(count))
+        out[f"{name}_count{count}"] = bool(same(want, got, p))
+        del want, got
     return out
 
 

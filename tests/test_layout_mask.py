@@ -272,46 +272,55 @@ def test_on_the_v5e_the_update_is_one_fusion_over_p_buf_g(
     assert [op for _, op in args] == ["parameter"] * 3, big
 
 
-#: VGG-16-BN's compressed block, its flat buffer and one worker's payload
-#: (benchmark/configs/vgg16_bn.json)
+#: the compressed block, the flat buffer and one worker's payload of the
+#: two benchmark configurations (benchmark/configs/*.json; PERF.md §4)
 _VGG_T, _VGG_P, _VGG_PAYLOAD = 139_028_480, 139_051_008, 138_360
+_R50_T, _R50_P, _R50_PAYLOAD = 27_068_416, 27_123_712, 25_583
+_GEOMETRY = {"vgg16_bn": (_VGG_T, _VGG_P, _VGG_PAYLOAD),
+             "resnet50": (_R50_T, _R50_P, _R50_PAYLOAD)}
 
 
-def _apply_pass_args(one_chip, W):
+def _apply_pass_args(one_chip, W, T=_VGG_T, payload=_VGG_PAYLOAD):
     from dgc_tpu.ops import kernels
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    n = W * _VGG_PAYLOAD
+    n = W * payload
     return (arg((n,), jnp.float32), arg((n,), jnp.int32), arg((n,), bool),
-            arg((kernels.num_sent_words(_VGG_T),), jnp.int32))
+            arg((kernels.num_sent_words(T),), jnp.int32))
 
 
-@pytest.mark.parametrize("opt_name, nesterov, masked, W", [
-    ("dgc_sgd", False, False, 1),          # the benchmark's one-chip cell
-    ("dgc_sgd", True, True, 4),
-    ("sgd", True, True, 4)])
-def test_on_the_v5e_the_update_pass_compiles_at_vggs_width(
-        one_chip, no_compile_cache, monkeypatch, opt_name, nesterov, masked,
-        W):
+@pytest.mark.parametrize("model, opt_name, nesterov, masked, W", [
+    ("vgg16_bn", "dgc_sgd", False, False, 1),  # the benchmark's one-chip cell
+    ("vgg16_bn", "dgc_sgd", True, True, 4),
+    ("vgg16_bn", "sgd", True, True, 4),
+    ("resnet50", "dgc_sgd", True, True, 1),    # resnet50.steady since PR 41
+    ("resnet50", "dgc_sgd", True, True, 4)])
+def test_on_the_v5e_the_update_pass_compiles_at_the_cells_widths(
+        one_chip, no_compile_cache, monkeypatch, model, opt_name, nesterov,
+        masked, W):
     """``kernels.payload_update_bits`` with the optimizers' own rule
-    through the TPU's compiler at VGG-16-BN's T, for one worker's pairs
-    and four, under ``jax.default_matmul_precision("highest")`` (what a
-    configuration with a model reference states; PERF.md §7.0): block
-    shapes, scalar memory, VMEM and the rule's operations are all
-    Mosaic's to refuse. The state moves in place: the program holds no
-    [T]-sized temporary."""
+    through the TPU's compiler at VGG-16-BN's T and at ResNet-50's (the
+    geometry rule sends both there when the step offers its rule), for
+    one worker's pairs and four, under
+    ``jax.default_matmul_precision("highest")`` (what a configuration
+    with a model reference states; PERF.md §7.0): block shapes, scalar
+    memory, VMEM and the rule's operations are all Mosaic's to refuse.
+    The state moves in place: the program holds no [T]-sized
+    temporary."""
+    from dgc_tpu.compression.flat import FlatDGCEngine
     from dgc_tpu.ops import kernels
     from dgc_tpu.optim.sgd import SGDState
     monkeypatch.setattr(kernels, "use_pallas", lambda: True)
+    T, P, payload = _GEOMETRY[model]
+    assert FlatDGCEngine._apply_streams(T, W * payload, offered=True)
     mask = None
     if masked:
-        mask = LayoutMask(_VGG_P, jnp.int32,
+        mask = LayoutMask(P, jnp.int32,
                           [(0, 1_000_000, True), (1_000_000, 1_000_512,
                                                   False),
-                           (1_000_512, _VGG_T, True), (_VGG_T, _VGG_P,
-                                                       False)])
+                           (1_000_512, T, True), (T, P, False)])
         assert mask.form == "runs" and len(mask.runs) == 2
     rule = OPTIMIZERS[opt_name](
         lambda c: 0.1 / (1.0 + c), momentum=0.9, weight_decay=5e-5,
@@ -320,16 +329,17 @@ def test_on_the_v5e_the_update_pass_compiles_at_vggs_width(
     def fused(v, i, f, bits, p, buf, count):
         state = SGDState(count, buf)
         return kernels.payload_update_bits(
-            v, i, f, _VGG_T, rule.blocks(state, p), rule.step,
+            v, i, f, T, rule.blocks(state, p), rule.step,
             rule.scalars(state), bits_donor=bits, max_dup=W)
 
-    flat = jax.ShapeDtypeStruct((_VGG_P,), jnp.float32, sharding=one_chip)
+    flat = jax.ShapeDtypeStruct((P,), jnp.float32, sharding=one_chip)
     count = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     with jax.default_matmul_precision("highest"):
         compiled = jax.jit(fused, donate_argnums=(3, 4, 5)).lower(
-            *_apply_pass_args(one_chip, W), flat, flat, count).compile()
+            *_apply_pass_args(one_chip, W, T, payload), flat, flat,
+            count).compile()
     assert "payload_update_bits" in compiled.as_text()
-    assert compiled.memory_analysis().temp_size_in_bytes < 4 * _VGG_T // 16
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * T // 16
 
 
 def test_on_the_v5e_the_apply_pass_compiles_under_highest(

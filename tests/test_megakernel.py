@@ -163,25 +163,40 @@ def test_apply_kernel_no_divisor_matches_fused_epilogue():
 
 
 @pytest.mark.parametrize("W", [1, 4])
-@pytest.mark.parametrize("name, T, payload, streams", [
-    ("resnet50", 27_068_416, 25_583, False),
-    ("vgg16_bn", 139_028_480, 138_360, True),
+@pytest.mark.parametrize("offered", [True, False])
+@pytest.mark.parametrize("name, T, payload", [
+    ("resnet50", 27_068_416, 25_583),
+    ("vgg16_bn", 139_028_480, 138_360),
 ])
 def test_apply_geometry_rule_on_the_benchmark_geometries(name, T, payload,
-                                                         streams, W):
+                                                         offered, W):
     """The rule `_apply`'s docstring states, on the static numbers of
-    the two benchmark configurations (PERF.md §4) and both world sizes,
-    without building a model: ResNet-50 keeps the zeros-scatter (its
-    [T] accumulator stays on chip), VGG-16-BN streams."""
-    from dgc_tpu.compression.flat import FlatDGCEngine
-    assert FlatDGCEngine._apply_streams(T, W * payload) is streams, name
-    # the boundary is the accumulator's bytes, nothing else
-    edge = FlatDGCEngine.APPLY_STREAM_MIN_BYTES // 4
-    assert not FlatDGCEngine._apply_streams(edge, W * payload)
-    assert FlatDGCEngine._apply_streams(edge + 128, W * payload)
-    # and the pairs' window maps have to fit the scalar memory
-    assert not FlatDGCEngine._apply_streams(
-        edge + 128, FlatDGCEngine.APPLY_STREAM_MAX_PAIRS + 1)
+    the two benchmark configurations (PERF.md §4), both world sizes and
+    both answers to "does the step offer its optimizer's rule", without
+    building a model: VGG-16-BN streams either way; ResNet-50 streams
+    where the offer leaves no [T] accumulator to keep on the chip and
+    keeps the zeros-scatter where one is written."""
+    from dgc_tpu.compression.flat import FlatDGCEngine as E
+    streams = offered or name == "vgg16_bn"
+    assert E._apply_streams(T, W * payload, offered) is streams, name
+    # the argument's default is the case that writes the accumulator
+    assert E._apply_streams(T, W * payload) is (name == "vgg16_bn")
+    # each case has its own boundary, in the block's bytes and nothing
+    # else: the offered one lies under ResNet-50's T, the other over it
+    floor = E.APPLY_UPDATE_MIN_BYTES if offered else E.APPLY_STREAM_MIN_BYTES
+    assert (E.APPLY_UPDATE_MIN_BYTES < 4 * 27_068_416
+            < E.APPLY_STREAM_MIN_BYTES)
+    edge = floor // 4
+    assert not E._apply_streams(edge, W * payload, offered)
+    assert E._apply_streams(edge + 128, W * payload, offered)
+    # the 128 MiB edge binds only the case without an offer
+    over = E.APPLY_STREAM_MIN_BYTES // 4
+    assert E._apply_streams(over, W * payload, offered) is offered
+    # and the pairs' window maps have to fit the scalar memory, offer
+    # or none
+    assert E._apply_streams(over + 128, E.APPLY_STREAM_MAX_PAIRS, offered)
+    assert not E._apply_streams(over + 128, E.APPLY_STREAM_MAX_PAIRS + 1,
+                                offered)
 
 
 @pytest.mark.parametrize("k", [257, 1024])
