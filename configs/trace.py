@@ -6,15 +6,17 @@ to turn structured tracing on (same as ``train.py --trace``):
 
 What it enables (``dgc_tpu.telemetry.trace.enable``, the one switch):
 * device-side ``dgcph.<phase>[.<part>][.b<bucket>]`` named scopes over the
-  whole step (params_view/plumbing/fwd_bwd/update.exchange/
-  update.optimizer/loss and the DGC pipeline's compensate/threshold/
-  select/pack/allgather/decode/apply/dense) — pure op metadata, zero new
+  whole step (params_view/plumbing/fwd_bwd with its part fwd_bwd.pack/
+  update.exchange/update.optimizer/loss and the DGC pipeline's
+  compensate/threshold/select/pack/allgather/decode/apply/dense, apply
+  with its parts apply.sort and apply.stage) — pure op metadata, zero new
   ops or collectives; a device profile then attributes per-bucket
   per-phase cost via dgc_tpu.telemetry.attrib or benchmark/trace_reduce;
 * the process-wide in-memory recorder: host spans where the work happens
-  (input.get_batch, input.queue_wait, input.stage, step.trace,
-  step.dispatch, step.drain, checkpoint.save, eval) and counts
-  (input.queue_depth, exchange.collective, optimizer.wd_mask), written
+  (input.get_batch, input.queue_wait, input.stage, step.trace and its
+  children step.trace_model and exchange.trace, step.dispatch,
+  step.drain, checkpoint.save, eval) and counts (input.queue_depth,
+  exchange.collective, exchange.apply, optimizer.wd_mask), written
   once at the end of the run to <save_path>/trace_records.jsonl. With
   ``--profile`` every span is also a ``dgc:<name>`` annotation in the
   profiler's own trace — host spans beside the device lanes, one file,

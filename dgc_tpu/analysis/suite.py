@@ -323,19 +323,25 @@ def run_contract_suite(mesh=None, log: Callable[[str], None] = None,
     # (markers live in compiled op_name=..., not default StableHLO — so
     # this pin reads compiled text). Lowering is lazy: check() must run
     # INSIDE the enable window. Both engines' builds pass through every
-    # scope the step opens: the parts of ``update``, ``params_view``,
-    # ``plumbing``, and the dense engine's own ``dense``.
+    # scope the step opens: the parts of ``update`` and of ``fwd_bwd``,
+    # ``params_view``, ``plumbing``, and the dense engine's own ``dense``.
+    # The DGC build takes the streamed apply pass (the chip's default,
+    # off the chip the opt-in kernel's, interpreted; it holds no
+    # collective), whose staging carries the parts of ``apply``.
     from dgc_tpu.telemetry import trace as _tr
     step_scopes = ["dgcph.update.exchange", "dgcph.update.optimizer",
-                   "dgcph.params_view", "dgcph.plumbing", "dgcph.fwd_bwd"]
+                   "dgcph.params_view", "dgcph.plumbing", "dgcph.fwd_bwd",
+                   "dgcph.fwd_bwd.pack"]
     prev_tr = _tr.enable(True)
     try:
-        _, step_tron, _, _ = build_fixture(mesh, donate=False,
-                                           telemetry=False)
+        _, step_tron, _, _ = build_fixture(
+            mesh, donate=False, telemetry=False,
+            compressor_kwargs={"fused_apply": True})
         tron = _step_contract(
             "trace-on-no-new-collectives", state, step_tron, inputs,
             collectives_delta=(plain, {"all-reduce": 0, "all-gather": 0}),
-            require_substrings_compiled=step_scopes + ["dgcph.compensate"],
+            require_substrings_compiled=step_scopes + [
+                "dgcph.compensate", "dgcph.apply.sort", "dgcph.apply.stage"],
             no_f64=True)
         run(tron.name, tron.check)
         _, step_tron_dn, _, _ = build_fixture(mesh, compressor="none",

@@ -2773,11 +2773,13 @@ class FlatDGCEngine:
 
     def _sent_flags(self, g_indices, axis_name):
         """Per gathered entry: THIS worker's and a real slot — the fused
-        apply kernels' transmit record, bitwise ``pack_sent_bits``."""
-        me = jax.lax.axis_index(axis_name)
-        rows = jnp.arange(g_indices.shape[0], dtype=jnp.int32)[:, None]
-        return ((rows == me)
-                & (g_indices != self.layout.sentinel)).reshape(-1)
+        apply kernels' transmit record, bitwise ``pack_sent_bits``. Part
+        of the pass's staging (``_apply`` calls it under ``apply``)."""
+        with _trace.phase("apply", part="stage"):
+            me = jax.lax.axis_index(axis_name)
+            rows = jnp.arange(g_indices.shape[0], dtype=jnp.int32)[:, None]
+            return ((rows == me)
+                    & (g_indices != self.layout.sentinel)).reshape(-1)
 
     def _transmit_record(self, st: _Exchange):
         """THIS step's transmit record for the next compensate:

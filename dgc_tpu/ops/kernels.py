@@ -1763,15 +1763,24 @@ def _sorted_pairs(values, indices, flags, total: int, divisor, max_dup):
     pad = nblocks * bp - n
     i32 = jnp.int32
     assert 2 * nblocks * bp < 2 ** 31, n
-    if divisor is not None:
-        values = values / divisor   # worker average, per entry (IEEE)
-    order = (jnp.arange(n, dtype=i32) << 1) | flags.astype(i32)
-    si, so, sv = jax.lax.sort(
-        (jnp.concatenate([indices.astype(i32),
-                          jnp.full((pad,), jnp.iinfo(i32).max, i32)]),
-         jnp.concatenate([order, jnp.zeros((pad,), i32)]),
-         jnp.concatenate([values, jnp.zeros((pad,), values.dtype)])),
-        num_keys=2, is_stable=False)
+    with _trace.phase("apply", part="sort"):
+        if divisor is not None:
+            values = values / divisor   # worker average, per entry (IEEE)
+        order = (jnp.arange(n, dtype=i32) << 1) | flags.astype(i32)
+        si, so, sv = jax.lax.sort(
+            (jnp.concatenate([indices.astype(i32),
+                              jnp.full((pad,), jnp.iinfo(i32).max, i32)]),
+             jnp.concatenate([order, jnp.zeros((pad,), i32)]),
+             jnp.concatenate([values, jnp.zeros((pad,), values.dtype)])),
+            num_keys=2, is_stable=False)
+    with _trace.phase("apply", part="stage"):
+        return _window_maps(si, so, sv, total, nblocks, max_dup)
+
+
+def _window_maps(si, so, sv, total: int, nblocks: int, max_dup):
+    """What :func:`_sorted_pairs` makes of the sorted arrays: the
+    duplicate fold and the scalar-prefetch maps (its docstring)."""
+    i32 = jnp.int32
     if max_dup != 1:  # dgclint: ok[tracer-branch] — static by contract (the engine passes the Python world size)
         same = jnp.concatenate([jnp.zeros((1,), bool), si[1:] == si[:-1]])
 
@@ -1879,11 +1888,12 @@ def _apply_staging(values, indices, flags, total: int, bits_donor,
     brows = num_sent_words(total) // _LANE
     *maps, sk, sv, sf, npages = _sorted_pairs(
         values, indices, flags, total, divisor, max_dup)
-    if bits_donor is None:
-        bits_donor = jnp.zeros((brows, _LANE), jnp.int32)
-    else:
-        assert bits_donor.shape == (brows * _LANE,), bits_donor.shape
-        bits_donor = bits_donor.reshape(brows, _LANE)
+    with _trace.phase("apply", part="stage"):
+        if bits_donor is None:
+            bits_donor = jnp.zeros((brows, _LANE), jnp.int32)
+        else:
+            assert bits_donor.shape == (brows * _LANE,), bits_donor.shape
+            bits_donor = bits_donor.reshape(brows, _LANE)
     pspec = pl.BlockSpec((_APPLY_WPB, _LANE),
                          lambda p, pc, pb, *_: (pb[p], 0),
                          memory_space=pltpu.VMEM)
@@ -1959,8 +1969,9 @@ def payload_update_bits(values, indices, flags, total: int, state, rule,
         values, indices, flags, total, bits_donor, None, max_dup)
     # a chunk's rule runs on its last page: the step before another
     # chunk opens, and the grid's last (pad pages revisit the last chunk)
-    last = jnp.concatenate([maps[2][1:], jnp.ones((1,), jnp.int32)])
-    prefetch = (*maps, last, *(jnp.reshape(s, (1,)) for s in scalars))
+    with _trace.phase("apply", part="stage"):
+        last = jnp.concatenate([maps[2][1:], jnp.ones((1,), jnp.int32)])
+        prefetch = (*maps, last, *(jnp.reshape(s, (1,)) for s in scalars))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(npages,),

@@ -175,8 +175,12 @@ class DistributedOptimizer:
         if telemetry:
             more["telemetry"] = True
         # parts of the step's ``update`` phase: the engine's own phases
-        # nest inside ``exchange``, and what they leave is its glue
-        with _trace.phase("update", part="exchange"):
+        # nest inside ``exchange``, and what they leave is its glue. The
+        # span times the engine's Python body while the step is traced;
+        # the counts made inside stay ``step.trace``'s
+        with _trace.phase("update", part="exchange"), _trace.span(
+                "exchange.trace", owns_counts=False,
+                engine=type(engine).__name__):
             exchanged, mem_state, *tstats = engine.exchange(
                 flat_grads, mem_state, key, self.axis_name,
                 self.num_nodes, local_axis=self.local_axis_name,

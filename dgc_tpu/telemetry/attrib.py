@@ -35,17 +35,18 @@ from typing import Dict, List, Optional, Tuple
 from dgc_tpu.telemetry import trace as _trace
 
 __all__ = ["PROFILE_SCHEMA", "PROFILE_VERSION", "load_trace_events",
-           "device_events", "op_phase", "phase_table",
+           "device_events", "op_phase", "phase_table", "phase_rows",
            "aggregate_by_source", "profile_json", "write_profile",
            "load_profile"]
 
 PROFILE_SCHEMA = "dgc-profile"
 PROFILE_VERSION = 1
 
-#: ``dgcph.<phase>`` / ``dgcph.<phase>.b<idx>`` anywhere in the op_name
-#: path (named scopes concatenate with "/" — the token survives as one
-#: component because the scope name uses dots)
-_PHASE_RE = re.compile(r"dgcph\.([A-Za-z_]+)(?:\.b(\d+))?")
+#: ``dgcph.<phase>`` / ``dgcph.<phase>.b<idx>`` / ``dgcph.<phase>.<part>``
+#: anywhere in the op_name path (named scopes concatenate with "/" — the
+#: token survives as one component because the scope name uses dots). A
+#: part (letters only) reads as its phase and is a row under it
+_PHASE_RE = re.compile(r"dgcph\.([A-Za-z_]+)(?:\.b(\d+)|\.([A-Za-z]+))?")
 
 #: envelope / non-op lanes excluded from leaf totals
 _ENVELOPES = ("jit_", "while", "Overhead", "idle")
@@ -117,32 +118,47 @@ def op_phase(event: Dict) -> Tuple[Optional[str], Optional[int]]:
     """(phase, bucket) of one device-op event, or (None, None) when the
     op's scope path carries no ``dgcph.`` token. The innermost (last)
     token wins — nested markers refine, not shadow."""
+    name, bucket, _ = _innermost(event)
+    return name, bucket
+
+
+def _innermost(event: Dict) -> Tuple[Optional[str], Optional[int],
+                                     Optional[str]]:
+    """(phase, bucket, part) of the op's innermost ``dgcph.`` token."""
     tf_op = (event.get("args", {}) or {}).get("tf_op", "")
     hits = _PHASE_RE.findall(tf_op)
     if not hits:
-        return None, None
-    name, bucket = hits[-1]
-    return name, (int(bucket) if bucket else None)
+        return None, None, None
+    name, bucket, part = hits[-1]
+    return name, (int(bucket) if bucket else None), part or None
 
 
 def phase_table(events: List[Dict], steps: int = 1) -> Dict:
     """Aggregate device-op durations by DGC phase and bucket.
 
     Returns ``{"total_ms", "attributed_ms", "unattributed_ms",
-    "phases": {phase: ms}, "buckets": {"b<idx>": {phase: ms}},
-    "ops": n}`` — all ms figures divided by ``steps`` (per-step)."""
+    "phases": {phase: ms}, "parts": {"<phase>.<part>": ms},
+    "buckets": {"b<idx>": {phase: ms}}, "ops": n}`` — all ms figures
+    divided by ``steps`` (per-step). A part's time is IN its phase's
+    (``apply.sort`` and ``apply.stage`` in ``apply``, whose rest is the
+    pass; ``fwd_bwd.pack``; ``update.optimizer`` / ``update.exchange``,
+    the latter what the engine runs outside its own phases): ``parts``
+    splits a phase, it adds nothing to a total."""
     phases: Dict[str, float] = defaultdict(float)
+    parts: Dict[str, float] = defaultdict(float)
     buckets: Dict[str, Dict[str, float]] = defaultdict(
         lambda: defaultdict(float))
     total = attributed = 0.0
     for ev in events:
         ms = ev["dur"] / 1e3
         total += ms
-        name, bucket = op_phase(ev)
+        name, bucket, part = _innermost(ev)
         if name is None:
             continue
         attributed += ms
         phases[name] += ms
+        if part is not None:
+            parts[f"{name}.{part}"] += ms
         if bucket is not None:
             buckets[f"b{bucket}"][name] += ms
     k = max(int(steps), 1)
@@ -153,12 +169,24 @@ def phase_table(events: List[Dict], steps: int = 1) -> Dict:
         "unattributed_ms": round((total - attributed) / k, 6),
         "phases": {p: round(v / k, 6) for p, v in sorted(
             phases.items(), key=lambda kv: order.get(kv[0], 99))},
+        "parts": {p: round(v / k, 6) for p, v in sorted(parts.items())},
         "buckets": {b: {p: round(v / k, 6) for p, v in sorted(
             t.items(), key=lambda kv: order.get(kv[0], 99))}
             for b, t in sorted(buckets.items(),
                                key=lambda kv: int(kv[0][1:]))},
         "ops": len(events),
     }
+
+
+def phase_rows(table: Dict) -> List[Tuple[str, float]]:
+    """The rows a :func:`phase_table` prints as: each phase in pipeline
+    order, its parts indented beneath it."""
+    rows = []
+    for phase, ms in table["phases"].items():
+        rows.append((phase, ms))
+        rows += [("  " + part, v) for part, v in table["parts"].items()
+                 if part.partition(".")[0] == phase]
+    return rows
 
 
 def aggregate_by_source(events: List[Dict], repo_root: str,

@@ -7,11 +7,15 @@ on two instruments, and with it off neither leaves anything behind:
   step in ``jax.named_scope`` so every XLA op a stage lowers carries a
   ``dgcph.<phase>[.<part>][.b<bucket>]`` token in its ``op_name`` metadata:
   the DGC pipeline (``compensate → threshold → select → pack → allgather →
-  decode → apply``, ``dense``), the step's ``params_view``, ``plumbing``,
-  ``fwd_bwd``, ``update`` (parts ``exchange`` and ``optimizer``) and
-  ``loss``. A device profile then attributes each op to a phase and bucket
-  (``benchmark/trace_reduce.py``, :mod:`telemetry.attrib`; both read a
-  part token as its phase). The scopes are **Python-static**: off,
+  decode → apply``, ``dense``; ``apply`` has the parts ``sort`` and
+  ``stage``, and what is left of it is the pass, a Pallas call), the
+  step's ``params_view``, ``plumbing``, ``fwd_bwd`` (part ``pack``: the
+  gradients into the flat layout), ``update`` (parts ``exchange`` and
+  ``optimizer``) and ``loss``. A device profile then attributes each op
+  to a phase and bucket (``benchmark/trace_reduce.py``,
+  :mod:`telemetry.attrib`; both read a part token as its phase, and a
+  metric of a part looks for the whole token). The scopes are
+  **Python-static**: off,
   :func:`phase` returns a nullcontext and the lowered program is
   byte-identical to a build that never imported this module (the
   ``trace-off-compiles-away`` contract in ``analysis/suite``); on, they
@@ -21,14 +25,18 @@ on two instruments, and with it off neither leaves anything behind:
 * **The recorder** — :func:`span`, :func:`count` and :func:`records` over
   one process-wide, in-memory recorder that ``enable(True)`` creates.
   Every layer reaches it as a module function, so spans (``input.*``,
-  ``step.*``, ``checkpoint.save``, ``eval``) and counts
-  (``input.queue_depth``, ``exchange.collective``,
-  ``optimizer.wd_mask``) sit where the work happens. A span records its
-  name, start and end (``perf_counter_ns``), thread, the id of the span
-  that caused it and the ids its request carries (``step``, ``seq``;
-  inherited by what it causes); a count
-  belongs to the span open when it was made. While a ``jax.profiler``
-  session is live, and only then, a span also opens
+  ``step.*``, ``exchange.trace``, ``checkpoint.save``, ``eval``) and
+  counts (``input.queue_depth``, ``exchange.collective``,
+  ``exchange.apply``, ``optimizer.wd_mask``) sit where the work happens.
+  A span records its name, start and end (``perf_counter_ns``), thread,
+  the id of the span that caused it and the ids its request carries
+  (``step``, ``seq``; inherited by what it causes); a count belongs to
+  the innermost span open when it was made that owns counts: every span
+  but one opened with ``owns_counts=False``, which times and nests and
+  leaves the counts to the span round it (``step.trace_model`` and
+  ``exchange.trace`` split ``step.trace``'s seconds and take none of its
+  counts). While a ``jax.profiler`` session is live, and only then, a
+  span also opens
   ``jax.profiler.TraceAnnotation("dgc:" + name)``: the program's spans
   land in the profiler's own trace, on its clock, beside the device lanes
   (``train.py --trace --profile``). Off, :func:`span` returns one shared
@@ -196,11 +204,11 @@ class _Carry:
 
 class _Span(_Carry):
     """One open span; ``with`` records it when it closes."""
-    __slots__ = ("name", "id", "_parent", "_t0", "_ann")
+    __slots__ = ("name", "id", "_parent", "_t0", "_ann", "_owns")
 
-    def __init__(self, rec, name, args):
+    def __init__(self, rec, name, args, owns_counts=True):
         super().__init__(rec, args)
-        self.name = name
+        self.name, self._owns = name, owns_counts
 
     def set(self, **args) -> None:
         """Add what only the span's own work can tell (the ``seq`` of the
@@ -209,10 +217,13 @@ class _Span(_Carry):
 
     def __enter__(self):
         super().__enter__()
-        stack = self._rec.here().stack
+        th = self._rec.here()
+        stack = th.stack
         self.id = next(self._rec.ids)
         self._parent = stack[-1] if stack else None
         stack.append(self.id)
+        if self._owns:
+            th.owners.append(self.id)
         prof = _profiling()
         self._ann = prof and prof.TraceAnnotation(
             ANNOTATION_PREFIX + self.name, **{**self._ids, **self.args})
@@ -225,7 +236,10 @@ class _Span(_Carry):
         t1 = time.perf_counter_ns()
         if self._ann is not None:
             self._ann.__exit__(*exc)
-        self._rec.here().stack.pop()
+        th = self._rec.here()
+        th.stack.pop()
+        if self._owns:
+            th.owners.pop()
         args = self.args
         ids = {k: args.pop(k, self._ids.get(k)) for k in REQUEST_IDS}
         self._rec.add({"kind": "span", "name": self.name, "id": self.id,
@@ -247,10 +261,11 @@ class _Recorder:
         self._step_ms: Dict[str, float] = {}
 
     def here(self):
-        """This thread's ``stack`` of open span ids and request ``ids``."""
+        """This thread's ``stack`` of open span ids, those of them that
+        own counts (``owners``) and request ``ids``."""
         th = self._thread
         if not hasattr(th, "stack"):
-            th.stack, th.ids = [], {}
+            th.stack, th.owners, th.ids = [], [], {}
         return th
 
     def add(self, record: Dict[str, Any], ms: Optional[float] = None):
@@ -263,7 +278,7 @@ class _Recorder:
     def count(self, name, value, args):
         th = self.here()
         self.add({"kind": "count", "name": name, "value": value,
-                  "parent": th.stack[-1] if th.stack else None,
+                  "parent": th.owners[-1] if th.owners else None,
                   "thread": threading.get_ident(),
                   "t_ns": time.perf_counter_ns(),
                   **{k: args.pop(k, th.ids.get(k)) for k in REQUEST_IDS},
@@ -286,13 +301,16 @@ if _ENABLED:
     enable(True)
 
 
-def span(name: str, **args):
+def span(name: str, owns_counts: bool = True, **args):
     """Record one host span, ``<layer>.<what>``; nests freely within a
     thread. ``step=`` / ``seq=`` are the request's ids: what the span
     causes on its thread inherits them. ``with span(...) as s`` gives
-    ``s.set(**args)`` for what is known only inside."""
+    ``s.set(**args)`` for what is known only inside. A span with
+    ``owns_counts=False`` splits the time of the span round it: spans
+    opened inside name it as ``parent``, counts made inside belong to
+    the nearest span that owns them."""
     rec = _RECORDER
-    return _NULL if rec is None else _Span(rec, name, args)
+    return _NULL if rec is None else _Span(rec, name, args, owns_counts)
 
 
 def carry(**ids):

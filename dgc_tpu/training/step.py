@@ -396,7 +396,9 @@ def build_train_step(apply_fn: Callable, dist_opt: DistributedOptimizer,
 
                 (lval, new_stats), gflat = jax.value_and_grad(
                     loss_flat, has_aux=True)(state.params)
-                return (gsum + gflat, pack_stats(new_stats),
+                with _trace.phase("fwd_bwd", part="pack"):
+                    gsum = gsum + gflat
+                return (gsum, pack_stats(new_stats),
                         losssum + lval, i + 1), None
         else:
             def micro(carry, mb):
@@ -406,14 +408,21 @@ def build_train_step(apply_fn: Callable, dist_opt: DistributedOptimizer,
                       else None)
                 (lval, new_stats), grads = grad_fn(
                     params, unpack_stats(pstats), imgs, lbls, r_nbps, dk)
-                gsum = jax.tree.map(jnp.add, gsum, pack_grads(grads))
+                # the one piece of fwd_bwd that is the flat layout's and
+                # not the model's
+                with _trace.phase("fwd_bwd", part="pack"):
+                    gsum = jax.tree.map(jnp.add, gsum, pack_grads(grads))
                 return (gsum, pack_stats(new_stats), losssum + lval,
                         i + 1), None
 
         stats0, memory0 = packed_stats, memory
         with _trace.phase("plumbing"):
             zeros = jax.tree.map(jnp.zeros_like, state.params)
-        with _trace.phase("fwd_bwd"):
+        # the model's forward and backward are traced here, under the
+        # scan: the span is that share of ``step.trace``'s seconds (the
+        # counts stay ``step.trace``'s)
+        with _trace.phase("fwd_bwd"), _trace.span(
+                "step.trace_model", owns_counts=False, nbps=nbps):
             (grads, packed_stats, loss, _), _ = jax.lax.scan(
                 micro, (zeros, packed_stats, jnp.zeros((), jnp.float32),
                         jnp.zeros((), jnp.int32)),

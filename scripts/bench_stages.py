@@ -194,7 +194,7 @@ def main():
         print(f"--- profile attribution: {table['attributed_ms']:.3f} "
               f"of {table['total_ms']:.3f} ms/iter attributed ---",
               file=sys.stderr)
-        for ph, ms in table["phases"].items():
+        for ph, ms in attrib.phase_rows(table):
             print(f"  {ms:8.4f}  {ph}", file=sys.stderr)
         for b, phases in table["buckets"].items():
             tot = sum(phases.values())

@@ -120,7 +120,7 @@ def main():
             table = attrib.phase_table(events, steps=args.k)
             print(f"  --- phases ({table['attributed_ms']:.3f} of "
                   f"{table['total_ms']:.3f} ms/step attributed) ---")
-            for ph, ms in table["phases"].items():
+            for ph, ms in attrib.phase_rows(table):
                 print(f"  {ms:8.4f}  {ph}")
 
     d, b = per_config["dgc"], per_config["dense"]

@@ -371,6 +371,56 @@ def test_phase_table_against_fixture():
 
 
 @pytest.mark.fast
+def test_phase_table_prints_a_part_as_a_row_under_its_phase():
+    """A part's time is in its phase's total; ``parts`` splits it and
+    ``phase_rows`` prints each part beneath its phase (the innermost
+    token decides: an op under ``update.exchange`` AND an engine phase is
+    the engine phase's, not glue)."""
+    def ev(tf_op, dur):
+        return {"name": "fusion", "dur": dur, "args": {"tf_op": tf_op}}
+
+    step = "jit(step_fn)/"
+    up = step + "dgcph.update/"
+    ex = up + "dgcph.update.exchange/"
+    events = [
+        ev(step + "dgcph.fwd_bwd/conv", 1000),
+        ev(step + "dgcph.fwd_bwd/dgcph.fwd_bwd.pack/concatenate", 70),
+        ev(ex + "convert", 5),                              # glue
+        ev(ex + "dgcph.select.b3/sort", 80),
+        ev(ex + "dgcph.apply/dgcph.apply/dgcph.apply.sort/sort", 120),
+        ev(ex + "dgcph.apply/dgcph.apply/dgcph.apply.stage/select_n", 30),
+        ev(ex + "dgcph.apply/dgcph.apply/pallas_call", 600),    # the pass
+        ev(up + "dgcph.update.optimizer/add", 200),
+        ev("", 40),
+    ]
+    t = attrib.phase_table(events, steps=1)
+    assert t["phases"] == {
+        "select": pytest.approx(0.08), "apply": pytest.approx(0.75),
+        "fwd_bwd": pytest.approx(1.07), "update": pytest.approx(0.205)}
+    assert t["parts"] == {
+        "apply.sort": pytest.approx(0.12), "apply.stage": pytest.approx(0.03),
+        "fwd_bwd.pack": pytest.approx(0.07),
+        "update.exchange": pytest.approx(0.005),
+        "update.optimizer": pytest.approx(0.2)}
+    # totals are what they were without the parts
+    assert t["total_ms"] == pytest.approx(2.145)
+    assert t["attributed_ms"] == pytest.approx(sum(t["phases"].values()))
+    assert t["buckets"] == {"b3": {"select": pytest.approx(0.08)}}
+    assert [row for row, _ in attrib.phase_rows(t)] == [
+        "select", "apply", "  apply.sort", "  apply.stage", "fwd_bwd",
+        "  fwd_bwd.pack", "update", "  update.exchange",
+        "  update.optimizer"]
+    assert dict(attrib.phase_rows(t))["  apply.sort"] == t["parts"][
+        "apply.sort"]
+    # a trace recorded before the parts has none, and the rows are the
+    # phases
+    dev = attrib.device_events(attrib.load_trace_events(FIXTURE))
+    old = attrib.phase_table(dev, steps=1)
+    assert old["parts"] == {} and attrib.phase_rows(old) == list(
+        old["phases"].items())
+
+
+@pytest.mark.fast
 def test_profile_json_roundtrip(tmp_path):
     dev = attrib.device_events(attrib.load_trace_events(FIXTURE))
     t = attrib.phase_table(dev, steps=2)

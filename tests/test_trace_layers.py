@@ -7,15 +7,20 @@ device scopes and kernel names in the lowered step (docs/TELEMETRY.md
   ``stage_ahead``; one batch's ``seq`` links them across the producer
   thread;
 * step — ``step.trace`` owns every count made while the step is traced;
+  ``step.trace_model`` and ``exchange.trace`` split its seconds and own
+  none;
 * exchange — ``exchange.collective`` bytes are the traced operands' bytes
   on the 8-device CPU mesh, for both engines;
 * scopes — the parts, ``params_view``, ``plumbing`` and the dense
-  engine's ``dense`` reach the compiled text when on;
+  engine's ``dense`` reach the compiled text when on; no op the step
+  lowers from ``compression/``, ``optim/`` or ``ops/`` is without one;
 * kernels — every ``pl.pallas_call`` site passes a unique ``name=`` that
   reaches the lowered text.
 """
 
 import ast
+import os
+import re
 import threading
 import time
 
@@ -289,6 +294,66 @@ def test_counts_hang_under_step_trace_and_a_second_trace_does_not_double(
                     + layout.total - engine.T) * 4
 
 
+@pytest.mark.parametrize("compressor, engine_class, counted", [
+    ("dgc", "FlatDGCEngine", ["exchange.apply"] + ["exchange.collective"] * 3),
+    ("none", "FlatDenseExchange", ["exchange.collective"]),
+])
+def test_child_spans_split_step_trace_and_own_no_count(rec, mesh8, compressor,
+                                                       engine_class, counted):
+    """``step.trace_model`` (round the scan) and ``exchange.trace`` (round
+    ``engine.exchange``) are children of ``step.trace``, once a trace, and
+    together no longer than it; the counts made inside ``engine.exchange``
+    keep ``step.trace`` as their parent, as the benchmark's readers (and
+    ``program_records.collective_bytes``) key on."""
+    from dgc_tpu.analysis.suite import build_fixture
+    state, step, _, inputs = build_fixture(mesh8, compressor=compressor,
+                                           donate=False)
+    step.lower(state, *inputs)
+    _, step2, _, _ = build_fixture(mesh8, compressor=compressor,
+                                   donate=False)
+    step2.lower(state, *inputs)
+    records = rec.records()
+    traces = _named(records, "step.trace")
+    models = _named(records, "step.trace_model")
+    exchanges = _named(records, "exchange.trace")
+    assert len(traces) == len(models) == len(exchanges) == 2
+    for trace, model, exchange in zip(traces, models, exchanges):
+        assert model["parent"] == exchange["parent"] == trace["id"]
+        assert model["args"] == {"nbps": 1}
+        assert exchange["args"] == {"engine": engine_class}
+        # they nest in time: the model first, then the exchange
+        assert (trace["t0_ns"] <= model["t0_ns"] <= model["t1_ns"]
+                <= exchange["t0_ns"] <= exchange["t1_ns"] <= trace["t1_ns"])
+        # every count of the trace was made while ``exchange.trace`` was
+        # open, and hangs under ``step.trace`` all the same
+        inside = [r for r in records if r["kind"] == "count"
+                  and exchange["t0_ns"] <= r["t_ns"] <= exchange["t1_ns"]]
+        assert sorted(c["name"] for c in inside) == counted
+        assert all(c["parent"] == trace["id"] for c in inside)
+    ids = {t["id"] for t in traces}
+    assert all(c["parent"] in ids for c in records if c["kind"] == "count")
+
+
+@pytest.mark.fast
+def test_a_span_that_owns_no_counts_nests_and_leaves_them_to_its_parent(rec):
+    with rec.span("outer") as outer:
+        with rec.span("timing", owns_counts=False) as timing:
+            rec.count("made.inside", 1)
+            with rec.span("inner") as inner:
+                rec.count("made.deeper", 2)
+        rec.count("made.after", 3)
+    with rec.span("alone", owns_counts=False):
+        rec.count("made.under.none", 4)
+    records = rec.records()
+    spans = {r["name"]: r for r in records if r["kind"] == "span"}
+    counts = {r["name"]: r["parent"] for r in records if r["kind"] == "count"}
+    assert spans["timing"]["parent"] == outer.id
+    assert spans["inner"]["parent"] == timing.id         # it nests
+    assert "owns_counts" not in spans["timing"]["args"]
+    assert counts == {"made.inside": outer.id, "made.deeper": inner.id,
+                      "made.after": outer.id, "made.under.none": None}
+
+
 # --------------------------------------------------------------------- #
 # device scopes                                                          #
 # --------------------------------------------------------------------- #
@@ -316,6 +381,126 @@ def test_new_scopes_reach_the_compiled_text_when_on(rec, mesh8, compressor,
                    if " all-reduce(" in line and "f32[" in line
                    and "dgcph.update.exchange" in line]
         assert reduces and all("dgcph.dense" in line for line in reduces)
+
+
+PARTS = ["dgcph.apply.sort", "dgcph.apply.stage", "dgcph.fwd_bwd.pack"]
+
+
+@pytest.mark.parametrize("compressor, on, present", [
+    ("dgc", True, PARTS),
+    ("none", True, PARTS[2:]),      # the dense arm packs its gradients too
+    ("dgc", False, []),
+    ("none", False, []),
+])
+def test_scope_parts_reach_the_compiled_text_when_on_and_only_then(
+        mesh8, compressor, on, present):
+    """The parts of ``apply`` and ``fwd_bwd``: off the chip the apply pass
+    is the opt-in kernel's (interpreted), whose staging is the step's own
+    on the chip (``kernels._sorted_pairs``)."""
+    from dgc_tpu.analysis.suite import build_fixture
+    prev = trace_mod.enable(on)
+    try:
+        state, step, _, inputs = build_fixture(
+            mesh8, compressor=compressor, donate=False,
+            compressor_kwargs=({"fused_apply": True} if compressor == "dgc"
+                               else None))
+        text = step.lower(state, *inputs).compile().as_text()
+    finally:
+        trace_mod.enable(prev)
+    for token in PARTS:
+        assert (token in text) == (token in present), token
+    if not on:
+        assert "dgcph." not in text
+
+
+_ALIAS = re.compile(r"^(#loc\d*) = loc\((.*)\)$", re.M)
+_CALLSITE = re.compile(r"callsite\((#loc\d+) at (#loc\d+)\)$")
+_NAMED = re.compile(r'"(.*)"\((#loc\d+)\)$')
+_FILE = re.compile(r'"(/[^"]+)":\d+')
+_FUNC = re.compile(r"^\s*func\.func (?:public |private )?@([\w.$-]+)\(")
+_CALL = re.compile(r"\bcall @([\w.$-]+)\(")
+_OP_LOC = re.compile(r"loc\((#loc\d+)\)$")
+
+
+def _unscoped_ops(text, under):
+    """Ops of a module lowered with ``debug_info=True`` whose innermost
+    source frame lies in one of the directories ``under`` and which carry
+    no ``dgcph.`` scope: (function, name stack, file) of each. An op of an
+    outlined function (an inner ``jit``, a ``closed_call``) carries a
+    name stack relative to its call: it is scoped where every call of its
+    function is."""
+    locs = dict(_ALIAS.findall(text))
+
+    def resolve(alias):
+        names, frames = [], False
+        while alias in locs:
+            value = locs[alias]
+            site = _CALLSITE.match(value)
+            named = _NAMED.match(value)
+            if site:
+                alias, frames = site.group(1), True     # the callee's frame
+            elif named:
+                if not frames:
+                    names.append(named.group(1))
+                alias = named.group(2)
+            else:
+                found = _FILE.match(value)
+                return "/".join(names), found and found.group(1)
+        return "/".join(names), None
+
+    ops, calls, func = [], {}, None
+    for line in text.splitlines():
+        opened = _FUNC.match(line)
+        if opened:
+            func = opened.group(1)
+            continue
+        at = _OP_LOC.search(line)
+        if func is None or at is None or line.lstrip().startswith("^"):
+            continue
+        name, file = resolve(at.group(1))
+        called = _CALL.search(line)
+        if called:
+            calls.setdefault(called.group(1), []).append((func, name))
+        ops.append((func, name, file))
+
+    scoped = {}
+
+    def is_scoped(fn):
+        if fn not in scoped:
+            scoped[fn] = False                  # a cycle is not a scope
+            sites = calls.get(fn, [])
+            scoped[fn] = bool(sites) and all(
+                "dgcph." in name or is_scoped(caller)
+                for caller, name in sites)
+        return scoped[fn]
+
+    return [(fn, name, file) for fn, name, file in ops
+            if file and any(f"/dgc_tpu/{d}/" in file for d in under)
+            and "dgcph." not in name and not is_scoped(fn)]
+
+
+@pytest.mark.parametrize("kwargs", [
+    {}, {"compressor_kwargs": {"fused_apply": True}},
+    {"compressor": "none"}], ids=["dgc", "dgc-stream", "dense"])
+def test_no_op_of_the_engine_the_optimizer_or_the_kernels_is_unscoped(
+        rec, mesh8, kwargs):
+    """As far as the CPU lowering goes: every op the step lowers from
+    ``compression/``, ``optim/`` or ``ops/`` carries a ``dgcph`` token, so
+    a device profile files its time under a phase and not under
+    ``unattributed``."""
+    from dgc_tpu.analysis.suite import build_fixture
+    state, step, _, inputs = build_fixture(mesh8, donate=False, **kwargs)
+    text = step.lower(state, *inputs).as_text(debug_info=True)
+    # the parser sees the scoped ops too: those of the sort, where there
+    # is one
+    assert ("dgcph.apply.sort/sort" in text) == (
+        "compressor_kwargs" in kwargs)
+    assert os.sep + os.path.join("dgc_tpu", "compression", "flat.py") in text
+    assert _unscoped_ops(text, ("compression", "optim", "ops")) == []
+    # and it finds what has none: the model's ops are under ``fwd_bwd``
+    # only through the scan's call
+    assert _unscoped_ops(text.replace("dgcph.", "dgcpx."),
+                         ("compression", "optim", "ops"))
 
 
 # --------------------------------------------------------------------- #
