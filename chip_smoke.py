@@ -34,7 +34,10 @@ Stages, in order:
    persistent compile cache.
 6. ``kernels`` ``scripts/tpu_check.py``'s compiled-vs-reference bitwise
    check of every Pallas kernel, in this same process (the chip belongs
-   to one process).
+   to one process); with it the apply pass that runs the optimizer's
+   rule (``check_update_pass``) and the gradient pack's placement pass
+   (``check_pack_pass``), each against the passes it replaced at the
+   benchmark's own geometry.
 
 Outputs go under ``runs/`` only (the full summary is
 ``runs/chip_smoke.json`` and the ``summary:`` line). The last line of

@@ -35,7 +35,9 @@ tests exercise:
 * **trace markers are free**: trace=off (default) is byte-identical to
   the plain build with no ``dgcph`` token in the compiled module;
   trace=on adds ZERO collectives while the ``dgcph.*`` phase markers
-  land in compiled op metadata (what telemetry/attrib aggregates).
+  land in compiled op metadata (what telemetry/attrib aggregates). The
+  gradient pack's placement pass (``kernels.place_rows``), where the
+  layout has a tensor for it, lowers under part ``fwd_bwd.pack``.
 * **elastic restart is free when off**: elastic resharding is restore-
   time host code — a step whose batch geometry went through
   ``resolve_batch_geometry`` (identity) is byte-identical to the plain
@@ -80,7 +82,8 @@ DENSE_COLLECTIVES = {"all-gather": 0, "all-reduce": 2}
 
 
 def build_fixture(mesh=None, world: int = 8, compressor: str = "dgc",
-                  compressor_kwargs=None, plan=None, **step_kwargs):
+                  compressor_kwargs=None, plan=None, head: int = 10,
+                  **step_kwargs):
     """(state, step, setup, (images, labels, key)) on a tiny model.
 
     Mirrors tests/test_telemetry.py's ``flat_step_pair`` geometry; any
@@ -90,7 +93,9 @@ def build_fixture(mesh=None, world: int = 8, compressor: str = "dgc",
     ``{"checksum": True}``). ``plan`` is an exchange plan
     (``dgc_tpu.compression.planner``) threaded through
     ``make_flat_setup`` — the engine re-fits it to the fixture's bucket
-    geometry."""
+    geometry. ``head`` is the width of the last layer: at 128 its
+    kernel [8, 128] is one (8, 128) tile at the head of the compressed
+    block, the smallest tensor the gradient pack can place."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -112,7 +117,7 @@ def build_fixture(mesh=None, world: int = 8, compressor: str = "dgc",
             x = nn.Conv(8, (3, 3))(x)
             x = nn.BatchNorm(use_running_average=not train)(x)
             x = nn.relu(x)
-            return nn.Dense(10)(x.mean(axis=(1, 2)))
+            return nn.Dense(head)(x.mean(axis=(1, 2)))
 
     model = M()
     v = dict(model.init(jax.random.PRNGKey(0), jnp.zeros((1, 16, 16, 3))))
@@ -353,6 +358,26 @@ def run_contract_suite(mesh=None, log: Callable[[str], None] = None,
             require_substrings_compiled=step_scopes + ["dgcph.dense"],
             no_f64=True)
         run(tron_dn.name, tron_dn.check)
+        # the gradient pack's placement pass (``kernels.place_rows``, off
+        # the chip interpreted) is the pack's: with the constant lowered
+        # to the fixture's one-tile kernel its ops sit under part
+        # ``fwd_bwd.pack``, where ``step.grad_pack_ms`` reads them
+        from dgc_tpu.compression import flat as _flat
+        prev_min, _flat.PLACE_MIN_BYTES = _flat.PLACE_MIN_BYTES, 4096
+        try:
+            state_pk, step_pk, setup_pk, _ = build_fixture(
+                mesh, donate=False, head=128)
+            placed = setup_pk.layout.placed_names()
+            pack = _step_contract(
+                "pack-pass-under-fwd_bwd.pack", state_pk, step_pk, inputs,
+                require_substrings_compiled=[
+                    "dgcph.fwd_bwd.pack/place_rows"],
+                no_f64=True)
+            run(pack.name, lambda: pack.check() + (
+                [] if placed == ("Dense_0/kernel",)
+                else [f"placed {placed}, expected Dense_0/kernel"]))
+        finally:
+            _flat.PLACE_MIN_BYTES = prev_min
     finally:
         _tr.enable(prev_tr)
 

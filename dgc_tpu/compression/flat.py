@@ -92,6 +92,27 @@ _REGIMES = {
 }
 
 
+#: bytes from which :meth:`ParamLayout.flatten` places a tile-aligned
+#: tensor into its slot (``kernels.place_rows``) instead of concatenating
+#: it: the smallest tensor that read a win IN THE STEP. Read on a v5e
+#: (PERF.md §6, PR 43). Bare programs, 25 packs in one program, ms a pack
+#: less the fc matmuls' own 0.68: VGG-16-BN's DGC layout concatenated
+#: 4.67, fc1 + fc2 (411 + 67 MB) placed 1.89, the five 9.4 MB
+#: convolutions placed too 1.59; ResNet-50's concatenated 0.73, its four
+#: tensors of 8.4-9.4 MB placed 0.54, everything from 4 MB 0.36, from
+#: 1 MB 0.35. In the step the small ones' win is not there: a 9.4 MB
+#: gradient leaves the backward pass in VMEM and the concatenation reads
+#: it from there, while a Mosaic call takes its operand from HBM. VGG
+#: with the convolutions placed too: ``fwd_bwd`` 47.794 against 47.790
+#: ms. ResNet-50 at 8 MiB / 4 MiB / 1 MiB: ``fwd_bwd`` -0.24 / -0.34 /
+#: -0.36 ms, but compensate +0.14 (its gradient operand no longer comes
+#: from VMEM: 100.2% -> 82.7% of its roofline) and the un-scoped copies
+#: +0.15 / +0.08 / +0.13: +0.05 / -0.11 / -0.09 ms of a 53 ms step. So
+#: the bound is fc2's own size, and every ResNet lowers to the
+#: concatenation
+PLACE_MIN_BYTES = 64 << 20
+
+
 def _round_up(n: int, align: int) -> int:
     return -(-n // align) * align
 
@@ -246,29 +267,96 @@ class ParamLayout:
 
     # -------------------------------------------------------------- #
 
-    def flatten(self, tree) -> jax.Array:
+    def placed_names(self) -> Tuple[str, ...]:
+        """The tensors :meth:`flatten` with ``place`` puts into their slot
+        with ``kernels.place_rows`` instead of concatenating them, in
+        storage order: geometry alone. A float32 tensor of
+        ``PLACE_MIN_BYTES`` or more whose 2-D view ``[prod(shape[:-1]),
+        shape[-1]]`` is whole (8, 128) tiles (so the view of the backward
+        pass's output is a bitcast), whose slot starts and ends on a tile
+        of the buffer's [total / 128, 128] view, and which has no row
+        tail."""
+        if self.dtype.itemsize != 4:
+            return ()
+        cols_of = {n: g.cols for g in self.buckets for n in g.names}
+        out = []
+        for n in self.names:
+            shape, size = self.shapes[n], self.sizes[n]
+            if (len(shape) >= 2 and 4 * size >= PLACE_MIN_BYTES
+                    and cols_of.get(n, size) == size
+                    and kernels.place_rows_eligible(
+                        self.total, self.offsets[n],
+                        size // shape[-1], shape[-1])):
+                out.append(n)
+        return tuple(out)
+
+    def pack_bytes(self) -> Dict[str, int]:
+        """Bytes of the flat buffer :meth:`flatten` with ``place`` writes
+        by each path: ``place`` (:meth:`placed_names`) and ``concat`` (the
+        rest, structural zeros included)."""
+        item = self.dtype.itemsize
+        placed = item * sum(self.sizes[n] for n in self.placed_names())
+        return {"place": placed, "concat": self.total * item - placed}
+
+    def flatten(self, tree, place: bool = False) -> jax.Array:
         """Pytree -> flat [P] (layout order, structural-zero row tails /
         gaps). Traced into the train step as the gradient packer
-        (training/step.py), where XLA fuses the concatenation into the
-        backward's writes — keep it free of host-side work."""
+        (training/step.py) — keep it free of host-side work. XLA does NOT
+        fuse the concatenation into the backward's writes: on the chip it
+        is an op of its own that copies every gradient once more, 1-D to
+        1-D, at 316-367 GB/s (half the chip's sustained rate), after a
+        relayout copy of each large 2-D gradient from the backward pass's
+        (8, 128) tiles to row-major (3.03 + 1.45 ms a step at VGG-16-BN;
+        PERF.md §6, PR 43). So a tensor of :meth:`placed_names` is written
+        into its slot by ONE pass from the tiles it was produced in, and
+        only the runs between such slots are concatenated, each put in
+        place by a ``dynamic_update_slice`` of the same buffer: every slot
+        of [0, total) is written exactly once, bitwise as the
+        concatenation of everything writes it. A layout with no such
+        tensor (every dense layout, every ResNet) traces the plain
+        concatenation, and so does every caller that does not say
+        ``place``: the step's body does (per-device code under
+        ``shard_map``); a state built under a ``jit`` over several
+        devices cannot, because XLA cannot partition a Mosaic call."""
         if not self.names:
             return jnp.zeros((0,), self.dtype)
         named, _ = named_flatten(tree)
-        parts = []
+        placed = self.placed_names() if place else ()
+        flat = None
+        for n in placed:
+            flat = kernels.place_rows(
+                named[n].reshape(-1, self.shapes[n][-1]), self.offsets[n],
+                self.total, into=flat)
+        for start, parts in self.unplaced_runs(named, placed):
+            run = jnp.concatenate(parts)
+            flat = run if flat is None else jax.lax.dynamic_update_slice(
+                flat, run, (start,))
+        return flat
+
+    def unplaced_runs(self, named, placed) -> List[Tuple[int, list]]:
+        """``(start, parts)`` of each run of the buffer between the slots
+        of ``placed``, in storage order: the 1-D forms of the tensors
+        ``named`` and the structural zeros that, concatenated, fill
+        [start, the next placed slot or total)."""
+        # storage order: (tensor or None for structural zeros, elements)
+        order: List[Tuple[Optional[str], int]] = []
         for g in self.buckets:
             for n in g.names:
-                parts.append(jnp.ravel(named[n]))
-                if g.cols > self.sizes[n]:
-                    parts.append(jnp.zeros((g.cols - self.sizes[n],),
-                                           self.dtype))
-        if self.t_compressed > self.t_data:
-            parts.append(jnp.zeros((self.t_compressed - self.t_data,),
-                                   self.dtype))
-        parts += [jnp.ravel(named[n]) for n in self.dense_names]
-        if self.total > self.p_data_end:
-            parts.append(jnp.zeros((self.total - self.p_data_end,),
-                                   self.dtype))
-        return jnp.concatenate(parts)
+                order += [(n, self.sizes[n]), (None, g.cols - self.sizes[n])]
+        order.append((None, self.t_compressed - self.t_data))
+        order += [(n, self.sizes[n]) for n in self.dense_names]
+        order.append((None, self.total - self.p_data_end))
+        runs: List[Tuple[int, list]] = [(0, [])]
+        at = 0
+        for n, count in order:
+            at += count
+            if n in placed:  # dgclint: ok[tracer-branch] — a tuple of names
+                runs.append((at, []))
+            elif n is not None:
+                runs[-1][1].append(jnp.ravel(named[n]))
+            elif count > 0:
+                runs[-1][1].append(jnp.zeros((count,), self.dtype))
+        return [run for run in runs if run[1]]
 
     def pieces(self) -> List[Tuple[int, int, Tuple[str, ...]]]:
         """The flat buffer cut where its content changes hands: half-open

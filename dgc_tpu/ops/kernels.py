@@ -2169,3 +2169,90 @@ def _ovf_bwd(base, numel, total, _, g):
 
 
 _opaque_from.defvjp(_ovf_fwd, _ovf_bwd)
+
+
+# ------------------------------------------------------------------ #
+# placement into an aligned slot of a flat buffer                    #
+# ------------------------------------------------------------------ #
+
+# appended here, not in the list at the top: a line more up there moves
+# every Mosaic body's source locations, which are in the lowered text
+__all__ += ["place_rows", "place_rows_eligible"]
+
+#: bytes of one block of :func:`place_rows` (the tensor's rows in, the
+#: same bytes of the flat buffer out; both double-buffered: 4x this of
+#: VMEM)
+_PLACE_BLOCK_BYTES = 2 << 20
+
+
+def place_rows_eligible(total: int, base: int, rows: int, cols: int) -> bool:
+    """Whether :func:`place_rows` can put a [rows, cols] float32 tensor at
+    ``flat[base : base + rows * cols]`` of a [total] buffer: the view of
+    the tensor is whole (8, 128) tiles, the slot starts and ends on a
+    tile of the buffer's [total / 128, 128] view, and eight rows fit a
+    block."""
+    numel = rows * cols
+    return (rows > 0 and cols > 0 and cols % _LANE == 0
+            and rows % _SUBLANE == 0
+            and total % (_SUBLANE * _LANE) == 0
+            and base % (_SUBLANE * _LANE) == 0 and base + numel <= total
+            and _SUBLANE * cols * 4 <= _PLACE_BLOCK_BYTES)
+
+
+def _place_kernel(x_ref, *refs):
+    # row r's 128-lane chunk j is row r * chunks + j of the buffer's
+    # [total / 128, 128] view: one strided sublane store a chunk
+    o_ref = refs[-1]
+    block_rows, cols = x_ref.shape
+    chunks = cols // _LANE
+    for j in range(chunks):
+        o_ref[pl.ds(j, block_rows, stride=chunks), :] = (
+            x_ref[:, j * _LANE:(j + 1) * _LANE])
+
+
+def place_rows(x: jax.Array, base: int, total: int,
+               into: jax.Array = None) -> jax.Array:
+    """``into`` with ``into[base : base + x.size] = x.reshape(-1)``,
+    written in ONE pass that reads the 2-D ``x`` in the (8, 128) tiles
+    its producer wrote and writes the slot in place: the mirror image of
+    :func:`opaque_view_from`, which streams a tensor OUT of an aligned
+    slot. ``into`` (flat [total], aliased to the result) may be None:
+    the call then CREATES the buffer and every slot outside
+    [base, base + x.size) is undefined, so the caller writes each of
+    them exactly once. A ``jnp.concatenate`` over the same tensor costs
+    two passes on the chip: XLA relays the tiles row-major first (its
+    1-D form), then copies the 1-D operand into the result at half the
+    chip's rate (PERF.md §6, PR 43). Caller must check
+    :func:`place_rows_eligible`. Bitwise a copy."""
+    rows, cols = x.shape
+    assert (x.dtype.itemsize == 4
+            and place_rows_eligible(total, base, rows, cols)), (
+        x.dtype, x.shape, base, total)
+    chunks = cols // _LANE
+    block_rows = _SUBLANE
+    while (rows % (2 * block_rows) == 0
+           and 2 * block_rows * cols * 4 <= _PLACE_BLOCK_BYTES):
+        block_rows *= 2
+    out_rows = block_rows * chunks
+    base_row = base // _LANE
+    in_specs = [pl.BlockSpec((block_rows, cols), lambda i: (i, 0),
+                             memory_space=pltpu.VMEM)]
+    args = [x]
+    if into is not None:
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+        args.append(into.reshape(total // _LANE, _LANE))
+    # element-indexed: the slot starts on a tile, not on a block
+    out_spec = pl.BlockSpec(
+        (pl.Element(out_rows), pl.Element(_LANE)),
+        lambda i: (pl.multiple_of(base_row + i * out_rows, _SUBLANE), 0),
+        memory_space=pltpu.VMEM)
+    out = pl.pallas_call(
+        _place_kernel,
+        grid=(rows // block_rows,),
+        out_shape=jax.ShapeDtypeStruct((total // _LANE, _LANE), x.dtype),
+        in_specs=in_specs, out_specs=out_spec,
+        input_output_aliases={1: 0} if into is not None else {},
+        interpret=_interpret(),
+        name="place_rows",
+    )(*args)
+    return out.reshape(-1)

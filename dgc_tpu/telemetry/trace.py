@@ -27,7 +27,8 @@ on two instruments, and with it off neither leaves anything behind:
   Every layer reaches it as a module function, so spans (``input.*``,
   ``step.*``, ``exchange.trace``, ``checkpoint.save``, ``eval``) and
   counts (``input.queue_depth``, ``exchange.collective``,
-  ``exchange.apply``, ``optimizer.wd_mask``) sit where the work happens.
+  ``exchange.apply``, ``step.pack``, ``optimizer.wd_mask``) sit where the
+  work happens.
   A span records its name, start and end (``perf_counter_ns``), thread,
   the id of the span that caused it and the ids its request carries
   (``step``, ``seq``; inherited by what it causes); a count belongs to

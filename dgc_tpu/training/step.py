@@ -274,7 +274,8 @@ def build_train_step(apply_fn: Callable, dist_opt: DistributedOptimizer,
         layout, stats_layout, engine = flat
         unpack_params = layout.unflatten
         unpack_stats = stats_layout.unflatten   # empty layout -> {} and back
-        pack_grads = layout.flatten
+        # per-device code under shard_map: the giant gradients are placed
+        pack_grads = partial(layout.flatten, place=True)
         pack_stats = stats_layout.flatten
 
         want_health = (guards is not None
@@ -418,6 +419,11 @@ def build_train_step(apply_fn: Callable, dist_opt: DistributedOptimizer,
         stats0, memory0 = packed_stats, memory
         with _trace.phase("plumbing"):
             zeros = jax.tree.map(jnp.zeros_like, state.params)
+        if flat is not None and model_dtype is None:
+            # how the gradients reach the flat buffer, once a trace: the
+            # bytes ``pack_grads`` places and the bytes it concatenates
+            for path, nbytes in layout.pack_bytes().items():
+                _trace.count("step.pack", nbytes, path=path)
         # the model's forward and backward are traced here, under the
         # scan: the span is that share of ``step.trace``'s seconds (the
         # counts stay ``step.trace``'s)

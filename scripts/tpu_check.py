@@ -24,6 +24,7 @@ Prints ONE JSON line like bench.py:
 Usage: python scripts/tpu_check.py
 """
 
+import contextlib
 import json
 import os
 import sys
@@ -246,6 +247,7 @@ def check_kernels():
         jax.jit(lambda v_, i_, f_: kernels.dgc_apply_rows_reference(
             v_, i_, f_, T50, divisor=4.0))(pval, pidx, pflag))
     out.update(check_update_pass())
+    out.update(check_pack_pass())
     return out
 
 
@@ -341,6 +343,129 @@ def _update_pass_case(name, opt, T, P, per, W, rng):
         out[f"{name}_count{count}"] = bool(same(want, got, p))
         del want, got
     return out
+
+
+#: a ``PLACE_MIN_BYTES`` no tensor reaches: the pack concatenates
+NEVER = 1 << 60
+
+
+@contextlib.contextmanager
+def place_min_bytes(bound):
+    """``flat.PLACE_MIN_BYTES`` at ``bound`` for what is traced inside
+    (the constant is read while ``ParamLayout.flatten`` is traced)."""
+    from dgc_tpu.compression import flat as flat_mod
+    shipped, flat_mod.PLACE_MIN_BYTES = flat_mod.PLACE_MIN_BYTES, bound
+    try:
+        yield
+    finally:
+        flat_mod.PLACE_MIN_BYTES = shipped
+
+
+def dgc_layout(make):
+    """(shapes of the model's parameters, its DGC compressor, layout)."""
+    from dgc_tpu import DGCCompressor, DGCSGDMemory
+    from dgc_tpu.compression.flat import ParamLayout
+    from dgc_tpu.utils.pytree import named_flatten
+
+    model = make()
+    shapes = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 224, 224, 3)),
+        train=True))["params"]
+    named, _ = named_flatten(shapes)
+    comp = DGCCompressor(0.001, memory=DGCSGDMemory(momentum=0.9))
+    comp.initialize((n, p) for n, p in named.items() if p.ndim > 1)
+    return shapes, comp, ParamLayout.for_compressor(shapes, comp)
+
+
+def check_pack_pass(steps: int = 3):
+    """The gradient pack's placement pass (``kernels.place_rows`` through
+    ``ParamLayout.flatten``) against the concatenation it replaces,
+    compiled, at VGG-16-BN's and ResNet-50's full DGC layouts: the flat
+    buffer bitwise, with the constant as shipped (VGG places fc1 + fc2,
+    478,150,656 B; ResNet-50 nothing) and lowered to 1 MiB (8 and 20
+    tensors: convolution kernels through their 2-D view, slots at every
+    tile-aligned base the layouts have); then VGG's whole DGC step,
+    ``steps`` dispatches from one state, every array of the state
+    bitwise the concatenate form's. Nothing that decides ``correct`` in
+    the benchmark runs the step's pack (``PERF.md`` §7.1c). Returns
+    {name: bool}."""
+    from dgc_tpu.compression import flat as flat_mod
+    from dgc_tpu.models import resnet50, vgg16_bn
+
+    shipped = flat_mod.PLACE_MIN_BYTES
+    out = {}
+
+    def packed(layout, tree, bound):
+        with place_min_bytes(bound):
+            return jax.jit(
+                lambda t: layout.flatten(t, place=True))(tree)
+
+    same = jax.jit(jnp.array_equal)
+    for make, at_shipped in ((vgg16_bn, 478_150_656), (resnet50, 0)):
+        shapes, _, layout = dgc_layout(make)
+        ok = layout.pack_bytes()["place"] == at_shipped
+        leaves, treedef = jax.tree.flatten(shapes)
+        keys = jax.random.split(jax.random.PRNGKey(43), len(leaves))
+        tree = jax.tree.unflatten(treedef, [
+            jax.random.normal(k, s.shape, jnp.float32)
+            for k, s in zip(keys, leaves)])
+        want = packed(layout, tree, NEVER)
+        for name, bound in (("shipped", shipped), ("1MiB", 1 << 20)):
+            got = packed(layout, tree, bound)
+            out[f"pack_pass_{make.__name__}_{name}"] = ok and bool(
+                same(want, got))
+            del got
+        del want, tree
+    out[f"pack_pass_vgg16_bn_step_x{steps}"] = _pack_pass_steps(
+        vgg16_bn, steps)
+    return out
+
+
+def _pack_pass_steps(make, steps):
+    """``steps`` dispatches of ``make``'s flat DGC step (the benchmark's
+    optimizer constants, the optimizer's rule offered, state donated) at
+    the constant as shipped and out of reach: every array of the two
+    final states bitwise equal, and the pack placed where it should."""
+    from dgc_tpu import DistributedOptimizer, dgc_sgd
+    from dgc_tpu.compression import flat as flat_mod
+    from dgc_tpu.parallel import make_mesh
+    from dgc_tpu.training import (build_train_step, make_flat_setup,
+                                  make_flat_state, shard_state)
+
+    model = make()
+    world = len(jax.devices())
+    mesh = make_mesh(world)
+    rng = np.random.RandomState(43)
+    images = jnp.asarray(rng.randn(world * 8, 224, 224, 3), jnp.float32)
+    labels = jnp.asarray(rng.randint(0, 1000, world * 8), jnp.int32)
+
+    finals, placed = [], []
+    for bound in (flat_mod.PLACE_MIN_BYTES, NEVER):
+        with place_min_bytes(bound):
+            v = dict(jax.jit(lambda k: model.init(
+                k, jnp.zeros((1, 224, 224, 3)), train=True))(
+                    jax.random.PRNGKey(0)))
+            _, comp, _ = dgc_layout(make)
+            dist = DistributedOptimizer(
+                dgc_sgd(0.0125, momentum=0.9, weight_decay=5e-5), comp,
+                world_size=world)
+            setup = make_flat_setup(v, dist)
+            placed.append(setup.layout.pack_bytes()["place"])
+            state = shard_state(make_flat_state(v, dist, setup, world),
+                                mesh, dist_opt=dist)
+            del v
+            # as ``benchmark/build.py`` builds it: VGG has dropout
+            step = build_train_step(model.apply, dist, mesh, flat=setup,
+                                    use_dropout=True, donate=True)
+            for i in range(steps):
+                state, metrics = step(state, images, labels,
+                                      jax.random.PRNGKey(10 + i))
+            finals.append((state, metrics["loss"]))
+            del state, step
+    got, want = (jax.tree.leaves(f) for f in finals)
+    return (placed[0] > 0 and placed[1] == 0 and len(got) == len(want)
+            and all(bool(jnp.array_equal(a, b)) for a, b in zip(got, want))
+            and bool(jnp.isfinite(finals[0][1])))
 
 
 def check_recall(threshold: float = 0.95):

@@ -177,6 +177,7 @@ def test_contract_suite_all_green(suite_results):
     "recompile-guard-same-shapes",
     "shard-state-collective-free",
     "control-plane-host-only",
+    "pack-pass-under-fwd_bwd.pack",
 ])
 def test_suite_covers_named_pin(suite_results, pin):
     assert pin in {n for n, _ in suite_results}

@@ -524,15 +524,15 @@ def test_every_pallas_call_site_passes_a_unique_name():
         value = {k.arg: k.value for k in call.keywords}.get("name")
         names.append(value.value if isinstance(value, ast.Constant)
                      else getattr(value, "id", None))
-    assert len(names) == 14 and None not in names, names
+    assert len(names) == 15 and None not in names, names
     # the one shared site takes its name from its two callers
     assert names.count("name") == 1
     shared = [call.args[0].value
               for call in _kernel_calls("_payload_apply_call")]
     assert sorted(shared) == ["dgc_apply_rows", "payload_apply_bits"]
     every = [n for n in names if n != "name"] + shared
-    assert len(set(every)) == len(every) == 15
-    assert "payload_update_bits" in every
+    assert len(set(every)) == len(every) == 16
+    assert "payload_update_bits" in every and "place_rows" in every
     # a kernel carries the name of the jitted function that launches it
     for name in every:
         assert callable(getattr(kernels, name, None)) or callable(
@@ -543,6 +543,8 @@ def test_every_pallas_call_site_passes_a_unique_name():
     ("opaque_view", lambda: kernels.opaque_view(jnp.ones((16, 128)))),
     ("opaque_view_from",
      lambda: kernels.opaque_view_from(jnp.ones((8192,)), 1024, 2048)),
+    ("place_rows",
+     lambda: kernels.place_rows(jnp.ones((8, 256)), 1024, 8192)),
     ("fused_compensate_bits_cands", None),
     ("topk_rows", lambda: kernels.topk_rows(jnp.ones((8, 256)), 4)),
 ])
