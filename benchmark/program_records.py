@@ -16,10 +16,15 @@ def records() -> List[Dict[str, Any]]:
     return read() if callable(read) else []
 
 
-def span_seconds(name: str) -> List[float]:
-    """Durations of the spans called ``name``, in the order they closed."""
-    return [(r["t1_ns"] - r["t0_ns"]) * 1e-9 for r in records()
-            if r.get("kind") == "span" and r.get("name") == name]
+def span_seconds(name: str, under: Optional[str] = None) -> List[float]:
+    """Durations of the spans called ``name``, in the order they closed;
+    with ``under``, of those opened directly inside a span of that name."""
+    recs = records()
+    spans = [r for r in recs if r.get("kind") == "span"]
+    inside = {r["id"] for r in spans if r.get("name") == under}
+    return [(r["t1_ns"] - r["t0_ns"]) * 1e-9 for r in spans
+            if r.get("name") == name
+            and (under is None or r.get("parent") in inside)]
 
 
 def batch_seconds(setup_spans: Dict[str, List[float]]

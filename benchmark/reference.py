@@ -1,7 +1,7 @@
 """Plain ``jax.numpy`` float32 reference of what the exchange must keep.
 
-Independent of the code under test: no kernel, no flat-engine helper, no
-bit-packed record. Three pieces, all straightforward:
+Independent of the code under test: no kernel, no flat-engine helper.
+Six pieces, all straightforward:
 
 * momentum correction with local accumulation (Lin et al., ICLR 2018,
   section 3.1; the reference's ``memory.py``): ``u <- m*u + g``,
@@ -10,7 +10,16 @@ bit-packed record. Three pieces, all straightforward:
   on the float's bit pattern (non-negative floats order like their
   integer bits), because an exact ``top_k`` of a 100M-wide row costs
   seconds and this costs 31 counting passes;
-* recall of a sent set against that exact top-k.
+* recall of a sent set against that exact top-k;
+* the same correction where nothing accumulates, for the tensors that are
+  exchanged dense (the reference's ``memory.py:64-70``, ``accumulate=
+  False``): the corrected gradient goes to the optimizer whole;
+* the optimizer's rule, DGC-split SGD (the reference's ``sgd.py:30-70``;
+  ``SURVEY.md`` section 2, point 9): momentum was applied before the
+  compression, so the optimizer runs it over the weight-decay term alone
+  and adds the exchanged gradient raw;
+* the transmit record as the memory states it: one bit a coordinate, 32
+  rows of the [rows, 128] view of the buffer to a row of words.
 """
 
 import jax
@@ -62,3 +71,62 @@ def topk_hits(values, sent):
     thr = kth_largest_bits(bits, n_sent)
     hits = jnp.sum(sent & (bits >= thr[:, None]), axis=1).astype(jnp.int32)
     return hits, n_sent
+
+
+def momentum_dense(u, g, momentum: float, nesterov: bool):
+    """(u', out) in float32 for a tensor that is exchanged dense: ``g`` is
+    the workers' mean gradient, nothing accumulates, and ``out`` is what
+    the optimizer gets."""
+    u = u.astype(jnp.float32)
+    g = g.astype(jnp.float32)
+    if nesterov:
+        u = (u + g) * momentum
+        return u, u + g
+    u = momentum * u + g
+    return u, u
+
+
+def dgc_sgd(p, buf, g, decayed, lr, momentum: float, dampening: float,
+            weight_decay: float, nesterov: bool):
+    """(p', buf') of one step of DGC-split SGD past its first, in float32.
+    ``g`` is the exchanged gradient, ``decayed`` the boolean mask of the
+    coordinates that take weight decay. Only the weight-decay term
+    ``wd * p`` passes through the momentum buffer; a coordinate that
+    takes none never touches its buffer; the gradient bypasses it;
+    ``p <- p - lr * (d_p + g)``. ``buf`` is None where the optimizer
+    keeps no buffer (no weight decay or no momentum)."""
+    p = p.astype(jnp.float32)
+    d_p = weight_decay * p
+    new_buf = buf
+    if buf is not None:
+        moved = momentum * buf.astype(jnp.float32) + (1 - dampening) * d_p
+        d_p = d_p + momentum * moved if nesterov else moved
+        new_buf = jnp.where(decayed, moved, buf)
+    d_p = jnp.where(decayed, d_p, 0.0)
+    return p - lr * (d_p + g.astype(jnp.float32)), new_buf
+
+
+def sent_words(sent):
+    """The bit-packed transmit record of the boolean ``sent`` [T] (T a
+    multiple of 128), as the memory keeps it: coordinate c is bit
+    ``(c // 128) % 32`` of word ``(c // 4096) * 128 + c % 128``; int32
+    words, ``ceil(T / 4096) * 128`` of them."""
+    rows = jnp.pad(sent, (0, -sent.shape[0] % 4096)).reshape(-1, 32, 128)
+    weight = jnp.left_shift(jnp.uint32(1), jnp.arange(32, dtype=jnp.uint32))
+    words = jnp.sum(rows.astype(jnp.uint32) * weight[None, :, None], axis=1,
+                    dtype=jnp.uint32)
+    return jax.lax.bitcast_convert_type(words, jnp.int32).reshape(-1)
+
+
+def ulps_apart(got, want, scale, slack=0.0):
+    """``|got - want|`` beyond ``slack`` (absolute), in units of the
+    float32 spacing at ``scale``'s magnitude (at 2**-100 where that is
+    smaller: the chip flushes subnormal spacings to zero). NaN where
+    either is."""
+    scale = jnp.maximum(jnp.abs(scale).astype(jnp.float32),
+                        jnp.float32(2.0 ** -100))
+    exponent = jax.lax.bitcast_convert_type(scale, jnp.int32) & 0x7F800000
+    ulp = jax.lax.bitcast_convert_type(exponent - (23 << 23), jnp.float32)
+    off = jnp.abs(got.astype(jnp.float32) - want.astype(jnp.float32))
+    # off itself where it is NaN: a maximum would drop it
+    return jnp.where(off > slack, off - slack, off * 0.0) / ulp

@@ -16,7 +16,8 @@ Workload names restrict ``aot`` to those cells (default: every cell).
           check's programs, compiled at the real size for a described
           ``v5e`` (one chip, or the 2x2 host), with ``memory_analysis()``
           held against the chip's 16 GB by ``memory_law``: what the
-          TPU's compiler would refuse shows here.
+          TPU's compiler would refuse shows here, and a Mosaic kernel of
+          the timed step that the check's programs do not lower.
 
 No rehearsal prints a time, a rate or any other device metric: nothing
 here ran on the device.
@@ -142,6 +143,14 @@ def _run_tiny_fresh(name, trace):
             e["name"] for e in cell.per_layer
             if e["source"] in HOST_SOURCES), sorted(values)
         out["per_layer_read"] = sorted(values)
+        # the check drives ``step_flat`` after the window, under no
+        # ``step.trace``: its ``exchange.trace`` span is on the record and
+        # is not among the step's, an arm each
+        step_spans = program_records.span_seconds("exchange.trace",
+                                                  under="step.trace")
+        assert len(step_spans) == 2 < len(
+            program_records.span_seconds("exchange.trace"))
+        assert values["exchange.trace_s"] == sum(step_spans)
         out["input_produce_source"] = program_records.batch_seconds(
             m["setup_spans"])[0]
         out["annotations"] = len(names)
@@ -202,6 +211,10 @@ def memory_law(row):
         f"follow), of {HOST_BYTES[row['chips']]}")
     fits = bool(needs < HBM_BYTES and check < HBM_BYTES
                 and disk < TMP_BYTES and host < HOST_BYTES[row["chips"]])
+    if row.get("uncovered_kernels"):
+        fits = False
+        law += (f"; the timed step lowers {row['uncovered_kernels']}, "
+                "which no program of the exchange check lowers")
     if residency == "one" and both < HBM_BYTES:
         fits = False
         law += ("; 'one' is stated where both arms fit the chip together: "
@@ -233,11 +246,12 @@ def aot_row(cell, topo):
         state = jax.eval_shape(arm.init, jax.random.PRNGKey(0))
         examples, labels = inputs.example_shapes(arm.dataset, gb)
         with build.matmul_precision(cell):
-            compiled = arm.step.lower(
+            lowered = arm.step.lower(
                 state,
                 jax.ShapeDtypeStruct(*examples, sharding=batch),
                 jax.ShapeDtypeStruct(*labels, sharding=batch),
-                key).compile()
+                key)
+            compiled = lowered.compile()
         program = check.check_program(arm) if name == "dgc" else None
         if program is not None:
             stages = check.stage_bytes(program)
@@ -245,6 +259,15 @@ def aot_row(cell, topo):
             row["check_bytes"] = max(stages.values())
             row["check_bytes_per_T"] = (row["check_bytes"]
                                         / arm.setup.engine.T)
+            # every Mosaic kernel the timed step lowers, the check lowers
+            # (the model's own views of its parameters apart)
+            checked = frozenset().union(*(
+                check.mosaic_kernels(stage.fn.lower(*stage.args))
+                for stage in program.stages()))
+            row["check_kernels"] = sorted(checked)
+            row["uncovered_kernels"] = sorted(
+                check.mosaic_kernels(lowered) - check.MODEL_VIEW_KERNELS
+                - checked)
         mem = compiled.memory_analysis()
         hlo = compiled.as_text()
         row["param_bytes"] = (cell.config["sizes"]["num_parameters"]

@@ -4,8 +4,8 @@ pass and the difference of the arms' ``fwd_bwd``, on a small hand-made
 trace with and without the tokens; the ones that read the children of
 ``step.trace`` and the count ``exchange.apply``, on hand-made records with
 and without them; and the whole result of every reader ``BENCHMARK.json``
-lists on the two chip fixtures (``test_trace_reduce.py`` pins 21 of the 29
-and is the parent's, byte for byte: ``benchmark/conftest.py``).
+lists on the two chip fixtures (``test_trace_reduce.py`` pins the same 29
+since PR 44 folded the eight into its dictionaries).
 
 A reader here returns a FINITE number wherever its enclosing thing ran: the
 driver runs the parent's program, which has none of the new tokens, under
@@ -130,6 +130,9 @@ RECORDS = [
     _span("exchange.trace", 32, 700.0, parent=30, engine="FlatDGCEngine"),
     _span("step.trace", 30, 2500.0, compressor="DGCCompressor", flat=True),
     _apply(9, None, "scatter"),                 # the check again, afterwards
+    # ... which since PR 44 drives ``step_flat``, and so opens the span
+    # under no ``step.trace``: not the step's seconds
+    _span("exchange.trace", 40, 800.0, engine="FlatDGCEngine"),
 ]
 #: the parent's recorder: the traces and their counts, no child span
 PARENT_RECORDS = [r for r in RECORDS if r["name"] not in (
@@ -268,8 +271,9 @@ PR42_ZERO = dict.fromkeys((
 ], ids=["one-chip", "four-chip"])
 def test_every_reader_of_the_benchmark_on_the_chip_fixtures(
         monkeypatch, fixture, steps, want):
-    """All 29 entries of ``per_layer``, the whole result: the 21 values
-    ``test_trace_reduce.py`` pins, letter for letter, and PR 42's eight."""
+    """All 29 entries of ``per_layer``, the whole result, in
+    ``BENCHMARK.json``'s order: the 21 values ``test_trace_reduce.py``
+    pinned before PR 42, letter for letter, and PR 42's eight."""
     monkeypatch.setattr(program_records, "records", lambda: [])
     got = _read_all(_chip_view(fixture, steps))
     want = {**NO_RECORDS, **PR23_ABSENT, **PR42_ZERO, **want}

@@ -80,8 +80,9 @@ def test_traced_measure_runs_the_repos_readers_on_the_cell(cell, source):
     out = rehearse._run_tiny(cell, trace=True)
     assert out["annotations"] >= 6 and "no_device_ops" in out
     assert out["per_layer_read"] == [
-        "exchange.dense_wire_bytes", "exchange.wire_bytes",
-        "input.produce_ms", "input.wait_ms", "step.trace_s"]
+        "exchange.apply_pairs", "exchange.dense_wire_bytes",
+        "exchange.trace_s", "exchange.wire_bytes", "input.produce_ms",
+        "input.wait_ms", "step.trace_model_s", "step.trace_s"]
     assert out["input_produce_source"] == source
 
 
@@ -127,7 +128,7 @@ def test_a_traced_line_that_lacks_a_metric_of_its_cell_is_refused(gone):
     ALL that is missing; the whole line passes."""
     cell = rehearse.fixture_cell("tiny_lm.one")
     values = _owed_values(cell)
-    assert len(values) == 17
+    assert len(values) == 25        # of 29: less the four-chip lists' 4
     run.refuse_a_short_line(cell, values, DEVICE, traced=True)
     for name in gone:
         del values[name]
@@ -421,7 +422,10 @@ def test_a_new_token_cell_runs_and_is_correct(tmp_path):
     # correct run is inside it (a floor is met from above)
     got = run.compared(m)
     assert set(got) == {
-        "step0_loss_gap", "nonfinite_losses",
+        "step0_loss_gap", "nonfinite_losses", "pack.misplaced_coords",
+        "check.uncovered_kernels", "exchange.record_wrong_bits",
+        "exchange.buffer_unexplained_coords",
+        "exchange.update_unexplained_coords", "exchange.forms_differ_coords",
         "exchange.inexact_residual_coords", "exchange.unconserved_coords",
         "exchange.over_quota_rows", "exchange.sent_outside_rows",
         "exchange.fill_floor", "exchange.recall_floor",
@@ -439,7 +443,11 @@ def test_a_new_token_cell_runs_and_is_correct(tmp_path):
 #: what ``compared`` holds in a cell whose configuration has no reference
 #: of its model: the benchmark's three cells (since PR 32)
 CELLS_COMPARED = [
-    "step0_loss_gap", "nonfinite_losses", "exchange.inexact_residual_coords",
+    "step0_loss_gap", "nonfinite_losses", "pack.misplaced_coords",
+    "check.uncovered_kernels", "exchange.record_wrong_bits",
+    "exchange.buffer_unexplained_coords",
+    "exchange.update_unexplained_coords", "exchange.forms_differ_coords",
+    "exchange.inexact_residual_coords",
     "exchange.unconserved_coords", "exchange.over_quota_rows",
     "exchange.sent_outside_rows", "exchange.fill_floor",
     "exchange.recall_floor", "exchange.bucket_recall_floor"]
@@ -452,7 +460,11 @@ def _a_cells_measurement(**check):
                       "over_quota_rows": 0, "sent_outside_rows": 0,
                       "fill": 0.93, "fill_floor": 0.8, "recall": 0.99,
                       "recall_floor": 0.95, "recall_per_bucket": [0.99, 0.96],
-                      "recall_floor_per_bucket": [0.948, 0.93], **check}}
+                      "recall_floor_per_bucket": [0.948, 0.93],
+                      "misplaced_coords": 0, "uncovered_kernels": [],
+                      "record_wrong_bits": 0, "buffer_unexplained_coords": 0,
+                      "update_unexplained_coords": 0,
+                      "forms_differ_coords": 0, **check}}
 
 
 def test_compared_lists_the_number_nearest_its_limit_first():
@@ -467,9 +479,19 @@ def test_compared_lists_the_number_nearest_its_limit_first():
     assert list(got) == [
         "exchange.bucket_recall_floor", "exchange.recall_floor",
         "exchange.fill_floor", "step0_loss_gap", "nonfinite_losses",
+        "pack.misplaced_coords", "check.uncovered_kernels",
+        "exchange.record_wrong_bits", "exchange.buffer_unexplained_coords",
+        "exchange.update_unexplained_coords", "exchange.forms_differ_coords",
         "exchange.inexact_residual_coords", "exchange.unconserved_coords",
         "exchange.over_quota_rows", "exchange.sent_outside_rows"]
     assert got["exchange.bucket_recall_floor"] == [0.96, 0.93]
+    # a fault under the offered step is seen by several counts: the one
+    # nearest the fault is listed first
+    assert list(run.compared(_a_cells_measurement(
+        record_wrong_bits=1, forms_differ_coords=1)))[:2] == [
+            "exchange.record_wrong_bits", "exchange.forms_differ_coords"]
+    assert run.compared(_a_cells_measurement(uncovered_kernels=[
+        "payload_update_bits"]))["check.uncovered_kernels"] == [1, 0]
     assert list(run.compared(_a_cells_measurement(unconserved_coords=3)))[0] \
         == "exchange.unconserved_coords"
     assert list(run.compared(_a_cells_measurement(fill=0.7)))[0] \
@@ -518,8 +540,9 @@ def test_arms_resident_one_at_a_time_keep_the_metrics_names(tmp_path):
     both = _measure(_new_token_cell(tmp_path))
     assert set(run.compared(m)) == set(run.compared(both))
     assert m["model_check"]["arms"] == both["model_check"]["arms"]
-    assert {k: v for k, v in m["check"].items() if k != "check_s"} == {
-        k: v for k, v in both["check"].items() if k != "check_s"}
+    clocks = ("check_s", "parts_s")
+    assert {k: v for k, v in m["check"].items() if k not in clocks} == {
+        k: v for k, v in both["check"].items() if k not in clocks}
     assert set(run.paired_summary(both)["dgc_minus_dense_ms"]) == {
         "q1", "median", "q3"}
 
@@ -631,7 +654,10 @@ def _break_the_step(monkeypatch, broken_step):
 
     def build_arm(cell, name, mesh):
         arm = real(cell, name, mesh)
-        return arm._replace(step=broken_step(arm.step))
+        broken = broken_step(arm.step)
+        # it lowers what the real one does (the check reads its kernels)
+        broken.lower = arm.step.lower
+        return arm._replace(step=broken)
 
     monkeypatch.setattr(build, "build_arm", build_arm)
 
