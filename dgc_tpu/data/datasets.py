@@ -24,7 +24,8 @@ import numpy as np
 from dgc_tpu.data.native import crop_flip_normalize
 from dgc_tpu.telemetry import trace as _trace
 
-__all__ = ["ArraySplit", "SyntheticSplit", "CIFAR", "ImageNet", "Synthetic",
+__all__ = ["ArraySplit", "SyntheticSplit", "SyntheticTokenSplit", "CIFAR",
+           "ImageNet", "Synthetic", "SyntheticTokens",
            "CIFAR_MEAN", "CIFAR_STD", "IMAGENET_MEAN", "IMAGENET_STD"]
 
 CIFAR_MEAN = np.array([0.4914, 0.4822, 0.4465], np.float32)
@@ -122,6 +123,59 @@ class SyntheticSplit:
         with _trace.span("input.get_batch", images=len(indices)):
             return (_normalize(self.images[indices], self.mean, self.std),
                     self.labels[indices])
+
+
+class SyntheticTokenSplit:
+    """Rows of ``seq_len`` token ids with their next-token labels, made
+    from the seed (no corpus in this environment). A token follows its
+    predecessor by a fixed random successor table half of the time and is
+    drawn from a Zipf distribution (P(rank) ~ 1 / rank) otherwise: a
+    STRUCTURED, learnable stream, as :class:`SyntheticSplit`'s images are.
+    ``get_batch(indices)`` gives ``(inputs int32 [n, seq_len], labels
+    int32 [n * seq_len])``: labels are token-major, as a token model's
+    logits are, so the batch axis still shards by sequence and the step's
+    micro-batch cut takes labels of one axis (training/step.py)."""
+
+    def __init__(self, n: int, seq_len: int, vocab_size: int, seed: int = 0):
+        rng = np.random.RandomState(seed)
+        # the successor table is split-independent: train and test share
+        # the language
+        follows = np.random.RandomState(20_000 + vocab_size).permutation(
+            vocab_size).astype(np.int32)
+        cdf = np.cumsum(1.0 / np.arange(1, vocab_size + 1))
+        # every position's Zipf draw, then in order of t: half of them
+        # replaced by the successor of the token before
+        rows = np.searchsorted(
+            cdf, rng.random_sample((n, seq_len + 1)) * cdf[-1], side="right"
+        ).clip(max=vocab_size - 1).astype(np.int32)
+        follow = rng.random_sample((n, seq_len + 1)) < 0.5
+        for t in range(1, seq_len + 1):
+            rows[:, t] = np.where(follow[:, t], follows[rows[:, t - 1]],
+                                  rows[:, t])
+        self.rows = rows
+
+    def __len__(self) -> int:
+        return len(self.rows)
+
+    def get_batch(self, indices: np.ndarray
+                  ) -> Tuple[np.ndarray, np.ndarray]:
+        with _trace.span("input.get_batch", sequences=len(indices)):
+            rows = self.rows[indices]
+            return (np.ascontiguousarray(rows[:, :-1]),
+                    np.ascontiguousarray(rows[:, 1:]).reshape(-1))
+
+
+def SyntheticTokens(seq_len: int = 2048, vocab_size: int = 32000,
+                    synthetic_size: int = 64, seed: int = 0
+                    ) -> Dict[str, object]:
+    """A token dataset: ``synthetic_size`` training rows and a quarter as
+    many test rows of ``seq_len`` tokens over ``vocab_size`` ids."""
+    return {
+        "train": SyntheticTokenSplit(synthetic_size, seq_len, vocab_size,
+                                     seed=seed),
+        "test": SyntheticTokenSplit(max(synthetic_size // 4, 1), seq_len,
+                                    vocab_size, seed=seed + 1),
+    }
 
 
 def CIFAR(root: str, num_classes: int = 10, image_size: int = 32,

@@ -10,8 +10,9 @@ on two instruments, and with it off neither leaves anything behind:
   decode → apply``, ``dense``; ``apply`` has the parts ``sort`` and
   ``stage``, and what is left of it is the pass, a Pallas call), the
   step's ``params_view``, ``plumbing``, ``fwd_bwd`` (part ``pack``: the
-  gradients into the flat layout), ``update`` (parts ``exchange`` and
-  ``optimizer``) and ``loss``. A device profile then attributes each op
+  gradients into the flat layout; a token model's own parts ``ssm``,
+  ``attn``, ``gmu``, ``mlp``, ``head``: ``models/sambay.py``), ``update``
+  (parts ``exchange`` and ``optimizer``) and ``loss``. A device profile then attributes each op
   to a phase and bucket (``benchmark/trace_reduce.py``,
   :mod:`telemetry.attrib`; both read a part token as its phase, and a
   metric of a part looks for the whole token). The scopes are
@@ -27,8 +28,9 @@ on two instruments, and with it off neither leaves anything behind:
   Every layer reaches it as a module function, so spans (``input.*``,
   ``step.*``, ``exchange.trace``, ``checkpoint.save``, ``eval``) and
   counts (``input.queue_depth``, ``exchange.collective``,
-  ``exchange.apply``, ``step.pack``, ``optimizer.wd_mask``) sit where the
-  work happens.
+  ``exchange.apply``, ``step.pack``, ``optimizer.wd_mask``,
+  ``model.layers``, ``model.tokens``, ``model.scan_chunks``) sit where
+  the work happens.
   A span records its name, start and end (``perf_counter_ns``), thread,
   the id of the span that caused it and the ids its request carries
   (``step``, ``seq``; inherited by what it causes); a count belongs to

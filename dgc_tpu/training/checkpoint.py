@@ -30,6 +30,22 @@ from dgc_tpu.serving import protocol as serving_protocol
 __all__ = ["CheckpointManager"]
 
 
+def _without_empty(tree: Any) -> Any:
+    """``tree`` with every zero-size leaf gone (None is an empty subtree):
+    orbax refuses to write one, and the flat state of a model without
+    batch statistics carries a [world, 0] ``batch_stats``."""
+    return jax.tree.map(lambda x: None if x.size == 0 else x, tree)
+
+
+def _with_empty(restored: Any, template: Any) -> Any:
+    """``restored`` (read against ``_without_empty(template)``) with the
+    template's zero-size leaves back in place."""
+    leaves, treedef = jax.tree.flatten(template)
+    read = iter(jax.tree.leaves(restored))
+    return treedef.unflatten(
+        [leaf if leaf.size == 0 else next(read) for leaf in leaves])
+
+
 class CheckpointManager:
     def __init__(self, directory: str, keep: int = 3):
         self.directory = os.path.abspath(directory)
@@ -71,6 +87,7 @@ class CheckpointManager:
             # world-size changes restore-compatible — restore re-seeds a
             # fresh full-send verdict from the caller's template
             state = state.replace(adaptive=None)
+        state = _without_empty(state)
         multi = jax.process_count() > 1
         coord = jax.process_index() == 0
         path = self._epoch_dir(epoch)
@@ -378,15 +395,18 @@ class CheckpointManager:
         adaptive = getattr(template, "adaptive", None)
         if adaptive is not None:
             template = template.replace(adaptive=None)
+
+        def read(tmpl):
+            # zero-size leaves are never written (see :meth:`save`)
+            return _with_empty(self._restore_state(
+                path, _without_empty(tmpl), force_host=force_host), tmpl)
+
         try:
-            state = self._restore_state(path, template,
-                                        force_host=force_host)
+            state = read(template)
         except Exception:
             if getattr(template, "guards", None) is None:
                 raise
-            state = self._restore_state(path,
-                                        template.replace(guards=None),
-                                        force_host=force_host)
+            state = read(template.replace(guards=None))
             print(f"[checkpoint] {path} predates the resilience guard "
                   "counters — they start fresh")
         if adaptive is not None:

@@ -378,7 +378,7 @@ def build_train_step(apply_fn: Callable, dist_opt: DistributedOptimizer,
                 frac = None
 
             mb_images = images.reshape((nbps, -1) + images.shape[1:])
-            mb_labels = labels.reshape((nbps, -1))
+            mb_labels = labels.reshape((nbps, -1) + labels.shape[1:])
 
         if flat is not None and model_dtype is not None:
             # mixed precision over the flat buffer: differentiate w.r.t.

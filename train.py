@@ -277,9 +277,13 @@ def main(argv=None):
     printr(f'\n==> creating model "{configs.model}"')
     model = configs.model()
     rng = jax.random.PRNGKey(seed)
-    sample_shape = (1, configs.dataset.image_size,
-                    configs.dataset.image_size, 3)
-    variables = model.init(rng, jnp.zeros(sample_shape), train=True)
+    # one example of the dataset's kind: a row of token ids, or an image
+    if configs.dataset.get("seq_len") is not None:
+        sample = jnp.zeros((1, configs.dataset.seq_len), jnp.int32)
+    else:
+        sample = jnp.zeros((1, configs.dataset.image_size,
+                            configs.dataset.image_size, 3))
+    variables = model.init(rng, sample, train=True)
     params = variables["params"]
     batch_stats = variables.get("batch_stats", {})
     # Always thread a dropout rng; flax ignores rngs a model doesn't use.
