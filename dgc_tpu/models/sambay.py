@@ -16,11 +16,19 @@ them is how the step fits the chip:
   published index sets differential attention's ``lambda0`` and the
   parameter names (``layer_<index>``), so a cut keeps the model's own.
 * the selective scan runs in chunks of ``scan_chunk`` steps: a
-  ``lax.scan`` over the chunks carries the [B, N, E] state, a
-  ``lax.associative_scan`` runs inside a chunk, and each chunk is a
-  ``jax.checkpoint``, so the backward pass keeps a state a chunk and not
-  the [S, N, E] history (671 MB a layer at the published widths and 2,048
-  tokens). The channels are the minor dimension throughout ([.., N, E]).
+  ``lax.scan`` over the chunks carries the [B, N, E] state, and inside a
+  chunk the recurrence runs step after step on that state, a block of up
+  to ``SCAN_UNROLL`` steps a loop iteration (``_scan_chunk``). The chunk
+  is a ``jax.custom_vjp``: the backward pass keeps the state a chunk
+  enters with and not the [S, N, E] history, computes the chunk's states
+  again, runs ONE reverse recurrence for dh, and takes every reduction
+  (d delta, d(delta u), dB, dC, dA) chunk-wide over those two
+  [B, L, N, E] arrays, the only ones of that size. Two forms not to go
+  back to: autodiff through a chunk's unrolled steps (hundreds of ops a
+  chunk, minutes of compile), and a backward that reduces step by step
+  (each reduction is a small fusion of its own, and the loops are bound
+  by their op count, not their bytes). The channels are the minor
+  dimension throughout ([.., N, E]).
 * attention runs a block of ``attn_block`` queries at a time, each block
   a ``jax.checkpoint``: no [heads, S, S] score is kept, and a ``swa``
   block reads only the keys its window reaches.
@@ -76,11 +84,120 @@ def _promote(dtype, *arrays):
     return nn.dtypes.promote_dtype(*arrays, dtype=dtype)
 
 
+#: steps of a scan chunk one iteration of its loops takes: as many of this
+#: power of two as divide the chunk's length. Past 8, XLA computes a
+#: block's chained steps again inside its fusions and the loops slow down.
+SCAN_UNROLL = 8
+
+
+def _step(h, d_t, du_t, b_t, a):
+    """h_t from h_{t-1} [B, N, E]: ``d_t``, ``du_t`` [B, E], ``b_t``
+    [B, N], ``a`` [N, E]."""
+    return (jnp.exp(d_t[:, None, :] * a) * h
+            + du_t[:, None, :] * b_t[:, :, None])
+
+
+def _unroll(steps: int) -> int:
+    return math.gcd(steps, SCAN_UNROLL)
+
+
+def _blocks(*arrays):
+    """[B, L, X] -> [L / unroll, B, unroll, X] each: what a loop over a
+    chunk's blocks of ``unroll`` steps scans."""
+    batch, steps = arrays[0].shape[:2]
+    unroll = _unroll(steps)
+    return tuple(
+        jnp.moveaxis(t.reshape(batch, steps // unroll, unroll, -1), 1, 0)
+        for t in arrays)
+
+
+@jax.custom_vjp
+def _scan_chunk(h, d, du, b, c, a):
+    """One chunk: ``h`` [B, N, E] the state it enters with, ``d``, ``du``
+    [B, L, E], ``b``, ``c`` [B, L, N], ``a`` [N, E] -> (the state after
+    it, y [B, L, E]). The recurrence runs step after step on the state, a
+    block of steps a loop iteration; no array over (L, N, E) is made."""
+    unroll = _unroll(d.shape[1])
+
+    def block(h, at_k):
+        d_k, du_k, b_k, c_k = at_k
+        y_k = []
+        for j in range(unroll):
+            h = _step(h, d_k[:, j], du_k[:, j], b_k[:, j], a)
+            y_k.append(jnp.sum(h * c_k[:, j, :, None], axis=1))
+        return h, jnp.stack(y_k, axis=1)
+
+    h, y = jax.lax.scan(block, h, _blocks(d, du, b, c))
+    return h, jnp.moveaxis(y, 0, 1).reshape(d.shape)
+
+
+def _scan_chunk_fwd(*args):
+    # through the ``custom_vjp`` again, not its plain body: XLA:TPU then
+    # makes four fusions of a block of the forward loop; with the body
+    # inlined here it makes six, a third dearer by its own cost model
+    return _scan_chunk(*args), args
+
+
+def _scan_chunk_bwd(kept, cotangents):
+    """The chunk's states again (the state each step ENTERS with), one
+    reverse recurrence for dh, both written step by step into [B, L, N, E]
+    arrays, the only two of that size; every reduction is taken over those
+    two, chunk-wide, in a few large fusions (taken step by step each is a
+    small fusion of its own: nine a step, and the loops are bound by their
+    op count)."""
+    h, d, du, b, c, a = kept
+    dh, dy = cotangents
+    unroll = _unroll(d.shape[1])
+    empty = jnp.zeros(d.shape[:2] + a.shape, jnp.float32)
+
+    def put(wide, t, state):
+        return jax.lax.dynamic_update_slice_in_dim(wide, state[:, None], t,
+                                                   axis=1)
+
+    def forward(carry, at_k):
+        h, entering = carry
+        k, d_k, du_k, b_k = at_k
+        for j in range(unroll):
+            entering = put(entering, k * unroll + j, h)
+            h = _step(h, d_k[:, j], du_k[:, j], b_k[:, j], a)
+        return (h, entering), None
+
+    def reverse(carry, at_k):
+        dh, dstates = carry
+        k, d_k, dy_k, c_k = at_k
+        for j in reversed(range(unroll)):
+            # the loss's gradient in h_t: y_t's, and what the later steps
+            # hand back through their decays
+            dh = dh + dy_k[:, j, None, :] * c_k[:, j, :, None]
+            dstates = put(dstates, k * unroll + j, dh)
+            dh = dh * jnp.exp(d_k[:, j, None, :] * a)
+        return (dh, dstates), None
+
+    block = (jnp.arange(d.shape[1] // unroll),)
+    (_, entering), _ = jax.lax.scan(forward, (h, empty),
+                                    block + _blocks(d, du, b))
+    (dh, dstates), _ = jax.lax.scan(reverse, (dh, empty),
+                                    block + _blocks(d, dy, c), reverse=True)
+    decay = jnp.exp(d[:, :, None, :] * a)
+    dlog = dstates * entering * decay        # the gradient in d_t * a
+    states = decay * entering + du[:, :, None, :] * b[:, :, :, None]
+    return (dh, jnp.sum(dlog * a, axis=2),
+            jnp.sum(dstates * b[:, :, :, None], axis=2),
+            jnp.sum(dstates * du[:, :, None, :], axis=3),
+            jnp.sum(states * dy[:, :, None, :], axis=3),
+            jnp.sum(dlog * d[:, :, None, :], axis=(0, 1)))
+
+
+_scan_chunk.defvjp(_scan_chunk_fwd, _scan_chunk_bwd)
+
+
 def selective_scan(delta, u, b_in, c_out, a, chunk: int):
     """y_t = h_t C_t with h_t = exp(delta_t * A) * h_{t-1} + (delta_t *
     u_t) (x) B_t and h_0 = 0, in float32. ``delta``, ``u`` [B, S, E];
     ``b_in``, ``c_out`` [B, S, N]; ``a`` [N, E]. Chunks of ``chunk`` steps
-    (the last one padded with steps that leave the state as it is)."""
+    (the last one padded with steps that leave the state as it is), the
+    state handed from chunk to chunk; the backward pass keeps the state a
+    chunk enters with and works a chunk at a time (``_scan_chunk_bwd``)."""
     delta, u, b_in, c_out, a = (t.astype(jnp.float32)
                                 for t in (delta, u, b_in, c_out, a))
     batch, seq, inner = u.shape
@@ -91,22 +208,10 @@ def selective_scan(delta, u, b_in, c_out, a, chunk: int):
         t = jnp.pad(t, ((0, 0), (0, pad), (0, 0)))
         return jnp.moveaxis(t.reshape(batch, chunks, chunk, -1), 1, 0)
 
-    def combine(left, right):
-        return left[0] * right[0], right[0] * left[1] + right[1]
-
-    @jax.checkpoint
-    def one_chunk(h, xs):
-        d, du, b, c = xs                # [B, L, E] [B, L, E] [B, L, N] x 2
-        decay = jnp.exp(d[:, :, None, :] * a)              # [B, L, N, E]
-        drive = du[:, :, None, :] * b[:, :, :, None]
-        decay_to, driven = jax.lax.associative_scan(
-            combine, (decay, drive), axis=1)
-        states = decay_to * h[:, None] + driven
-        return states[:, -1], jnp.sum(states * c[:, :, :, None], axis=2)
-
     h0 = jnp.zeros((batch, a.shape[0], inner), jnp.float32)
-    _, y = jax.lax.scan(one_chunk, h0, (chunked(delta), chunked(delta * u),
-                                        chunked(b_in), chunked(c_out)))
+    _, y = jax.lax.scan(
+        lambda h, xs: _scan_chunk(h, *xs, a), h0,
+        (chunked(delta), chunked(delta * u), chunked(b_in), chunked(c_out)))
     return jnp.moveaxis(y, 0, 1).reshape(batch, chunks * chunk, inner)[:, :seq]
 
 
@@ -212,11 +317,12 @@ class Mamba(nn.Module):
                         for i in range(self.conv)) + conv_bias)
         rbc = u @ x_proj
         delta = jax.nn.softplus(rbc[..., :rank] @ dt_proj + dt_bias)
-        _trace.count("model.scan_chunks", -(-seq // self.scan_chunk),
-                     chunk=min(self.scan_chunk, seq))
+        chunk = min(self.scan_chunk, seq)
+        _trace.count("model.scan_chunks", -(-seq // chunk), chunk=chunk,
+                     unroll=_unroll(chunk))
         y = selective_scan(
             delta, u, rbc[..., rank:rank + state], rbc[..., rank + state:],
-            -jnp.exp(a_log.astype(jnp.float32)), min(self.scan_chunk, seq))
+            -jnp.exp(a_log.astype(jnp.float32)), chunk)
         y = y.astype(u.dtype) + skip * u
         return (y * nn.silu(z)) @ out_proj, y
 
@@ -399,7 +505,7 @@ class SambaY(nn.Module):
     ssm_state: int = 16
     ssm_conv: int = 4
     ssm_rank: int = 160        # ceil(hidden / 16)
-    scan_chunk: int = 64
+    scan_chunk: int = 128
     attn_block: int = 512
     dtype: Any = None          # compute dtype; configs/bf16.py narrows it
 

@@ -95,42 +95,106 @@ def test_model_agrees_with_the_plain_reference(layers):
         assert err < 1e-4, (name, err)
 
 
-@pytest.mark.parametrize("chunk", [1, 7, 16])
-def test_chunked_scan_is_the_sequential_recurrence(chunk):
-    """Three chunk lengths, one (7) not dividing S = 32: outputs and the
-    gradients of every input against the plain ``lax.scan`` over t."""
-    rng = np.random.RandomState(chunk)
-    batch, inner, state = 2, 24, 5
-    delta = jnp.asarray(rng.uniform(1e-3, 0.5, (batch, SEQ, inner)),
+def _scan_inputs(seed, batch, seq, inner=24, state=5, delta_max=0.5):
+    rng = np.random.RandomState(seed)
+    delta = jnp.asarray(rng.uniform(1e-3, delta_max, (batch, seq, inner)),
                         jnp.float32)
-    u, b_in, c_out = (jnp.asarray(rng.randn(batch, SEQ, width), jnp.float32)
-                      for width in (inner, state, state))
+    u, b_in, c_out, weights = (
+        jnp.asarray(rng.randn(batch, seq, width), jnp.float32)
+        for width in (inner, state, state, inner))
     a = -jnp.asarray(rng.uniform(0.5, 8.0, (state, inner)), jnp.float32)
+    return (delta, u, b_in, c_out, a), weights
 
-    def sequential(delta, u, b_in, c_out, a):
-        def step(h, at_t):
-            d_t, u_t, b_t, c_t = at_t
-            h = (jnp.exp(d_t[:, None, :] * a) * h
-                 + (d_t * u_t)[:, None, :] * b_t[:, :, None])
-            return h, jnp.sum(h * c_t[:, :, None], axis=1)
 
-        _, y = jax.lax.scan(
-            step, jnp.zeros((batch, state, inner)),
-            tuple(jnp.moveaxis(t, 1, 0) for t in (delta, u, b_in, c_out)))
-        return jnp.moveaxis(y, 0, 1)
+def _sequential_scan(delta, u, b_in, c_out, a):
+    """The plain ``lax.scan`` over t, autodiff's backward."""
+    def step(h, at_t):
+        d_t, u_t, b_t, c_t = at_t
+        h = (jnp.exp(d_t[:, None, :] * a) * h
+             + (d_t * u_t)[:, None, :] * b_t[:, :, None])
+        return h, jnp.sum(h * c_t[:, :, None], axis=1)
 
-    def chunked(*args):
-        return sambay.selective_scan(*args, chunk=chunk)
+    _, y = jax.lax.scan(
+        step, jnp.zeros((u.shape[0],) + a.shape),
+        tuple(jnp.moveaxis(t, 1, 0) for t in (delta, u, b_in, c_out)))
+    return jnp.moveaxis(y, 0, 1)
 
-    args = (delta, u, b_in, c_out, a)
-    np.testing.assert_allclose(chunked(*args), sequential(*args),
-                               rtol=1e-5, atol=1e-6)
-    weights = jnp.asarray(rng.randn(batch, SEQ, inner), jnp.float32)
+
+@pytest.mark.parametrize("chunk, batch, seq, delta_max", [
+    (1, 2, SEQ, 0.5),
+    (7, 2, SEQ, 0.5),           # does not divide S: three padded steps
+    (16, 2, SEQ, 0.5),
+    (SEQ, 2, SEQ, 0.5),         # one chunk
+    (40, 1, SEQ, 0.5),          # a chunk longer than S, batch 1
+    (8, 1, 1, 0.5),             # S = 1
+    (12, 2, SEQ, 0.5),          # neither a multiple of the unroll nor of S
+    (5, 1, 19, 0.5),
+    (16, 2, SEQ, 200.0),        # exp(delta_t * A) underflows to 0
+], ids=["1", "7", "16", "one_chunk", "longer_than_s", "s_1",
+        "off_the_unroll", "odd", "decay_underflows"])
+def test_chunked_scan_is_the_sequential_recurrence(chunk, batch, seq,
+                                                   delta_max):
+    """Outputs and the gradients of EVERY input (``a``'s sums over chunks
+    and the batch) against the plain ``lax.scan`` over t, under a
+    cotangent that is non-zero on every real step (the padded steps'
+    is zero: they are cut off)."""
+    args, weights = _scan_inputs(chunk, batch, seq, delta_max=delta_max)
+    if delta_max > 1:
+        decays = np.exp(np.asarray(args[0])[:, :, None, :]
+                        * np.asarray(args[4]))
+        assert (decays == 0).any() and (decays > 0).any()
+
+    def chunked(*xs):
+        return sambay.selective_scan(*xs, chunk=chunk)
+
+    want_y = _sequential_scan(*args)
+    np.testing.assert_allclose(chunked(*args), want_y, rtol=1e-5, atol=1e-6)
     got, want = (jax.grad(lambda *xs: jnp.sum(fn(*xs) * weights),
                           argnums=range(5))(*args)
-                 for fn in (chunked, sequential))
+                 for fn in (chunked, _sequential_scan))
     for g, w in zip(got, want):
+        assert np.all(np.isfinite(g))
         np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-5)
+
+
+def _avals(jaxpr):
+    """Every variable's aval in a jaxpr and in the jaxprs its equations
+    carry (scan and while bodies, calls, custom rules)."""
+    jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
+    for var in jaxpr.invars + jaxpr.constvars:
+        yield var.aval
+    for eqn in jaxpr.eqns:
+        for var in eqn.outvars:
+            yield var.aval
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                if hasattr(getattr(sub, "jaxpr", sub), "eqns"):
+                    yield from _avals(sub)
+
+
+def test_the_scans_backward_is_the_hand_written_one():
+    """The forward holds the chunk's ``custom_vjp`` call and no array over
+    (steps, N, E) at all; the gradient holds none but whole chunks'
+    ([B, L, N, E]: the recomputed states, the stored dh and what the
+    chunk-wide reductions make of them), the single states written into
+    them ([B, 1, N, E]) and the state a chunk the outer scan keeps: no
+    halves and quarters of a log-depth sweep, no [S, N, E] history."""
+    chunk, batch, state, inner = 8, 2, 5, 24
+    args, weights = _scan_inputs(0, batch, SEQ, inner, state)
+
+    def loss(*xs):
+        return jnp.sum(sambay.selective_scan(*xs, chunk=chunk) * weights)
+
+    def wide(fn):
+        return {aval.shape for aval in _avals(jax.make_jaxpr(fn)(*args))
+                if len(aval.shape) >= 4 and aval.shape[-2:] == (state, inner)
+                and aval.shape[:2] != (1, 1)}       # ``a`` beside [B, L, N, E]
+
+    assert "custom_vjp_call" in str(jax.make_jaxpr(loss)(*args))
+    assert wide(loss) == set()
+    assert wide(jax.grad(loss, argnums=range(5))) == {
+        (batch, chunk, state, inner), (batch, 1, state, inner),
+        (SEQ // chunk, batch, state, inner)}
 
 
 @pytest.mark.parametrize("kind, reaches", [("swa", False), ("full", True)])
@@ -348,8 +412,9 @@ def test_the_counts_and_scopes_a_traced_build_leaves(rec):
     assert [(r["args"]["kind"], r["args"]["index"])
             for r in by_name["model.layers"]] == list(CUT)
     assert [r["value"] for r in by_name["model.tokens"]] == [2 * SEQ]
-    assert [(r["value"], r["args"]["chunk"])
-            for r in by_name["model.scan_chunks"]] == [(4, 8)]
+    assert [(r["value"], r["args"]["chunk"], r["args"]["unroll"])
+            for r in by_name["model.scan_chunks"]] == [
+                (4, 8, min(8, sambay.SCAN_UNROLL))]
     text = lowered.as_text(debug_info=True)
     for part in ("ssm", "attn", "gmu", "mlp", "head"):
         assert f"dgcph.fwd_bwd.{part}" in text, part
