@@ -30,8 +30,19 @@ them is how the step fits the chip:
   by their op count, not their bytes). The channels are the minor
   dimension throughout ([.., N, E]).
 * attention runs a block of ``attn_block`` queries at a time, each block
-  a ``jax.checkpoint``: no [heads, S, S] score is kept, and a ``swa``
-  block reads only the keys its window reaches.
+  a ``jax.checkpoint``: no [heads, S, S] score is kept. A block is given
+  only keys its mask can reach, by extents that are static
+  (``attention_extents``, from the row, the window and the block alone):
+  a causal layer's blocks are cut into ``CAUSAL_RUNS`` runs in order, a
+  run is one ``lax.map`` and reads the keys up to the run's end; a
+  window layer's block is at most the window over ``WINDOW_BLOCKS`` and
+  reads the blocks its window reaches back plus its own. The softmax is
+  over the keys given: what is left out was ``exp(-inf)``. Two forms not
+  to go back to: every key to every block (half of a causal layer's
+  scores, and of their matmuls, exponentials and gradients, are masked
+  away), and a flash-style inner loop over key blocks with a running
+  maximum and sum under plain autodiff (the loop stores every key block's
+  residuals, a skipped block's too: it wants a hand-written backward).
 * ``A_log`` and the depthwise conv's kernel are STORED with the channels
   minor ([N, E], [K, E]): a tensor whose minor dimension is under 128
   among the flat buffer's makes XLA:TPU view the whole buffer in that
@@ -40,7 +51,8 @@ them is how the step fits the chip:
   (``vocab_size`` rows): ids, logits and the loss are over the slice.
 * device parts under ``fwd_bwd`` (``telemetry.trace.phase``): ``ssm``,
   ``attn``, ``gmu``, ``mlp``, ``head``; counts, once a trace:
-  ``model.layers``, ``model.tokens``, ``model.scan_chunks``.
+  ``model.layers``, ``model.tokens``, ``model.scan_chunks``,
+  ``model.attn_scores``.
 
 Logits are token-major, [B * S, V]: the labels the step's micro-batch cut
 hands the loss are flat (``training/step.py``).
@@ -215,22 +227,64 @@ def selective_scan(delta, u, b_in, c_out, a, chunk: int):
     return jnp.moveaxis(y, 0, 1).reshape(batch, chunks * chunk, inner)[:, :seq]
 
 
+#: runs a causal layer's query blocks are cut into, in order: a run's
+#: blocks are one ``lax.map`` and are given the keys up to the run's end
+CAUSAL_RUNS = 2
+#: a window layer's block holds at most this part of its window: a block
+#: of b queries is given ceil((window - 1) / b) b + b keys
+WINDOW_BLOCKS = 2
+
+
+def attention_extents(seq: int, window: Optional[int], block: int):
+    """What :func:`masked_attention` computes, from the shapes alone:
+    ``(window, block, runs)`` with ``window`` None where it reaches the
+    whole row, ``block`` the queries a block, and ``runs`` the blocks in
+    order as ``(first block, blocks, keys each is given)``."""
+    if window is not None and window >= seq:
+        window = None
+    block = min(block, seq)
+    if window is not None:
+        block = min(block, max(1, window // WINDOW_BLOCKS))
+    blocks = -(-seq // block)
+    if window is None:
+        ends = sorted({-(-blocks * g // CAUSAL_RUNS)
+                       for g in range(CAUSAL_RUNS + 1)})
+        runs = tuple((lo, hi - lo, hi * block)
+                     for lo, hi in zip(ends, ends[1:]))
+    else:
+        reach = min(-(-(window - 1) // block), blocks - 1) * block
+        runs = ((0, blocks, reach + block),)
+    return window, block, runs
+
+
+def attention_scores(seq: int, window: Optional[int], block: int):
+    """Score entries of one attention layer a head a row: ``(block,
+    computed, kept)``, the queries a block, the entries
+    :func:`masked_attention` computes and those its mask keeps."""
+    window, block, runs = attention_extents(seq, window, block)
+    width = seq if window is None else window
+    return (block, sum(n * block * keys for _, n, keys in runs),
+            width * (width + 1) // 2 + (seq - width) * width)
+
+
 def masked_attention(q, k, v, window: Optional[int], block: int):
     """softmax(mask(q k^T / sqrt(hd))) v in float32, grouped queries, a
     block of queries at a time. ``q`` [.., B, KV, G, S, hd] (query head
     g of group kv reads key-value head kv), ``k`` [.., B, KV, S, hd], ``v``
     [B, KV, S, dv] -> [.., B, KV, G, S, dv]. Mask: causal, and with a
-    ``window`` also j > t - window; a windowed block is given only the
-    keys it can reach."""
+    ``window`` also j > t - window. A block is given only keys its mask
+    can reach (:func:`attention_extents`): under a window the
+    ``reach + block`` before its last query, else the keys up to the end
+    of its run of blocks, a static slice."""
     q, k, v = (t.astype(jnp.float32) for t in (q, k, v))
     seq, head_dim = q.shape[-2:]
-    block = min(block, seq)
+    window, block, runs = attention_extents(seq, window, block)
     blocks = -(-seq // block)
     pad = blocks * block - seq
     # keys padded behind lie after every real query: the causal mask drops
     # them; keys padded in front (a window's reach before position 0) are
     # dropped by position
-    reach = 0 if window is None else -(-(window - 1) // block) * block
+    reach = 0 if window is None else runs[0][2] - block
 
     def padded(t, front):
         widths = [(0, 0)] * t.ndim
@@ -242,26 +296,31 @@ def masked_attention(q, k, v, window: Optional[int], block: int):
     q_blocks = jnp.moveaxis(
         q.reshape(q.shape[:-2] + (blocks, block, head_dim)), -3, 0)
 
-    @jax.checkpoint
-    def one_block(n, q_n):
-        t = n * block + jnp.arange(block)[:, None]
+    def run(first, count, keys):
         if window is None:
-            k_n, v_n = k, v
-            j = jnp.arange(blocks * block)[None, :]
-            mask = j <= t
-        else:
-            k_n = jax.lax.dynamic_slice_in_dim(k, n * block, reach + block,
-                                               axis=k.ndim - 2)
-            v_n = jax.lax.dynamic_slice_in_dim(v, n * block, reach + block,
-                                               axis=v.ndim - 2)
-            j = n * block - reach + jnp.arange(reach + block)[None, :]
-            mask = (j <= t) & (j > t - window) & (j >= 0)
-        scores = jnp.einsum("...kgtd,...kjd->...kgtj", q_n, k_n)
-        probs = jax.nn.softmax(jnp.where(mask, scores, -jnp.inf), axis=-1)
-        return jnp.einsum("...bkgtj,bkjd->...bkgtd", probs, v_n)
+            k_run, v_run = (jax.lax.slice_in_dim(t, 0, keys, axis=t.ndim - 2)
+                            for t in (k, v))
 
-    out = jax.lax.map(lambda xs: one_block(*xs),
-                      (jnp.arange(blocks), q_blocks))
+        @jax.checkpoint
+        def one_block(n, q_n):
+            t = n * block + jnp.arange(block)[:, None]
+            if window is None:
+                k_n, v_n = k_run, v_run
+                mask = jnp.arange(keys)[None, :] <= t
+            else:
+                k_n, v_n = (jax.lax.dynamic_slice_in_dim(
+                    t_, n * block, keys, axis=t_.ndim - 2) for t_ in (k, v))
+                j = n * block - reach + jnp.arange(keys)[None, :]
+                mask = (j <= t) & (j > t - window) & (j >= 0)
+            scores = jnp.einsum("...kgtd,...kjd->...kgtj", q_n, k_n)
+            probs = jax.nn.softmax(jnp.where(mask, scores, -jnp.inf), axis=-1)
+            return jnp.einsum("...bkgtj,bkjd->...bkgtd", probs, v_n)
+
+        return jax.lax.map(lambda xs: one_block(*xs),
+                           (first + jnp.arange(count),
+                            q_blocks[first:first + count]))
+
+    out = jnp.concatenate([run(*r) for r in runs])
     out = jnp.moveaxis(out, 0, -3)
     return out.reshape(out.shape[:-3] + (blocks * block, -1))[..., :seq, :]
 
@@ -455,6 +514,10 @@ class Block(nn.Module):
                                 use_fast_variance=False, name=name)
 
         def attention(window):
+            block, computed, kept = attention_scores(x.shape[1], window,
+                                                     self.attn_block)
+            _trace.count("model.attn_scores", computed, kind=self.kind,
+                         index=self.index, block=block, kept=kept)
             return DiffAttention(
                 index=self.index, heads=self.heads, kv_heads=self.kv_heads,
                 head_dim=self.head_dim, window=window, block=self.attn_block,
@@ -506,7 +569,7 @@ class SambaY(nn.Module):
     ssm_conv: int = 4
     ssm_rank: int = 160        # ceil(hidden / 16)
     scan_chunk: int = 128
-    attn_block: int = 512
+    attn_block: int = 256
     dtype: Any = None          # compute dtype; configs/bf16.py narrows it
 
     @nn.compact

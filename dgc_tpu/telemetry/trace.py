@@ -29,8 +29,8 @@ on two instruments, and with it off neither leaves anything behind:
   ``step.*``, ``exchange.trace``, ``checkpoint.save``, ``eval``) and
   counts (``input.queue_depth``, ``exchange.collective``,
   ``exchange.apply``, ``step.pack``, ``optimizer.wd_mask``,
-  ``model.layers``, ``model.tokens``, ``model.scan_chunks``) sit where
-  the work happens.
+  ``model.layers``, ``model.tokens``, ``model.scan_chunks``,
+  ``model.attn_scores``) sit where the work happens.
   A span records its name, start and end (``perf_counter_ns``), thread,
   the id of the span that caused it and the ids its request carries
   (``step``, ``seq``; inherited by what it causes); a count belongs to

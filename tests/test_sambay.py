@@ -197,6 +197,92 @@ def test_the_scans_backward_is_the_hand_written_one():
         (SEQ // chunk, batch, state, inner)}
 
 
+def _dense_attention(q, k, v, window):
+    """softmax over the whole [S, S] mask: no blocks, no extents."""
+    seq, head_dim = q.shape[-2:]
+    t, j = jnp.arange(seq)[:, None], jnp.arange(seq)[None, :]
+    mask = j <= t if window is None else (j <= t) & (j > t - window)
+    scores = jnp.einsum("...kgtd,...kjd->...kgtj", q, k) / np.sqrt(head_dim)
+    probs = jax.nn.softmax(jnp.where(mask, scores, -jnp.inf), axis=-1)
+    return jnp.einsum("...bkgtj,bkjd->...bkgtd", probs, v)
+
+
+@pytest.mark.parametrize("seq, window, block", [
+    (32, None, 8),              # the cell's ratios: S = 4 blocks ...
+    (32, 8, 8),                 # ... and the window a block
+    (64, None, 8),
+    (64, 16, 8),                # S = 8 blocks, the window two
+    (29, None, 8),              # S no multiple of the block: padded behind
+    (29, 8, 8),
+    (12, None, 16),             # one block, shorter than ``block``
+    (12, 5, 16),
+    (20, 32, 8),                # a window past the row: the causal mask
+    (32, 3, 8),                 # a window inside a block
+    (40, 11, 8),                # a window that is no multiple of the block
+    (23, 7, 4),
+    (20, 18, 8),                # a window that reaches back past key 0
+    (24, None, 8),              # three blocks in two runs
+], ids=lambda value: str(value))
+def test_blocked_attention_is_the_dense_masked_softmax(seq, window, block):
+    """The value and the three gradients against a softmax over the dense
+    [S, S] mask, written here; the keys and values are drawn apart from
+    the queries, as a ``cross`` layer's come from another layer (a causal
+    case is a ``cross``-shaped call)."""
+    _attention_agrees(seq, window, block)
+
+
+@pytest.mark.parametrize("window", [None, 11])
+@pytest.mark.parametrize("runs, part", [(1, 1), (3, 1), (4, 4)])
+def test_the_extents_constants_are_free(monkeypatch, runs, part, window):
+    """One run and a block as wide as its window (every key to every
+    block: the form before the extents), and more runs and smaller window
+    blocks than ship: the same numbers."""
+    monkeypatch.setattr(sambay, "CAUSAL_RUNS", runs)
+    monkeypatch.setattr(sambay, "WINDOW_BLOCKS", part)
+    _attention_agrees(40, window, 8)
+
+
+def _attention_agrees(seq, window, block):
+    keys = jax.random.split(jax.random.PRNGKey(seq * 31 + block), 4)
+    q = jax.random.normal(keys[0], (2, 2, 2, 3, seq, 8))
+    k = 2.0 * jax.random.normal(keys[1], (2, 2, 2, seq, 8))
+    v = jax.random.normal(keys[2], (2, 2, seq, 16))
+    weights = jax.random.normal(keys[3], (2, 2, 2, 3, seq, 16))
+
+    def blocked(*xs):
+        return sambay.masked_attention(*xs, window, block)
+
+    def dense(*xs):
+        return _dense_attention(*xs, window)
+
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(blocked(q, k, v), dense(q, k, v),
+                                   rtol=1e-5, atol=1e-6)
+        got, want = (jax.grad(lambda *xs: jnp.sum(fn(*xs) * weights),
+                              argnums=range(3))(q, k, v)
+                     for fn in (blocked, dense))
+    for g, w in zip(got, want):
+        assert np.all(np.isfinite(g))
+        np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("seq, window, block, given", [
+    # S = 4 blocks: two runs of two, keys to each run's end
+    (2048, None, 512, (512, ((0, 2, 1024), (2, 2, 2048)))),
+    # a window's block is half of it; it reaches two blocks back
+    (2048, 512, 512, (256, ((0, 8, 768),))),
+    (2048, 512, 128, (128, ((0, 16, 640),))),
+    (100, None, 512, (100, ((0, 1, 100),))),        # one block: no runs
+    (24, None, 8, (8, ((0, 2, 16), (2, 1, 24)))),
+    (100, 2000, 8, (8, ((0, 7, 56), (7, 6, 104)))),   # past the row: causal
+    (100, 90, 40, (40, ((0, 3, 120),))),    # no further back than key 0
+])
+def test_the_keys_a_block_is_given(seq, window, block, given):
+    """``attention_extents``: the block and the runs ``(first block,
+    blocks, keys each is given)``, from the shapes alone."""
+    assert sambay.attention_extents(seq, window, block)[1:] == given
+
+
 @pytest.mark.parametrize("kind, reaches", [("swa", False), ("full", True)])
 def test_a_token_eight_back_reaches_full_attention_only(kind, reaches):
     """Window 8 counts the query itself: position t reads keys t-7 .. t. A
@@ -396,8 +482,9 @@ def test_the_token_split():
 
 def test_the_counts_and_scopes_a_traced_build_leaves(rec):
     """Once a trace: a ``model.layers`` count a layer with its kind and
-    published index, the micro-batch's tokens, the scan's chunks; and the
-    five device parts in the lowered step's op names."""
+    published index, the micro-batch's tokens, the scan's chunks, the
+    score entries an attention layer computes and keeps; and the five
+    device parts in the lowered step's op names."""
     model = _model()
     tokens, _ = _batch()
     shapes = _shapes(model, SEQ)
@@ -415,6 +502,17 @@ def test_the_counts_and_scopes_a_traced_build_leaves(rec):
     assert [(r["value"], r["args"]["chunk"], r["args"]["unroll"])
             for r in by_name["model.scan_chunks"]] == [
                 (4, 8, min(8, sambay.SCAN_UNROLL))]
+    # S = 4 blocks of 8, window 8: blocks of 4 given 12 keys, and two
+    # runs of two blocks given 16 and 32 keys
+    scores = [(r["args"]["kind"], r["args"]["index"], r["args"]["block"],
+               r["value"], r["args"]["kept"])
+              for r in by_name["model.attn_scores"]]
+    assert scores == [("swa", 15, 4, 8 * 4 * 12, 36 + 24 * 8),
+                      ("full", 17, 8, 2 * 8 * 16 + 2 * 8 * 32, 32 * 33 // 2)]
+    # the extents engage: under every key to every block (SEQ a row) and
+    # a block's own and a window's worth to a block of 8 (16 a row)
+    for (*_, value, kept), every_key in zip(scores, (SEQ * 16, SEQ * SEQ)):
+        assert kept <= value < every_key
     text = lowered.as_text(debug_info=True)
     for part in ("ssm", "attn", "gmu", "mlp", "head"):
         assert f"dgcph.fwd_bwd.{part}" in text, part
